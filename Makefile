@@ -4,7 +4,7 @@
 PY ?= python
 IMG ?= ghcr.io/tpujob/operator:v0.1.0
 
-.PHONY: all verify test test-fast analyze race chaos recovery sched migrate obs metrics-lint loadtest startup artifacts serve fleetweek bench native manifests gen-deploy helm run install deploy docker-build clean notices notices-check
+.PHONY: all verify test test-fast analyze race chaos recovery sched migrate obs metrics-lint loadtest startup artifacts serve fleetweek chip-smoke bench native manifests gen-deploy helm run install deploy docker-build clean notices notices-check
 
 all: native test
 
@@ -58,7 +58,6 @@ race:
 	env TPUJOB_RACE_DETECT=1 $(PY) -m pytest -x -q -m "not slow" \
 	  tests/test_aggregate.py \
 	  tests/test_analysis.py tests/test_artifacts.py \
-	  tests/test_bench_supervision.py \
 	  tests/test_chaos.py tests/test_compile_cache.py \
 	  tests/test_control_plane.py tests/test_coordination.py \
 	  tests/test_data.py tests/test_elastic_e2e.py tests/test_fake_client.py \
@@ -205,6 +204,10 @@ serve:
 	env TPUJOB_LEAK_TRACK=1 $(PY) scripts/chaos_stress.py \
 	  --scenario serving_brownout --seeds 1 --quick
 	$(PY) scripts/perf_serving.py --quick
+
+# on a TPU host only (through the chip tool): both exit non-zero off the chip
+chip-smoke:
+	$(PY) chip_smoke.py
 
 bench:
 	$(PY) bench.py

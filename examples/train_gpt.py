@@ -9,6 +9,8 @@ import functools
 import logging
 import os
 
+import jax
+
 from paddle_operator_tpu.models import gpt
 from paddle_operator_tpu.ops import optim
 from paddle_operator_tpu.parallel import gpt_rules, moe_rules, ring_attention
@@ -23,8 +25,12 @@ SP = int(os.environ.get("TPUJOB_SP", "1"))
 MOE = int(os.environ.get("TPUJOB_MOE_EXPERTS", "0"))
 
 
-def main():
-    cfg = dict(gpt.BASE_CONFIG, max_seq=SEQ)
+def build_job(total_steps: int = STEPS, batch: int = BATCH, seq: int = SEQ,
+              config: dict = gpt.BASE_CONFIG) -> TrainJob:
+    """The TrainJob this example trains; ``chip_smoke.py`` runs the same
+    one (its CPU rehearsal passes ``gpt.TINY_CONFIG``). ``total_steps``
+    also sets the cosine schedule's horizon."""
+    cfg = dict(config, max_seq=seq)
     for knob, key in (("TPUJOB_LAYERS", "layers"), ("TPUJOB_HIDDEN", "hidden"),
                       ("TPUJOB_HEADS", "heads"), ("TPUJOB_MLP_DIM", "mlp_dim"),
                       ("TPUJOB_VOCAB", "vocab_size")):
@@ -43,25 +49,32 @@ def main():
             attn = functools.partial(
                 ring_attention, mesh=mesh, axis="sp", causal=True)
         return gpt.loss_fn(p, b, remat=True, attn_impl=attn,
-                           ce_chunk=ce_chunk)
+                           ce_chunk=ce_chunk, mesh=mesh)
 
-    job = TrainJob(
+    return TrainJob(
         init_params=lambda rng: gpt.init(rng, cfg),
         loss_fn=loss_fn,
         optimizer=optim.adamw(
-            optim.cosine_schedule(3e-4, STEPS, STEPS // 10), weight_decay=0.1,
+            optim.cosine_schedule(3e-4, total_steps, total_steps // 10),
+            weight_decay=0.1,
         ),
         make_batch=lambda rng, step: gpt.synthetic_batch(
-            rng, BATCH, SEQ, cfg["vocab_size"]),
+            rng, batch, seq, cfg["vocab_size"]),
         rules=gpt_rules() + moe_rules(),
         mesh_axes={"dp": -1, "sp": SP} if SP > 1 else None,
         seq_axis="sp" if SP > 1 else None,
         grad_clip=1.0,
-        total_steps=STEPS,
+        total_steps=total_steps,
         steps_per_call=int(os.environ.get("TPUJOB_STEPS_PER_CALL", "1")),
         checkpoint_dir=os.environ.get("TPUJOB_CHECKPOINT_DIR", ""),
     )
-    out = run_training(job)
+
+
+def main():
+    out = run_training(build_job())
+    leaf = jax.tree_util.tree_leaves(out["state"]["params"])[0]
+    print("mesh:", out["mesh_history"][-1], "first parameter on:",
+          leaf.sharding)
     print("final loss:", out.get("loss"))
 
 

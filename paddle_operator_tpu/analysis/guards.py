@@ -2,7 +2,7 @@
 
 Before this module, the ``racedetect.guard_fields`` wiring lived as an
 inline list in each harness (OperatorHarness, compile_cache's import
-hook, the bench canary pool): the *dynamic* happens-before checker knew
+hook): the *dynamic* happens-before checker knew
 which fields a lock owns, but the *static* analyzer had to re-infer the
 same contract from guarded writes — and a field the tests never wrote
 under its lock was invisible to both. :data:`SPECS` is now the single
@@ -50,7 +50,6 @@ class GuardSpec:
 #: sorted by module path; the OPS9xx spec audit fails on entries naming
 #: classes/locks/fields the tree no longer has.
 SPECS: Tuple[GuardSpec, ...] = (
-    GuardSpec("bench", "_CanaryPool", "_alock", ("_attempts",)),
     GuardSpec("paddle_operator_tpu.artifacts.server", "_ServerState",
               "_lock", ("leases", "counts")),
     GuardSpec("paddle_operator_tpu.artifacts.store", "ArtifactStore",
@@ -134,7 +133,7 @@ SPECS: Tuple[GuardSpec, ...] = (
 def specs_for_class(cls: type) -> List[GuardSpec]:
     """Every spec matching ``cls`` or a base of it (guard_fields swaps
     the class for a generated subclass, so lookups walk the MRO). A
-    ``__main__`` module (bench.py run as a script) matches by class
+    ``__main__`` module (a script run directly) matches by class
     name alone."""
     out: List[GuardSpec] = []
     for klass in cls.__mro__:

@@ -505,7 +505,7 @@ class ChaosHarness:
         if running:
             if self._degrade_ticks > 0:
                 self._degrade_ticks -= 1
-                eps = 0.4     # the r03–r05 CPU-fallback floor
+                eps = 0.4     # a CPU-fallback floor
                 mfu = 2e-5    # CPU FLOP/s against the TPU peak
             else:
                 eps = 1000.0

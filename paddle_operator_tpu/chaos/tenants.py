@@ -81,7 +81,7 @@ FIRST_PROGRESS_BOUND = 120
 HIGH_PRIO = PRIORITY_CLASSES["tpu-high"]
 
 #: the throughput model the ledger's degradation detector sees: healthy
-#: examples/s vs the r03-r05 CPU-fallback floor
+#: examples/s vs a CPU-fallback floor
 HEALTHY_EPS = 1000.0
 DEGRADED_EPS = 0.4
 #: healthy samples the detector needs before a collapse can fire

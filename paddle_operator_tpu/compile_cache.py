@@ -18,7 +18,7 @@ resume cheap on three rungs, each falling back transparently to the next:
    process-wide with a project-managed directory, so even paths that
    cannot AOT (shape-polymorphic callers, multi-host wrappers) skip the
    XLA optimization pipeline on recompile. Hit/miss counts are surfaced
-   via ``jax._src.monitoring`` where available.
+   via ``jax._src.monitoring``.
 3. **Plain ``jax.jit``** (`cold` rung): always correct, always available.
 
 Consistency bar (EasyScale, arXiv 2208.14228): a cached or AOT-compiled
@@ -29,8 +29,13 @@ copy of them (rung 1), so this holds by construction and is asserted by
 
 Knobs:
 
-* ``TPUJOB_COMPILE_CACHE_DIR`` — cache directory (default
-  ``~/.cache/tpujob/compile``; ``/tmp/tpujob_compile_cache`` fallback).
+* ``JAX_COMPILATION_CACHE_DIR`` — when set, JAX's own persistent cache
+  stays where it points (this module never re-points it) and the AOT
+  executables and cost sidecars live under the same root.
+* ``TPUJOB_COMPILE_CACHE_DIR`` — cache directory when the JAX variable is
+  unset (the pods' cache volume). Default: ``.compile_cache/`` beside the
+  package, inside the checkout — one fixed path, because the path is part
+  of JAX's cache key and a directory that moves never hits.
 * ``TPUJOB_COMPILE_CACHE=0`` — disable both persistent and AOT layers.
 * ``TPUJOB_COMPILE_CACHE_AOT=0`` — disable only executable serialization.
 
@@ -75,8 +80,7 @@ class _CacheState:
         self.stats: Dict[str, Any] = {
             "persistent_enabled": False,
             "persistent_dir": "",
-            # jax persistent-cache events (monitoring hook; -1 = not
-            # observable)
+            # jax persistent-cache events (monitoring hook)
             "persistent_hits": 0,
             "persistent_misses": 0,
             # this module's own ladder accounting
@@ -86,7 +90,12 @@ class _CacheState:
             "aot_misses": 0,     # compiled AOT fresh (and tried to save)
             "aot_saves": 0,      # executables serialized to disk
             "fleet_hits": 0,     # executable served by the artifact store
-            "jit_fallbacks": 0,  # AOT unavailable -> plain jax.jit
+            "jit_fallbacks": 0,  # built as plain jax.jit (any reason)
+            # the two ways a run can LOOK cached when it is not — a
+            # caller that must not be fooled (chip_smoke.py) fails on
+            # either being non-zero
+            "first_call_rejects": 0,   # cached executable refused its args
+            "aot_lower_failures": 0,   # AOT lower/compile raised -> jit
             "compile_seconds": 0.0,  # wall in lower+compile / jit warmup
         }
         self.enabled_dir: Optional[str] = None
@@ -138,18 +147,19 @@ def aot_enabled() -> bool:
         "TPUJOB_COMPILE_CACHE_AOT", "1") != "0"
 
 
+#: the fixed in-checkout default, resolved from the package's location
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".compile_cache")
+
+
 def default_cache_dir() -> str:
-    env = os.environ.get("TPUJOB_COMPILE_CACHE_DIR", "")
-    if env:
-        return env
-    home = os.path.expanduser("~")
-    if home and home != "/" and os.path.isdir(home):
-        return os.path.join(home, ".cache", "tpujob", "compile")
-    # no usable $HOME: uid-scoped fallback — AOT entries are pickles, and
-    # a world-shared predictable path would let another local user plant
-    # a payload under a computable fingerprint name
-    uid = os.getuid() if hasattr(os, "getuid") else 0
-    return "/tmp/tpujob_compile_cache_%d" % uid
+    """Root of every cache layer: where ``JAX_COMPILATION_CACHE_DIR``
+    points if it is set (JAX's cache is already there), else
+    ``TPUJOB_COMPILE_CACHE_DIR``, else the fixed in-checkout default."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.environ.get("TPUJOB_COMPILE_CACHE_DIR")
+            or _CHECKOUT_CACHE_DIR)
 
 
 def _writable_dir(path: str) -> bool:
@@ -174,68 +184,57 @@ def _writable_dir(path: str) -> bool:
 
 
 def _hook_monitoring() -> None:
-    """Count the persistent cache's own hit/miss events. Internal JAX API
-    — version-gated, and its absence only costs observability."""
+    """Count the persistent cache's own hit/miss events."""
     global _monitoring_hooked
     if _monitoring_hooked:
         return
     _monitoring_hooked = True
-    try:
-        from jax._src import monitoring
+    from jax._src import monitoring
 
-        def _listener(name, **kwargs):
-            if name.endswith("/compilation_cache/cache_hits"):
-                with _state._lock:
-                    _state.stats["persistent_hits"] += 1
-            elif name.endswith("/compilation_cache/cache_misses"):
-                with _state._lock:
-                    _state.stats["persistent_misses"] += 1
+    def _listener(name, **kwargs):
+        if name.endswith("/compilation_cache/cache_hits"):
+            with _state._lock:
+                _state.stats["persistent_hits"] += 1
+        elif name.endswith("/compilation_cache/cache_misses"):
+            with _state._lock:
+                _state.stats["persistent_misses"] += 1
 
-        monitoring.register_event_listener(_listener)
-    except Exception:  # pragma: no cover - jax internals moved
-        with _state._lock:
-            _state.stats["persistent_hits"] = -1
-            _state.stats["persistent_misses"] = -1
+    monitoring.register_event_listener(_listener)
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None) -> bool:
-    """Point JAX's persistent compilation cache at the project directory.
+def enable_persistent_cache() -> bool:
+    """Turn on JAX's persistent compilation cache under
+    :func:`default_cache_dir`.
 
     Idempotent; safe to call before or after backend init. Returns True
     iff the cache is active. Read-only/unwritable directories disable the
     layer with one warning (the AOT layer checks writability separately).
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already points there
+    and ``jax_compilation_cache_dir`` is left alone.
     """
     if not cache_enabled():
         return False
-    path = cache_dir or default_cache_dir()
+    path = default_cache_dir()
     with _state._lock:
         if _state.enabled_dir == path:
             return bool(_state.stats["persistent_enabled"])
     ok = _writable_dir(path)
     if ok:
-        try:
-            import jax
+        import jax
 
+        # cache everything: the fleet's restart tax is dominated by
+        # many medium programs, not a few giant ones
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             jax.config.update("jax_compilation_cache_dir", path)
-            # cache everything: the fleet's restart tax is dominated by
-            # many medium programs, not a few giant ones
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
             # the cache binds its directory lazily at FIRST compile and the
             # decision is sticky: a process that already jitted something
             # (model init, a probe matmul) before this call would silently
             # keep running uncached — force a re-bind against the new dir
-            try:
-                from jax._src import compilation_cache as _cc
+            from jax._src import compilation_cache as _cc
 
-                _cc.reset_cache()
-            except Exception:  # pragma: no cover - internal API drift
-                pass
-        except Exception as e:  # config knob missing on this jax
-            log.warning("persistent compilation cache unavailable: %s", e)
-            ok = False
+            _cc.reset_cache()
     else:
         log.warning("compile cache dir %s not writable; persistent "
                     "cache disabled", path)
@@ -703,12 +702,20 @@ def _try_load_aot(path: str) -> Optional[Callable]:
     if not path or not os.path.exists(path):
         return None
     try:
+        import jax
         from jax.experimental.serialize_executable import (
             deserialize_and_load)
 
         with open(path, "rb") as fh:
-            payload, in_tree, out_tree = pickle.load(fh)
-        return deserialize_and_load(payload, in_tree, out_tree)
+            payload, in_tree, out_tree, device_ids = pickle.load(fh)
+        # load onto the devices it was compiled for, in their order: the
+        # default is every device of the backend, and an executable
+        # built for one device but loaded onto eight refuses its first
+        # call ("expected 8 shards, got 1")
+        by_id = {d.id: d for d in jax.devices()}
+        return deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in device_ids])
     except Exception as e:
         # stale jax version, torn write, foreign topology: treat as miss
         # and let the fresh compile overwrite it
@@ -728,12 +735,16 @@ def _try_save_aot(path: str, compiled) -> bool:
         from jax.experimental.serialize_executable import serialize
 
         payload, in_tree, out_tree = serialize(compiled)
+        # the same private handle serialize() itself reads
+        device_ids = [d.id for d in
+                      compiled._executable._unloaded_executable.device_list]
         with open(tmp, "wb") as fh:
-            pickle.dump((payload, in_tree, out_tree), fh)
+            pickle.dump((payload, in_tree, out_tree, device_ids), fh)
         os.replace(tmp, path)  # atomic publish: readers never see a torn file
         return True
     except Exception as e:
-        log.info("AOT executable not serializable on this backend: %s", e)
+        log.info("AOT executable not saved (not serializable on this "
+                 "backend, or not writable): %s", e)
         try:
             os.remove(tmp)  # a torn tmp must not accrete next to the cache
         except OSError:
@@ -773,6 +784,16 @@ class CachedStep:
         self.fingerprint = fingerprint
         self.compile_seconds = compile_seconds
 
+    def as_text(self, *args) -> str:
+        """Optimized HLO of the executable behind this step — what a
+        caller greps to see which kernels the step really contains.
+        ``args`` are only read when the step is a plain jit function,
+        which must be lowered and compiled for them (a persistent-cache
+        hit once the step has run)."""
+        if hasattr(self._fn, "as_text"):
+            return self._fn.as_text()
+        return self._fn.lower(*args).compile().as_text()
+
     def __call__(self, *args):
         if self._called_ok or self._fallback is None:
             return self._fn(*args)
@@ -800,6 +821,7 @@ class CachedStep:
             self.source = "jit"
             with _state._lock:
                 _state.stats["jit_fallbacks"] += 1
+                _state.stats["first_call_rejects"] += 1
                 _memo_put_locked(self.fingerprint, self._fn)
             out = self._fn(*args)
         self._called_ok = True
@@ -920,8 +942,10 @@ def cached_jit(fn: Callable, example_args: Tuple,
             except Exception as e:
                 # shape-polymorphic / backend quirks: stay on plain jit —
                 # the persistent cache still applies to its first call
-                log.info("AOT lowering unavailable for %s, plain jit: %s",
-                         label or "step", e)
+                log.warning("AOT lowering failed for %s, plain jit: %s",
+                            label or "step", e)
+                with _state._lock:
+                    _state.stats["aot_lower_failures"] += 1
         dt = time.perf_counter() - t0
         out_fn = compiled if compiled is not None else jitted
         with _state._lock:
@@ -970,7 +994,8 @@ def reset_stats_for_tests() -> None:
             persistent_enabled=False, persistent_dir="",
             persistent_hits=0, persistent_misses=0, memo_hits=0,
             memo_evictions=0, aot_hits=0, aot_misses=0, aot_saves=0,
-            fleet_hits=0, jit_fallbacks=0, compile_seconds=0.0)
+            fleet_hits=0, jit_fallbacks=0, first_call_rejects=0,
+            aot_lower_failures=0, compile_seconds=0.0)
 
 
 def startup_block() -> Dict[str, Any]:
@@ -984,7 +1009,7 @@ def startup_block() -> Dict[str, Any]:
         cache = "fleet"
     elif s["aot_hits"]:
         cache = "aot"
-    elif s["persistent_hits"] > 0:
+    elif s["persistent_hits"]:
         cache = "warm"
     else:
         cache = "cold"
@@ -998,6 +1023,8 @@ def startup_block() -> Dict[str, Any]:
         "fleet_hits": s["fleet_hits"],
         "memo_hits": s["memo_hits"],
         "jit_fallbacks": s["jit_fallbacks"],
+        "first_call_rejects": s["first_call_rejects"],
+        "aot_lower_failures": s["aot_lower_failures"],
         "compile_seconds": round(s["compile_seconds"], 2),
         "artifacts": artifacts.stats_block(),
     }
@@ -1014,14 +1041,14 @@ def metrics_text() -> str:
         "in-process memo)",
         "# TYPE tpujob_compile_cache_hits_total counter",
         'tpujob_compile_cache_hits_total{layer="persistent"} %d'
-        % max(0, s["persistent_hits"]),
+        % s["persistent_hits"],
         'tpujob_compile_cache_hits_total{layer="aot"} %d' % s["aot_hits"],
         'tpujob_compile_cache_hits_total{layer="memo"} %d' % s["memo_hits"],
         "# HELP tpujob_compile_cache_misses_total compile cache misses "
         "by layer",
         "# TYPE tpujob_compile_cache_misses_total counter",
         'tpujob_compile_cache_misses_total{layer="persistent"} %d'
-        % max(0, s["persistent_misses"]),
+        % s["persistent_misses"],
         'tpujob_compile_cache_misses_total{layer="aot"} %d'
         % s["aot_misses"],
         "# HELP tpujob_compile_seconds total wall seconds spent "

@@ -223,6 +223,16 @@ def main(argv: Optional[List[str]] = None) -> int:
         flush=True,
     )
     initialize_distributed(cfg)
+    import jax
+
+    # which backend this job really got, before it spends an hour on it:
+    # with no platform pinned JAX takes the CPU when it finds no chip
+    dev = jax.devices()[0]
+    print(
+        "[tpujob.launch] platform=%s device_kind=%s devices=%d"
+        % (dev.platform, dev.device_kind, len(jax.devices())),
+        flush=True,
+    )
     script, sys.argv = argv[0], argv
     runpy.run_path(script, run_name="__main__")
     return 0

@@ -14,6 +14,7 @@ Pre-LN blocks, bf16 compute, optional switch-MoE FFNs (expert axis over
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional
 
 import jax
@@ -115,8 +116,14 @@ def encode(params, input_ids, dtype=jnp.bfloat16, remat: bool = False,
 
 def loss_fn(params, batch, train=True, dtype=jnp.bfloat16, remat: bool = False,
             attn_impl="auto", moe_aux_weight: float = 0.01,
-            ce_chunk: int = 0):
+            ce_chunk: int = 0, mesh=None):
     """Next-token LM loss. batch = {"input_ids" [B,S], optional "loss_mask"}.
+
+    ``mesh`` is the mesh the step is jitted over (``run_training`` hands it
+    to any loss function that declares the argument). On the TPU backend
+    ``attn_impl="auto"`` then runs the flash kernel per device shard
+    (:func:`parallel.sharded_flash_attention`): called bare under a
+    mesh-wide jit the kernel does not lower at all.
 
     Labels are input_ids shifted left; the final position is dropped. A
     ``loss_mask`` (e.g. padding) applies to the *label* position.
@@ -128,6 +135,12 @@ def loss_fn(params, batch, train=True, dtype=jnp.bfloat16, remat: bool = False,
     materialized. Same loss/accuracy as the dense path up to fp32
     summation order.
     """
+    if mesh is not None and attn_impl == "auto" \
+            and jax.default_backend() == "tpu":
+        from ..parallel import sharded_flash_attention
+
+        attn_impl = functools.partial(
+            sharded_flash_attention, mesh=mesh, causal=True)
     ids = batch["input_ids"]
     labels = ids[:, 1:]
     mask = batch.get("loss_mask")

@@ -13,12 +13,12 @@ independent inputs, combined into per-step MFU and a roofline class:
   (:class:`StepCost` built by the caller) when XLA's cost model is
   unavailable — the source is always stamped, never guessed.
 * **chip capability** — peak bf16 FLOP/s and HBM bandwidth per TPU
-  generation (:data:`CHIP_PEAKS`, resolved from ``device_kind`` or the
-  ``TPU_ACCELERATOR_TYPE`` env), with a CPU/unknown-kind fallback
-  calibrated by a measured matmul ceiling (the bench's readback-synced
-  calibration). The r05 bug — an MFU divided by a ceiling measured on a
-  DIFFERENT backend — is structurally impossible: every
-  :class:`ChipSpec` carries the backend it describes.
+  generation (:data:`CHIP_PEAKS`, resolved from ``device_kind``). A TPU
+  whose kind is not in the table is an error, never a default; only a
+  CPU device gets the stamped fallback ceiling (or the caller's measured
+  matmul ceiling). Every :class:`ChipSpec` carries the platform of the
+  device it was resolved from, so an MFU can never be divided by a
+  ceiling that belongs to another backend.
 * **device memory** — live ``device.memory_stats()`` sampling
   (:func:`device_memory_stats`) where the backend provides it; absent
   stats degrade to an empty block, never a crash.
@@ -30,7 +30,8 @@ compute-vs-memory-bound roofline classification against the chip's
 ridge point (``peak_flops / hbm_bandwidth``).
 
 :class:`HardwarePlane` is the runner-side accumulator: fed executed
-steps + dispatch seconds, it renders the self-conserving
+steps + the wall seconds of windows that END IN A DEVICE SYNC (enqueue
+time says nothing about the chip), it renders the self-conserving
 ``result["hardware"]`` block (``total_flops == flops_per_step x
 steps`` by construction) and mirrors it into the process trace
 (``hardware_block`` events), so ``scripts/obs_report.py --hardware``
@@ -39,8 +40,8 @@ conservation offline. :class:`MfuBaseline` is the detector primitive
 the ledger aggregates worker samples through: the eps baseline's
 never-normalize rule PLUS an absolute collapse floor — MFU is measured
 against the chip's own peak, so a CPU-fallback resume reads ~1e-5 on
-the very first sample, no primed baseline needed (the exact r03–r05
-class the eps detector could only catch after min_samples).
+the very first sample, no primed baseline needed (the class the eps
+detector could only catch after min_samples).
 
 Everything here is stdlib-only at import time; jax is imported lazily
 inside the functions that need a live backend, so the operator plane
@@ -50,7 +51,6 @@ inside the functions that need a live backend, so the operator plane
 from __future__ import annotations
 
 import logging
-import os
 import threading
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
@@ -61,8 +61,8 @@ from .worker import ThroughputBaseline
 log = logging.getLogger("tpujob.obs.hardware")
 
 #: peak dense bf16 FLOP/s and HBM bandwidth (bytes/s) per chip, keyed by
-#: a lowercase substring of ``device_kind`` / ``TPU_ACCELERATOR_TYPE``.
-#: Ordered most-specific first: resolution takes the first match.
+#: a lowercase substring of ``device_kind``. Ordered most-specific
+#: first: resolution takes the first match.
 CHIP_PEAKS: Tuple[Tuple[str, float, float], ...] = (
     ("v6e", 918e12, 1640e9),     # Trillium
     ("v5p", 459e12, 2765e9),
@@ -82,8 +82,8 @@ DEFAULT_CPU_PEAK_FLOPS = 1e12
 DEFAULT_CPU_BANDWIDTH = 100e9
 
 #: below this absolute MFU a training step is not plausibly running on
-#: the chip the peak describes (even badly-shaped models clear ~1%; the
-#: r03–r05 CPU fallback reads ~1e-5 against a TPU peak)
+#: the chip the peak describes (even badly-shaped models clear ~1%; a
+#: CPU fallback reads ~1e-5 against a TPU peak)
 MFU_COLLAPSE_FLOOR = 1e-3
 
 
@@ -133,7 +133,7 @@ UNAVAILABLE_COST = StepCost(0.0, 0.0, "unavailable")
 
 
 def lookup_chip(kind: str) -> Optional[Tuple[float, float]]:
-    """Registry lookup by device_kind / accelerator-type substring."""
+    """Registry lookup by device_kind substring."""
     k = kind.lower()
     for pat, flops, bw in CHIP_PEAKS:
         if pat in k:
@@ -147,12 +147,12 @@ def resolve_chip(device: Any = None,
     """Resolve the chip capability envelope for ``device`` (default: the
     first jax device, when jax is importable; else a pure-CPU spec).
 
-    Resolution ladder: device_kind against :data:`CHIP_PEAKS`, then the
-    ``TPU_ACCELERATOR_TYPE`` env (set by the TPU runtime before jax
-    knows anything), then — for CPU backends and UNKNOWN device kinds —
-    the caller's calibrated matmul ceiling, then the conservative
-    default. Never raises: hardware telemetry must not take a training
-    run down."""
+    ``backend`` is always the device's own platform. A known
+    ``device_kind`` resolves against :data:`CHIP_PEAKS`; an unknown kind
+    on the ``tpu`` platform raises — inventing a ceiling for a real chip
+    would print an MFU that means nothing. Other platforms (the CPU the
+    tests run on) take the caller's calibrated matmul ceiling, then the
+    stamped conservative default."""
     kind, backend = "cpu", "cpu"
     if device is None:
         try:
@@ -165,15 +165,13 @@ def resolve_chip(device: Any = None,
         kind = str(getattr(device, "device_kind", "") or "cpu")
         backend = str(getattr(device, "platform", "") or "cpu")
     hit = lookup_chip(kind)
-    if hit is None:
-        env_kind = os.environ.get("TPU_ACCELERATOR_TYPE", "")
-        if env_kind:
-            hit = lookup_chip(env_kind)
-            if hit is not None:
-                kind = env_kind
-                backend = "tpu"
     if hit is not None:
         return ChipSpec(kind, backend, hit[0], hit[1], "registry")
+    if backend == "tpu":
+        raise ValueError(
+            "TPU device_kind %r is not in obs.hardware.CHIP_PEAKS; add "
+            "its published peaks rather than measuring against a guess"
+            % kind)
     if calibrated_flops is not None and calibrated_flops > 0:
         return ChipSpec(
             kind, backend, float(calibrated_flops),
@@ -184,10 +182,8 @@ def resolve_chip(device: Any = None,
 
 
 def _normalize_cost(raw: Any) -> Optional[Dict[str, float]]:
-    """cost_analysis() returns a dict on current jax, a list of dicts on
-    older versions; normalize to one flat dict or None."""
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else None
+    """``cost_analysis()``'s dict, numeric entries only; None if the
+    backend has no cost model."""
     if not isinstance(raw, dict):
         return None
     return {str(k): float(v) for k, v in raw.items()
@@ -348,21 +344,28 @@ class HardwarePlane:
                  device: Any = None):
         self.chip = chip
         self.cost = cost if cost is not None else UNAVAILABLE_COST
+        #: devices one step spans; the cost is the whole (unpartitioned)
+        #: program's, so the ceiling it divides by is peak x devices
+        self.devices = 1
         self._device = device
         self._lock = threading.Lock()
         self._steps = 0
         self._step_seconds = 0.0
         self._hbm: Dict[str, float] = {}
 
-    def set_cost(self, cost: Optional[StepCost]) -> None:
+    def set_cost(self, cost: Optional[StepCost], devices: int = 1) -> None:
         """Install the step cost once the step is built/compiled (the
-        chip is known at plane construction, the cost only per cycle)."""
+        chip is known at plane construction, the cost and the mesh it
+        runs over only per cycle)."""
+        self.devices = max(1, int(devices))
         if cost is not None:
             self.cost = cost
 
     def record(self, steps: int, seconds: float) -> None:
-        """Bank ``steps`` optimizer steps that took ``seconds`` of
-        step-dispatch time."""
+        """Bank ``steps`` optimizer steps that took ``seconds`` of wall
+        time. The caller's window must end in a device sync
+        (``block_until_ready``): the time a dispatch takes to ENQUEUE
+        says nothing about how long the chip ran."""
         if steps <= 0 or seconds < 0:
             return
         with self._lock:
@@ -385,7 +388,8 @@ class HardwarePlane:
         if self.cost.source == "unavailable" or self.cost.flops <= 0:
             return None
         mfu, _clamped = clamped_mfu(
-            steps_per_second * self.cost.flops, self.chip.peak_flops)
+            steps_per_second * self.cost.flops,
+            self.chip.peak_flops * self.devices)
         return mfu
 
     def block(self) -> Dict[str, Any]:
@@ -400,7 +404,7 @@ class HardwarePlane:
         if self.cost.source != "unavailable" and step_seconds > 0 \
                 and self.cost.flops > 0:
             mfu, clamped = clamped_mfu(total_flops / step_seconds,
-                                       self.chip.peak_flops)
+                                       self.chip.peak_flops * self.devices)
         intensity = self.cost.arithmetic_intensity
         out: Dict[str, Any] = {
             "device_kind": self.chip.device_kind,
@@ -408,6 +412,7 @@ class HardwarePlane:
             "peak_flops": self.chip.peak_flops,
             "hbm_bandwidth": self.chip.hbm_bandwidth,
             "peak_source": self.chip.source,
+            "devices": self.devices,
             "cost_source": self.cost.source,
             "flops_per_step": self.cost.flops,
             "bytes_per_step": self.cost.bytes_accessed,
@@ -463,13 +468,14 @@ def conservation_violations(block: Dict[str, Any],
         mfu = float(mfu)
         if not (0.0 <= mfu <= 1.0):
             errs.append("%s: mfu %.6g outside [0, 1]" % (label, mfu))
-        peak = float(block.get("peak_flops") or 0.0)
+        peak = float(block.get("peak_flops") or 0.0) \
+            * float(block.get("devices") or 1)
         secs = float(block.get("step_seconds") or 0.0)
         if peak > 0 and secs > 0 and not block.get("mfu_clamped"):
             derived = min(1.0, total / secs / peak)
             if abs(derived - mfu) > max(1e-4, 0.01 * derived):
                 errs.append(
                     "%s: mfu %.6g not derivable from its own totals "
-                    "(total_flops/step_seconds/peak = %.6g)"
+                    "(total_flops/step_seconds/(peak x devices) = %.6g)"
                     % (label, mfu, derived))
     return errs

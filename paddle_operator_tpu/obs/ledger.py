@@ -36,7 +36,7 @@ from trace alone and re-checks conservation offline.
 The **backend-degradation detector** (:meth:`GoodputLedger.
 observe_throughput`) compares observed examples/s against the job's own
 recent healthy baseline: a resumed job silently landing on a slow
-backend (the r03–r05 CPU-fallback class) collapses orders of magnitude
+backend (the CPU-fallback class) collapses orders of magnitude
 below its own history and fires within one sample — Warning Event (via
 ``on_alert``), flight/trace entry, ``tpujob_backend_degraded_total``,
 and the job's time flips to the ``backend_degraded`` bucket until the
@@ -356,7 +356,7 @@ class GoodputLedger:
         The SECOND trigger of the backend-degradation detector: MFU is
         measured against the chip's own peak, so a CPU-fallback resume
         collapses below the absolute floor on the very FIRST sample —
-        no primed eps baseline needed (the r03–r05 class). A sample
+        no primed eps baseline needed (the CPU-fallback class). A sample
         > 1.0 is a warning and a clamped gauge, never a crash; degraded
         samples are never folded into the healthy mean or the baseline
         (the eps never-normalize mirror)."""

@@ -526,10 +526,17 @@ def _paged_decode_kernel(seq_lens_ref, tables_ref, q_ref, k_ref, v_ref,
                          o_ref, acc_ref, m_ref, l_ref, *, scale,
                          block_size, pages_per_seq):
     """One (sequence, page) cell: score the query row against this page's
-    tokens, fold into the running online softmax held in scratch."""
+    tokens, fold into the running online softmax held in scratch.
+
+    Score and context are multiply-and-reduce on the VPU, not matmuls: a
+    single query row per head gives the MXU nothing to tile (M = 1), and
+    the batched ``dot_general`` this replaces — heads as a non-leading
+    batch dimension, no free lhs dimension — does not lower for the TPU
+    at all (Mosaic rejects its dimension numbers). Every intermediate
+    keeps the page's own layout: tokens major, heads on sublanes,
+    head_dim on lanes."""
     b = pl.program_id(0)
     t = pl.program_id(1)
-    heads = q_ref.shape[1]
 
     @pl.when(t == 0)
     def _init():
@@ -540,36 +547,20 @@ def _paged_decode_kernel(seq_lens_ref, tables_ref, q_ref, k_ref, v_ref,
     q = q_ref[0].astype(jnp.float32) * scale             # [H, D]
     k = k_ref[0].astype(jnp.float32)                     # [bs, H, D]
     v = v_ref[0].astype(jnp.float32)
-    # s[h, j] = Σ_d q[h, d] · k[j, h, d]  (h is a batch dim)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32,
-    )                                                    # [H, bs]
-    pos = t * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (heads, block_size), 1)
+    s = jnp.sum(k * q[None], axis=-1, keepdims=True)     # [bs, H, 1]
+    pos = t * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     s = jnp.where(pos < seq_lens_ref[b], s, NEG_INF)
-    # scratch m/l are lane-replicated [H, MIN_BLOCK] (every lane equal);
-    # a rowwise max recovers the [H, 1] column exactly
-    m_prev = jnp.max(m_ref[...], axis=-1, keepdims=True)
-    l_prev = jnp.max(l_ref[...], axis=-1, keepdims=True)
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)                               # [H, bs]
+    m_prev = m_ref[...]                                  # [H, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+    p = jnp.exp(s - m_new[None])                         # [bs, H, 1]
     correction = jnp.exp(m_prev - m_new)
-    l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
-    # ctx[h, d] = Σ_j p[h, j] · v[j, h, d]
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32,
-    )                                                    # [H, D]
-    acc_ref[...] = acc_ref[...] * correction + pv
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    l_ref[...] = l_ref[...] * correction + jnp.sum(p, axis=0)
+    acc_ref[...] = acc_ref[...] * correction + jnp.sum(p * v, axis=0)
+    m_ref[...] = m_new
 
     @pl.when(t == pages_per_seq - 1)
     def _write():
-        l = jnp.max(l_ref[...], axis=-1, keepdims=True)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def supports_paged(q_shape, block_size: int) -> bool:
@@ -630,9 +621,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
         ],
         out_specs=pl.BlockSpec((1, h, d), q_index),
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),           # ctx accumulator
-            pltpu.VMEM((h, MIN_BLOCK), jnp.float32),   # running max
-            pltpu.VMEM((h, MIN_BLOCK), jnp.float32),   # running denom
+            pltpu.VMEM((h, d), jnp.float32),   # ctx accumulator
+            pltpu.VMEM((h, 1), jnp.float32),   # running max
+            pltpu.VMEM((h, 1), jnp.float32),   # running denom
         ],
     )
     return pl.pallas_call(

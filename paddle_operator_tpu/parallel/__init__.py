@@ -16,5 +16,6 @@ from .sharding import (  # noqa: F401
 from .train import batch_shardings, build_train_step  # noqa: F401
 from .pipeline import pipeline_apply, stack_stage_params  # noqa: F401
 from .context import (  # noqa: F401
-    ring_attention, ring_flash_attention, ulysses_attention,
+    ring_attention, ring_flash_attention, sharded_flash_attention,
+    ulysses_attention,
 )

@@ -258,6 +258,53 @@ def ring_flash_attention(
     return run(q, k, v)
 
 
+def sharded_flash_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    mesh: Mesh,
+    causal: bool = False,
+    scale: Optional[float] = None,
+    batch_axis: str = "dp",
+    head_axis: str = "tp",
+) -> jnp.ndarray:
+    """The Pallas flash kernel under a mesh that shards batch and/or heads.
+    BHSD layout.
+
+    GSPMD cannot partition a Mosaic kernel — a jit over a mesh fails at
+    lowering with "Mosaic kernels cannot be automatically partitioned" —
+    so the call is wrapped in ``shard_map``: each device runs the kernel
+    on its own ``[B/dp, H/tp, S, D]`` shard, and attention needs no
+    traffic along either axis. An axis the mesh lacks, or that does not
+    divide its dimension, stays out of the spec (replicated). Shapes the
+    kernel does not support take the dense path, which GSPMD partitions
+    by itself.
+    """
+    from ..ops import attention_pallas
+
+    if not attention_pallas.supports(q.shape, q.dtype):
+        return reference_attention(q, k, v, causal=causal, scale=scale)
+
+    def axis_for(name, dim):
+        return name if mesh.shape.get(name, 1) > 1 \
+            and dim % mesh.shape[name] == 0 else None
+
+    spec = P(axis_for(batch_axis, q.shape[0]),
+             axis_for(head_axis, q.shape[1]), None, None)
+    interpret = jax.default_backend() != "tpu"
+
+    @functools.partial(
+        jax.shard_map, mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,  # pallas outputs carry no vma metadata
+    )
+    def run(ql, kl, vl):
+        return attention_pallas.flash_attention(
+            ql, kl, vl, scale=scale, causal=causal, interpret=interpret)
+
+    return run(q, k, v)
+
+
 def ulysses_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,

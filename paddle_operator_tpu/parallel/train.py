@@ -101,7 +101,7 @@ def build_train_step(
       ``P(None, *spec)``) so the window's shardings are known at build time.
     """
     # Build the optimizer state under ONE cached executable: one dispatch
-    # instead of one per leaf (the tunnel-latency killer on TPU pods), with
+    # instead of one per leaf, with
     # output shardings declared when a mesh is given (the state materializes
     # sharded — no replicated ghost copy) and the compile itself served from
     # the cache ladder, so restore-heavy paths (arbiter preempt -> resume)

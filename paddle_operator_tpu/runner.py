@@ -182,10 +182,7 @@ def _materialize_state(state):
             y = jnp.logical_or(x, False)
         else:
             y = x * jnp.ones((), getattr(x, "dtype", None))
-        try:
-            return jax.lax.optimization_barrier(y)
-        except AttributeError:  # older jax: barrier unavailable
-            return y
+        return jax.lax.optimization_barrier(y)
 
     return jax.jit(
         lambda t: jax.tree_util.tree_map(copy_leaf, t))(state)
@@ -381,7 +378,7 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
     profiler = StepProfiler()
     detector = StragglerDetector(k=job.straggler_k)
     # the worker is the authoritative source of its own examples/s, so
-    # the silent-CPU-fallback alarm runs HERE too: a resumed process
+    # the throughput-collapse alarm runs HERE too: a resumed process
     # whose throughput collapses against its own recent baseline warns,
     # traces, and counts — even when nothing operator-side scrapes it
     tput_watch = ThroughputBaseline()
@@ -391,10 +388,7 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
     # hardware-efficiency plane (docs/observability.md "Hardware
     # efficiency"): chip capability resolved once per process, the
     # per-step cost installed per cycle from the compiled step itself
-    try:
-        _hw_dev = jax.devices()[0]
-    except Exception:
-        _hw_dev = None
+    _hw_dev = jax.devices()[0]
     hw = guard_declared(HardwarePlane(resolve_chip(_hw_dev),
                                       device=_hw_dev))
     if job.flops_per_step:
@@ -522,38 +516,37 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
         # back instead of re-tracing the step (the probe must not hand
         # back startup tax the cache removed). Analytic fallback
         # (TrainJob.flops_per_step) or suppression when unavailable.
-        try:
-            fp = str(getattr(step_fn, "fingerprint", "") or "")
-            cost = None
-            if fp:
-                raw = compile_cache.load_step_cost(fp)
-                if raw and float(raw.get("flops") or 0) > 0:
-                    cost = StepCost(
-                        float(raw["flops"]),
-                        max(0.0, float(raw.get("bytes") or 0.0)),
-                        str(raw.get("source") or "cost_analysis"))
-            if cost is None:
-                def _sds(x: Any, lead: Optional[int] = None) -> Any:
-                    shape = tuple(getattr(x, "shape", ()))
-                    if lead is not None:
-                        shape = (lead,) + shape
-                    return jax.ShapeDtypeStruct(
-                        shape, getattr(x, "dtype", jnp.float32))
+        fp = str(getattr(step_fn, "fingerprint", "") or "")
+        cost = None
+        if fp:
+            raw = compile_cache.load_step_cost(fp)
+            if raw and float(raw.get("flops") or 0) > 0:
+                cost = StepCost(
+                    float(raw["flops"]),
+                    max(0.0, float(raw.get("bytes") or 0.0)),
+                    str(raw.get("source") or "cost_analysis"))
+        if cost is None:
+            def _sds(x: Any, lead: Optional[int] = None) -> Any:
+                shape = tuple(getattr(x, "shape", ()))
+                if lead is not None:
+                    shape = (lead,) + shape
+                return jax.ShapeDtypeStruct(
+                    shape, getattr(x, "dtype", jnp.float32))
 
-                abstract_batch = jax.tree_util.tree_map(
-                    functools.partial(_sds, lead=K if K > 1 else None),
-                    sample)
-                abstract_state = jax.tree_util.tree_map(_sds, state)
-                cost = step_cost_of(step_fn, abstract_state,
-                                    abstract_batch, steps_per_call=K)
-                if cost is not None and fp:
-                    compile_cache.save_step_cost(fp, {
-                        "flops": cost.flops,
-                        "bytes": cost.bytes_accessed,
-                        "source": cost.source})
-            hw.set_cost(cost)
-        except Exception:
-            pass  # telemetry must never take the training run down
+            abstract_batch = jax.tree_util.tree_map(
+                functools.partial(_sds, lead=K if K > 1 else None),
+                sample)
+            abstract_state = jax.tree_util.tree_map(_sds, state)
+            cost = step_cost_of(step_fn, abstract_state,
+                                abstract_batch, steps_per_call=K)
+            if cost is not None and fp:
+                compile_cache.save_step_cost(fp, {
+                    "flops": cost.flops,
+                    "bytes": cost.bytes_accessed,
+                    "source": cost.source})
+        # the probed cost is the whole program's, so the ceiling is the
+        # peak of every device the step spans
+        hw.set_cost(cost, devices=mesh.size if mesh is not None else 1)
         single_fn = None  # tail windows shorter than K, built lazily
 
         def make_single_fn():
@@ -699,6 +692,22 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
             batch_sharding=pick_sharding, prefetch=job.prefetch,
             place=not multi, timings=times)
         t_dispatched = None  # end of the previous dispatch (host clock)
+        # hardware-plane seconds: a window opens at a dispatch and closes
+        # at a device sync (bank_synced), so the MFU it yields is the
+        # chip's, not the enqueue's. A fn's FIRST call is warm-up — it
+        # traces and compiles (or loads) before it enqueues, and the
+        # first execution loads the program onto the devices — so it is
+        # synced and left out of both the steps and the seconds.
+        win = {"t0": None, "steps": 0}
+        warmed = set()
+
+        def bank_synced(sync_on):
+            """Close the open window: wait for ``sync_on`` (an output of
+            the newest dispatch) and bank its steps and wall seconds."""
+            if win["steps"]:
+                jax.block_until_ready(sync_on)
+                hw.record(win["steps"], time.perf_counter() - win["t0"])
+            win["t0"], win["steps"] = None, 0
 
         def fetch():
             """Dequeue the next prestaged batch/window, charging the
@@ -716,19 +725,28 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
             time) recorded as the `dispatch_gap` stage and the per-step
             phases (data_wait, dispatch) in the bounded profiler ring.
             ``span`` is the optimizer steps this one call executes (K
-            for a fused window) — the hardware plane banks them against
-            the dispatch seconds for the MFU totals."""
+            for a fused window) — counted into the open hardware-plane
+            window (see ``bank_synced``)."""
             nonlocal t_dispatched
             batch, data_wait = fetched
             if t_dispatched is not None:
                 times.add("dispatch_gap", time.perf_counter() - t_dispatched)
+            warm_up = id(fn) not in warmed
+            if warm_up:
+                bank_synced(state)
+                warmed.add(id(fn))
             t_d0 = time.perf_counter()
             with times.timed("step_dispatch"):
                 out = fn(state, batch)
             t_dispatched = time.perf_counter()
             profiler.record(at_step, data_wait=data_wait,
                             dispatch=t_dispatched - t_d0)
-            hw.record(span, t_dispatched - t_d0)
+            if warm_up:
+                jax.block_until_ready(out[1])
+            else:
+                if win["t0"] is None:
+                    win["t0"] = t_d0
+                win["steps"] += span
             return out
 
         def straggler_check(at_step):
@@ -795,6 +813,7 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
                     incident_first_step(step)
                 if job.log_every and (
                         step % job.log_every < k_here):
+                    bank_synced(metrics)
                     # deferred readback: start the D2H copy for THIS
                     # boundary, log the PREVIOUS one (already on host)
                     log_resolved(deferred.start(step, metrics))
@@ -804,6 +823,7 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
                                  in profiler.stats().items()})
                 if job.checkpoint_dir and (
                         step % job.checkpoint_every < k_here):
+                    bank_synced(metrics)  # the snapshot syncs anyway
                     t_ck0 = time.perf_counter()
                     save(step, state, epoch)
                     ck_s = time.perf_counter() - t_ck0
@@ -812,6 +832,7 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
                     last_saved = step
                 outcome = poll_boundary()
                 if outcome != _POLL_NONE:
+                    bank_synced(metrics)
                     drained = outcome == _POLL_DRAIN
                     log.info(
                         "%s at step %d",
@@ -882,6 +903,7 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
                     return False
                 result["state"] = state
                 result["steps"] = step
+            bank_synced(metrics)
         finally:
             # a step that raises mid-window must still finalize the device
             # trace, or the capture is lost and re-entry hits "already

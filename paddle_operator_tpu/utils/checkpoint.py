@@ -8,7 +8,11 @@ collective job cannot shrink below its compiled mesh, so preemption recovery
 
 Format (v2): one directory per step, `state.npz` (flat path -> array) +
 `manifest.json` (treedef + dtypes + membership epoch + per-leaf CRC32
-checksums + a terminal COMMIT marker). Atomic via tmp-dir rename so a
+checksums + a terminal COMMIT marker). No file outgrows
+:data:`PART_BYTES` (or the process's RLIMIT_FSIZE, if lower): a bigger
+state is the same npz byte stream cut into `state.npz.000`, `.001`, ...
+with the count in the manifest — the v5e machine refused a 1.95 GB
+`state.npz` with EFBIG. Atomic via tmp-dir rename so a
 preempted writer never leaves a half checkpoint on a POSIX filesystem —
 and crash-safe beyond that: on storage where rename is not atomic (NFS,
 FUSE-mounted object stores) a torn write leaves either an unparseable or
@@ -27,9 +31,12 @@ metrics layer (obs.JobMetrics) count them.
 
 from __future__ import annotations
 
+import bisect
+import io
 import json
 import logging
 import os
+import resource
 import shutil
 import tempfile
 import threading
@@ -48,6 +55,8 @@ FORMAT_VERSION = 2
 #: terminal manifest key: written last, so a torn manifest either fails to
 #: parse or visibly lacks the marker — both read as "uncommitted"
 COMMIT_MARKER = "COMMIT"
+#: largest file the npz writer produces; a bigger state is cut into parts
+PART_BYTES = 32 << 20
 
 
 class CorruptCheckpointError(ValueError):
@@ -144,14 +153,126 @@ def _unflatten(structure: Any, flat: Dict[str, Any], prefix: str = "") -> Any:
     return flat[prefix[:-1]]
 
 
+def _part_bytes() -> int:
+    """:data:`PART_BYTES`, or what the process may write to one file if
+    that is less (past RLIMIT_FSIZE a write fails with EFBIG)."""
+    soft, _ = resource.getrlimit(resource.RLIMIT_FSIZE)
+    return PART_BYTES if soft == resource.RLIM_INFINITY \
+        else max(1, min(PART_BYTES, soft))
+
+
+def _part_names(parts: int) -> List[str]:
+    if parts <= 1:
+        return ["state.npz"]
+    return ["state.npz.%03d" % i for i in range(parts)]
+
+
+class _PartWriter(io.RawIOBase):
+    """Write-only byte stream over files of at most ``part_bytes`` each.
+    It cannot seek, so ``zipfile`` writes a streamed archive (member
+    sizes after the data) and never goes back into an earlier part."""
+
+    def __init__(self, dirpath: str, part_bytes: int):
+        super().__init__()
+        self._dir = dirpath
+        self._part_bytes = part_bytes
+        self._fh: Optional[io.BufferedWriter] = None
+        self._room = 0
+        self.parts = 0
+
+    def writable(self) -> bool:
+        return True
+
+    def write(self, data) -> int:
+        view = memoryview(data).cast("B")
+        done = 0
+        while done < len(view):
+            if self._room == 0:
+                self._next_part()
+            chunk = view[done:done + self._room]
+            self._fh.write(chunk)
+            self._room -= len(chunk)
+            done += len(chunk)
+        return done
+
+    def _next_part(self) -> None:
+        if self._fh is not None:
+            self._fh.close()
+        self._fh = open(os.path.join(
+            self._dir, "state.npz.%03d" % self.parts), "wb")
+        self._room = self._part_bytes
+        self.parts += 1
+
+    def close(self) -> None:
+        if self._fh is not None:
+            self._fh.close()
+            self._fh = None
+        super().close()
+
+
+class _PartReader(io.RawIOBase):
+    """The parts read back as the one seekable stream they were cut
+    from — what ``np.load`` needs to find the zip directory."""
+
+    def __init__(self, paths: List[str]):
+        super().__init__()
+        self._paths = paths
+        self._starts = [0]
+        for path in paths:  # a lost part is FileNotFoundError here
+            self._starts.append(self._starts[-1] + os.path.getsize(path))
+        self._pos = 0
+        self._open_part = -1
+        self._fh: Optional[io.BufferedReader] = None
+
+    def readable(self) -> bool:
+        return True
+
+    def seekable(self) -> bool:
+        return True
+
+    def tell(self) -> int:
+        return self._pos
+
+    def seek(self, offset: int, whence: int = os.SEEK_SET) -> int:
+        base = {os.SEEK_SET: 0, os.SEEK_CUR: self._pos,
+                os.SEEK_END: self._starts[-1]}[whence]
+        self._pos = max(0, base + offset)
+        return self._pos
+
+    def readinto(self, buf) -> int:
+        if self._pos >= self._starts[-1]:
+            return 0
+        part = bisect.bisect_right(self._starts, self._pos) - 1
+        if part != self._open_part:
+            self._close_part()
+            self._fh = open(self._paths[part], "rb")
+            self._open_part = part
+        self._fh.seek(self._pos - self._starts[part])
+        want = min(len(buf), self._starts[part + 1] - self._pos)
+        got = self._fh.readinto(memoryview(buf)[:want])
+        self._pos += got
+        return got
+
+    def _close_part(self) -> None:
+        if self._fh is not None:
+            self._fh.close()
+            self._fh = None
+            self._open_part = -1
+
+    def close(self) -> None:
+        self._close_part()
+        super().close()
+
+
 def save_checkpoint(ckpt_dir: str, step: int, state: Any,
-                    meta: Optional[dict] = None, keep: int = 3) -> str:
+                    meta: Optional[dict] = None, keep: int = 3,
+                    part_bytes: Optional[int] = None) -> str:
     """Write state atomically; prune to the newest `keep` checkpoints.
 
     Crash-safe (format v2): the manifest carries per-leaf CRC32 checksums
     and ends with the COMMIT marker, written after every array byte — a
     reader never trusts a step whose manifest is missing, torn, or
-    uncommitted.
+    uncommitted. ``part_bytes`` overrides the file-size bound (tests).
     """
     flat = _flatten(state)
     # owned snapshots: a zero-copy view of a donated device buffer would
@@ -162,12 +283,17 @@ def save_checkpoint(ckpt_dir: str, step: int, state: Any,
     final = os.path.join(ckpt_dir, "step_%012d" % step)
     tmp = tempfile.mkdtemp(dir=ckpt_dir, prefix=".tmp_")
     try:
-        np.savez(os.path.join(tmp, "state.npz"), **arrays)
+        with _PartWriter(tmp, part_bytes or _part_bytes()) as out:
+            np.savez(out, **arrays)
+        if out.parts == 1:  # the usual case keeps the plain name
+            os.rename(os.path.join(tmp, "state.npz.000"),
+                      os.path.join(tmp, "state.npz"))
         manifest = {
             "step": step,
             "structure": _structure(state),
             "meta": meta or {},
             "format_version": FORMAT_VERSION,
+            "state_parts": out.parts,
             "checksums": {k: _leaf_crc(a) for k, a in arrays.items()},
             # terminal key: json preserves insertion order, so a torn
             # manifest write truncates BEFORE the marker
@@ -815,11 +941,15 @@ def restore_checkpoint(ckpt_dir: str, step: Optional[int] = None,
 
         checksums = manifest.get("checksums") or {}
         try:
-            with np.load(os.path.join(path, "state.npz")) as npz:
+            names = _part_names(int(manifest.get("state_parts") or 1))
+            with io.BufferedReader(_PartReader(
+                    [os.path.join(path, n) for n in names])) as fh, \
+                    np.load(fh) as npz:
                 flat = {k: npz[k] for k in npz.files}
-        except FileNotFoundError:
+        except FileNotFoundError as e:
             raise CorruptCheckpointError(
-                "checkpoint step %d has no state.npz" % step)
+                "checkpoint step %d has no %s"
+                % (step, os.path.basename(e.filename or "state.npz")))
         except (ValueError, OSError, KeyError,
                 zipfile.BadZipFile, zlib.error) as e:
             # zip directory/entry damage, npy header damage, payload

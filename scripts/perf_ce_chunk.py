@@ -1,4 +1,4 @@
-"""Measure the chunked LM-head cross-entropy claim (round-4 verdict item 5).
+"""Measure the chunked LM-head cross-entropy claim.
 
 ops/nn.py's chunked_lm_xent claims to avoid materializing the [B, S, V]
 logits and their backward residuals. Two measurements, same train step,
@@ -8,8 +8,8 @@ dense vs chunked:
   (``compiled.memory_analysis().temp_size_in_bytes``) — the compiler's
   peak temp-buffer requirement, deterministic, no timing noise, valid on
   CPU and TPU alike.
-* host-readback-synced step wall time (bench.py methodology: this
-  environment's block_until_ready returns before execution completes).
+* step wall time, by bench.py's ``_timed_windows`` (windows that end
+  in ``block_until_ready``); a device time only when run on the chip.
 
 Run:  JAX_PLATFORMS=cpu python scripts/perf_ce_chunk.py         (small cfg)
       PERF_CE_PRESET=base python scripts/perf_ce_chunk.py       (GPT-2 scale)
@@ -52,9 +52,7 @@ def main():
                                      vocab_size=cfg["vocab_size"])
     opt = optim.adamw(1e-4)
 
-    # bench._timed_windows is THE home of the readback-sync timing
-    # methodology (this environment's block_until_ready lies) — reuse it
-    # so a future sync fix reaches this script too
+    # bench._timed_windows is the one home of the step-timing method
     import bench
 
     out = {"stage": "ce_chunk", "backend": jax.default_backend(),
@@ -72,7 +70,8 @@ def main():
         mem = lowered.compile().memory_analysis()
         if mem is not None:
             out["%s_temp_bytes" % name] = int(mem.temp_size_in_bytes)
-        best = bench._timed_windows(step_fn, state, batch_data, steps)
+        best, _backend = bench._timed_windows(step_fn, state, batch_data,
+                                              steps)
         out["%s_step_ms" % name] = round(best * 1000, 1)
         del state
     if "dense_temp_bytes" in out and "chunked_temp_bytes" in out:

@@ -1,0 +1,483 @@
+"""What the first runs on the v5e found, checked on the CPU (ISSUE 21).
+
+* the kernels lower — and, where this installation's libtpu can describe a
+  v5e without owning one, COMPILE — for the TPU at the shapes the chip
+  smoke uses: the check that would have caught a decode kernel that had
+  only ever run interpreted;
+* a Mosaic kernel under a mesh needs ``shard_map``, and ``gpt.loss_fn``
+  supplies it;
+* the compile cache stays where ``JAX_COMPILATION_CACHE_DIR`` puts it;
+* an AOT executable reloads onto the devices it was compiled for;
+* ``chip_smoke.py`` and ``bench.py`` refuse to run off the chip;
+* a checkpoint is written in files of bounded size — the driver's chip
+  machine refused GPT-2 small's 1.95 GB ``state.npz`` with EFBIG.
+"""
+
+import functools
+import os
+import shutil
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_operator_tpu import compile_cache
+from paddle_operator_tpu.ops import attention_pallas as ap
+from paddle_operator_tpu.parallel import (
+    make_mesh, sharded_flash_attention)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: ServingEngine defaults at gpt.BASE_CONFIG: 8 rows, 12 heads of 64,
+#: 256 + 1 pages of 16 slots, 1024 // 16 pages per sequence
+ENGINE = dict(b=8, h=12, d=64, bs=16, pages=257, per_seq=64)
+#: chip_smoke's kernel check, and the trainer's attention (batch 16)
+FLASH_SHAPES = [(2, 12, 1024, 64), (16, 12, 1024, 64)]
+
+
+def _sds(shape, dtype, sharding=None):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _paged_args(dtype, sharding=None):
+    e = ENGINE
+    pool = (e["pages"], e["bs"], e["h"], e["d"])
+    return (_sds((e["b"], e["h"], e["d"]), dtype, sharding),
+            _sds(pool, dtype, sharding), _sds(pool, dtype, sharding),
+            _sds((e["b"], e["per_seq"]), jnp.int32, sharding),
+            _sds((e["b"],), jnp.int32, sharding))
+
+
+def _flash_loss(q, k, v):
+    return ap.flash_attention(q, k, v, causal=True).astype(
+        jnp.float32).sum()
+
+
+# ---------------------------------------------------------------------------
+# (i) the kernels lower for the TPU
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_lowers_for_tpu(dtype):
+    exp = jax.export.export(jax.jit(ap.paged_decode_attention),
+                            platforms=("tpu",))(*_paged_args(dtype))
+    assert "tpu_custom_call" in exp.mlir_module()
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES)
+def test_flash_fwd_bwd_lowers_for_tpu(shape):
+    q = _sds(shape, jnp.bfloat16)
+    exp = jax.export.export(
+        jax.jit(jax.value_and_grad(_flash_loss, argnums=(0, 1, 2))),
+        platforms=("tpu",))(q, q, q)
+    # forward, dQ and dK/dV kernels
+    assert exp.mlir_module().count("tpu_custom_call") >= 3
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of a described (not owned) v5e 2x2: lets the real TPU
+    compiler — Mosaic included — run in a sandbox with no chip."""
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:  # no libtpu here, or it cannot describe one
+        pytest.skip("no TPU topology description available: %r" % (e,))
+    return topo.devices
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_compiles_for_v5e(v5e, dtype):
+    sh = jax.sharding.SingleDeviceSharding(v5e[0])
+    compiled = jax.jit(ap.paged_decode_attention).lower(
+        *_paged_args(dtype, sh)).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+def test_flash_fwd_bwd_compiles_for_v5e(v5e):
+    sh = jax.sharding.SingleDeviceSharding(v5e[0])
+    q = _sds(FLASH_SHAPES[0], jnp.bfloat16, sh)
+    compiled = jax.jit(jax.value_and_grad(
+        _flash_loss, argnums=(0, 1, 2))).lower(q, q, q).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') >= 3
+
+
+def test_paged_decode_matches_reference_interpreted():
+    """The repaired kernel (multiply-and-reduce, no batched matmul)
+    against the gather-einsum reference at the engine's head shape."""
+    e = dict(ENGINE, pages=33, per_seq=8)
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    pool = (e["pages"], e["bs"], e["h"], e["d"])
+    q = jax.random.normal(ks[0], (e["b"], e["h"], e["d"]))
+    kp, vp = jax.random.normal(ks[1], pool), jax.random.normal(ks[2], pool)
+    tables = jax.random.randint(ks[3], (e["b"], e["per_seq"]), 0,
+                                e["pages"] - 1)
+    lens = jax.random.randint(ks[4], (e["b"],), 1,
+                              e["per_seq"] * e["bs"] + 1)
+    got = ap.paged_decode_attention(q, kp, vp, tables, lens, interpret=True)
+    want = ap._reference_paged_decode(q, kp, vp, tables, lens,
+                                      1.0 / np.sqrt(e["d"]))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# a Mosaic kernel under a mesh
+# ---------------------------------------------------------------------------
+
+def _export_over_mesh(fn, mesh, shape):
+    sh = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec("dp"))
+    q = _sds(shape, jnp.bfloat16)
+    return jax.export.export(
+        jax.jit(fn, in_shardings=(sh, sh, sh), out_shardings=sh),
+        platforms=("tpu",))(q, q, q)
+
+
+def test_bare_kernel_under_a_mesh_does_not_lower():
+    """Why sharded_flash_attention exists: GSPMD cannot partition the
+    kernel, so the default dp=n trainer on a four-chip host failed at
+    lowering."""
+    mesh = make_mesh({"dp": 4}, jax.devices()[:4])
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _export_over_mesh(
+            lambda q, k, v: ap.flash_attention(q, k, v, causal=True),
+            mesh, (16, 12, 1024, 64))
+
+
+def test_sharded_flash_lowers_under_a_mesh(monkeypatch):
+    # compiled, not interpreted, as on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_mesh({"dp": 4}, jax.devices()[:4])
+    exp = _export_over_mesh(
+        functools.partial(sharded_flash_attention, mesh=mesh, causal=True),
+        mesh, (16, 12, 1024, 64))
+    assert "tpu_custom_call" in exp.mlir_module()
+
+
+def test_sharded_flash_matches_reference_on_a_mesh():
+    """Interpreted on the CPU mesh: batch over dp, heads over tp."""
+    from paddle_operator_tpu.parallel.context import reference_attention
+
+    mesh = make_mesh({"dp": 2, "tp": 2}, jax.devices()[:4])
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    q, k, v = (jax.random.normal(kk, (2, 2, 256, 64)) for kk in ks)
+    got = sharded_flash_attention(q, k, v, mesh, causal=True)
+    want = reference_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_sharded_flash_unsupported_shape_takes_dense_path():
+    mesh = make_mesh({"dp": 2}, jax.devices()[:2])
+    q = jax.random.normal(jax.random.PRNGKey(2), (2, 2, 32, 16))
+    from paddle_operator_tpu.parallel.context import reference_attention
+
+    np.testing.assert_allclose(
+        sharded_flash_attention(q, q, q, mesh, causal=True),
+        reference_attention(q, q, q, causal=True), atol=1e-6)
+
+
+def test_gpt_loss_routes_auto_attention_through_shard_map(monkeypatch):
+    """On the TPU backend with a mesh, attn_impl="auto" must reach the
+    kernel through shard_map: the exported step holds the Mosaic call."""
+    from paddle_operator_tpu.models import gpt
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_mesh({"dp": 4}, jax.devices()[:4])
+    cfg = dict(gpt.TINY_CONFIG, layers=1, heads=2, max_seq=256)
+    params = jax.eval_shape(lambda k: gpt.init(k, cfg),
+                            jax.random.PRNGKey(0))
+    batch = {"input_ids": _sds((4, 256), jnp.int32)}
+    loss = functools.partial(gpt.loss_fn, mesh=mesh)
+    exp = jax.export.export(
+        jax.jit(lambda p, b: jax.grad(lambda p: loss(p, b)[0])(p),
+                in_shardings=(None, jax.sharding.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec("dp")))),
+        platforms=("tpu",))(params, batch)
+    assert "tpu_custom_call" in exp.mlir_module()
+
+
+# ---------------------------------------------------------------------------
+# (ii) where the compile cache lives
+# ---------------------------------------------------------------------------
+
+_PLACEMENT_PROBE = """
+import json, os, sys
+sys.path.insert(0, %(repo)r)
+import jax, jax.numpy as jnp
+updates = []
+real = jax.config.update
+jax.config.update = lambda k, v: (updates.append(k), real(k, v))[1]
+from paddle_operator_tpu import compile_cache
+step = compile_cache.cached_jit(lambda x: x * 2 + 1, (jnp.ones((8,)),),
+                                label="probe")
+step(jnp.ones((8,)))
+print(json.dumps({"updates": updates,
+                  "jax_dir": jax.config.jax_compilation_cache_dir,
+                  "block": compile_cache.startup_block()["dir"]}))
+"""
+
+
+def test_jax_compilation_cache_dir_is_honoured(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set nothing re-points JAX's cache,
+    and the AOT executables land under the same root — even with the
+    pods' own variable pointing elsewhere."""
+    import json
+
+    jax_dir, other = tmp_path / "jaxcache", tmp_path / "other"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(jax_dir),
+               TPUJOB_COMPILE_CACHE_DIR=str(other))
+    out = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_PROBE % {"repo": REPO}],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert "jax_compilation_cache_dir" not in got["updates"]
+    assert got["jax_dir"] == got["block"] == str(jax_dir)
+    assert [f for f in os.listdir(jax_dir / "aot") if f.endswith(".aotx")]
+    assert [f for f in os.listdir(jax_dir) if f != "aot"], \
+        "no XLA cache entry beside the AOT dir"
+    assert not other.exists()
+
+
+def test_default_cache_dir_is_inside_the_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("TPUJOB_COMPILE_CACHE_DIR", raising=False)
+    assert compile_cache.default_cache_dir() == os.path.join(
+        REPO, ".compile_cache")
+    with open(os.path.join(REPO, ".gitignore")) as fh:
+        assert ".compile_cache/" in fh.read().split()
+
+
+def test_cache_dir_precedence(monkeypatch):
+    monkeypatch.setenv("TPUJOB_COMPILE_CACHE_DIR", "/vol/tpujob")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.default_cache_dir() == "/vol/tpujob"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/vol/jax")
+    assert compile_cache.default_cache_dir() == "/vol/jax"
+
+
+# ---------------------------------------------------------------------------
+# (iii) AOT executables reload onto their own devices
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def fresh_cache(tmp_path, monkeypatch):
+    monkeypatch.setenv("TPUJOB_COMPILE_CACHE_DIR", str(tmp_path))
+    compile_cache.reset_stats_for_tests()
+    yield tmp_path
+    compile_cache.reset_stats_for_tests()
+
+
+def test_one_device_executable_reloads_among_eight(fresh_cache):
+    """deserialize_and_load defaults to EVERY device of the backend; an
+    executable built for one then refuses its first call."""
+    assert len(jax.devices()) == 8
+    x = jnp.arange(8.0)
+    cold = compile_cache.cached_jit(lambda a: a * 3, (x,), label="one")
+    assert cold.source == "compiled"
+    compile_cache.reset_stats_for_tests()          # drop the memo
+    warm = compile_cache.cached_jit(lambda a: a * 3, (x,), label="one")
+    assert warm.source == "aot"
+    np.testing.assert_array_equal(warm(x), x * 3)
+    s = compile_cache.stats()
+    assert s["first_call_rejects"] == 0 and s["jit_fallbacks"] == 0
+
+
+def test_submesh_executable_reloads_in_device_order(fresh_cache):
+    mesh = make_mesh({"dp": 4}, jax.devices()[2:6])
+    sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("dp"))
+    x = jax.device_put(jnp.arange(8.0), sh)
+    build = functools.partial(
+        compile_cache.cached_jit, lambda a: a + 1, (x,), mesh=mesh,
+        in_shardings=(sh,), out_shardings=sh, label="sub")
+    assert build().source == "compiled"
+    compile_cache.reset_stats_for_tests()
+    warm = build()
+    assert warm.source == "aot"
+    out = warm(x)
+    np.testing.assert_array_equal(out, np.arange(8.0) + 1)
+    assert out.sharding.device_set == set(jax.devices()[2:6])
+    assert compile_cache.stats()["first_call_rejects"] == 0
+
+
+def test_first_call_reject_is_counted(fresh_cache):
+    def refuse(*a):
+        raise ValueError("expected 8 shards, got 1")
+
+    step = compile_cache.CachedStep(
+        refuse, "aot", "f" * 32, 0.0, fallback=lambda: (lambda a: a + 1))
+    assert step(1) == 2 and step.source == "jit"
+    assert compile_cache.stats()["first_call_rejects"] == 1
+
+
+def test_aot_lowering_failure_is_counted(fresh_cache):
+    def cannot_trace(a):
+        raise TypeError("not traceable")
+
+    step = compile_cache.cached_jit(cannot_trace, (jnp.ones(2),),
+                                    label="bad")
+    assert step.source == "jit"
+    assert compile_cache.stats()["aot_lower_failures"] == 1
+    assert compile_cache.startup_block()["aot_lower_failures"] == 1
+
+
+# ---------------------------------------------------------------------------
+# (iv) off the chip: no result
+# ---------------------------------------------------------------------------
+
+def _run(script, *args, cwd=REPO, timeout=300):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run([sys.executable, script, *args], cwd=cwd, env=env,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+def test_chip_smoke_fails_without_a_chip():
+    out = _run("chip_smoke.py")
+    assert out.returncode != 0
+    assert "platform=cpu" in out.stdout
+    assert '"ok"' not in out.stdout and "CHIP_SMOKE OK" not in out.stdout
+
+
+def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    out = _run("chip_smoke.py", "--rehearse-on-cpu", cwd=str(tmp_path))
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout and "CHIP_SMOKE OK" not in out.stdout
+
+
+def test_chip_smoke_rehearsal_runs_the_tiny_config_to_the_end():
+    out = _run("chip_smoke.py", "--rehearse-on-cpu")
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = [l for l in out.stdout.splitlines() if l.startswith("CHIP_SMOKE")]
+    assert lines[-1].startswith("CHIP_SMOKE OK")
+    assert all(l.endswith("platform=cpu DRY RUN") for l in lines[1:])
+    # a rehearsal is not a result
+    assert '"ok"' not in out.stdout
+    assert any("resume_steps=[3]" in l for l in lines)
+
+
+def test_bench_fails_without_a_chip():
+    out = _run("bench.py")
+    assert out.returncode != 0 and out.stdout.strip() == ""
+
+
+# ---------------------------------------------------------------------------
+# a checkpoint no file of which outgrows a bound
+# ---------------------------------------------------------------------------
+
+def _ckpt_state():
+    rng = np.random.default_rng(0)
+    return {"params": {"w": rng.random((512, 1024), np.float32),
+                       "b": np.arange(7)},
+            "opt": [rng.random(70000), np.float32(3.0)],
+            "step": np.int32(4)}
+
+
+def _same(got, want):
+    return all(np.array_equal(a, b) for a, b in zip(
+        jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want),
+        strict=True))
+
+
+def test_checkpoint_is_cut_into_bounded_files(tmp_path):
+    from paddle_operator_tpu.utils import checkpoint as ckpt
+
+    state, d = _ckpt_state(), str(tmp_path)
+    small = ckpt.save_checkpoint(d, 1, {"w": np.arange(4)})
+    assert sorted(os.listdir(small)) == ["manifest.json", "state.npz"]
+    big = ckpt.save_checkpoint(d, 2, state, part_bytes=300_000)
+    names = sorted(os.listdir(big))
+    assert names[:3] == ["manifest.json", "state.npz.000", "state.npz.001"]
+    assert max(os.path.getsize(os.path.join(big, n)) for n in names) \
+        <= 300_000
+    got, manifest = ckpt.restore_checkpoint(d)
+    assert manifest["step"] == 2
+    assert manifest["state_parts"] == len(names) - 1 > 8
+    assert _same(got, state)
+    # a lost part is a corrupt step: quarantined, and resume walks back
+    os.remove(os.path.join(big, "state.npz.004"))
+    with pytest.raises(ckpt.CorruptCheckpointError, match="state.npz.004"):
+        ckpt.restore_checkpoint(d, 2)
+    assert ckpt.restore_latest(d)[1]["step"] == 1
+    assert os.path.isdir(big + ".corrupt")
+
+
+def test_checkpoint_is_written_under_a_file_size_limit(tmp_path):
+    """RLIMIT_FSIZE below the state's size (what the chip machine had):
+    the writer cuts its files to the limit instead of dying of EFBIG."""
+    code = (
+        "import resource, sys, numpy as np\n"
+        "from paddle_operator_tpu.utils import checkpoint as ckpt\n"
+        "resource.setrlimit(resource.RLIMIT_FSIZE, (1 << 20, 1 << 20))\n"
+        "state = {'w': np.arange(1 << 20, dtype=np.float32)}\n"
+        "ckpt.save_checkpoint(sys.argv[1], 3, state)\n"
+        "got, _ = ckpt.restore_latest(sys.argv[1])\n"
+        "assert np.array_equal(got['w'], state['w'])\n")
+    out = subprocess.run([sys.executable, "-c", code, str(tmp_path)],
+                         cwd=REPO, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode == 0, out.stderr[-3000:]
+    step = os.path.join(str(tmp_path), "step_%012d" % 3)
+    sizes = [os.path.getsize(os.path.join(step, n))
+             for n in os.listdir(step)]
+    assert len(sizes) == 6 and max(sizes) == 1 << 20
+
+
+# ---------------------------------------------------------------------------
+# what the runner banks for the hardware block
+# ---------------------------------------------------------------------------
+
+def test_mfu_divides_by_every_device_the_step_spans():
+    from paddle_operator_tpu.obs.hardware import (
+        ChipSpec, HardwarePlane, analytic_cost, conservation_violations)
+
+    plane = HardwarePlane(ChipSpec("x", "tpu", 100e12, 800e9, "registry"))
+    plane.set_cost(analytic_cost(40e12), devices=4)
+    plane.record(10, 10.0)
+    blk = plane.block()
+    assert blk["devices"] == 4 and blk["mfu"] == pytest.approx(0.1)
+    assert plane.mfu_of_rate(1.0) == pytest.approx(0.1)
+    assert conservation_violations(blk) == []
+
+
+def test_runner_banks_synced_windows_without_the_warm_up_call(monkeypatch):
+    """Every banked window ends in block_until_ready, and the step
+    function's first call (compile + first execution) is in none."""
+    import paddle_operator_tpu.runner as runner_mod
+    from paddle_operator_tpu.models import gpt
+    from paddle_operator_tpu.obs.hardware import HardwarePlane
+    from paddle_operator_tpu.ops import optim
+
+    events = []
+    real_sync = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: (events.append("sync"), real_sync(x))[1])
+    real_record = HardwarePlane.record
+    monkeypatch.setattr(
+        HardwarePlane, "record",
+        lambda self, steps, secs: (events.append(("bank", steps)),
+                                   real_record(self, steps, secs))[1])
+    job = runner_mod.TrainJob(
+        init_params=lambda rng: gpt.init(rng, gpt.TINY_CONFIG),
+        loss_fn=gpt.loss_fn, optimizer=optim.adamw(1e-3),
+        make_batch=lambda rng, step: gpt.synthetic_batch(rng, 8, 16, 1024),
+        total_steps=5, log_every=2)
+    res = runner_mod.run_training(job, init_distributed=False)
+    banks = [e for e in events if e != "sync"]
+    # step 1 is warm-up; windows close at the log boundaries (2, 4) and
+    # at the end of the run (5)
+    assert banks == [("bank", 1), ("bank", 2), ("bank", 1)]
+    for i, e in enumerate(events):
+        if e != "sync":
+            assert events[i - 1] == "sync"
+    assert res["hardware"]["steps"] == 4 and res["steps"] == 5

@@ -441,7 +441,7 @@ def test_backend_degradation_emits_event_through_harness():
     for _ in range(3):
         h.job_metrics.ledger.observe_throughput(
             "default", "fallback", 151_000.0)  # the healthy r02 rate
-    # the resumed-on-CPU rate (r03-r05): one sample is enough
+    # the resumed-on-CPU rate: one sample is enough
     assert h.job_metrics.ledger.observe_throughput(
         "default", "fallback", 0.4)
     events = [e for e in h.client.all_objects("Event")
