@@ -1,0 +1,1 @@
+"""Part of the cell benchmark; see ../README.md."""
