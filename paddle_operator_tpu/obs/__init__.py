@@ -18,7 +18,7 @@ Layout:
   fast/slow burn-rate window pairs (:class:`SloEvaluator`), surfaced as
   Events, flight-recorder entries, and ``tpujob_slo_burn_rate`` gauges.
 * :mod:`.worker` — :class:`WorkerMetricsServer` (the runner's /metrics),
-  :class:`StepProfiler` (bounded per-step phase ring), and
+  :func:`step_phase_stats` (per-step phase quantiles), and
   :class:`StragglerDetector` (gang-median p50 drift).
 * :mod:`.hardware` — the hardware-efficiency plane (ISSUE 13):
   :class:`ChipSpec` / :class:`StepCost` / :class:`HardwarePlane`
@@ -62,8 +62,8 @@ from .slo import (  # noqa: F401
     serving_slos,
 )
 from .worker import (  # noqa: F401
-    STEP_PHASES, STRAGGLER_K, StepProfiler, StragglerDetector,
-    ThroughputBaseline, WorkerMetricsServer, median,
+    STEP_PHASES, STRAGGLER_K, StragglerDetector,
+    ThroughputBaseline, WorkerMetricsServer, median, step_phase_stats,
 )
 
 __all__ = [
@@ -78,12 +78,13 @@ __all__ = [
     "JobMetrics", "MfuBaseline", "ObsAggregator",
     "ObservedEventRecorder", "SloEvaluator",
     "SloSpec", "StepCost",
-    "StepProfiler", "StragglerDetector", "ThroughputBaseline",
+    "StragglerDetector", "ThroughputBaseline",
     "WorkerMetricsServer", "analytic_cost", "clamped_mfu",
     "configured_top_k", "detail_jobs_threshold",
     "device_memory_stats", "median",
     "default_slos", "format_float", "format_value", "http_respond",
     "incident_cause", "job_key", "parse_exposition", "parse_slo_spec",
     "resolve_chip", "roofline_class", "serving_slos", "step_cost_of",
+    "step_phase_stats",
     "wire_checkpoint_observer",
 ]

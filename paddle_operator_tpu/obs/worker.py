@@ -1,13 +1,12 @@
 """Worker-plane observability: the runner's /metrics endpoint, the
-bounded per-step phase profiler, and cross-worker straggler detection.
+per-step phase quantiles, and cross-worker straggler detection.
 
-* :class:`StepProfiler` — a bounded ring of per-step phase timings
-  (``data_wait`` / ``h2d`` / ``dispatch`` / ``collective`` / ``d2h`` /
-  ``checkpoint``), built on the same host clocks as
-  :class:`~..utils.trace.StageTimes` but kept per step so quantiles and
-  drift are computable. ``stats()`` is what the runner exports in
-  ``result["step_profile"]``, the worker /metrics endpoint, and the
-  trace JSONL (``step_profile`` events at log boundaries).
+* :func:`step_phase_stats` — per-step phase quantiles (``data_wait`` /
+  ``dispatch`` / ``d2h`` / ``checkpoint``) read from the ring of the
+  runner's one accumulator, :class:`~..utils.trace.StageTimes`: what the
+  runner exports in ``result["step_profile"]``, the worker /metrics
+  endpoint, and the trace JSONL (``step_profile`` events at log
+  boundaries).
 * :class:`StragglerDetector` — a worker whose dispatch p50 drifts more
   than ``k``x above the gang median is a straggler: one slow host stalls
   the whole slice's collectives, so the *gang* pays its latency. The
@@ -27,83 +26,31 @@ import threading
 import time
 from collections import deque
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import Any, Deque, Dict, List, Optional
 
 from ..k8s.runtime import escape_label_value
+from ..utils.trace import StageTimes
 from .exposition import format_value, http_respond
 
-#: per-step phases the profiler understands (a record may carry any
-#: subset — e.g. ``checkpoint`` only on boundary steps)
-STEP_PHASES = ("data_wait", "h2d", "dispatch", "collective", "d2h",
-               "checkpoint")
 
 #: straggler threshold: p50 above k x gang median
 STRAGGLER_K = 2.0
 
 
-class StepProfiler:
-    """Bounded ring of per-step phase timings (seconds). Thread-safe;
-    ``depth`` bounds memory no matter how long the run."""
-
-    def __init__(self, depth: int = 512):
-        self._lock = threading.Lock()
-        self._ring: Deque[Tuple[int, Dict[str, float]]] = \
-            deque(maxlen=depth)
-
-    def record(self, step: int, **phases: float) -> None:
-        clean = {k: float(v) for k, v in phases.items()
-                 if v is not None and v >= 0}
-        if not clean:
-            return
-        with self._lock:
-            self._ring.append((int(step), clean))
-
-    def __len__(self) -> int:
-        with self._lock:
-            return len(self._ring)
-
-    def stats(self) -> Dict[str, Dict[str, float]]:
-        """Per-phase ``{p50, p90, p99, mean, count}`` over the ring."""
-        with self._lock:
-            entries = list(self._ring)
-        series: Dict[str, List[float]] = {}
-        for _step, phases in entries:
-            for phase, s in phases.items():
-                series.setdefault(phase, []).append(s)
-        out: Dict[str, Dict[str, float]] = {}
-        for phase, vals in series.items():
-            vals.sort()
-            out[phase] = {
-                "p50": round(_quantile(vals, 0.50), 6),
-                "p90": round(_quantile(vals, 0.90), 6),
-                "p99": round(_quantile(vals, 0.99), 6),
-                "mean": round(sum(vals) / len(vals), 6),
-                "count": len(vals),
-            }
-        return out
-
-    def p50(self, phase: str) -> float:
-        with self._lock:
-            vals = sorted(s for _step, phases in self._ring
-                          for p, s in phases.items() if p == phase)
-        return _quantile(vals, 0.50) if vals else 0.0
-
-    def totals(self) -> Dict[str, float]:
-        """Accumulated seconds per phase across the ring (badput feed)."""
-        with self._lock:
-            entries = list(self._ring)
-        out: Dict[str, float] = {}
-        for _step, phases in entries:
-            for phase, s in phases.items():
-                out[phase] = out.get(phase, 0.0) + s
-        return out
+#: step phase -> the runner's ``StageTimes`` stage that holds it (a
+#: phase shows only once sampled — ``checkpoint`` on boundary steps)
+PHASE_STAGES = {"data_wait": "data_wait", "dispatch": "step_dispatch",
+                "d2h": "d2h", "checkpoint": "checkpoint"}
+STEP_PHASES = tuple(PHASE_STAGES)
 
 
-def _quantile(sorted_vals: List[float], q: float) -> float:
-    if not sorted_vals:
-        return 0.0
-    idx = min(len(sorted_vals) - 1, max(0, int(q * len(sorted_vals))))
-    return sorted_vals[idx]
+def step_phase_stats(times: StageTimes) -> Dict[str, Dict[str, float]]:
+    """Per-phase ``{p50, p90, p99, mean, count}`` (seconds) over the
+    newest samples of the runner's accumulator: what the runner exports
+    in ``result["step_profile"]``, the worker /metrics endpoint and the
+    ``step_profile`` trace events. A phase with no sample is left out."""
+    return {phase: st for phase, stage in PHASE_STAGES.items()
+            if (st := times.stats(stage))}
 
 
 class StragglerDetector:
@@ -304,7 +251,7 @@ class WorkerMetricsServer:
             self._stages = {k: dict(v) for k, v in summary.items()}
 
     def set_step_stats(self, stats: Dict[str, Dict[str, float]]) -> None:
-        """Publish a :meth:`StepProfiler.stats` breakdown (per-phase
+        """Publish a :func:`step_phase_stats` breakdown (per-phase
         quantiles over the bounded step ring)."""
         with self._lock:
             self._step_stats = {k: dict(v) for k, v in stats.items()}

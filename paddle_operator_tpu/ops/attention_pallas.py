@@ -248,6 +248,7 @@ def _flash_fwd(q, k, v, scale, block_q, block_k, interpret, causal):
             jax.ShapeDtypeStruct((b * h, s, MIN_BLOCK), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q3, k3, v3)
     return out.reshape(b, h, s, d), lse
 
@@ -290,6 +291,7 @@ def _flash_bwd(q, k, v, out, lse, g, scale, block_q, block_k, interpret,
         out_specs=pl.BlockSpec((1, block_q, d), qo_index),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         interpret=interpret,
+        name="flash_dq",
     )(q3, k3, v3, do3, lse, delta)
 
     def dkv_q_index(bh, ki, qi):
@@ -323,6 +325,7 @@ def _flash_bwd(q, k, v, out, lse, g, scale, block_q, block_k, interpret,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q3, k3, v3, do3, lse, delta)
 
     shape = (b, h, s, d)
@@ -633,5 +636,6 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
       q, k_pages, v_pages)

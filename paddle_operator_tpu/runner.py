@@ -16,6 +16,7 @@ import math
 import os
 import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -30,7 +31,7 @@ from .obs.hardware import (
     HardwarePlane, StepCost, analytic_cost, resolve_chip, step_cost_of,
 )
 from .obs.worker import (
-    StepProfiler, StragglerDetector, ThroughputBaseline, median,
+    StragglerDetector, ThroughputBaseline, median, step_phase_stats,
 )
 from .ops.optim import Optimizer
 from .parallel import batch_shardings, build_train_step, make_mesh
@@ -40,8 +41,8 @@ from .utils.checkpoint import (
     save_checkpoint_sharded,
 )
 from .utils.trace import (
-    SpanContext, StageTimes, clear_incident_context, profile_steps,
-    set_incident_context, tracer,
+    SpanContext, StageTimes, clear_incident_context, export_stage_times,
+    profile_steps, set_incident_context, tracer,
 )
 
 log = logging.getLogger("tpujob.runner")
@@ -49,6 +50,9 @@ log = logging.getLogger("tpujob.runner")
 # boundary-poll outcomes (broadcast as ints on multi-host: the decision
 # must be identical on every process at the same step)
 _POLL_NONE, _POLL_RESTART, _POLL_DRAIN = 0, 1, 2
+#: the stages of the loop that lie inside a ``host_gap``: a stall names
+#: the larger
+HOST_GAP_STAGES = ("data_wait", "log_boundary")
 
 
 class DrainMonitor:
@@ -373,9 +377,12 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
     # training" number (EasyScale-style regression triage needs it)
     goodput_acc = {"wall": 0.0, "step": 0.0}
     # step-level observability (docs/observability.md "Goodput & SLOs"):
-    # a bounded per-step phase ring, the gang straggler detector, and the
+    # this call's span accumulator (utils.trace: totals, maxima and a
+    # bounded ring per stage; the loop, its loader, the straggler check
+    # and the step profile all read it; exported under "train" for who
+    # reads in the same process), the gang straggler detector, and the
     # run-level badput attribution that becomes result["goodput_detail"]
-    profiler = StepProfiler()
+    times = export_stage_times("train", StageTimes())
     detector = StragglerDetector(k=job.straggler_k)
     # the worker is the authoritative source of its own examples/s, so
     # the throughput-collapse alarm runs HERE too: a resumed process
@@ -384,6 +391,7 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
     tput_watch = ThroughputBaseline()
     badput_acc: Dict[str, float] = {}
     result["straggler_events"] = 0
+    result["stall_events"] = 0
     result["backend_degraded_events"] = 0
     # hardware-efficiency plane (docs/observability.md "Hardware
     # efficiency"): chip capability resolved once per process, the
@@ -612,8 +620,26 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
         metrics = {}
         prof = profile_steps()
         trc = tracer()
-        times = StageTimes()
         deferred = DeferredMetrics()
+
+        def stalled(stage, at_step):
+            """One line and one trace event where the stage's newest
+            sample stands out from its running median (utils.trace
+            ``STALL_FACTOR``): an untraced run then says WHERE a pause
+            was. A ``host_gap`` names the largest stage inside it."""
+            over = times.excess(stage)
+            if over is None:
+                return
+            inside = times.by_span(HOST_GAP_STAGES).get(at_step) \
+                if stage == "host_gap" else None
+            within = max(inside, key=inside.get) if inside else None
+            log.warning("stall before step %d: %s stood %.3f s over its "
+                        "running median%s", at_step, stage, over,
+                        " (%s %.3f s of it)" % (within, inside[within])
+                        if within else "")
+            trc.event("stall", step=at_step, stage=stage,
+                      excess=round(over, 6), within=within)
+            result["stall_events"] += 1
 
         def log_resolved(resolved):
             """Log a boundary resolved by the deferred-readback helper:
@@ -622,11 +648,13 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
             stalls the dispatch pipeline."""
             if resolved is None:
                 return
-            t_d2h0 = time.perf_counter()
             pstep, t_submit, host = resolved
             rate = (pstep - start_step) / max(t_submit - t0, 1e-9)
-            log.info("step %d loss=%.4f steps/s=%.2f",
-                     pstep, float(host["loss"]), rate)
+            # the readback that really lands here is the d2h phase of
+            # this boundary's step profile (usually ~0: deferred design)
+            with times.timed("d2h"):
+                loss = float(host["loss"])
+            log.info("step %d loss=%.4f steps/s=%.2f", pstep, loss, rate)
             eps = rate * examples_per_step
             if examples_per_step > 0 and \
                     tput_watch.observe(eps) == "degraded":
@@ -640,15 +668,12 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
                 result["backend_degraded_events"] += 1
                 if metrics_srv is not None:
                     metrics_srv.inc("tpujob_worker_backend_degraded_total")
-            # the readback that really landed here is the d2h phase of
-            # this boundary's step profile (usually ~0: deferred design)
-            profiler.record(pstep, d2h=time.perf_counter() - t_d2h0)
             if metrics_srv is not None:
                 metrics_srv.update(
                     steps_total=pstep,
                     steps_per_second=rate,
                     examples_per_second=rate * examples_per_step,
-                    loss=float(host["loss"]),
+                    loss=loss,
                     loader_queue_depth=loader.queue_depth(),
                     # hardware-efficiency gauges: MFU at this boundary's
                     # readback-synced rate (None = suppressed, not
@@ -700,52 +725,70 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
         # synced and left out of both the steps and the seconds.
         win = {"t0": None, "steps": 0}
         warmed = set()
+        # seconds of the open dispatch gap spent waiting on something
+        # outside the loop, each under a stage of its own: the device
+        # (sync_wait, warmup_wait), the checkpoint's write, the poll of
+        # the control plane. host_gap is the gap less these.
+        gap = {"outside": 0.0}
 
-        def bank_synced(sync_on):
+        @contextmanager
+        def outside(stage, at_step):
+            with times.timed(stage, span=at_step) as waited:
+                yield waited
+            gap["outside"] += waited.seconds
+
+        def bank_synced(sync_on, at_step):
             """Close the open window: wait for ``sync_on`` (an output of
             the newest dispatch) and bank its steps and wall seconds."""
             if win["steps"]:
-                jax.block_until_ready(sync_on)
+                with outside("sync_wait", at_step):
+                    jax.block_until_ready(sync_on)
                 hw.record(win["steps"], time.perf_counter() - win["t0"])
+                stalled("sync_wait", at_step)
             win["t0"], win["steps"] = None, 0
 
-        def fetch():
+        def fetch(at_step):
             """Dequeue the next prestaged batch/window, charging the
             host wait (consumer starved = producer-bound) to data_stall
-            badput and the step profile's data_wait phase."""
-            t_f0 = time.perf_counter()
-            batch = next(loader)
-            wait = time.perf_counter() - t_f0
-            add_badput("data_stall", wait)
-            return batch, wait
+            badput and the ``data_wait`` stage."""
+            with times.timed("data_wait", span=at_step) as waited:
+                batch = next(loader)
+            add_badput("data_stall", waited.seconds)
+            return batch
 
-        def dispatch(fn, fetched, at_step, span=1):
-            """One step_fn/single_fn call, with the host gap between
-            consecutive dispatches (batch wait + logging + checkpoint
-            time) recorded as the `dispatch_gap` stage and the per-step
-            phases (data_wait, dispatch) in the bounded profiler ring.
+        def dispatch(fn, batch, at_step, span=1):
+            """One step_fn/single_fn call. The host time since the
+            previous dispatch returned (batch wait + logging +
+            checkpoint + the waits for the device at boundaries) is
+            banked as ``dispatch_gap``, and the same less its waits on
+            what lies outside the loop (``outside``) as ``host_gap``:
+            what the host itself did between two launches.
             ``span`` is the optimizer steps this one call executes (K
             for a fused window) — counted into the open hardware-plane
             window (see ``bank_synced``)."""
             nonlocal t_dispatched
-            batch, data_wait = fetched
             if t_dispatched is not None:
-                times.add("dispatch_gap", time.perf_counter() - t_dispatched)
+                gap_s = time.perf_counter() - t_dispatched
+                times.add("dispatch_gap", gap_s, start=t_dispatched,
+                          span=at_step)
+                times.add("host_gap", max(0.0, gap_s - gap["outside"]),
+                          start=t_dispatched, span=at_step)
+                stalled("host_gap", at_step)
             warm_up = id(fn) not in warmed
             if warm_up:
-                bank_synced(state)
+                bank_synced(state, at_step)
                 warmed.add(id(fn))
-            t_d0 = time.perf_counter()
-            with times.timed("step_dispatch"):
+            gap["outside"] = 0.0
+            with times.timed("step_dispatch", span=at_step) as call:
                 out = fn(state, batch)
             t_dispatched = time.perf_counter()
-            profiler.record(at_step, data_wait=data_wait,
-                            dispatch=t_dispatched - t_d0)
+            goodput_acc["step"] += call.seconds
             if warm_up:
-                jax.block_until_ready(out[1])
+                with outside("warmup_wait", at_step + span):
+                    jax.block_until_ready(out[1])
             else:
                 if win["t0"] is None:
-                    win["t0"] = t_d0
+                    win["t0"] = call.t0
                 win["steps"] += span
             return out
 
@@ -753,21 +796,21 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
             """Compare this worker's dispatch p50 against the gang view
             (injected source, or an allgather on multi-host — an aligned
             collective: every process reaches the same log boundary)."""
-            own = profiler.p50("dispatch")
+            if job.gang_p50_source is None and not multi:
+                return
+            own = times.p50("step_dispatch")
             if own <= 0.0:
                 return
             if job.gang_p50_source is not None:
                 gang = job.gang_p50_source(own)
                 me = cfg.worker_id
-            elif multi:
+            else:
                 from jax.experimental import multihost_utils
 
                 arr = multihost_utils.process_allgather(
                     np.asarray(own, dtype=np.float64))
                 gang = {i: float(v) for i, v in enumerate(np.ravel(arr))}
                 me = jax.process_index()
-            else:
-                return
             slow = detector.evaluate(gang or {})
             if me in slow:
                 # the SAME median the detector thresholded against
@@ -787,7 +830,7 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
                 if k_here == K:
                     # full window (K>1) or plain per-step batch (K==1),
                     # prestaged by the loader
-                    state, metrics = dispatch(step_fn, fetch(), step,
+                    state, metrics = dispatch(step_fn, fetch(step), step,
                                               span=K)
                     if K > 1:
                         # fused metrics come back stacked [K]; report the last
@@ -799,9 +842,9 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
                     if single_fn is None:
                         single_fn = make_single_fn()
                     for tail_i in range(k_here):
-                        state, metrics = dispatch(single_fn, fetch(),
-                                                  step + tail_i)
-                prof.after(step, span=k_here)
+                        state, metrics = dispatch(
+                            single_fn, fetch(step + tail_i), step + tail_i)
+                prof.after(step, span=k_here, sync_on=metrics)
                 step += k_here
                 trc.event("train_step", step=step, epoch=epoch)
                 if inc_state["ctx"] is not None:
@@ -813,26 +856,27 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
                     incident_first_step(step)
                 if job.log_every and (
                         step % job.log_every < k_here):
-                    bank_synced(metrics)
-                    # deferred readback: start the D2H copy for THIS
-                    # boundary, log the PREVIOUS one (already on host)
-                    log_resolved(deferred.start(step, metrics))
-                    straggler_check(step)
-                    trc.event("step_profile", step=step,
-                              **{ph: st["p50"] for ph, st
-                                 in profiler.stats().items()})
+                    bank_synced(metrics, step)
+                    with times.timed("log_boundary", span=step):
+                        # deferred readback: start the D2H copy for THIS
+                        # boundary, log the PREVIOUS one (already on host)
+                        log_resolved(deferred.start(step, metrics))
+                        straggler_check(step)
+                        if trc.enabled:
+                            trc.event("step_profile", step=step,
+                                      **{ph: st["p50"] for ph, st
+                                         in step_phase_stats(times).items()})
                 if job.checkpoint_dir and (
                         step % job.checkpoint_every < k_here):
-                    bank_synced(metrics)  # the snapshot syncs anyway
-                    t_ck0 = time.perf_counter()
-                    save(step, state, epoch)
-                    ck_s = time.perf_counter() - t_ck0
-                    add_badput("checkpoint", ck_s)
-                    profiler.record(step, checkpoint=ck_s)
+                    bank_synced(metrics, step)  # the snapshot syncs anyway
+                    with outside("checkpoint", step) as ck:
+                        save(step, state, epoch)
+                    add_badput("checkpoint", ck.seconds)
                     last_saved = step
-                outcome = poll_boundary()
+                with outside("poll", step):
+                    outcome = poll_boundary()
                 if outcome != _POLL_NONE:
-                    bank_synced(metrics)
+                    bank_synced(metrics, step)
                     drained = outcome == _POLL_DRAIN
                     log.info(
                         "%s at step %d",
@@ -903,7 +947,7 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
                     return False
                 result["state"] = state
                 result["steps"] = step
-            bank_synced(metrics)
+            bank_synced(metrics, step)
         finally:
             # a step that raises mid-window must still finalize the device
             # trace, or the capture is lost and re-entry hits "already
@@ -911,15 +955,13 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
             prof.close()
             loader.close()
             result["host_stages"] = times.summary()
-            # goodput accounting: productive step-dispatch time over this
-            # cycle's wall (compile, restore, data waits and logging are
-            # the non-productive remainder)
+            # goodput accounting: productive step-dispatch time (summed
+            # in ``dispatch``) over this cycle's wall (compile, restore,
+            # data waits and logging are the non-productive remainder)
             goodput_acc["wall"] += time.perf_counter() - cycle_t0
-            goodput_acc["step"] += result["host_stages"].get(
-                "step_dispatch", {}).get("ms", 0.0) / 1e3
             if metrics_srv is not None:
                 metrics_srv.set_stage_summary(result["host_stages"])
-                metrics_srv.set_step_stats(profiler.stats())
+                metrics_srv.set_step_stats(step_phase_stats(times))
                 metrics_srv.set_badput(badput_acc)
                 if goodput_acc["wall"] > 0:
                     metrics_srv.update(goodput_ratio=min(
@@ -995,7 +1037,7 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
         result["goodput"] = round(
             min(1.0, goodput_acc["step"] / goodput_acc["wall"]), 4)
     result["compile_cache"] = compile_cache.startup_block()
-    result["step_profile"] = profiler.stats()
+    result["step_profile"] = step_phase_stats(times)
     # hardware-efficiency block (obs.hardware): self-conserving by
     # construction (total_flops == flops_per_step x steps) and mirrored
     # into the trace (hardware_block event) so obs_report --hardware

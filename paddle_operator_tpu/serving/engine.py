@@ -38,6 +38,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..utils.trace import StageTimes, export_stage_times
 from .batching import Request
 from .kv_cache import KvCacheFull, PagedKvCache
 
@@ -114,6 +115,13 @@ class ServingEngine:
         self._prefilled: Dict[str, bool] = {}
         self._prefill_fn = None
         self._decode_fn = None
+        #: this engine's spans (utils.trace): one ``serve.step`` per
+        #: step_fn call with its phases inside, ``serve.admit`` per
+        #: reservation; always on, bounded. Exported under the engine's
+        #: label for who reads in the same process; hand it to
+        #: ``ServeMetrics(stages=...)`` for the ``tpujob_serve_stage_*``
+        #: families
+        self.times = export_stage_times(label, StageTimes())
 
     # -- admission hooks (wired into ContinuousBatcher) ------------------
 
@@ -134,12 +142,13 @@ class ServingEngine:
             raise ValueError(
                 "request %s prompt length %d outside (0, %d]"
                 % (req.request_id, len(req.prompt), self.prompt_pad))
-        try:
-            self.cache.allocator.alloc_sequence(
-                req.request_id, need, live_tokens=len(req.prompt))
-        except KvCacheFull:
-            return False
-        return True
+        with self.times.timed("serve.admit", request_id=req.request_id):
+            try:
+                self.cache.allocator.alloc_sequence(
+                    req.request_id, need, live_tokens=len(req.prompt))
+            except KvCacheFull:
+                return False
+            return True
 
     def retire(self, req: Request) -> None:
         self.cache.allocator.free_sequence(req.request_id)
@@ -187,10 +196,16 @@ class ServingEngine:
                               dtype=jnp.float32)[0]
             return jnp.argmax(logits).astype(jnp.int32), ks, vs
 
+        def serve_prefill(*args: Any) -> Any:
+            # the name XProf's ``XLA Modules`` line shows
+            # (``jit_serve_prefill``) and the scope of its operations
+            with jax.named_scope("serve_prefill"):
+                return prefill(*args)
+
         ex = (self.params, jnp.zeros((1, pad), jnp.int32),
               jnp.zeros((), jnp.int32))
         return compile_cache.cached_jit(
-            prefill, ex, config=dict(self.config, prompt_pad=pad),
+            serve_prefill, ex, config=dict(self.config, prompt_pad=pad),
             label="%s-prefill" % self.label)
 
     def _build_decode(self) -> Callable[..., Any]:
@@ -253,6 +268,12 @@ class ServingEngine:
             return (jnp.argmax(logits, -1).astype(jnp.int32),
                     new_k, new_v)
 
+        def serve_decode(*args: Any) -> Any:
+            # ``jit_serve_decode`` on ``XLA Modules``: what the
+            # benchmark's ``decode_device_ms`` looks for
+            with jax.named_scope("serve_decode"):
+                return decode(*args)
+
         b = self.max_batch
         layers = self.config["layers"]
         pshape = self.cache.k_pages[0].shape
@@ -262,7 +283,7 @@ class ServingEngine:
               jnp.zeros((b, self.pages_per_seq), jnp.int32),
               jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool))
         return compile_cache.cached_jit(
-            decode, ex,
+            serve_decode, ex,
             config=dict(self.config, attn=attn, max_batch=b,
                         block_size=bs, num_blocks=pshape[0] - 1),
             label="%s-decode" % self.label)
@@ -272,22 +293,27 @@ class ServingEngine:
     def step_fn(self, active: List[Request]) -> List[Tuple[int, bool]]:
         """One engine iteration for the batcher's active set: prefill
         newly admitted sequences (their first token comes from the
-        prefill logits), then one batched decode step for the rest."""
+        prefill logits), then one batched decode step for the rest.
+        Banked as one ``serve.step`` span with the phases of
+        ``_prefill`` and ``_decode`` inside it."""
         if len(active) > self.max_batch:
             raise RuntimeError("active set %d exceeds max_batch %d"
                                % (len(active), self.max_batch))
         results: Dict[str, Tuple[int, bool]] = {}
-        decode_rows: List[Request] = []
-        for req in active:
-            if not self._prefilled.get(req.request_id):
+        new = [r for r in active if not self._prefilled.get(r.request_id)]
+        decode_rows = [r for r in active
+                       if self._prefilled.get(r.request_id)]
+        with self.times.timed(
+                "serve.step", new=len(new), decode_rows=len(decode_rows),
+                prefill_tokens=sum(len(r.prompt) for r in new)):
+            for req in new:
                 token = self._prefill(req)
                 results[req.request_id] = (token, token == self.eos_id)
                 self._prefilled[req.request_id] = True
-            else:
-                decode_rows.append(req)
-        if decode_rows:
-            for req, token in zip(decode_rows, self._decode(decode_rows)):
-                results[req.request_id] = (token, token == self.eos_id)
+            if decode_rows:
+                for req, token in zip(decode_rows,
+                                      self._decode(decode_rows)):
+                    results[req.request_id] = (token, token == self.eos_id)
         return [results[r.request_id] for r in active]
 
     def _prefill(self, req: Request) -> int:
@@ -296,42 +322,55 @@ class ServingEngine:
                              % (len(req.prompt), self.prompt_pad))
         if self._prefill_fn is None:
             self._prefill_fn = self._build_prefill()
-        ids = jnp.zeros((1, self.prompt_pad), jnp.int32).at[
-            0, :len(req.prompt)].set(jnp.asarray(req.prompt, jnp.int32))
-        token, ks, vs = self._prefill_fn(
-            self.params, ids, jnp.asarray(len(req.prompt), jnp.int32))
-        n = len(req.prompt)
-        for li in range(self.config["layers"]):
-            self.cache.write_prefill(req.request_id, li, ks[li][:n],
-                                     vs[li][:n])
-        return int(token)
+        timed, n = self.times.timed, len(req.prompt)
+        rid = req.request_id
+        with timed("serve.prefill.build", request_id=rid, prompt_len=n):
+            ids = jnp.zeros((1, self.prompt_pad), jnp.int32).at[
+                0, :n].set(jnp.asarray(req.prompt, jnp.int32))
+            length = jnp.asarray(n, jnp.int32)
+        with timed("serve.prefill.dispatch", request_id=rid):
+            token, ks, vs = self._prefill_fn(self.params, ids, length)
+        with timed("serve.prefill.scatter", request_id=rid,
+                   pages=-(-n // self.cache.allocator.block_size)):
+            for li in range(self.config["layers"]):
+                self.cache.write_prefill(rid, li, ks[li][:n], vs[li][:n])
+        with timed("serve.prefill.wait", request_id=rid):
+            return int(token)
 
     def _decode(self, rows: List[Request]) -> List[int]:
         if self._decode_fn is None:
             self._decode_fn = self._build_decode()
-        alloc = self.cache.allocator
-        b = self.max_batch
-        tokens = [0] * b
-        positions = [0] * b
-        tables = [[0] * self.pages_per_seq for _ in range(b)]
-        lens = [0] * b
-        live = [False] * b
-        for i, req in enumerate(rows):
-            sid = req.request_id
-            tokens[i] = req.generated[-1]
-            lens[i] = alloc.seq_len(sid)
-            positions[i] = alloc.advance(sid)   # == lens[i], slot reserved
-            table = alloc.block_table(sid)
-            tables[i][:len(table)] = table
-            live[i] = True
-        out, kp, vp = self._decode_fn(
-            self.params, list(self.cache.k_pages),
-            list(self.cache.v_pages),
-            jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(positions, jnp.int32),
-            jnp.asarray(tables, jnp.int32),
-            jnp.asarray(lens, jnp.int32),
-            jnp.asarray(live, bool))
-        self.cache.k_pages = list(kp)
-        self.cache.v_pages = list(vp)
-        return [int(out[i]) for i in range(len(rows))]
+        timed = self.times.timed
+        with timed("serve.decode.tables"):
+            alloc = self.cache.allocator
+            b = self.max_batch
+            tokens = [0] * b
+            positions = [0] * b
+            tables = [[0] * self.pages_per_seq for _ in range(b)]
+            lens = [0] * b
+            live = [False] * b
+            for i, req in enumerate(rows):
+                sid = req.request_id
+                tokens[i] = req.generated[-1]
+                lens[i] = alloc.seq_len(sid)
+                positions[i] = alloc.advance(sid)  # == lens[i], slot reserved
+                table = alloc.block_table(sid)
+                tables[i][:len(table)] = table
+                live[i] = True
+        with timed("serve.decode.put"):
+            args = (jnp.asarray(tokens, jnp.int32),
+                    jnp.asarray(positions, jnp.int32),
+                    jnp.asarray(tables, jnp.int32),
+                    jnp.asarray(lens, jnp.int32),
+                    jnp.asarray(live, bool))
+        with timed("serve.decode.dispatch"):
+            out, kp, vp = self._decode_fn(
+                self.params, list(self.cache.k_pages),
+                list(self.cache.v_pages), *args)
+            self.cache.k_pages = list(kp)
+            self.cache.v_pages = list(vp)
+        with timed("serve.decode.wait"):
+            # the first int() below waited here before: no wait is added
+            jax.block_until_ready(out)
+        with timed("serve.decode.readback"):
+            return [int(out[i]) for i in range(len(rows))]

@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ..k8s.runtime import escape_label_value
 from ..obs.exposition import format_float
+from ..utils.trace import StageTimes
 from .batching import Request
 
 #: latency histogram buckets (seconds) — ttft skews larger than tpot but
@@ -56,13 +57,17 @@ class ServeMetrics:
 
     ``ledger``/``namespace``/``name`` wire the optional goodput-ledger
     charge: each completed request's queue wait lands as ``sched_wait``
-    badput against that job.
+    badput against that job. ``stages`` is the engine's span accumulator
+    (``ServingEngine.times``): where the host's time inside the serving
+    step goes, exported as ``tpujob_serve_stage_*`` at every scrape.
     """
 
     def __init__(self, job: str = "default/serve",
                  ledger: Optional[Any] = None,
-                 namespace: str = "", name: str = "") -> None:
+                 namespace: str = "", name: str = "",
+                 stages: Optional[StageTimes] = None) -> None:
         self.job = job
+        self._stages = stages
         self._ledger = ledger
         self._ns = namespace
         self._name = name
@@ -192,4 +197,29 @@ class ServeMetrics:
                          % (fam, job, hist_sum.get(which, 0.0)))
             lines.append('%s_count{job="%s"} %d'
                          % (fam, job, hist_count.get(which, 0)))
+        stages = self._stages.summary() if self._stages is not None else {}
+        if stages:
+            lines.append("# HELP tpujob_serve_stage_seconds_total Host "
+                         "wall-clock accumulated per stage of the serving "
+                         "step (the engine's serve.* spans).")
+            lines.append("# TYPE tpujob_serve_stage_seconds_total counter")
+            for stage in sorted(stages):
+                lines.append(
+                    'tpujob_serve_stage_seconds_total{job="%s",stage="%s"} '
+                    '%.6f' % (job, esc(stage), stages[stage]["ms"] / 1e3))
+            lines.append("# HELP tpujob_serve_stage_calls_total Times each "
+                         "stage of the serving step was entered.")
+            lines.append("# TYPE tpujob_serve_stage_calls_total counter")
+            for stage in sorted(stages):
+                lines.append(
+                    'tpujob_serve_stage_calls_total{job="%s",stage="%s"} %d'
+                    % (job, esc(stage), stages[stage]["count"]))
+            lines.append("# HELP tpujob_serve_stage_max_seconds Longest "
+                         "single sample of each stage of the serving step.")
+            lines.append("# TYPE tpujob_serve_stage_max_seconds gauge")
+            for stage in sorted(stages):
+                lines.append(
+                    'tpujob_serve_stage_max_seconds{job="%s",stage="%s"} '
+                    '%.6f' % (job, esc(stage),
+                              stages[stage]["max_ms"] / 1e3))
         return "\n".join(lines)

@@ -19,14 +19,16 @@ zap logging and k8s Events). This subsystem goes beyond it, in two layers:
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Deque, Dict, Iterable, List, NamedTuple, Optional
 
 _local = threading.local()
 
@@ -287,35 +289,142 @@ def tracer() -> Tracer:
     return _global
 
 
-class StageTimes:
-    """Thread-safe accumulator of per-stage host time.
+#: newest samples each stage's ring keeps (oldest dropped): a reader's
+#: window of a thousand serving steps fits. A running statistic (a
+#: quantile, the straggler check's median, the stall test's) is taken
+#: over the newest RECENT of them, because its memory is its reaction
+#: time: a host that turns slow has to move its median within minutes.
+RING_DEPTH = 8192
+RECENT = 512
+#: a sample is a stall when it passes STALL_FACTOR x the median of the
+#: stage's samples before it by more than STALL_FLOOR_S. The pause
+#: PERF.md section 6 caught (one log interval of 2.246 s among 29 of
+#: 1.496 s) crosses it (1.92 s) whether it fell in the wait for the
+#: device or in the host's gap; an ordinary boundary (intervals alike to
+#: 0.1%) and a host gap of a few milliseconds do not.
+STALL_FACTOR = 1.25
+STALL_FLOOR_S = 0.05
+STALL_MIN_SAMPLES = 4
 
-    The async input pipeline (`data.ShardedLoader`) and the training loop
-    record where host wall-clock goes — ``batch_build`` (source pull +
-    window stack), ``device_put`` (H2D issue), ``enqueue_wait`` (producer
-    blocked on a full queue = consumer is the bottleneck), ``dequeue_wait``
-    (consumer starved = producer is the bottleneck), ``dispatch_gap`` (host
-    time between step dispatches). ``summary()`` is the breakdown bench.py
-    and ``run_training`` report.
+_span_ids = itertools.count(1)
+
+
+class Sample(NamedTuple):
+    """One timed span: when it began (``time.perf_counter()``), how long
+    it took, the id of the step or request span it lies in (its own id
+    where it is the outermost), and its attributes."""
+
+    start: float
+    seconds: float
+    span: Optional[int]
+    attrs: Dict[str, Any]
+
+
+def _quantile(sorted_vals: List[float], q: float) -> float:
+    if not sorted_vals:
+        return 0.0
+    idx = min(len(sorted_vals) - 1, max(0, int(q * len(sorted_vals))))
+    return sorted_vals[idx]
+
+
+_TraceAnnotation: Any = None
+
+
+def _annotation(stage: str) -> Any:
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        _TraceAnnotation = jax.profiler.TraceAnnotation
+    return _TraceAnnotation(stage)
+
+
+class _Timed:
+    """``StageTimes.timed``'s context manager. A class, not a generator:
+    it sits on the serving step's hot path."""
+
+    __slots__ = ("times", "stage", "span", "attrs", "t0", "seconds",
+                 "_outer", "_ann")
+
+    def __init__(self, times: "StageTimes", stage: str,
+                 span: Optional[int], attrs: Dict[str, Any]) -> None:
+        self.times, self.stage, self.span, self.attrs = \
+            times, stage, span, attrs
+
+    def __enter__(self) -> "_Timed":
+        self._outer = getattr(_local, "span", None)
+        if self.span is None:
+            self.span = self._outer if self._outer is not None \
+                else next(_span_ids)
+        _local.span = self.span
+        # on /host:CPU of a profiler session, on the device trace's
+        # clock; inert when no session runs. Only where JAX is loaded
+        # already: the control plane traces without it.
+        self._ann = _annotation(self.stage)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        seconds = self.seconds = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _local.span = self._outer
+        self.times.add(self.stage, seconds, start=self.t0, span=self.span,
+                       **self.attrs)
+
+
+class StageTimes:
+    """Thread-safe accumulator of per-stage host time: the program's one
+    span mechanism.
+
+    The async input pipeline (`data.ShardedLoader`), the training loop
+    and the serving engine record where host wall-clock goes —
+    ``batch_build`` (source pull + window stack), ``device_put`` (H2D
+    issue), ``enqueue_wait`` / ``dequeue_wait``, ``dispatch_gap`` (host
+    time between step dispatches), ``sync_wait`` (blocked on the device),
+    ``serve.decode.readback`` ... Each stage keeps a total, a count, its
+    largest sample and a bounded ring of its newest samples
+    (:class:`Sample`, stamped with ``time.perf_counter()``), from which a
+    median, a cut by time and a sum per step can be taken.
+    ``summary()`` is the breakdown bench.py and ``run_training`` report;
+    ``timed()`` also enters a ``jax.profiler.TraceAnnotation`` of the
+    stage's name, so a device trace shows the same spans on its clock.
     """
 
-    def __init__(self):
+    def __init__(self) -> None:
         self._lock = threading.Lock()
         self._total: Dict[str, float] = {}
         self._count: Dict[str, int] = {}
+        self._max: Dict[str, float] = {}
+        self._ring: Dict[str, Deque[Sample]] = {}
 
-    def add(self, stage: str, seconds: float) -> None:
+    def add(self, stage: str, seconds: float, start: Optional[float] = None,
+            span: Optional[int] = None, **attrs: Any) -> None:
+        if start is None:
+            start = time.perf_counter() - seconds
+        sample = Sample(start, seconds, span, attrs)
         with self._lock:
-            self._total[stage] = self._total.get(stage, 0.0) + seconds
-            self._count[stage] = self._count.get(stage, 0) + 1
+            ring = self._ring.get(stage)
+            if ring is None:
+                ring = self._ring[stage] = deque(maxlen=RING_DEPTH)
+                self._total[stage], self._count[stage] = 0.0, 0
+                self._max[stage] = seconds
+            self._total[stage] += seconds
+            self._count[stage] += 1
+            if seconds > self._max[stage]:
+                self._max[stage] = seconds
+            ring.append(sample)
 
-    @contextmanager
-    def timed(self, stage: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(stage, time.perf_counter() - t0)
+    def timed(self, stage: str, span: Optional[int] = None,
+              **attrs: Any) -> _Timed:
+        """Time a ``with`` block as one sample of ``stage``. Blocks
+        nested inside it on the same thread carry its span id (or
+        ``span``, where the caller has an id of its own: the runner's
+        step number), so a step's phases can be summed."""
+        return _Timed(self, stage, span, attrs)
 
     def summary(self) -> Dict[str, Dict[str, float]]:
         with self._lock:
@@ -325,14 +434,97 @@ class StageTimes:
                     "count": self._count[stage],
                     "mean_ms": round(
                         self._total[stage] * 1e3 / self._count[stage], 3),
+                    "max_ms": round(self._max[stage] * 1e3, 3),
                 }
                 for stage in sorted(self._total)
             }
+
+    def samples(self, stage: str, since: Optional[float] = None,
+                until: Optional[float] = None) -> List[Sample]:
+        """The ring's samples of ``stage`` that lie wholly inside
+        ``[since, until]`` on ``time.perf_counter()``, oldest first."""
+        with self._lock:
+            ring = list(self._ring.get(stage, ()))
+        return [s for s in ring
+                if (since is None or s.start >= since)
+                and (until is None or s.start + s.seconds <= until)]
+
+    def by_span(self, stages: Iterable[str], since: Optional[float] = None,
+                until: Optional[float] = None
+                ) -> Dict[Optional[int], Dict[str, float]]:
+        """Seconds of each of ``stages`` summed per span id: one step's
+        (or one request's) phases side by side."""
+        out: Dict[Optional[int], Dict[str, float]] = {}
+        for stage in stages:
+            for s in self.samples(stage, since, until):
+                row = out.setdefault(s.span, {})
+                row[stage] = row.get(stage, 0.0) + s.seconds
+        return out
+
+    def _newest(self, stage: str, n: int) -> List[float]:
+        with self._lock:
+            ring = self._ring.get(stage, ())
+            return [s.seconds for s in itertools.islice(reversed(ring), n)]
+
+    def stats(self, stage: str) -> Dict[str, float]:
+        """``{p50, p90, p99, mean, count}`` in seconds over the stage's
+        newest ``RECENT`` samples; empty where it has none."""
+        vals = sorted(self._newest(stage, RECENT))
+        if not vals:
+            return {}
+        return {"p50": round(_quantile(vals, 0.50), 6),
+                "p90": round(_quantile(vals, 0.90), 6),
+                "p99": round(_quantile(vals, 0.99), 6),
+                "mean": round(sum(vals) / len(vals), 6),
+                "count": len(vals)}
+
+    def p50(self, stage: str) -> float:
+        return _quantile(sorted(self._newest(stage, RECENT)), 0.50)
+
+    def excess(self, stage: str) -> Optional[float]:
+        """By how much the stage's newest sample stands out from the
+        running median of those before it, or None where it does not
+        (``STALL_FACTOR``). One comparison for an ordinary sample."""
+        with self._lock:
+            ring = self._ring.get(stage)
+            if not ring or ring[-1].seconds <= STALL_FLOOR_S \
+                    or len(ring) <= STALL_MIN_SAMPLES:
+                return None
+            recent = [s.seconds for s in itertools.islice(
+                reversed(ring), RECENT + 1)]
+        median = _quantile(sorted(recent[1:]), 0.50)
+        if recent[0] <= STALL_FACTOR * median + STALL_FLOOR_S:
+            return None
+        return recent[0] - median
 
     def reset(self) -> None:
         with self._lock:
             self._total.clear()
             self._count.clear()
+            self._max.clear()
+            self._ring.clear()
+
+
+_exported_lock = threading.Lock()
+_exported: Dict[str, StageTimes] = {}
+
+
+def export_stage_times(label: str, times: StageTimes) -> StageTimes:
+    """Make ``times`` findable under ``label`` by whoever runs in the
+    same process and only reads: an exporter of metrics, a benchmark's
+    reader. Its owner (one ``run_training`` call under ``"train"``, one
+    serving engine under its own label, ``"serve"`` unless told
+    otherwise) goes on holding and filling it; a later owner under the
+    same label takes the label over. Returns ``times``."""
+    with _exported_lock:
+        _exported[label] = times
+    return times
+
+
+def stage_times(label: str) -> Optional[StageTimes]:
+    """The accumulator last exported under ``label``, or None."""
+    with _exported_lock:
+        return _exported.get(label)
 
 
 class profile_steps:
@@ -341,8 +533,8 @@ class profile_steps:
     >>> prof = profile_steps()        # reads TPUJOB_PROFILE_DIR/_STEPS
     >>> for step in range(n):
     ...     prof.before(step)
-    ...     state, _ = train_step(state, batch)
-    ...     prof.after(step)
+    ...     state, metrics = train_step(state, batch)
+    ...     prof.after(step, sync_on=metrics)
 
     Captures device + host traces for steps in [start, stop) into
     ``profile_dir`` (default window: steps 10:13 once a dir is set).
@@ -377,10 +569,17 @@ class profile_steps:
             jax.profiler.start_trace(self.dir)
             self._active = True
 
-    def after(self, step: int, span: int = 1) -> None:
+    def after(self, step: int, span: int = 1, sync_on: Any = None) -> None:
+        """``sync_on``: an output of the step just dispatched. Dispatch
+        is asynchronous, so the device may be a whole log interval
+        behind the host here; the capture waits for the window's last
+        step to have RUN before it stops, or it ends before the steps it
+        names (PERF.md section 6)."""
         if self._active and step + span >= self.stop:
             import jax
 
+            if sync_on is not None:
+                jax.block_until_ready(sync_on)
             jax.profiler.stop_trace()
             self._active = False
 

@@ -266,7 +266,8 @@ def selftest_worker_text() -> str:
     the straggler counter) and return its exposition — previously this
     endpoint shipped UNVALIDATED while only the operator scrape was
     gated."""
-    from paddle_operator_tpu.obs import StepProfiler, WorkerMetricsServer
+    from paddle_operator_tpu.obs import WorkerMetricsServer, step_phase_stats
+    from paddle_operator_tpu.utils.trace import StageTimes
 
     srv = WorkerMetricsServer().start()
     try:
@@ -275,11 +276,12 @@ def selftest_worker_text() -> str:
                    loader_queue_depth=2, goodput_ratio=0.85)
         srv.set_stage_summary({"batch_build": {"ms": 10.0, "count": 12,
                                                "mean_ms": 0.83}})
-        prof = StepProfiler()
+        times = StageTimes()
         for i in range(8):
-            prof.record(i, data_wait=0.001 * i, dispatch=0.01,
-                        checkpoint=0.002)
-        srv.set_step_stats(prof.stats())
+            times.add("data_wait", 0.001 * i, span=i)
+            times.add("step_dispatch", 0.01, span=i)
+            times.add("checkpoint", 0.002, span=i)
+        srv.set_step_stats(step_phase_stats(times))
         srv.set_badput({"data_stall": 0.004, "checkpoint": 0.016,
                         'evil"cause\\x': 0.001})
         srv.inc("tpujob_straggler_total")
@@ -394,8 +396,12 @@ def selftest_serving_text() -> str:
     plane's ``tpujob_serve_*`` exposition."""
     from paddle_operator_tpu.serving import Request, ServeMetrics
     from paddle_operator_tpu.serving.metrics import OUTCOMES
+    from paddle_operator_tpu.utils.trace import StageTimes
 
-    m = ServeMetrics(job='default/evil"serve\\x')
+    stages = StageTimes()
+    stages.add("serve.decode.wait", 0.031)
+    stages.add('serve.evil"stage\\x', 0.002)
+    m = ServeMetrics(job='default/evil"serve\\x', stages=stages)
     ok = Request("r0", prompt=[1, 2, 3], max_new_tokens=4)
     ok.t_arrival, ok.t_admitted = 0.0, 0.25
     ok.t_first_token, ok.t_done = 0.5, 1.1
@@ -413,7 +419,10 @@ def selftest_serving_text() -> str:
                 "tpujob_serve_queue_depth",
                 "tpujob_serve_replicas",
                 "tpujob_serve_ttft_seconds",
-                "tpujob_serve_tpot_seconds"):
+                "tpujob_serve_tpot_seconds",
+                "tpujob_serve_stage_seconds_total",
+                "tpujob_serve_stage_calls_total",
+                "tpujob_serve_stage_max_seconds"):
         assert "# TYPE %s" % fam in text, "serving selftest lost %s" % fam
     assert 'outcome="shed_overflow"} 1' in text, \
         "an outcome label fell out of the requests counter"

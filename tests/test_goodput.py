@@ -9,13 +9,13 @@ import pytest
 
 from paddle_operator_tpu.api import types as api
 from paddle_operator_tpu.obs import (
-    GoodputLedger, JobMetrics, SloEvaluator, SloSpec, StepProfiler,
+    GoodputLedger, JobMetrics, SloEvaluator, SloSpec,
     StragglerDetector, ThroughputBaseline, WorkerMetricsServer,
-    parse_exposition, parse_slo_spec,
+    parse_exposition, parse_slo_spec, step_phase_stats,
 )
 from paddle_operator_tpu.testing import OperatorHarness
 from paddle_operator_tpu.utils import trace as trace_mod
-from paddle_operator_tpu.utils.trace import Tracer
+from paddle_operator_tpu.utils.trace import StageTimes, Tracer
 
 sys.path.insert(0, "scripts")  # tests/conftest.py puts repo root first
 from obs_report import (  # noqa: E402
@@ -286,17 +286,21 @@ def test_obs_state_bounded_under_job_churn():
 # ---------------------------------------------------------------------------
 
 class TestStepProfiler:
-    def test_ring_is_bounded_and_stats(self):
-        prof = StepProfiler(depth=16)
+    def test_ring_is_bounded_and_stats(self, monkeypatch):
+        monkeypatch.setattr(trace_mod, "RING_DEPTH", 16)
+        times = StageTimes()
         for i in range(100):
-            prof.record(i, dispatch=0.01 * (i % 4 + 1), data_wait=0.001)
-        assert len(prof) == 16
-        stats = prof.stats()
+            times.add("step_dispatch", 0.01 * (i % 4 + 1), span=i)
+            times.add("data_wait", 0.001, span=i)
+        assert len(times.samples("step_dispatch")) == 16
+        stats = step_phase_stats(times)
         assert stats["dispatch"]["count"] == 16
         assert 0.01 <= stats["dispatch"]["p50"] <= 0.04
         assert stats["dispatch"]["p99"] >= stats["dispatch"]["p50"]
-        assert prof.p50("dispatch") == stats["dispatch"]["p50"]
-        assert prof.p50("missing") == 0.0
+        assert times.p50("step_dispatch") == stats["dispatch"]["p50"]
+        assert times.p50("missing") == 0.0
+        # totals and counts go on past the ring
+        assert times.summary()["step_dispatch"]["count"] == 100
 
 
 class TestStragglerDetector:
@@ -536,11 +540,13 @@ def test_waterfall_rebuilt_from_trace_alone(tmp_path, monkeypatch):
 def test_worker_metrics_new_families_strict():
     srv = WorkerMetricsServer()
     try:
-        prof = StepProfiler()
+        times = StageTimes()
         for i in range(6):
-            prof.record(i, dispatch=0.02, data_wait=0.001, d2h=0.0005)
+            times.add("step_dispatch", 0.02, span=i)
+            times.add("data_wait", 0.001, span=i)
+            times.add("d2h", 0.0005, span=i)
         srv.update(steps_total=6, goodput_ratio=0.9)
-        srv.set_step_stats(prof.stats())
+        srv.set_step_stats(step_phase_stats(times))
         srv.set_badput({"data_stall": 0.006, "compile": 1.2})
         srv.inc("tpujob_straggler_total", 2)
         text = srv.metrics_text()

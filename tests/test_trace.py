@@ -159,3 +159,35 @@ def test_profile_window_intersects_fused_span(tmp_path, monkeypatch):
     assert calls == [("start", str(tmp_path))]  # 11 < stop: still tracing
     p2.after(11)
     assert calls[-1][0] == "stop"
+
+
+def test_profile_steps_stops_at_a_sync(tmp_path, monkeypatch):
+    """Dispatch is asynchronous: the capture waits for the output of its
+    window's last step before it stops, so it holds the steps it names
+    (PERF.md section 6 found it ending before they had run)."""
+    calls = []
+
+    class FakeProfiler:
+        @staticmethod
+        def start_trace(d):
+            calls.append("start")
+
+        @staticmethod
+        def stop_trace():
+            calls.append("stop")
+
+    monkeypatch.setattr(jax, "profiler", FakeProfiler)
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(("sync", x)))
+    prof = profile_steps(profile_dir=str(tmp_path), window="1:3")
+    for step in range(5):
+        prof.before(step)
+        prof.after(step, sync_on="metrics-of-step-%d" % step)
+    # one wait, on the window's last step, between start and stop
+    assert calls == ["start", ("sync", "metrics-of-step-2"), "stop"]
+    # without an output to wait on it stops as before
+    calls.clear()
+    prof = profile_steps(profile_dir=str(tmp_path), window="0:1")
+    prof.before(0)
+    prof.after(0)
+    assert calls == ["start", "stop"]
