@@ -123,7 +123,9 @@ def loss_fn(params, batch, train=True, dtype=jnp.bfloat16, remat: bool = False,
     to any loss function that declares the argument). On the TPU backend
     ``attn_impl="auto"`` then runs the flash kernel per device shard
     (:func:`parallel.sharded_flash_attention`): called bare under a
-    mesh-wide jit the kernel does not lower at all.
+    mesh-wide jit the kernel does not lower at all. The chunked loss
+    runs per ``dp`` shard for the same reason in kind: the partitioner
+    cannot split its scan (:func:`ops.nn.chunked_lm_xent`).
 
     Labels are input_ids shifted left; the final position is dropped. A
     ``loss_mask`` (e.g. padding) applies to the *label* position.
@@ -152,7 +154,7 @@ def loss_fn(params, batch, train=True, dtype=jnp.bfloat16, remat: bool = False,
                                  attn_impl=attn_impl)
         loss, acc = nn.chunked_lm_xent(
             params["lm_head"], hidden[:, :-1], labels, mask=mask,
-            chunk=ce_chunk, dtype=dtype)
+            chunk=ce_chunk, dtype=dtype, mesh=mesh)
         loss = loss + moe_aux_weight * moe_aux
         return loss, {"accuracy": acc, "moe_aux": moe_aux}
 

@@ -2,12 +2,17 @@
 
 from __future__ import annotations
 
+import functools
+import logging
 import math
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
+
+log = logging.getLogger("tpujob.nn")
 
 
 # ---------------------------------------------------------------------------
@@ -324,48 +329,30 @@ def accuracy(logits, labels):
     return jnp.mean((jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32))
 
 
-def chunked_lm_xent(head_params, hidden, labels, mask=None,
-                    chunk: int = 1024, dtype=jnp.bfloat16):
-    """Cross-entropy through a big-vocab LM head WITHOUT materializing the
-    full ``[tokens, vocab]`` logits tensor.
+def _chunking(n: int, chunk: int) -> Tuple[int, int]:
+    """(number of chunks, chunk size) for ``n`` tokens: the last chunk is
+    padded, and fewer tokens than ``chunk`` make one chunk of ``n``."""
+    chunk = max(1, min(chunk, n))
+    return -(-n // chunk), chunk
 
-    The dense path stores fp32 logits plus their backward residuals —
-    at GPT scale (S=2048, V=50k) that is gigabytes of HBM per batch and
-    the dominant memory (and bandwidth) cost of the loss. Measured
-    (scripts/perf_ce_chunk.py, XLA memory_analysis + readback-synced
-    timing): at B=2/S=512/V=32k the chunked step needs 262 MB less XLA
-    temp memory (1.62x) and runs ~1.5x faster than the dense loss; the
-    bench's gpt stage (BENCH_GPT_CE_COMPARE) records the same on-TPU
-    comparison at full scale. Here tokens are
-    processed in ``chunk``-sized slices under ``jax.checkpoint``: the
-    forward keeps only per-token scalars (logsumexp, picked logit,
-    argmax-correct), and the backward recomputes each chunk's logits from
-    ``(hidden_chunk, W)`` — the same FLOPs-for-memory trade flash
-    attention makes for S^2 scores. Peak extra memory: O(chunk * vocab).
 
-    Args:
-      head_params: dense-layer params ``{"kernel": [D, V], ...}``.
-      hidden: ``[..., D]`` activations entering the LM head.
-      labels: int ids, shape = hidden.shape[:-1].
-      mask: optional float weights on label positions (same shape).
-    Returns:
-      (mean_loss fp32, accuracy fp32) over masked positions — matching
-      ``softmax_cross_entropy`` + ``accuracy`` on the dense path.
-    """
+def _lm_xent_sums(head_params, hidden, labels, mask, chunk, dtype):
+    """The local part of :func:`chunked_lm_xent`: ``(loss_sum, acc_sum,
+    mask_sum)`` over the rows it is handed, one ``jax.checkpoint``ed chunk
+    of tokens at a time under ``lax.scan``."""
     d = hidden.shape[-1]
     flat_h = hidden.reshape(-1, d)
     flat_l = labels.reshape(-1)
     n = flat_h.shape[0]
     flat_m = (jnp.ones((n,), jnp.float32) if mask is None
               else mask.reshape(-1).astype(jnp.float32))
-    chunk = max(1, min(chunk, n))
-    pad = (-n) % chunk
+    n_chunks, chunk = _chunking(n, chunk)
+    pad = n_chunks * chunk - n
     if pad:
         flat_h = jnp.concatenate(
             [flat_h, jnp.zeros((pad, d), flat_h.dtype)])
         flat_l = jnp.concatenate([flat_l, jnp.zeros((pad,), flat_l.dtype)])
         flat_m = jnp.concatenate([flat_m, jnp.zeros((pad,), jnp.float32)])
-    n_chunks = flat_h.shape[0] // chunk
     hc = flat_h.reshape(n_chunks, chunk, d)
     lc = flat_l.reshape(n_chunks, chunk)
     mc = flat_m.reshape(n_chunks, chunk)
@@ -394,5 +381,66 @@ def chunked_lm_xent(head_params, hidden, labels, mask=None,
 
     (loss_sum, acc_sum), _ = jax.lax.scan(
         body, (jnp.float32(0), jnp.float32(0)), (hc, lc, mc))
-    denom = jnp.maximum(jnp.sum(flat_m), 1.0)
+    return loss_sum, acc_sum, jnp.sum(flat_m)
+
+
+def chunked_lm_xent(head_params, hidden, labels, mask=None,
+                    chunk: int = 1024, dtype=jnp.bfloat16,
+                    mesh=None, batch_axis: str = "dp"):
+    """Cross-entropy through a big-vocab LM head WITHOUT materializing the
+    full ``[tokens, vocab]`` logits tensor.
+
+    The dense path stores fp32 logits plus their backward residuals: at
+    GPT scale (S=1024, V=50k) gigabytes of HBM a batch. Here tokens are
+    processed in ``chunk``-sized slices under ``jax.checkpoint``: the
+    forward keeps only per-token scalars (logsumexp, picked logit,
+    argmax-correct), and the backward recomputes each chunk's logits from
+    ``(hidden_chunk, W)`` — the same FLOPs-for-memory trade flash
+    attention makes for S^2 scores. Peak extra memory: O(chunk * vocab).
+    On the chip the two loops (forward, backward) are 191 ms = 25% of
+    GPT-2 small's 771 ms step at 64 x 1024 (PERF.md section 5).
+
+    ``mesh``: the mesh the caller's step is jitted over. The tokens are
+    flattened batch-first and scanned chunk by chunk, so the scanned axis
+    is the data-parallel one, and GSPMD cannot partition a scan over its
+    own leading axis: it all-gathers hidden states, labels and mask to
+    every device, forward and backward, and every device loops over the
+    whole batch (PERF.md section 5: four chips gave one chip's
+    throughput). So where ``mesh`` has ``batch_axis`` with a size > 1 that
+    divides the batch, the loops run per shard under ``shard_map``, manual
+    over ``batch_axis`` ONLY, and three scalars are ``psum``med; any other
+    axis (a ``tp``-sharded head kernel) stays with GSPMD. Otherwise the
+    traced program is the unsharded one, operation for operation.
+
+    Args:
+      head_params: dense-layer params ``{"kernel": [D, V], ...}``.
+      hidden: ``[..., D]`` activations entering the LM head.
+      labels: int ids, shape = hidden.shape[:-1].
+      mask: optional float weights on label positions (same shape).
+    Returns:
+      (mean_loss fp32, accuracy fp32) over masked positions — matching
+      ``softmax_cross_entropy`` + ``accuracy`` on the dense path.
+    """
+    shards = mesh.shape.get(batch_axis, 1) if mesh is not None else 1
+    if shards > 1 and hidden.shape[0] % shards == 0:
+        if mask is None:
+            mask = jnp.ones(labels.shape, jnp.float32)
+        rows = P(batch_axis)
+
+        @functools.partial(
+            jax.shard_map, mesh=mesh, in_specs=(P(), rows, rows, rows),
+            out_specs=P(), axis_names={batch_axis}, check_vma=False)
+        def sums(hp, h, l, m):
+            return lax.psum(
+                _lm_xent_sums(hp, h, l, m, chunk, dtype), batch_axis)
+
+        loss_sum, acc_sum, mask_sum = sums(head_params, hidden, labels, mask)
+        log.info("chunked_lm_xent: %d shards over '%s', %d chunks of %d a "
+                 "shard", shards, batch_axis,
+                 *_chunking(math.prod(labels.shape) // shards, chunk))
+    else:
+        loss_sum, acc_sum, mask_sum = _lm_xent_sums(
+            head_params, hidden, labels, mask, chunk, dtype)
+        log.info("chunked_lm_xent: unsharded")
+    denom = jnp.maximum(mask_sum, 1.0)
     return loss_sum / denom, acc_sum / denom

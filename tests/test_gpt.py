@@ -2,6 +2,9 @@
 sequence-parallel integration, training convergence."""
 
 import functools
+import logging
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +14,8 @@ import pytest
 from paddle_operator_tpu.models import gpt
 from paddle_operator_tpu.ops import attention_pallas, nn, optim
 from paddle_operator_tpu.parallel import (
-    build_train_step, gpt_rules, make_mesh, moe_rules, ring_attention,
+    P, build_train_step, gpt_rules, make_mesh, moe_rules, named,
+    ring_attention, shard_tree,
 )
 
 KEY = jax.random.PRNGKey(0)
@@ -228,3 +232,158 @@ def test_chunked_ce_matches_dense():
     m_d = gpt.loss_fn(params, batch)[1]
     m_c = gpt.loss_fn(params, batch, ce_chunk=24)[1]
     assert abs(float(m_d["accuracy"]) - float(m_c["accuracy"])) < 1e-5
+
+
+# ---------------------------------------------------------------------------
+# chunked LM-head loss under a mesh: per data-parallel shard (PR 25)
+# ---------------------------------------------------------------------------
+
+CE_MESHES = {
+    "dp4": ({"dp": 4}, 8),
+    "dp2tp2": ({"dp": 2, "tp": 2}, 8),
+    "dp1": ({"dp": 1}, 8),
+    "none": (None, 8),
+    "dp4-6rows": ({"dp": 4}, 6),   # 6 % 4: falls back to the unsharded loops
+}
+
+
+def _ce_mesh(axes):
+    if axes is None:
+        return None
+    return make_mesh(axes, devices=jax.devices()[:math.prod(axes.values())])
+
+
+def _ce_case(rows):
+    """Params, a batch whose mask zeroes rows 0 and 1 whole (all of dp=4's
+    first shard: only the GLOBAL mask sum is the right denominator)."""
+    params = gpt.init(jax.random.PRNGKey(0), gpt.TINY_CONFIG)
+    batch = gpt.synthetic_batch(jax.random.PRNGKey(1), rows, 32, 1024)
+    mask = (jax.random.uniform(jax.random.PRNGKey(2), (rows, 32)) > 0.2
+            ).astype(jnp.float32)
+    batch["loss_mask"] = mask.at[:2].set(0.0)
+    return params, batch
+
+
+def _place(params, batch, mesh):
+    """Params by ``gpt_rules``, batch rows over ``dp`` where they divide."""
+    if mesh is None:
+        return params, batch
+    params = jax.device_put(params, shard_tree(params, mesh, gpt_rules()))
+    rows = batch["input_ids"].shape[0]
+    spec = P("dp") if rows % mesh.shape["dp"] == 0 else P()
+    return params, jax.device_put(batch, named(mesh, spec))
+
+
+@pytest.mark.parametrize("case", list(CE_MESHES))
+def test_chunked_ce_under_mesh_matches_dense(case):
+    """``loss_fn(ce_chunk=24, mesh=mesh)`` == the unsharded dense path in
+    loss, accuracy and every gradient, whatever the mesh: the loops run
+    per ``dp`` shard and the mean is over the global mask sum."""
+    axes, rows = CE_MESHES[case]
+    mesh = _ce_mesh(axes)
+    params, batch = _ce_case(rows)
+
+    (l_d, m_d), g_d = jax.value_and_grad(
+        lambda p: gpt.loss_fn(p, batch), has_aux=True)(params)
+
+    sp, sb = _place(params, batch, mesh)
+    (l_c, m_c), g_c = jax.jit(jax.value_and_grad(
+        lambda p, b: gpt.loss_fn(p, b, ce_chunk=24, mesh=mesh),
+        has_aux=True))(sp, sb)
+
+    assert abs(float(l_d) - float(l_c)) < 1e-3, (float(l_d), float(l_c))
+    assert abs(float(m_d["accuracy"]) - float(m_c["accuracy"])) < 1e-5
+    for a, b in zip(jax.tree_util.tree_leaves(g_d),
+                    jax.tree_util.tree_leaves(g_c)):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            atol=2e-2, rtol=2e-2)
+
+
+def _gathered_shapes(hlo_text):
+    """(dtype, dims) of every array an ``all-gather`` of the compiled text
+    returns (a combined gather returns a tuple)."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = re.search(r"= (.*?) all-gather(?:-start)?\(", line)
+        if m:
+            out += [(t, tuple(int(x) for x in dims.split(",") if x))
+                    for t, dims in re.findall(r"(\w+)\[([\d,]*)\]", m.group(1))]
+    return out
+
+
+def _loss_gathers(shapes, width):
+    """The gathers the unpartitioned scan costs: activations (last
+    dimension the hidden width) and the integer labels."""
+    return [s for s in shapes
+            if (s[1] and s[1][-1] == width) or s[0].startswith("s")]
+
+
+@pytest.mark.parametrize("case", ["dp4", "dp4-gspmd", "dp1", "none"])
+def test_chunked_ce_compiled_program(case):
+    """What the partitioner makes of the chunked loss. ``dp4``: with the
+    batch sharded ``P("dp")`` the compiled step holds NO all-gather of the
+    hidden states (last dimension = hidden width) and none of the labels.
+    The same check failed on the parent of PR 25: ``lax.scan`` over the
+    flattened ``[n_chunks, chunk, d]`` scans the data-parallel axis, which
+    GSPMD cannot partition, so it compiled to two ``all-gather
+    f32[n_chunks, chunk, d]`` and two ``all-gather s32[n_chunks, chunk]``
+    and every device looped over the whole batch (on the v5e: 4 chips gave
+    1 chip's throughput). ``dp4-gspmd`` keeps that observation alive: the
+    local loops handed to GSPMD (no ``mesh``) still gather, so the check is
+    not vacuous; the day it fails, XLA has learned to partition the scan
+    and the ``shard_map`` can go. ``dp1`` / ``none``: the wrapper is not
+    entered and the lowered text equals the call without ``mesh``."""
+    width = gpt.TINY_CONFIG["hidden"]
+    axes, rows = CE_MESHES["dp4" if case.startswith("dp4") else case]
+    mesh = _ce_mesh(axes)
+    params, batch = _place(*_ce_case(rows), mesh)
+
+    def lowered(m):
+        return jax.jit(jax.value_and_grad(
+            lambda p, b: gpt.loss_fn(p, b, ce_chunk=24, mesh=m)[0])
+        ).lower(params, batch)
+
+    if case == "dp4":
+        assert _loss_gathers(
+            _gathered_shapes(lowered(mesh).compile().as_text()), width) == []
+    elif case == "dp4-gspmd":
+        bad = _loss_gathers(
+            _gathered_shapes(lowered(None).compile().as_text()), width)
+        assert any(s[1][-1] == width for s in bad), bad
+        assert any(s[0] == "s32" for s in bad), bad
+    else:
+        assert lowered(mesh).as_text() == lowered(None).as_text()
+
+
+@pytest.mark.parametrize("accum_steps", [1, 2])
+def test_train_step_dp4_chunked_ce_matches_single_device(accum_steps, caplog):
+    """One ``build_train_step`` step at ``dp=4`` with the chunked loss ==
+    the single-device step (loss and updated parameters), also inside the
+    gradient-accumulation scan; the trace-time log names the 4 shards."""
+    mesh = _ce_mesh({"dp": 4})
+    _, batch = _ce_case(8)
+    if accum_steps > 1:
+        batch = jax.tree_util.tree_map(
+            lambda x: jnp.stack([x, x[::-1]]), batch)
+
+    def run(m):
+        # float32 throughout: what is left between the two is summation
+        # order (four partial sums added, not one running sum)
+        loss = functools.partial(
+            gpt.loss_fn, ce_chunk=24, mesh=m, dtype=jnp.float32)
+        step, state = build_train_step(
+            loss, optim.sgd(0.1), gpt.init(KEY, gpt.TINY_CONFIG), batch,
+            mesh=m, rules=gpt_rules(), accum_steps=accum_steps, cache=False)
+        state, metrics = step(state, batch)
+        return float(metrics["loss"]), jax.device_get(state["params"])
+
+    loss_1, params_1 = run(None)
+    with caplog.at_level(logging.INFO, logger="tpujob.nn"):
+        loss_4, params_4 = run(mesh)
+    assert "chunked_lm_xent: 4 shards over 'dp'" in caplog.text
+    assert "unsharded" not in caplog.text
+    assert abs(loss_1 - loss_4) < 1e-5, (loss_1, loss_4)
+    for a, b in zip(jax.tree_util.tree_leaves(params_1),
+                    jax.tree_util.tree_leaves(params_4)):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
