@@ -5,8 +5,9 @@ TPU devices JAX finds, through the entry points pods use, at the full
 width of ``models/gpt.BASE_CONFIG`` (GPT-2 small: 768 wide, 12 layers,
 12 heads, vocabulary 50304, 1024 positions; random weights from a seed):
 
-1. kernel checks — ``flash_attention`` forward and gradients and
-   ``paged_decode_attention`` against their references, compiled
+1. kernel checks — ``flash_attention`` forward and gradients,
+   ``paged_decode_attention`` and ``mla_paged_decode`` (at the shapes of
+   the ``axk1-share16`` cell) against their references, compiled
    (``interpret=False``), each under a written tolerance;
 2. trainer — the ``TrainJob`` of ``examples/train_gpt.py`` through
    ``launch.detect_env`` + ``runner.run_training`` over all local
@@ -53,6 +54,7 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 # gradients accumulate a few), f32 paged decode only reassociates sums
 FLASH_FWD_TOL = 2e-2      # max |out - ref| / max |ref|
 FLASH_GRAD_TOL = 4e-2
+MLA_TOL = 2e-2        # bfloat16 pages, probabilities and result
 PAGED_TOL = 1e-4
 
 
@@ -146,6 +148,31 @@ def kernel_checks(sm: Smoke) -> None:
            pages="x".join(map(str, kp.shape)), dtype="f32",
            interpret=interpret, rel_err="%.2e" % err, tol=PAGED_TOL)
     sm.check(err <= PAGED_TOL, "paged_decode_attention error %g" % err)
+
+    # latent paged decode at the shapes of axk1-share16.serve-decode-1k:
+    # 64 rows x 64 heads over bfloat16 pages of 128 rows [512 | 64 | pad]
+    b, h, c, r, bs = (4, 4, 16, 8, 8) if sm.rehearsal \
+        else (64, 64, 512, 64, 128)
+    layers, pages, per_seq = (2, 17, 4) if sm.rehearsal else (2, 513, 36)
+    width = -(-(c + r) // 128) * 128
+    keys = jax.random.split(jax.random.PRNGKey(sm.seed + 2), 5)
+    q_lat = jax.random.normal(keys[0], (b, h, c), jnp.bfloat16)
+    q_rope = jax.random.normal(keys[1], (b, h, r), jnp.bfloat16)
+    pool = jax.random.normal(keys[2], (layers, pages, bs, width),
+                             jnp.bfloat16)
+    tables = jax.random.randint(keys[3], (b, per_seq), 0, pages - 1)
+    lens = jax.random.randint(keys[4], (b,), 1, per_seq * bs + 1)
+    scale = (c + r) ** -0.5
+    got = jax.jit(lambda *a: ap.mla_paged_decode(
+        *a, scale, layer=1, interpret=interpret))(
+            q_lat, q_rope, pool, tables, lens)
+    want = jax.jit(lambda *a: ap._reference_mla_paged_decode(
+        *a, scale))(q_lat, q_rope, pool[1], tables, lens)
+    err = rel_err(got, want)
+    sm.say("kernel mla_paged_decode", q_lat="x".join(map(str, q_lat.shape)),
+           pool="x".join(map(str, pool.shape)), dtype="bf16",
+           interpret=interpret, rel_err="%.2e" % err, tol=MLA_TOL)
+    sm.check(err <= MLA_TOL, "mla_paged_decode error %g" % err)
 
 
 # ---------------------------------------------------------------------------
@@ -334,7 +361,7 @@ def server_leg(sm: Smoke) -> None:
            joined_a_batch_of=joined_at, iterations=iterations,
            wall_s="%.1f" % wall, blocks_used=stats["blocks_used"],
            blocks_peak=stats["blocks_peak"],
-           prefill_source=engine._prefill_fn.source,
+           prefill_source=engine._prefill_fns[engine.prompt_pad].source,
            decode_source=engine._decode_fn.source)
     sm.check(joined_at == 4, "second wave met %d in flight" % joined_at)
     sm.check(batcher.counts()["completed"] == len(requests),

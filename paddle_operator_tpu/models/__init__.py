@@ -5,9 +5,11 @@
 * GPT — decoder-only causal LM, the long-context flagship (RoPE + causal
   flash attention + ring/Ulysses sequence parallelism)
 * wide_and_deep / deepfm — PS-mode CTR models (deploy/examples/*.yaml)
+* axk1 — latent attention and sigmoid-routed experts beside a shared one,
+  as one chip's share of a wide expert-parallel deployment (serving only)
 
 All models are (init, apply) pure functions over dict pytrees, bf16 compute,
 built from `paddle_operator_tpu.ops.nn`.
 """
 
-from . import resnet, bert, gpt, wide_deep, deepfm  # noqa: F401
+from . import resnet, bert, gpt, wide_deep, deepfm, axk1  # noqa: F401

@@ -175,3 +175,166 @@ def synthetic_batch(key, batch_size: int, seq_len: int = 256,
                     vocab_size: int = 50304):
     ids = jax.random.randint(key, (batch_size, seq_len), 0, vocab_size)
     return {"input_ids": ids}
+
+
+# -- what the serving engine asks of a model's module -----------------------
+#
+# ``serving.engine.ServingEngine`` keeps the scheduling, the slots, the
+# block tables, page memory and the spans; the layer stack, the cache's
+# layout and the two step functions come from the model's module
+# (``models.axk1`` has the same five). These are the bodies the engine
+# held inline before it took a second model, unchanged: float32
+# throughout, one padded prompt length, K and V pages per head.
+
+def _rope_rows(x: jnp.ndarray, positions: jnp.ndarray,
+               base: float = 10000.0) -> jnp.ndarray:
+    """Rotary embedding with PER-ROW positions: x [B, S, H, D],
+    positions [B, S]. Training's shared ``arange`` (ops.nn.rope) does not
+    apply to a mixed decode batch where every sequence sits at its own
+    depth."""
+    half = x.shape[-1] // 2
+    inv_freq = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq  # [B,S,half]
+    cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
+    sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
+
+
+def _qkv(layer: Dict, h: jnp.ndarray):
+    """The mha projections with the head axis explicit (ops.nn.mha_init
+    layout: kernels are [dim, heads, head_dim])."""
+    def proj(p: Dict) -> jnp.ndarray:
+        return jnp.einsum("bsd,dhk->bshk", h, p["kernel"]) + p["bias"]
+
+    attn = layer["attn"]
+    return proj(attn["q"]), proj(attn["k"]), proj(attn["v"])
+
+
+def _ffn(layer: Dict, x: jnp.ndarray) -> jnp.ndarray:
+    z = nn.layernorm(layer["ln2"], x, dtype=jnp.float32)
+    z = nn.dense(layer["mlp"]["fc1"], z, dtype=jnp.float32)
+    z = nn.gelu(z)
+    z = nn.dense(layer["mlp"]["fc2"], z, dtype=jnp.float32)
+    return x + z
+
+
+def serve_buckets(config: dict, prompt_pad: int):
+    """The padded prompt lengths prefill compiles for: one."""
+    del config
+    return (prompt_pad,)
+
+
+def serve_cache(config: dict, num_blocks: int, block_size: int):
+    """K and V pages per layer and head, float32."""
+    from ..serving.kv_cache import PagedKvCache
+
+    if config.get("moe_experts"):
+        raise ValueError(
+            "models.gpt serves no expert configuration: its Switch layer "
+            "drops tokens over capacity and has no decode path; an expert "
+            "layer that is served is models.axk1's (ops.moe.moe_share_apply)")
+    heads = config["heads"]
+    return PagedKvCache(num_blocks, block_size, layers=config["layers"],
+                        heads=heads, head_dim=config["hidden"] // heads,
+                        dtype=jnp.float32)
+
+
+def serve_prefill(config: dict, pad: int):
+    del config
+    import math
+
+    def prefill(params, ids: jnp.ndarray, length: jnp.ndarray):
+        """ids [1, pad] zero-padded, length [] int32 -> (first
+        sampled token [] int32, ([k per layer], [v per layer])) with
+        k/v shaped [pad, H, Dh] (callers slice to the real length).
+        Plain causal attention — prefill sees the whole prompt, so
+        the training-style full-sequence path is exactly right."""
+        x = nn.embedding(params["embed"]["tok"], ids, jnp.float32)
+        positions = jnp.arange(pad)[None, :]
+        cmask = jnp.tril(jnp.ones((pad, pad), bool))[None, None]
+        ks, vs = [], []
+        for layer in params["layers"]:
+            h = nn.layernorm(layer["ln1"], x, dtype=jnp.float32)
+            q, k, v = _qkv(layer, h)
+            q = _rope_rows(q, positions)
+            k = _rope_rows(k, positions)
+            ks.append(k[0])
+            vs.append(v[0])
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) \
+                / math.sqrt(q.shape[-1])
+            scores = jnp.where(cmask, scores, -1e30)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), -1)
+            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+            y = jnp.einsum("bqhd,hdo->bqo", ctx,
+                           layer["attn"]["o"]["kernel"]) \
+                + layer["attn"]["o"]["bias"]
+            x = _ffn(layer, x + y)
+        x = nn.layernorm(params["final_ln"], x, dtype=jnp.float32)
+        last = x[0, length - 1]
+        logits = nn.dense(params["lm_head"], last[None],
+                          dtype=jnp.float32)[0]
+        return jnp.argmax(logits).astype(jnp.int32), (ks, vs)
+
+    return prefill
+
+
+def serve_decode(config: dict, attn: str, block_size: int, dummy_page: int):
+    del config
+    import math
+
+    bs, dummy = block_size, dummy_page
+
+    def decode(params, pools, tokens: jnp.ndarray, positions: jnp.ndarray,
+               tables: jnp.ndarray, lens: jnp.ndarray, live: jnp.ndarray):
+        """One token for every row: pools = (k_pages, v_pages), tokens
+        [B] int32 (each row's last sampled token), positions [B] (its
+        0-based index), tables [B, T], lens [B] (live cache tokens
+        BEFORE this step), live [B] bool (False = pad row). Returns
+        (next tokens [B], (new k_pages, v_pages), no counters)."""
+        from ..ops.attention_pallas import (
+            _reference_paged_decode, paged_decode_attention,
+        )
+
+        k_pages, v_pages = pools
+        x = nn.embedding(params["embed"]["tok"], tokens[:, None],
+                         jnp.float32)                       # [B,1,D]
+        pos2 = positions[:, None]
+        gathered = jnp.take_along_axis(
+            tables, (positions // bs)[:, None], axis=1)[:, 0]
+        # pad rows scatter into the reserved dummy page: every pad
+        # row writes the same value there (identical inert inputs),
+        # and no live block table can reference it
+        blocks = jnp.where(live, gathered, dummy)
+        slots = jnp.where(live, positions % bs, 0)
+        new_lens = lens + 1
+        new_k, new_v = [], []
+        for li, layer in enumerate(params["layers"]):
+            h = nn.layernorm(layer["ln1"], x, dtype=jnp.float32)
+            q, k, v = _qkv(layer, h)
+            q = _rope_rows(q, pos2)
+            k = _rope_rows(k, pos2)
+            kp = k_pages[li].at[blocks, slots].set(k[:, 0])
+            vp = v_pages[li].at[blocks, slots].set(v[:, 0])
+            new_k.append(kp)
+            new_v.append(vp)
+            if attn == "paged":
+                ctx = paged_decode_attention(
+                    q[:, 0], kp, vp, tables, new_lens,
+                    interpret=jax.default_backend() != "tpu")
+            else:
+                ctx = _reference_paged_decode(
+                    q[:, 0], kp, vp, tables, new_lens,
+                    1.0 / math.sqrt(q.shape[-1]))
+            y = jnp.einsum("bhd,hdo->bo", ctx.astype(jnp.float32),
+                           layer["attn"]["o"]["kernel"]) \
+                + layer["attn"]["o"]["bias"]
+            x = _ffn(layer, x + y[:, None])
+        x = nn.layernorm(params["final_ln"], x, dtype=jnp.float32)
+        logits = nn.dense(params["lm_head"], x[:, 0],
+                          dtype=jnp.float32)               # [B,V]
+        return (jnp.argmax(logits, -1).astype(jnp.int32),
+                (new_k, new_v), {})
+
+    return decode

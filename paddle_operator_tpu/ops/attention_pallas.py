@@ -639,3 +639,155 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
         name="paged_decode",
     )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
       q, k_pages, v_pages)
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA) paged decode attention
+# ---------------------------------------------------------------------------
+#
+# Multi-head latent attention caches ONE row a token for all heads:
+# [c_kv | k_rope], the compressed key/value (after its norm) beside the
+# shared rotary key. With the up-projection absorbed into the query
+# (q~_h = q_nope,h W^K_h^T) every head scores against the same row and
+# the context is a weighted sum of the same c_kv, so a page is read once
+# for all heads and both products are real matmuls ([H, 576] x [576, bs]
+# and [H, bs] x [bs, 512]) for the MXU. The grid is (sequence, page) as
+# in ``paged_decode``; pages past a sequence's last live one repeat that
+# page's index, so the pipeline fetches nothing new for them, and their
+# body is skipped.
+
+
+def _reference_mla_paged_decode(q_lat, q_rope, pages, block_tables,
+                                seq_lens, scale):
+    """Gather-then-einsum reference in float32: q_lat [B,H,C], q_rope
+    [B,H,R], pages [P,bs,W] with W >= C+R, block_tables [B,T], seq_lens
+    [B] -> [B,H,C]."""
+    b, h, c = q_lat.shape
+    bs = pages.shape[1]
+    t = block_tables.shape[1]
+    width = c + q_rope.shape[-1]
+    rows = jnp.take(pages[..., :width], block_tables, axis=0).reshape(
+        b, t * bs, width).astype(jnp.float32)
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(jnp.float32)
+    s = jnp.einsum("bhw,bkw->bhk", q, rows,
+                   precision=jax.lax.Precision.HIGHEST) * scale
+    valid = jnp.arange(t * bs)[None, :] < seq_lens[:, None]
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhk,bkc->bhc", p, rows[..., :c],
+                      precision=jax.lax.Precision.HIGHEST
+                      ).astype(q_lat.dtype)
+
+
+def _mla_paged_decode_kernel(seq_lens_ref, tables_ref, layer_ref, q_ref,
+                             page_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                             scale, block_size, pages_per_seq, latent,
+                             operand_dtype):
+    del tables_ref, layer_ref          # read by the index maps
+    b = pl.program_id(0)
+    t = pl.program_id(1)
+
+    @pl.when(t == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(t * block_size < seq_lens_ref[b])
+    def _page():
+        q = q_ref[0].astype(operand_dtype)                   # [H, W]
+        page = page_ref[0, 0].astype(operand_dtype)          # [bs, W]
+        s = jax.lax.dot_general(
+            q, page, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # [H, bs]
+        pos = t * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(pos < seq_lens_ref[b], s, NEG_INF)
+        m_prev = m_ref[...]                                  # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * correction \
+            + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * correction + jnp.dot(
+            p.astype(page.dtype), page[:, :latent],
+            preferred_element_type=jnp.float32)              # [H, C]
+        m_ref[...] = m_new
+
+    @pl.when(t == pages_per_seq - 1)
+    def _write():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def mla_paged_decode(q_lat, q_rope, pages, block_tables, seq_lens,
+                     scale: float, layer=None, interpret: bool = False):
+    """Absorbed latent-attention decode over a paged latent cache.
+
+    q_lat ``[B, H, C]`` (the no-position query through the key
+    up-projection), q_rope ``[B, H, R]`` (rotated), pages ``[P, bs, W]``
+    rows ``[c_kv | k_rope | zeros]`` with ``W >= C+R`` (the cache rounds
+    a row up to whole 128-lane tiles, which the chip's tiled layout of a
+    row-major page occupies anyway; a pool whose minor axis is not a
+    multiple of 128 is given a token-minor layout by XLA and copied
+    whole before every call) — or ``[L, P, bs, W]`` with ``layer`` an
+    int32 scalar (traced or not) that picks the layer's pool without
+    slicing it out — block_tables ``[B, T]``, seq_lens ``[B]`` (at least
+    1). Returns ``sum_k p_k c_kv,k`` ``[B, H, C]`` in q_lat's type;
+    float32 online softmax, as :func:`_reference_mla_paged_decode` to
+    reassociation. Inference only."""
+    b, h, c = q_lat.shape
+    r = q_rope.shape[-1]
+    if pages.ndim == 3:
+        pages, layer = pages[None], 0
+    elif layer is None:
+        raise ValueError("pages %r hold several layers: say which"
+                         % (pages.shape,))
+    _, _, block_size, width = pages.shape
+    if width < c + r or q_rope.shape[:2] != (b, h):
+        raise ValueError(
+            "latent pages %r do not match q_lat %r + q_rope %r"
+            % (pages.shape, q_lat.shape, q_rope.shape))
+    if block_tables.shape[0] != b or seq_lens.shape != (b,):
+        raise ValueError(
+            "block_tables %r / seq_lens %r do not cover batch %d"
+            % (block_tables.shape, seq_lens.shape, b))
+    pages_per_seq = block_tables.shape[1]
+    q = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros((b, h, width - c - r), q_lat.dtype)],
+        axis=-1).astype(pages.dtype)
+
+    def q_index(bi, ti, lens_ref, tables_ref, layer_ref):
+        return (bi, 0, 0)
+
+    def page_index(bi, ti, lens_ref, tables_ref, layer_ref):
+        last = jnp.maximum(lens_ref[bi] - 1, 0) // block_size
+        return (layer_ref[0], tables_ref[bi, jnp.minimum(ti, last)], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, pages_per_seq),
+        in_specs=[
+            pl.BlockSpec((1, h, width), q_index),
+            pl.BlockSpec((1, 1, block_size, width), page_index),
+        ],
+        out_specs=pl.BlockSpec((1, h, c), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((h, c), jnp.float32),   # context accumulator
+            pltpu.VMEM((h, 1), jnp.float32),   # running max
+            pltpu.VMEM((h, 1), jnp.float32),   # running denominator
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_mla_paged_decode_kernel, scale=scale,
+                          block_size=block_size,
+                          pages_per_seq=pages_per_seq, latent=c,
+                          # the MXU takes the pages' own type; the CPU
+                          # the interpreter runs on has no such dot
+                          operand_dtype=jnp.float32 if interpret
+                          else pages.dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, c), q_lat.dtype),
+        interpret=interpret,
+        name="mla_paged_decode",
+    )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, pages)

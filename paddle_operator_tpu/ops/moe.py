@@ -23,6 +23,11 @@ formulations:
 Equivalence is tested in ``tests/test_fused_ops.py`` (forward and
 gradients, interpret mode on CPU). Rules (see
 ``parallel.sharding.moe_rules``): wi/wo shard P("ep", None, None).
+
+Both of those are what TRAINING uses. Serving a wide expert layer is
+:func:`moe_share_apply` at the end of the file: top-k over sigmoid
+scores, a shared expert, no capacity and no drop, and only the experts
+this chip holds of a layer that several chips share (``tests/test_axk1.py``).
 """
 
 from __future__ import annotations
@@ -385,3 +390,99 @@ def moe_apply_fused(params, x, capacity_factor: float = 1.25,
     out = _fused_combine(expert_out, gate_rep, choice_rep, pos_rep,
                          capacity, block_t, interpret, dtype)
     return out[:tokens].reshape(b, s, d), aux
+
+
+# ---------------------------------------------------------------------------
+# one chip's share of a wide expert layer (serving)
+# ---------------------------------------------------------------------------
+
+def moe_share_apply(params, z, held, top_k: int, scale: float = 1.0,
+                    live=None, layer=None, dtype=jnp.bfloat16,
+                    block: int = 256):
+    """The part of an expert layer that THIS chip computes when the
+    layer's routed experts are divided over several chips: it is told
+    which experts it holds, routes every token over ALL of them, and adds
+    up what its own experts give. What the experts held elsewhere would
+    add is left out, and nothing here stands in for those chips or for
+    the exchange with them. No capacity and no drop: every pair (token,
+    held expert) that the router makes is computed.
+
+    ``params``: ``router`` [D, R] over all R routed experts; ``gate`` /
+    ``up`` [G, D, F] and ``down`` [G, F, D], the G held experts in the
+    order of ``held`` (their ids among the R, static); ``shared``, one
+    gated MLP every chip holds and adds once. ``z`` [T, D]; ``live`` [T]
+    bool marks the rows that are tokens (padding routes nowhere). With
+    ``layer`` (an int32 scalar, traced under a scan over layers) the
+    three expert kernels carry the layers as a leading axis and are
+    indexed ``[layer, expert]`` where they are multiplied: sliced out
+    by the scan instead, a layer's experts are copied whole every step.
+
+    Routing (sigmoid scoring, plain top-k, gates normalised over the
+    chosen and scaled): ``s = sigmoid(z W_r)`` in float32, ``I = top_k(s)``,
+    ``g_i = scale * s_i / sum_{j in I} s_j``. The pairs routed to a held
+    expert are sorted by expert; each expert then multiplies its own
+    contiguous group, ``block`` rows at a time, in a loop whose trip
+    count is the group's size — so the work follows the pairs that are
+    here, not the T x top_k that a static shape would have to assume
+    (``jax.lax.ragged_dot`` takes that static count as its M). Plain
+    XLA; operands in ``dtype``, sums, scores and gates in float32.
+
+    Returns ``(out [T, D] float32, counters)`` with ``counters`` the
+    int32 scalars ``pairs_here`` (pairs computed on this chip) and
+    ``experts_hit`` (held experts with at least one pair)."""
+    t, _ = z.shape
+    held = tuple(int(e) for e in held)
+    g = len(held)
+    routed = params["router"].shape[-1]
+    scores = jax.nn.sigmoid(jnp.matmul(
+        z.astype(jnp.float32), params["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    top_s, top_i = jax.lax.top_k(scores, top_k)                    # [T, k]
+    gates = scale * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    # expert id -> its slot among the held, g for "held elsewhere"
+    slot_of = [g] * routed
+    for slot, expert in enumerate(held):
+        slot_of[expert] = slot
+    slot = jnp.asarray(slot_of, jnp.int32)[top_i]
+    if live is not None:
+        slot = jnp.where(live[:, None], slot, g)
+    slot = slot.reshape(t * top_k)
+    order = jnp.argsort(slot, stable=True)
+    counts = jnp.sum(slot[:, None] == jnp.arange(g, dtype=jnp.int32),
+                     axis=0, dtype=jnp.int32)                       # [G]
+    starts = jnp.cumsum(counts) - counts
+    # a token meets an expert at most once, so a group has at most T rows
+    rows = min(block, t)
+    pad = jnp.zeros((rows,), jnp.int32)
+    token_of = jnp.concatenate([(order // top_k).astype(jnp.int32), pad])
+    gate_of = jnp.concatenate([gates.reshape(t * top_k)[order],
+                               pad.astype(jnp.float32)])
+    zb = z.astype(dtype)
+
+    def kernels(e):
+        if layer is None:
+            return {k: jax.lax.dynamic_index_in_dim(params[k], e, 0, False)
+                    for k in ("gate", "up", "down")}
+        return {k: jax.lax.dynamic_slice(
+            params[k], (layer, e, 0, 0), (1, 1) + params[k].shape[2:])[0, 0]
+            for k in ("gate", "up", "down")}
+
+    def expert(e, out):
+        end = starts[e] + counts[e]
+
+        def rows_block(j, out):
+            weights = kernels(e)
+            lo = starts[e] + j * rows
+            tokens = jax.lax.dynamic_slice(token_of, (lo,), (rows,))
+            gate = jax.lax.dynamic_slice(gate_of, (lo,), (rows,))
+            gate = jnp.where(lo + jnp.arange(rows) < end, gate, 0.0)
+            y = nn.gated_mlp(weights, zb[tokens], dtype)
+            return out.at[tokens].add(y * gate[:, None])
+
+        return jax.lax.fori_loop(0, (counts[e] + rows - 1) // rows,
+                                 rows_block, out)
+
+    out = jax.lax.fori_loop(0, g, expert,
+                            nn.gated_mlp(params["shared"], zb, dtype))
+    return out, {"pairs_here": jnp.sum(counts),
+                 "experts_hit": jnp.sum(counts > 0, dtype=jnp.int32)}

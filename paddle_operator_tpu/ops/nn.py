@@ -149,6 +149,14 @@ def layernorm(params, x, eps: float = 1e-6, dtype=jnp.bfloat16):
     return y.astype(dtype)
 
 
+def rmsnorm(scale, x, eps: float = 1e-6, dtype=jnp.bfloat16):
+    """Root-mean-square norm over the last axis, no mean and no bias:
+    ``x / sqrt(mean(x^2) + eps) * scale``, computed in float32."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(dtype)
+
+
 # ---------------------------------------------------------------------------
 # embedding
 # ---------------------------------------------------------------------------
@@ -208,6 +216,48 @@ def rope(x: jnp.ndarray, positions: Optional[jnp.ndarray] = None,
     sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> jnp.ndarray:
+    """The ``dim // 2`` rotary frequencies under YaRN (Peng et al. 2023)
+    as DeepSeek-V3's published code blends them: pairs that turn more
+    than ``beta_fast`` times over the ``original`` context keep their
+    frequency, pairs that turn fewer than ``beta_slow`` times have it
+    divided by ``factor``, and a linear ramp over the pair index lies
+    between. Float32 [dim // 2]."""
+    def pair_that_turns(rotations: float) -> float:
+        return dim * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    keep = 1.0 - jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    plain = base ** (-2.0 * i / dim)
+    return plain / factor * (1.0 - keep) + plain * keep
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_rows(x: jnp.ndarray, positions: jnp.ndarray,
+              inv_freq: jnp.ndarray) -> jnp.ndarray:
+    """Rotary embedding with a position for every row and frequencies
+    handed in: x [..., S, H, D], positions [..., S], inv_freq [D // 2].
+    Pairs are ``(i, i + D // 2)`` as in :func:`rope`; the angle and the
+    rotation are float32, the result has x's type."""
+    half = x.shape[-1] // 2
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def mha(params, x, mask: Optional[jnp.ndarray] = None, dtype=jnp.bfloat16,
@@ -296,6 +346,20 @@ def _out_proj(params, ctx, dtype):
 
 def gelu(x):
     return jax.nn.gelu(x, approximate=True)
+
+
+def gated_mlp(params, x, dtype=jnp.bfloat16):
+    """SwiGLU: ``down(silu(gate(x)) * up(x))``, kernels ``gate`` / ``up``
+    [D, F] and ``down`` [F, D], no biases; operands in ``dtype``, sums and
+    the activation in float32."""
+    x = x.astype(dtype)
+
+    def mm(a, w):
+        return jnp.matmul(a, w.astype(dtype),
+                          preferred_element_type=jnp.float32)
+
+    h = jax.nn.silu(mm(x, params["gate"])) * mm(x, params["up"])
+    return mm(h.astype(dtype), params["down"])
 
 
 def max_pool(x, window: int, stride: int, padding="SAME"):

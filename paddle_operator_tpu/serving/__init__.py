@@ -12,7 +12,8 @@ is finally cheap enough to build. The plane has three layers:
   ``spec.worker.replicas`` (the same spec path elastic resize uses), so
   pods scale with zero new pod-lifecycle code;
 * **data plane** (:mod:`.batching`, :mod:`.kv_cache`, :mod:`.engine`) —
-  a continuous-batching engine over :mod:`..models.gpt`: a request queue
+  a continuous-batching engine over a model's module (:mod:`..models.gpt`,
+  :mod:`..models.axk1`): a request queue
   with admission / load-shedding, iteration-level scheduling that admits
   new sequences into in-flight batches, and a paged KV-cache (block-table
   allocator + the ``paged_decode_attention`` Pallas kernel in
@@ -39,15 +40,18 @@ from .controller import (  # noqa: F401
     ANNOT_DESIRED_REPLICAS, SERVING_DEFAULTS, apply_desired_replicas,
     serving_config, serving_replicas, sync_serving_spec,
 )
-from .kv_cache import KvBlockAllocator, KvCacheFull, PagedKvCache  # noqa: F401
+from .kv_cache import (  # noqa: F401
+    KvBlockAllocator, KvCacheFull, LatentKvCache, PagedKvCache,
+)
 from .metrics import ServeMetrics  # noqa: F401
 
 __all__ = [
     "ANNOT_DESIRED_REPLICAS", "ContinuousBatcher", "KvBlockAllocator",
-    "KvCacheFull", "PagedKvCache", "Request", "RequestQueue",
-    "SERVING_DEFAULTS", "SHED_POLICIES", "ScaleDecision", "ServeMetrics",
-    "ServingAutoscaler", "ServingEngine", "apply_desired_replicas",
-    "serving_config", "serving_replicas", "sync_serving_spec",
+    "KvCacheFull", "LatentKvCache", "PagedKvCache", "Request",
+    "RequestQueue", "SERVING_DEFAULTS", "SHED_POLICIES", "ScaleDecision",
+    "ServeMetrics", "ServingAutoscaler", "ServingEngine",
+    "apply_desired_replicas", "serving_config", "serving_replicas",
+    "sync_serving_spec",
 ]
 
 

@@ -15,6 +15,12 @@ scenario and ``make race`` exercise. :class:`PagedKvCache` is the array
 half: the ``[num_blocks, block_size, heads, head_dim]`` K/V pages per
 layer that :func:`..ops.attention_pallas.paged_decode_attention`
 consumes, plus the writes that fill them during prefill / decode.
+:class:`LatentKvCache` is the array half for latent attention: ONE
+compressed row a token and layer for all heads, behind the same
+allocator. The engine asks either for ``pools()`` / ``set_pools()``
+(what the decode step takes and hands back), ``write_rows()`` (a
+prefill's rows) and ``donate_pools`` (whether the decode step may
+overwrite the pools it is handed).
 
 Thread safety: every allocator field is owned by ``_lock`` (declared in
 analysis/guards.py — the static OPS9xx passes and the runtime race
@@ -238,6 +244,22 @@ class PagedKvCache:
         self.k_pages = [jnp.zeros(shape, dtype) for _ in range(layers)]
         self.v_pages = [jnp.zeros(shape, dtype) for _ in range(layers)]
 
+    #: the decode step copies the pools it updates (ROADMAP Queue 1)
+    donate_pools = False
+
+    def pools(self) -> Any:
+        return list(self.k_pages), list(self.v_pages)
+
+    def set_pools(self, pools: Any) -> None:
+        self.k_pages, self.v_pages = list(pools[0]), list(pools[1])
+
+    def write_rows(self, seq_id: str, rows: Any, n: int) -> None:
+        """A prefill's keys and values (per layer [pad, H, D], the first
+        ``n`` rows the prompt's) into the sequence's pages."""
+        ks, vs = rows
+        for layer in range(self.layers):
+            self.write_prefill(seq_id, layer, ks[layer][:n], vs[layer][:n])
+
     def write_prefill(self, seq_id: str, layer: int,
                       k: Any, v: Any) -> None:
         """Store a prefill's K/V ([S, H, D]) into the sequence's pages."""
@@ -264,3 +286,79 @@ class PagedKvCache:
         slot = pos % bs
         self.k_pages[layer] = self.k_pages[layer].at[block, slot].set(k)
         self.v_pages[layer] = self.v_pages[layer].at[block, slot].set(v)
+
+
+class LatentKvCache:
+    """The array half for latent attention (``models.axk1``): what a
+    token leaves behind in a layer is one row ``[c_kv | k_rope]`` shared
+    by every head, so there is one pool and no separate values.
+
+    The pool is ONE array ``[layers, num_blocks + 1, block_size, W]``
+    (the expert layers are one scan, which indexes it by layer; the last
+    page is the pad rows' target as in :class:`PagedKvCache`), ``W`` the
+    row's width rounded up to whole 128-lane tiles: the chip's tiled
+    layout of a row-major page holds that many lanes anyway, and a minor
+    axis that is no multiple of 128 makes XLA lay the pool out
+    token-minor and copy it whole before every kernel call. It answers to
+    the names the paged cache has: ``k_pages`` is the list holding it,
+    ``v_pages`` an empty list. A prefill lands through one jitted,
+    donating scatter (:meth:`write_rows`), and the decode step updates
+    the pool in place (``donate_pools``): an undonated copy of a pool
+    sized to fill the chip does not fit beside it.
+    """
+
+    donate_pools = True
+    LANES = 128
+
+    def __init__(self, num_blocks: int, block_size: int, layers: int,
+                 width: int, dtype: Any = None) -> None:
+        import jax.numpy as jnp
+
+        self.allocator = KvBlockAllocator(num_blocks, block_size)
+        self.layers = layers
+        self.width = width
+        self.dummy_page = num_blocks
+        stored = -(-width // self.LANES) * self.LANES
+        self.k_pages = [jnp.zeros(
+            (layers, num_blocks + 1, block_size, stored),
+            dtype or jnp.bfloat16)]
+        self.v_pages: List[Any] = []
+        self._scatter: Optional[Any] = None
+
+    def pools(self) -> Any:
+        return self.k_pages[0]
+
+    def set_pools(self, pools: Any) -> None:
+        self.k_pages = [pools]
+
+    def write_rows(self, seq_id: str, rows: Any, n: int) -> None:
+        """A prefill's rows ``[layers, pad, width]`` (the first ``n`` the
+        prompt's) into the sequence's pages: one program a padded length,
+        whole pages of every layer at once. Pages past the prompt's last
+        go to the dummy page; the last page's slots past ``n`` take
+        padding, which ``seq_lens`` masks until decode overwrites it.
+        (A scatter row by row makes XLA copy the whole pool into a layout
+        of its own first: 3 GB of temporaries for the benchmark's pool.)"""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        bs = self.allocator.block_size
+        table = self.allocator.block_table(seq_id)
+        pages = -(-rows.shape[1] // bs)
+        blocks = np.full((pages,), self.dummy_page, np.int32)
+        live = -(-n // bs)
+        blocks[:live] = table[:live]
+        if self._scatter is None:
+            def scatter(pool, rows, blocks):
+                layers, _, size, stored = pool.shape
+                rows = jnp.pad(rows, ((0, 0),
+                                      (0, blocks.shape[0] * size
+                                       - rows.shape[1]),
+                                      (0, stored - rows.shape[2])))
+                return pool.at[:, blocks].set(rows.astype(pool.dtype).reshape(
+                    layers, blocks.shape[0], size, stored))
+
+            self._scatter = jax.jit(scatter, donate_argnums=(0,))
+        self.k_pages = [self._scatter(self.k_pages[0], rows,
+                                      jnp.asarray(blocks))]

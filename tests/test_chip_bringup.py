@@ -109,6 +109,24 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e):
         'custom_call_target="tpu_custom_call"') >= 3
 
 
+def test_mla_paged_decode_compiles_for_v5e_without_copying_its_pool(v5e):
+    """At the shapes of ``axk1-share16.serve-decode-1k``: 64 rows x 64
+    heads over a seven-layer pool of bfloat16 pages whose rows are padded
+    to whole lanes. With rows of 576 XLA lays the pool out token-minor
+    and copies all of it before every call (2.6 GB of temporaries)."""
+    sh = jax.sharding.SingleDeviceSharding(v5e[0])
+    compiled = jax.jit(lambda ql, qr, pool, tables, lens, layer:
+                       ap.mla_paged_decode(ql, qr, pool, tables, lens, 0.1,
+                                           layer=layer)).lower(
+        _sds((64, 64, 512), jnp.bfloat16, sh),
+        _sds((64, 64, 64), jnp.bfloat16, sh),
+        _sds((7, 2305, 128, 640), jnp.bfloat16, sh),
+        _sds((64, 36), jnp.int32, sh), _sds((64,), jnp.int32, sh),
+        _sds((), jnp.int32, sh)).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 def test_paged_decode_matches_reference_interpreted():
     """The repaired kernel (multiply-and-reduce, no batched matmul)
     against the gather-einsum reference at the engine's head shape."""
