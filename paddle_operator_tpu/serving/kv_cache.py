@@ -14,13 +14,15 @@ arrays): alloc/append/free with conservation invariants the chaos
 scenario and ``make race`` exercise. :class:`PagedKvCache` is the array
 half: the ``[num_blocks, block_size, heads, head_dim]`` K/V pages per
 layer that :func:`..ops.attention_pallas.paged_decode_attention`
-consumes, plus the writes that fill them during prefill / decode.
-:class:`LatentKvCache` is the array half for latent attention: ONE
-compressed row a token and layer for all heads, behind the same
-allocator. The engine asks either for ``pools()`` / ``set_pools()``
-(what the decode step takes and hands back), ``write_rows()`` (a
-prefill's rows) and ``donate_pools`` (whether the decode step may
-overwrite the pools it is handed).
+consumes. :class:`LatentKvCache` is the array half for latent
+attention: ONE compressed row a token and layer for all heads, behind
+the same allocator. The engine asks either for ``pools()`` /
+``set_pools()`` (what the decode step takes and hands back; the decode
+step itself writes each new token's rows), ``write_rows()`` (a
+prefill's rows: in both caches one jitted program a padded prompt
+length that takes the pools donated and writes whole pages in place)
+and ``donate_pools`` (whether the decode step may overwrite the pools
+it is handed).
 
 Thread safety: every allocator field is owned by ``_lock`` (declared in
 analysis/guards.py — the static OPS9xx passes and the runtime race
@@ -31,6 +33,19 @@ from __future__ import annotations
 
 import threading
 from typing import Any, Dict, List, Optional
+
+
+def _prompt_pages(cache: Any, seq_id: str, n: int, padded: int) -> Any:
+    """Where the whole pages of a prefill's ``padded`` rows go in either
+    cache: the first ``ceil(n / block_size)`` entries of the sequence's
+    table, then the dummy page for the pages that hold padding alone."""
+    import numpy as np
+
+    size = cache.allocator.block_size
+    blocks = np.full((-(-padded // size),), cache.dummy_page, np.int32)
+    live = -(-n // size)
+    blocks[:live] = cache.allocator.block_table(seq_id)[:live]
+    return blocks
 
 
 class KvCacheFull(Exception):
@@ -222,8 +237,11 @@ class PagedKvCache:
     """The array half: per-layer K/V pages shaped
     ``[num_blocks, block_size, heads, head_dim]`` plus an allocator.
 
-    Writes go through functional ``.at[].set()`` updates (JAX arrays are
-    immutable); the arrays live wherever JAX puts them (HBM on TPU).
+    The arrays are immutable and live wherever JAX puts them (HBM on
+    TPU): a prefill's rows land through one jitted program that takes
+    the pools donated and hands back the same buffers with the prompt's
+    pages written (:meth:`write_rows`); the decode step writes its new
+    token's rows itself and hands back copies (``donate_pools``).
     Single-engine-thread by design — the batcher serializes model steps —
     so only the ALLOCATOR is locked.
     """
@@ -243,6 +261,7 @@ class PagedKvCache:
         dtype = dtype or jnp.float32
         self.k_pages = [jnp.zeros(shape, dtype) for _ in range(layers)]
         self.v_pages = [jnp.zeros(shape, dtype) for _ in range(layers)]
+        self._write: Optional[Any] = None
 
     #: the decode step copies the pools it updates (ROADMAP Queue 1)
     donate_pools = False
@@ -254,38 +273,35 @@ class PagedKvCache:
         self.k_pages, self.v_pages = list(pools[0]), list(pools[1])
 
     def write_rows(self, seq_id: str, rows: Any, n: int) -> None:
-        """A prefill's keys and values (per layer [pad, H, D], the first
-        ``n`` rows the prompt's) into the sequence's pages."""
+        """A prefill's keys and values (per layer ``[pad, H, D]``, the
+        first ``n`` rows the prompt's) into the sequence's pages: one
+        program a padded length, whole pages of every layer's K and V
+        at once into the donated pools. Pages past the prompt's last go
+        to the dummy page; the last page's slots past ``n`` take
+        padding, which ``seq_lens`` masks until decode overwrites it."""
+        import jax
+        import jax.numpy as jnp
+
         ks, vs = rows
-        for layer in range(self.layers):
-            self.write_prefill(seq_id, layer, ks[layer][:n], vs[layer][:n])
+        blocks = _prompt_pages(self, seq_id, n, ks[0].shape[0])
+        if self._write is None:
+            def write(k_pages, v_pages, ks, vs, blocks):
+                def paged(pool, rows):
+                    size = pool.shape[1]
+                    rows = jnp.pad(
+                        rows, ((0, blocks.shape[0] * size - rows.shape[0]),
+                               (0, 0), (0, 0)))
+                    return pool.at[blocks].set(
+                        rows.astype(pool.dtype).reshape(
+                            (blocks.shape[0],) + pool.shape[1:]))
 
-    def write_prefill(self, seq_id: str, layer: int,
-                      k: Any, v: Any) -> None:
-        """Store a prefill's K/V ([S, H, D]) into the sequence's pages."""
-        bs = self.allocator.block_size
-        table = self.allocator.block_table(seq_id)
-        s = k.shape[0]
-        for j, block in enumerate(table):
-            lo = j * bs
-            n = min(bs, s - lo)
-            if n <= 0:
-                break
-            self.k_pages[layer] = self.k_pages[layer].at[
-                block, :n].set(k[lo:lo + n])
-            self.v_pages[layer] = self.v_pages[layer].at[
-                block, :n].set(v[lo:lo + n])
+                return ([paged(p, r) for p, r in zip(k_pages, ks)],
+                        [paged(p, r) for p, r in zip(v_pages, vs)])
 
-    def write_token(self, seq_id: str, layer: int,
-                    k: Any, v: Any) -> None:
-        """Store one decode step's K/V ([H, D]) at the sequence's current
-        last slot (call AFTER allocator.append_token)."""
-        bs = self.allocator.block_size
-        pos = self.allocator.seq_len(seq_id) - 1
-        block = self.allocator.block_table(seq_id)[pos // bs]
-        slot = pos % bs
-        self.k_pages[layer] = self.k_pages[layer].at[block, slot].set(k)
-        self.v_pages[layer] = self.v_pages[layer].at[block, slot].set(v)
+            self._write = jax.jit(write, donate_argnums=(0, 1))
+        self.k_pages, self.v_pages = self._write(
+            self.k_pages, self.v_pages, list(ks), list(vs),
+            jnp.asarray(blocks))
 
 
 class LatentKvCache:
@@ -341,14 +357,8 @@ class LatentKvCache:
         of its own first: 3 GB of temporaries for the benchmark's pool.)"""
         import jax
         import jax.numpy as jnp
-        import numpy as np
 
-        bs = self.allocator.block_size
-        table = self.allocator.block_table(seq_id)
-        pages = -(-rows.shape[1] // bs)
-        blocks = np.full((pages,), self.dummy_page, np.int32)
-        live = -(-n // bs)
-        blocks[:live] = table[:live]
+        blocks = _prompt_pages(self, seq_id, n, rows.shape[1])
         if self._scatter is None:
             def scatter(pool, rows, blocks):
                 layers, _, size, stored = pool.shape

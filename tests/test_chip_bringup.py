@@ -127,6 +127,29 @@ def test_mla_paged_decode_compiles_for_v5e_without_copying_its_pool(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
+def test_paged_cache_prefill_write_compiles_for_v5e_in_place(v5e):
+    """At the shapes of ``gpt2-small.serve-steady``: 12 layers' K and V
+    pools of 192 + 1 pages of 128 slots (76 MB each, 1.82 GB in all) and
+    a prefill's rows padded to 512. The one write program updates every
+    donated pool where it lies: no temporary of a pool's size, every
+    pool's bytes aliased to a result."""
+    from paddle_operator_tpu.serving.kv_cache import PagedKvCache
+
+    cache = PagedKvCache(2, 8, layers=1, heads=2, head_dim=8)
+    cache.allocator.alloc_sequence("s", 3)
+    tiny = [jnp.zeros((8, 2, 8))]
+    cache.write_rows("s", (tiny, tiny), 3)   # builds the jitted write
+    sh = jax.sharding.SingleDeviceSharding(v5e[0])
+    pools = [_sds((193, 128, 12, 64), jnp.float32, sh)] * 12
+    rows = [_sds((512, 12, 64), jnp.float32, sh)] * 12
+    compiled = cache._write.lower(
+        pools, pools, rows, rows, _sds((4,), jnp.int32, sh)).compile()
+    pool_bytes = 193 * 128 * 12 * 64 * 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_bytes
+    assert mem.alias_size_in_bytes >= 24 * pool_bytes
+
+
 def test_paged_decode_matches_reference_interpreted():
     """The repaired kernel (multiply-and-reduce, no batched matmul)
     against the gather-einsum reference at the engine's head shape."""

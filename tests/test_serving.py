@@ -491,11 +491,25 @@ def test_paged_decode_interpret_matches_reference():
     assert jnp.max(jnp.abs(out - ref)) < 1e-5
 
 
+def _greedy_full_forward(params, prompt, budget):
+    """What greedy generation gives when every token is a full-context
+    ``gpt.apply``: the engine tests' golden."""
+    import jax.numpy as jnp
+
+    from paddle_operator_tpu.models import gpt
+
+    ids = list(prompt)
+    for _ in range(budget):
+        logits, _ = gpt.apply(params, jnp.asarray([ids], jnp.int32),
+                              dtype=jnp.float32, attn_impl="einsum")
+        ids.append(int(jnp.argmax(logits[0, -1])))
+    return ids[len(prompt):]
+
+
 def _engine_golden(attn):
     """Incremental serving (prefill + paged decode) must reproduce the
     full-context greedy generation token for token."""
     import jax
-    import jax.numpy as jnp
 
     from paddle_operator_tpu.models import gpt
     from paddle_operator_tpu.serving.engine import ServingEngine
@@ -505,15 +519,8 @@ def _engine_golden(attn):
     prompts = [[5, 99, 7], [11, 3, 250, 42, 8], [1023]]
     budgets = [4, 3, 5]
 
-    def golden(prompt, budget):
-        ids = list(prompt)
-        for _ in range(budget):
-            logits, _ = gpt.apply(params, jnp.asarray([ids], jnp.int32),
-                                  dtype=jnp.float32, attn_impl="einsum")
-            ids.append(int(jnp.argmax(logits[0, -1])))
-        return ids[len(prompt):]
-
-    want = [golden(p, n) for p, n in zip(prompts, budgets)]
+    want = [_greedy_full_forward(params, p, n)
+            for p, n in zip(prompts, budgets)]
 
     eng = ServingEngine(params, cfg, max_batch=4, prompt_pad=16,
                         num_blocks=64, block_size=8, attn=attn,
@@ -542,6 +549,139 @@ def test_engine_paged_kernel_matches_full_forward():
     # interpret-mode Pallas on CPU is slow; the reference-path twin above
     # covers the engine logic in tier-1, this one proves the kernel path
     _engine_golden("paged")
+
+
+_WRITE_BS, _WRITE_PAD = 4, 16
+
+
+@pytest.mark.parametrize(
+    "n", [1, _WRITE_BS - 1, _WRITE_BS, _WRITE_BS + 1, _WRITE_PAD])
+def test_paged_cache_write_rows_lands_whole_pages(n):
+    """A prefill's padded rows land in the sequence's pages (slots < n
+    hold the prompt's), and nothing else moves: not an earlier
+    sequence's pages, not the sequence's reserved pages past the
+    prompt's last live one, not a free page."""
+    import jax
+    import numpy as np
+
+    from paddle_operator_tpu.serving.kv_cache import PagedKvCache
+
+    bs, pad, layers, heads, dim = _WRITE_BS, _WRITE_PAD, 2, 2, 8
+    cache = PagedKvCache(12, bs, layers, heads, dim)
+
+    def rows(seed):
+        keys = jax.random.split(jax.random.PRNGKey(seed), 2 * layers)
+        draw = [jax.random.normal(k, (pad, heads, dim)) for k in keys]
+        return draw[:layers], draw[layers:]
+
+    # an earlier sequence whose table is not contiguous with the next's
+    cache.allocator.alloc_sequence("hole", bs)
+    first = cache.allocator.alloc_sequence("first", 2 * bs + 1,
+                                           live_tokens=bs + 2)
+    cache.allocator.free_sequence("hole")
+    first_rows = rows(1)
+    cache.write_rows("first", first_rows, bs + 2)
+    # prompt n, with a generation budget that reserves pages past it
+    table = cache.allocator.alloc_sequence("second", pad + 2 * bs,
+                                           live_tokens=n)
+    before = [[np.asarray(p) for p in pool]
+              for pool in (cache.k_pages, cache.v_pages)]
+    second_rows = rows(2)
+    cache.write_rows("second", second_rows, n)
+
+    live = -(-n // bs)
+    untouched = [b for b in range(cache.dummy_page)
+                 if b not in table[:live]]
+    assert set(first) | set(table[live:]) <= set(untouched)
+    for pool, was, mine, theirs in zip(
+            (cache.k_pages, cache.v_pages), before, second_rows,
+            first_rows):
+        for layer in range(layers):
+            now = np.asarray(pool[layer])
+            got = now[table[:live]].reshape(live * bs, heads, dim)
+            np.testing.assert_array_equal(got[:n],
+                                          np.asarray(mine[layer])[:n])
+            np.testing.assert_array_equal(
+                now[first[:2]].reshape(2 * bs, heads, dim)[:bs + 2],
+                np.asarray(theirs[layer])[:bs + 2])
+            np.testing.assert_array_equal(now[untouched],
+                                          was[layer][untouched])
+
+
+def test_paged_cache_write_rows_is_one_program_a_padded_length(caplog):
+    """After a first request, prefills of other prompt lengths trace and
+    compile no further write: it is one program a padded length,
+    whatever the prompt's length and page count."""
+    import jax
+
+    from paddle_operator_tpu.models import gpt
+    from paddle_operator_tpu.serving.engine import ServingEngine
+
+    cfg = dict(gpt.TINY_CONFIG)
+    params = gpt.init(jax.random.PRNGKey(0), cfg)
+    eng = ServingEngine(params, cfg, max_batch=4, prompt_pad=16,
+                        num_blocks=32, block_size=4, attn="reference",
+                        label="test-one-write")
+
+    def prefill(i, n):
+        req = Request("w%d" % i, prompt=list(range(1, n + 1)),
+                      max_new_tokens=2)
+        assert eng.admit(req)
+        eng.step_fn([req])
+        eng.retire(req)
+
+    prefill(0, 6)
+    assert eng.cache._write._cache_size() == 1
+    with jax.log_compiles(), caplog.at_level("WARNING", logger="jax"):
+        for i, n in enumerate((1, 4, 16), 1):
+            prefill(i, n)
+    assert eng.cache._write._cache_size() == 1
+    seen = [r.getMessage() for r in caplog.records]
+    # (the engine's own eager prompt build still compiles a length)
+    assert any("scatter" in m for m in seen)
+    assert not [m for m in seen if "write" in m], seen
+
+
+def test_engine_reuses_pages_with_stale_rows_past_a_shorter_prompt():
+    """A sequence retires and a SHORTER prompt takes its pages: the last
+    live page keeps the old sequence's rows (and the new prompt's
+    padding) in the slots past ``n``; ``seq_lens`` masks them, so the
+    generated tokens are the full forward's."""
+    import jax
+    import numpy as np
+
+    from paddle_operator_tpu.models import gpt
+    from paddle_operator_tpu.serving.engine import ServingEngine
+
+    cfg = dict(gpt.TINY_CONFIG)
+    params = gpt.init(jax.random.PRNGKey(0), cfg)
+    eng = ServingEngine(params, cfg, max_batch=2, prompt_pad=16,
+                        num_blocks=4, block_size=8, attn="reference",
+                        label="test-stale-pages")
+    long_req = Request("long", prompt=[7, 300, 12, 9, 41, 5, 88, 1000, 3,
+                                       64, 2], max_new_tokens=3)
+    short_req = Request("short", prompt=[11, 3, 250], max_new_tokens=6)
+    q = RequestQueue(capacity=4)
+    b = ContinuousBatcher(q, max_batch=2, on_admit=eng.admit,
+                          on_retire=eng.retire)
+    tables = {}
+    for req in (long_req, short_req):
+        q.submit(req)
+        for _ in range(16):
+            live = b.step(eng.step_fn)
+            if req.request_id in eng.cache.allocator.sequences():
+                tables[req.request_id] = eng.cache.allocator.block_table(
+                    req.request_id)
+            if live == 0 and q.depth() == 0:
+                break
+    # LIFO free list: the short prompt's first page is one the long
+    # sequence filled, and its slots past the prompt's are not zeros
+    assert tables["short"][0] in tables["long"]
+    assert np.asarray(eng.cache.k_pages[0])[tables["short"][0], 3:].any()
+    for req in (long_req, short_req):
+        assert req.generated == _greedy_full_forward(
+            params, req.prompt, req.max_new_tokens)
+    assert eng.cache.allocator.check() == []
 
 
 # ---------------------------------------------------------------------------
