@@ -1484,7 +1484,7 @@ class _LockHarvest:
 
     def declare_lock(self, cls_key: str, attr: str) -> LockId:
         """A lock the guard spec declares but no factory call assigns
-        (a lock object passed in, like the bench canary pool's): its
+        (a lock object passed in to the constructor): its
         identity anchors at the first ``self.<attr> = ...`` line."""
         info = self.classes.get(cls_key)
         if info is None:

@@ -135,11 +135,10 @@ REPO_ROOT = os.path.dirname(os.path.dirname(
 
 
 def default_paths() -> List[str]:
-    """The analysis scope both CLIs share: the package, the operational
-    scripts, and the bench harness."""
+    """The analysis scope both CLIs share: the package and the
+    operational scripts."""
     return [os.path.join(REPO_ROOT, "paddle_operator_tpu"),
-            os.path.join(REPO_ROOT, "scripts"),
-            os.path.join(REPO_ROOT, "bench.py")]
+            os.path.join(REPO_ROOT, "scripts")]
 
 
 def axis_paths() -> List[str]:

@@ -963,7 +963,7 @@ def _iter_py_files(paths: Sequence[str]) -> List[str]:
 def lint_paths(paths: Sequence[str], root: Optional[str] = None,
                rules: Optional[Iterable[str]] = None) -> List[Finding]:
     """Lint files/trees. Metric families resolve PACKAGE-WIDE: a family
-    declared in runtime.py and emitted from obs.py is fine."""
+    declared in runtime.py and emitted from obs/ is fine."""
     findings: List[Finding] = []
     inv = _MetricsInventory()
     for fpath in _iter_py_files(paths):

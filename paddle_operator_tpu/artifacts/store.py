@@ -712,7 +712,7 @@ def metrics_text() -> str:
 
 
 def stats_block() -> Dict[str, float]:
-    """Compact summary for ``result["compile_cache"]`` / bench blocks."""
+    """Compact summary for ``result["compile_cache"]``."""
     store = get_store()
     if store is None:
         return {"configured": False}

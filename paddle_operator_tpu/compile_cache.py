@@ -2,8 +2,8 @@
 
 Every elastic resize, arbiter preemption, and operator-driven restart
 re-enters :func:`parallel.build_train_step` in a fresh process and pays
-full XLA compilation again (~20 s for the bench ResNet step on CPU, more
-on TPU pods). Singularity (arXiv 2202.07848) makes the point structurally:
+full XLA compilation again (what a cold process costs on the chip is each
+cell's ``first_setup_s`` against its ``setup_s``: PERF.md). Singularity (arXiv 2202.07848) makes the point structurally:
 transparent preemption is only cheap if resume is cheap. This module makes
 resume cheap on three rungs, each falling back transparently to the next:
 
@@ -12,8 +12,7 @@ resume cheap on three rungs, each falling back transparently to the next:
    model/batch avals, mesh shape, sharding + donation signature). The
    compiled executable is serialized via
    ``jax.experimental.serialize_executable`` into the cache directory; a
-   warm process deserializes it and skips tracing, lowering AND XLA —
-   milliseconds instead of tens of seconds.
+   warm process deserializes it and skips tracing, lowering AND XLA.
 2. **JAX persistent compilation cache** (`warm` rung): enabled
    process-wide with a project-managed directory, so even paths that
    cannot AOT (shape-polymorphic callers, multi-host wrappers) skip the
@@ -757,7 +756,7 @@ class CachedStep:
 
     Callable exactly like the ``jax.jit`` result it replaces. ``source``
     is one of ``memo`` | ``aot`` | ``compiled`` | ``jit`` — what the
-    bench's ``startup.cache`` field and the runner's result block report.
+    runner's result block reports (``result["compile_sources"]``).
 
     An AOT executable is stricter than ``jit`` at the call boundary (no
     weak-type promotion, exact sharding match): if the FIRST call fails
@@ -999,9 +998,9 @@ def reset_stats_for_tests() -> None:
 
 
 def startup_block() -> Dict[str, Any]:
-    """The compact summary bench.py embeds as the ``startup.compile_cache``
-    block and the runner as ``result["compile_cache"]``: which rung served
-    this process, plus the hit/miss ledger."""
+    """The compact summary the runner returns as
+    ``result["compile_cache"]``: which rung served this process, plus the
+    hit/miss ledger."""
     from . import artifacts
 
     s = stats()

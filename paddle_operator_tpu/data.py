@@ -22,7 +22,7 @@ MXU. Design (the asynchronous host pipeline):
 
 Per-stage host timings (batch-build / enqueue-wait / dequeue-wait /
 device-put) are recorded into a :class:`~.utils.trace.StageTimes` when one
-is passed, and reported by ``bench.py`` and ``run_training``.
+is passed, and reported by ``run_training`` (``result["host_stages"]``).
 """
 
 from __future__ import annotations

@@ -576,7 +576,7 @@ class Manager:
     def start(self, seed_queues: bool = True) -> None:
         """Blocks on leadership (if enabled), then starts workers. On a lost
         lease all workers halt and ``on_lost_lease`` fires (reference:
-        controller-runtime exits the binary; main.py wires that).
+        controller-runtime exits the binary; manager.py wires that).
         ``seed_queues=False`` skips the initial-list replay — for harnesses
         that measure the drain of a hand-built backlog; production always
         seeds.

@@ -2,7 +2,7 @@
 SLO burn-rate alerting, flight recorder, worker exposition, and the
 text-format tooling shared by both planes.
 
-Grown from the original single-module ``obs.py`` into a package when the
+Grown from a single module into a package when the
 goodput ledger landed (ISSUE 10); the public surface is re-exported here
 so ``from paddle_operator_tpu.obs import JobMetrics`` keeps working.
 Layout:

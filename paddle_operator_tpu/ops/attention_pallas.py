@@ -443,16 +443,14 @@ def _warn_block_override_once(which, env, seq):
 
 
 def _auto_block(seq: int, which: str = "q") -> int:
-    """Largest well-measured tile that divides the sequence. 512 measures
-    ~1.9x faster than 128 for fwd+bwd at S=4k-8k on v5e (block sweep in the
-    round-3 bench): bigger tiles feed the MXU [512,128]x[128,512] matmuls
-    and amortize the online-softmax loop; beyond 512 the curve is flat and
-    VMEM pressure grows. Falls back down the ladder for short sequences.
+    """Largest tile of the ladder that divides the sequence, 512 first:
+    bigger tiles feed the MXU [512,128]x[128,512] matmuls and amortize
+    the online-softmax loop, and VMEM pressure grows beyond that. No
+    sweep of the tile has run in a cell of the benchmark (ROADMAP Queue 1
+    item 3b). Falls back down the ladder for short sequences.
 
     ``TPUJOB_FLASH_BLOCK_Q`` / ``TPUJOB_FLASH_BLOCK_K`` override the
-    auto choice fleet-wide (still subject to divisibility) — the bench's
-    attention_sweep stage maps the block space on hardware, and its best
-    config deploys through these without a code change."""
+    auto choice fleet-wide (still subject to divisibility)."""
     import os
 
     env = os.environ.get("TPUJOB_FLASH_BLOCK_" + which.upper())

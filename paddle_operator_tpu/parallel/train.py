@@ -227,7 +227,7 @@ def build_train_step(
     # the AOT example signature must match what callers actually pass:
     # fused windows carry the leading [K] axis on every leaf (the mesh
     # contract; the runner's single-device loader prestages the same).
-    # A broadcast caller (same-batch-every-step bench mode) falls back to
+    # A broadcast caller (the same batch every step) falls back to
     # plain jit via the CachedStep first-call guard.
     if steps_per_call > 1:
         example_batch = jax.tree_util.tree_map(
@@ -301,8 +301,8 @@ def _globalize_batches(step_fn, batch_sh, host_local):
         batch = jax.tree_util.tree_map(to_global, batch, batch_sh)
         return step_fn(state, batch)
 
-    # surface the cache provenance through the wrapper (runner/bench
-    # report step_fn.source in their startup blocks)
+    # surface the cache provenance through the wrapper (the runner
+    # reports step_fn.source as result["compile_sources"])
     wrapped.source = getattr(step_fn, "source", "jit")
     wrapped.compile_seconds = getattr(step_fn, "compile_seconds", 0.0)
     return wrapped

@@ -23,8 +23,8 @@ MODEL'S MODULE (``model=``, default :mod:`..models.gpt`), which offers:
   counters)``: one fixed-shape step over the whole batch, the new
   token's rows written into each sequence's current page slot,
   attention through the model's paged kernel (``attn="paged"``) or its
-  gather-einsum reference (``attn="reference"``, which the perf gate
-  compares token for token). ``counters`` are int32 scalars the engine
+  gather-einsum reference (``attn="reference"``, which the tests
+  compare token for token). ``counters`` are int32 scalars the engine
   banks under their names (``moe.pairs_here``, ``moe.experts_hit``).
 
 Models served: :mod:`..models.gpt` (float32; no expert configuration:
@@ -32,18 +32,16 @@ its Switch layer drops tokens over capacity and has no decode path) and
 :mod:`..models.axk1` (bfloat16; latent attention through a latent page
 cache, an expert layer that computes the experts this chip holds).
 
-Both steps compile through :func:`..compile_cache.cached_jit`, so a
-serving replica warms from the fleet artifact store exactly like a
-training worker does: replica N+1 serves its first token with
-``cache="fleet"`` and zero compile seconds (scripts/perf_serving.py
-proves it; the serving_brownout chaos scenario models it).
+Both steps compile through :func:`..compile_cache.cached_jit`, as a
+training worker's step does, so a replica takes them from whichever rung
+of the compile ladder holds them.
 
 Shapes are FIXED by construction — prompts pad to a bucket, the decode
 batch pads to ``max_batch`` with inert dummy rows aimed at the cache's
 reserved dummy page — so the decode step compiles once and prefill once
 a bucket per engine config. Sampling is greedy argmax: serving replicas
-must be deterministic so the paged-vs-reference bit-identity gate and
-the chaos replays can compare token ids exactly.
+must be deterministic so the paged-vs-reference tests and the chaos
+replays can compare token ids exactly.
 """
 
 from __future__ import annotations

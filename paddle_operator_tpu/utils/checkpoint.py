@@ -319,11 +319,8 @@ class AsyncCheckpointer:
     releases the HBM references instead of pinning an extra copy of the
     whole state until the disk write finishes); serialization + atomic
     rename + pruning happen off-thread, so checkpoint_every stops costing
-    a disk write's worth of step time. Measured
-    (scripts/perf_ckpt_async.py, the production runner path with 6 x
-    ~400 MB writes over a 12-step run): async takes 4.0 s of disk time
-    off a 19.1 s run (1.27x) — pure overlap, since both modes drain the
-    final write before returning.
+    a disk write's worth of step time. Stall per save is not measured
+    in any cell of the benchmark (ROADMAP Queue 2 B.7).
 
     Semantics (matching what restart-from-checkpoint needs):
 

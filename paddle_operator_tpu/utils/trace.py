@@ -389,7 +389,7 @@ class StageTimes:
     largest sample and a bounded ring of its newest samples
     (:class:`Sample`, stamped with ``time.perf_counter()``), from which a
     median, a cut by time and a sum per step can be taken.
-    ``summary()`` is the breakdown bench.py and ``run_training`` report;
+    ``summary()`` is the breakdown ``run_training`` reports;
     ``timed()`` also enters a ``jax.profiler.TraceAnnotation`` of the
     stage's name, so a device trace shows the same spans on its clock.
     """

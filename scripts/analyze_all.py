@@ -39,9 +39,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from paddle_operator_tpu.analysis import engine, opslint  # noqa: E402
 
-# analysis scope (engine.default_paths): the package, the operational
-# scripts, and the bench harness — the three trees production code
-# ships from; tests/ and examples/ contribute mesh-axis vocabulary only
+# analysis scope (engine.default_paths): the package and the operational
+# scripts — the two trees production code ships from; tests/ and
+# examples/ contribute mesh-axis vocabulary only
 REPO = engine.REPO_ROOT
 DEFAULT_BASELINE = os.path.join(REPO, "opslint_baseline.json")
 
@@ -100,7 +100,7 @@ def main(argv=None) -> int:
         description="all static-analysis families + JSON report")
     ap.add_argument("paths", nargs="*", default=None,
                     help="files/trees to analyze (default: package + "
-                         "scripts/ + bench.py)")
+                         "scripts/)")
     ap.add_argument("--changed", nargs="?", const="HEAD", default=None,
                     metavar="REF",
                     help="incremental mode: report findings only for "
@@ -214,10 +214,10 @@ def main(argv=None) -> int:
             "mypy", "paddle_operator_tpu/api", "paddle_operator_tpu/analysis",
             "paddle_operator_tpu/sched", "paddle_operator_tpu/obs",
             "paddle_operator_tpu/serving", "paddle_operator_tpu/artifacts",
-            "scripts", "bench.py",
+            "scripts",
         ], report["findings"]) and 1
         rc |= _run_optional_tool("ruff", [
-            "ruff", "check", "paddle_operator_tpu", "scripts", "bench.py",
+            "ruff", "check", "paddle_operator_tpu", "scripts",
         ], report["findings"]) and 1
 
     out_path = args.out or os.path.join(REPO, "build",

@@ -4,9 +4,9 @@
 Runs every analysis family in ``paddle_operator_tpu.analysis`` — the
 syntactic opslint passes (OPS1xx–5xx), the interprocedural dataflow
 families (OPS6xx/7xx/8xx), and the OPS001 stale-suppression audit —
-over the package + scripts/ + bench.py (or any paths given) and fails
-on findings not recorded in the committed baseline. See
-docs/static-analysis.md for the rule catalog and suppression syntax.
+over the package + scripts/ (or any paths given) and fails on findings
+not recorded in the committed baseline. See docs/static-analysis.md for
+the rule catalog and suppression syntax.
 ``scripts/analyze_all.py`` is the same engine plus the JSON report,
 budget gate, and mypy/ruff stages (what ``make analyze`` runs).
 
@@ -35,7 +35,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description="project-specific lint")
     ap.add_argument("paths", nargs="*",
                     help="files/trees to lint (default: package + "
-                         "scripts/ + bench.py)")
+                         "scripts/)")
     ap.add_argument("--baseline", default=DEFAULT_BASELINE)
     ap.add_argument("--no-baseline", action="store_true",
                     help="report every finding, baselined or not")

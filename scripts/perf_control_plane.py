@@ -2,7 +2,7 @@
 
 Synthetic TpuJob churn over FakeKubeClient/OperatorHarness at 1k/5k/10k
 objects, publishing a reconcile-throughput curve as bench-style JSON
-(BENCH_CONTROL_PLANE.json next to the training BENCH_*.json files).
+(BENCH_CONTROL_PLANE.json at the root of the checkout).
 
     python scripts/perf_control_plane.py                # full 1k/5k/10k curve
     python scripts/perf_control_plane.py --quick        # 1k profile (CI lane)

@@ -551,24 +551,65 @@ class TestCompileCacheIntegration:
              "y": jax.random.normal(k4, (8, 4), jnp.float32)}
         return mlp_loss, p, b
 
-    def test_fleet_fetch_bit_identical(self, fleet):
+    def _train_step(self):
+        """Build the step through the ladder, run it: its loss."""
         from paddle_operator_tpu import compile_cache
 
         fn, p, b = self._setup()
+        loss, _ = compile_cache.cached_jit(fn, (p, b))(p, b)
+        return float(loss)
+
+    @staticmethod
+    def _serve_engine():
+        """A serving replica from nothing (its prefill and decode steps
+        built through the ladder): tiny GPT, one request served to its
+        end. Its tokens."""
+        import jax
+
+        from paddle_operator_tpu.models import gpt
+        from paddle_operator_tpu.serving import (
+            ContinuousBatcher, Request, RequestQueue)
+        from paddle_operator_tpu.serving.engine import ServingEngine
+
+        cfg = dict(gpt.TINY_CONFIG)
+        eng = ServingEngine(gpt.init(jax.random.PRNGKey(0), cfg), cfg,
+                            max_batch=2, prompt_pad=16, num_blocks=32,
+                            block_size=8, attn="reference",
+                            label="serve-replica")
+        req = Request("r0", prompt=[5, 99, 7], max_new_tokens=6)
+        queue = RequestQueue(4)
+        batcher = ContinuousBatcher(queue, 2, on_admit=eng.admit,
+                                    on_retire=eng.retire)
+        queue.submit(req)
+        for _ in range(16):
+            if batcher.step(eng.step_fn) == 0 and queue.depth() == 0:
+                break
+        assert len(req.generated) == 6
+        return req.generated
+
+    @pytest.mark.parametrize("program, steps", [("train-step", 1),
+                                                ("serve-engine", 2)])
+    def test_fleet_fetch_bit_identical(self, fleet, program, steps):
+        """Host b, with an empty cache directory of its own, takes every
+        step host a compiled from the store, compiles nothing and gets
+        the same result: a train step's loss, a serving replica's tokens
+        (from its prefill and its decode step)."""
+        from paddle_operator_tpu import compile_cache
+
+        run = {"train-step": self._train_step,
+               "serve-engine": self._serve_engine}[program]
         fleet("host-a")
-        f1 = compile_cache.cached_jit(fn, (p, b))
-        if f1.source != "compiled":
+        result_a = run()
+        if compile_cache.stats()["aot_saves"] < steps:
             pytest.skip("backend cannot serialize executables")
-        loss_a, _ = f1(p, b)
-        assert artifacts.get_store().stats()["publishes_local"] >= 1
+        assert artifacts.get_store().stats()["publishes_local"] >= steps
 
         fleet("host-b")
-        f2 = compile_cache.cached_jit(fn, (p, b))
-        assert f2.source == "aot"
-        loss_b, _ = f2(p, b)
+        result_b = run()
         s = compile_cache.stats()
-        assert s["fleet_hits"] == 1 and s["compile_seconds"] == 0.0
-        assert float(loss_a) == float(loss_b)
+        assert s["aot_hits"] == s["fleet_hits"] == steps
+        assert s["compile_seconds"] == 0.0
+        assert result_a == result_b
         assert compile_cache.startup_block()["cache"] == "fleet"
 
     def test_poisoned_artifact_downgrades_to_recompile(self, fleet,

@@ -8,16 +8,21 @@
   supplies it;
 * the compile cache stays where ``JAX_COMPILATION_CACHE_DIR`` puts it;
 * an AOT executable reloads onto the devices it was compiled for;
-* ``chip_smoke.py`` and ``bench.py`` refuse to run off the chip;
+* ``chip_smoke.py`` refuses to run off the chip, and no document names a
+  program that is not in the tree;
 * a checkpoint is written in files of bounded size — the driver's chip
   machine refused GPT-2 small's 1.95 GB ``state.npz`` with EFBIG.
 """
 
+import ast
 import functools
+import io
 import os
+import re
 import shutil
 import subprocess
 import sys
+import tokenize
 
 import jax
 import jax.numpy as jnp
@@ -406,9 +411,58 @@ def test_chip_smoke_rehearsal_runs_the_tiny_config_to_the_end():
     assert any("resume_steps=[3]" in l for l in lines)
 
 
-def test_bench_fails_without_a_chip():
-    out = _run("bench.py")
-    assert out.returncode != 0 and out.stdout.strip() == ""
+def _documents():
+    """(path, text) of what tells a reader which file to run or read; of a
+    Python source, its comments and docstrings."""
+    paths = [os.path.join(REPO, n)
+             for n in ("Makefile", "README.md", "pyproject.toml")]
+    paths += sorted(os.path.join(REPO, "docs", n)
+                    for n in os.listdir(os.path.join(REPO, "docs"))
+                    if n.endswith(".md"))
+    for top in ("scripts", "paddle_operator_tpu"):
+        for where, dirs, names in os.walk(os.path.join(REPO, top)):
+            dirs[:] = sorted(d for d in dirs if d != "__pycache__")
+            paths += [os.path.join(where, n) for n in sorted(names)
+                      if n.endswith(".py")]
+    for path in paths:
+        with open(path, encoding="utf-8") as fh:
+            text = fh.read()
+        if path.endswith(".py"):
+            said = [ast.get_docstring(node, clean=False) or ""
+                    for node in ast.walk(ast.parse(text))
+                    if isinstance(node, (ast.Module, ast.ClassDef,
+                                         ast.FunctionDef,
+                                         ast.AsyncFunctionDef))]
+            said += [tok.string for tok in tokenize.generate_tokens(
+                io.StringIO(text).readline) if tok.type == tokenize.COMMENT]
+            text = "\n".join(said)
+        yield os.path.relpath(path, REPO), text
+
+
+def test_every_file_a_document_names_is_in_the_tree():
+    """A sentence that sends a reader to a file that is gone is a defect:
+    each ``scripts/<name>.py``, each ``BENCH_*.json`` and each ``*.py``
+    named without a directory (a program at the root of the checkout, or a
+    module by its file name) exists in the tree."""
+    modules = set()
+    for _, dirs, names in os.walk(REPO):
+        dirs[:] = [d for d in dirs if not d.startswith(".")
+                   and d not in ("__pycache__", "chiprun_out")]
+        modules.update(n for n in names if n.endswith(".py"))
+    rules = [
+        (re.compile(r"\bscripts/(\w+\.py)\b"), lambda n: os.path.exists(
+            os.path.join(REPO, "scripts", n)), "scripts/"),
+        (re.compile(r"\b(BENCH_[A-Z_]+\.json)\b"), lambda n: os.path.exists(
+            os.path.join(REPO, n)), ""),
+        (re.compile(r"(?<![\w/.*\-])([A-Za-z_]\w*\.py)\b"),
+         modules.__contains__, ""),
+    ]
+    gone = ["%s names %s%s" % (here, prefix, name)
+            for here, text in _documents()
+            for pattern, in_tree, prefix in rules
+            for name in sorted(set(pattern.findall(text)))
+            if not in_tree(name)]
+    assert gone == [], "\n".join(gone)
 
 
 # ---------------------------------------------------------------------------

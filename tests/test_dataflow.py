@@ -406,14 +406,13 @@ def test_ops801_explicit_block_until_ready_not_flagged():
 
 def test_real_tree_clean_and_baseline_empty():
     """The acceptance gate in-suite: OPS6xx/7xx/8xx (plus every opslint
-    family and the OPS001 audit) run clean over the package + scripts +
-    bench.py, and the committed baseline holds zero entries."""
+    family and the OPS001 audit) run clean over the package + scripts,
+    and the committed baseline holds zero entries."""
     from paddle_operator_tpu.analysis import opslint
 
     findings = engine.run_all(
         [os.path.join(REPO, "paddle_operator_tpu"),
-         os.path.join(REPO, "scripts"),
-         os.path.join(REPO, "bench.py")],
+         os.path.join(REPO, "scripts")],
         root=REPO,
         axis_paths=[os.path.join(REPO, "tests"),
                     os.path.join(REPO, "examples")])
