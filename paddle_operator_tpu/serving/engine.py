@@ -42,6 +42,12 @@ reserved dummy page — so the decode step compiles once and prefill once
 a bucket per engine config. Sampling is greedy argmax: serving replicas
 must be deterministic so the paged-vs-reference tests and the chaos
 replays can compare token ids exactly.
+
+A step crosses the host-device boundary once each way: what the host
+hands a compiled step is filled into numpy arrays and sent by ONE
+``jax.device_put`` of the tuple; a decode step's tokens and counters
+come back by ONE ``jax.device_get``. No eager ``jnp`` program runs in
+``step_fn``: only the compiled steps and the cache's page write.
 """
 
 from __future__ import annotations
@@ -50,7 +56,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 
 from ..utils.trace import StageTimes, export_stage_times
 from .batching import Request
@@ -144,8 +150,8 @@ class ServingEngine:
             with jax.named_scope("serve_prefill"):
                 return prefill(*args)
 
-        ex = (self.params, jnp.zeros((1, pad), jnp.int32),
-              jnp.zeros((), jnp.int32))
+        ex = (self.params, jax.ShapeDtypeStruct((1, pad), np.int32),
+              jax.ShapeDtypeStruct((), np.int32))
         return compile_cache.cached_jit(
             serve_prefill, ex, config=dict(self.config, prompt_pad=pad),
             label="%s-prefill" % self.label)
@@ -168,10 +174,10 @@ class ServingEngine:
         pools = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             self.cache.pools())
-        ex = (self.params, pools,
-              jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-              jnp.zeros((b, self.pages_per_seq), jnp.int32),
-              jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool))
+        row = jax.ShapeDtypeStruct((b,), np.int32)
+        ex = (self.params, pools, row, row,
+              jax.ShapeDtypeStruct((b, self.pages_per_seq), np.int32),
+              row, jax.ShapeDtypeStruct((b,), np.bool_))
         return compile_cache.cached_jit(
             serve_decode, ex,
             config=dict(self.config, attn=attn, max_batch=b,
@@ -218,9 +224,9 @@ class ServingEngine:
             self._prefill_fns[pad] = self._build_prefill(pad)
         rid = req.request_id
         with timed("serve.prefill.build", request_id=rid, prompt_len=n):
-            ids = jnp.zeros((1, pad), jnp.int32).at[
-                0, :n].set(jnp.asarray(req.prompt, jnp.int32))
-            length = jnp.asarray(n, jnp.int32)
+            ids = np.zeros((1, pad), np.int32)
+            ids[0, :n] = req.prompt
+            ids, length = jax.device_put((ids, np.int32(n)))
         with timed("serve.prefill.dispatch", request_id=rid, bucket=pad):
             token, rows = self._prefill_fns[pad](self.params, ids, length)
         with timed("serve.prefill.scatter", request_id=rid,
@@ -234,39 +240,31 @@ class ServingEngine:
             self._decode_fn = self._build_decode()
         timed = self.times.timed
         with timed("serve.decode.tables"):
-            alloc = self.cache.allocator
-            b = self.max_batch
-            tokens = [0] * b
-            positions = [0] * b
-            tables = [[0] * self.pages_per_seq for _ in range(b)]
-            lens = [0] * b
-            live = [False] * b
+            alloc, b = self.cache.allocator, self.max_batch
+            # filled on the host; pad rows stay zero and not live
+            tokens, positions, lens = np.zeros((3, b), np.int32)
+            tables = np.zeros((b, self.pages_per_seq), np.int32)
+            live = np.arange(b) < len(rows)
             for i, req in enumerate(rows):
                 sid = req.request_id
                 tokens[i] = req.generated[-1]
                 lens[i] = alloc.seq_len(sid)
                 positions[i] = alloc.advance(sid)  # == lens[i], slot reserved
                 table = alloc.block_table(sid)
-                tables[i][:len(table)] = table
-                live[i] = True
+                tables[i, :len(table)] = table
         with timed("serve.decode.put"):
-            args = (jnp.asarray(tokens, jnp.int32),
-                    jnp.asarray(positions, jnp.int32),
-                    jnp.asarray(tables, jnp.int32),
-                    jnp.asarray(lens, jnp.int32),
-                    jnp.asarray(live, bool))
+            # host -> device, once a step: one transfer call for the five
+            args = jax.device_put((tokens, positions, tables, lens, live))
         with timed("serve.decode.dispatch"):
             out, pools, counters = self._decode_fn(
                 self.params, self.cache.pools(), *args)
             self.cache.set_pools(pools)
         with timed("serve.decode.wait"):
-            # the first int() below waited here before: no wait is added
+            # the device's part of the step: the read-back finds it done
             jax.block_until_ready(out)
         with timed("serve.decode.readback"):
-            if not counters:
-                return [int(out[i]) for i in range(len(rows))]
-            # a step that counts hands its counters back with its
-            # tokens: one transfer for both, none per row
+            # device -> host, once a step: every row's token and the
+            # model's counters (a model that counts nothing hands none)
             out, counters = jax.device_get((out, counters))
             # banked as samples whose VALUE is the count (a stage's
             # total is then the count's, its calls the steps')

@@ -637,9 +637,158 @@ def test_paged_cache_write_rows_is_one_program_a_padded_length(caplog):
             prefill(i, n)
     assert eng.cache._write._cache_size() == 1
     seen = [r.getMessage() for r in caplog.records]
-    # (the engine's own eager prompt build still compiles a length)
-    assert any("scatter" in m for m in seen)
-    assert not [m for m in seen if "write" in m], seen
+    # (nor anything else: the engine builds the prompt in numpy)
+    assert seen == []
+
+
+def _tiny_engine(model, attn, label, prompt_pad=16):
+    """An engine over ``model`` ("gpt": its decode step returns no
+    counters; "axk1": it counts its expert pairs) at the model's tiny
+    configuration."""
+    import jax
+
+    from paddle_operator_tpu.models import axk1, gpt
+    from paddle_operator_tpu.serving.engine import ServingEngine
+
+    module = {"gpt": gpt, "axk1": axk1}[model]
+    cfg = dict(module.TINY_CONFIG)
+    return ServingEngine(module.init(jax.random.PRNGKey(0), cfg), cfg,
+                         max_batch=4, prompt_pad=prompt_pad, num_blocks=32,
+                         block_size=8, attn=attn, model=module,
+                         label="%s-%s-%s" % (label, model, attn))
+
+
+_BOUNDARY_PROMPTS = ([5, 99, 7], [11, 3, 250, 42, 8, 9, 9, 9, 9], [511])
+
+
+def _decode_steps(eng, steps):
+    """Prefill ``_BOUNDARY_PROMPTS`` in one step, then ``steps`` decode-
+    only steps; each step's tokens as the engine returned them."""
+    reqs = [Request("b%d" % i, prompt=list(p), max_new_tokens=steps + 1)
+            for i, p in enumerate(_BOUNDARY_PROMPTS)]
+    assert all(eng.admit(r) for r in reqs)
+    out = []
+    for _ in range(steps + 1):
+        tokens = [t for t, _ in eng.step_fn(reqs)]
+        for req, token in zip(reqs, tokens):
+            req.generated.append(token)
+        out.append(tokens)
+    for req in reqs:
+        eng.retire(req)
+    return out[1:]
+
+
+class _FetchedWhole:
+    """A decode step's tokens as the host may touch them: waited for
+    and fetched whole (``__array__`` is what ``jax.device_get`` calls),
+    never indexed or converted element by element."""
+
+    def __init__(self, array):
+        self._array = array
+
+    def block_until_ready(self):
+        self._array.block_until_ready()
+        return self
+
+    def __array__(self, *args, **kwargs):
+        import numpy as np
+
+        return np.asarray(self._array)
+
+    def _touched(self, *args):
+        raise AssertionError("a device array indexed on the host")
+
+    __getitem__ = __int__ = __index__ = __iter__ = __len__ = _touched
+
+
+@pytest.mark.parametrize("model", ["gpt", "axk1"])
+def test_a_decode_step_crosses_back_in_one_device_get(model, monkeypatch):
+    """Whatever the model returns beside its tokens, the engine reads a
+    decode step back by ONE ``jax.device_get`` of tokens and counters,
+    banks the counters it got (GPT: none) and hands on Python ints: the
+    tokens of an engine on the reference attention path."""
+    import jax
+
+    steps = 3
+    want = _decode_steps(_tiny_engine(model, "reference", "want"), steps)
+
+    eng = _tiny_engine(model, "paged", "once")
+    _decode_steps(eng, 1)                    # builds both steps
+    eng.times.reset()
+    decode_fn, counted = eng._decode_fn, set()
+
+    def guarded(*args):
+        out, pools, counters = decode_fn(*args)
+        counted.update(counters)
+        return _FetchedWhole(out), pools, counters
+
+    eng._decode_fn = guarded
+    gets = []
+    real_get = jax.device_get
+
+    def device_get(x):
+        gets.append(x)
+        return real_get(x)
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    got = _decode_steps(eng, steps)
+    assert len(gets) == steps
+    assert got == want
+    assert all(type(t) is int for row in got for t in row)
+    # the counters' samples, one a step, and no other stage beside the spans
+    assert counted == ({"moe.pairs_here", "moe.experts_hit"}
+                       if model == "axk1" else set())
+    banked = {k: v["count"] for k, v in eng.times.summary().items()
+              if not k.startswith("serve.")}
+    assert banked == {name: steps for name in counted}
+
+
+@pytest.mark.parametrize("model, pad", [("gpt", 48), ("axk1", 40)])
+def test_a_buckets_prompts_are_one_numpy_fill_and_no_new_program(model, pad):
+    """After one prefill of a bucket, prompts of other lengths in it
+    lower and compile NOTHING (counted as the benchmark counts programs
+    inside its window), and what the prefill step is handed is the
+    padded prompt: ``[1, pad]`` int32, zero past the prompt. (A padded
+    length of the case's own: no earlier test of the process can have
+    compiled a program of these shapes.)"""
+    import jax
+    import numpy as np
+
+    from benchmark.harness.compiles import CompileCounter
+
+    eng = _tiny_engine(model, "reference", "fill", prompt_pad=pad)
+    handed = []
+
+    def prefill(i, n):
+        req = Request("f%d" % i, prompt=list(range(1, n + 1)),
+                      max_new_tokens=2)
+        assert eng.admit(req)
+        eng.step_fn([req])
+        eng.retire(req)
+
+    prefill(0, 6)
+    step = eng._prefill_fns[pad]
+    assert list(eng._prefill_fns) == [pad]
+
+    def spy(params, ids, length):
+        handed.append((ids, length))
+        return step(params, ids, length)
+
+    eng._prefill_fns[pad] = spy
+    counter = CompileCounter.get()
+    counter.mark()
+    lengths = (1, pad // 2 + 3, pad)
+    for i, n in enumerate(lengths, 1):
+        prefill(i, n)
+    assert counter.mark() == (0, 0)
+    assert len(handed) == len(lengths)
+    for n, (ids, length) in zip(lengths, handed):
+        assert isinstance(ids, jax.Array) and isinstance(length, jax.Array)
+        assert (ids.shape, ids.dtype) == ((1, pad), np.int32)
+        assert (length.shape, length.dtype) == ((), np.int32)
+        assert int(length) == n
+        assert np.asarray(ids)[0].tolist() \
+            == list(range(1, n + 1)) + [0] * (pad - n)
 
 
 def test_engine_reuses_pages_with_stale_rows_past_a_shorter_prompt():

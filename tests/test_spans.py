@@ -251,10 +251,10 @@ def test_serve_metrics_export_the_engines_stages():
 
 
 def test_the_wait_before_the_read_back_changes_no_token(monkeypatch):
-    """``serve.decode.wait`` blocks where the first ``int()`` of the
-    read-back used to: with the wait taken out again the engine serves
-    the same tokens (and tests/test_serving.py holds them to the full
-    forward pass)."""
+    """``serve.decode.wait`` blocks where the read-back's one
+    ``jax.device_get`` would: with the wait taken out again the engine
+    serves the same tokens (and tests/test_serving.py holds them to the
+    full forward pass)."""
     from paddle_operator_tpu.serving import engine as engine_mod
 
     prompts, budgets = [[5, 99, 7], [11, 3, 250, 42, 8], [1023]], [4, 3, 5]
