@@ -6,9 +6,11 @@ width of ``models/gpt.BASE_CONFIG`` (GPT-2 small: 768 wide, 12 layers,
 12 heads, vocabulary 50304, 1024 positions; random weights from a seed):
 
 1. kernel checks — ``flash_attention`` forward and gradients,
-   ``paged_decode_attention`` and ``mla_paged_decode`` (at the shapes of
-   the ``axk1-share16`` cell) against their references, compiled
-   (``interpret=False``), each under a written tolerance;
+   ``paged_decode_attention``, ``mla_paged_decode`` (at the shapes of
+   the ``axk1-share16`` cell) and ``dsa_index_scores`` + ``select_rows``
+   + ``mla_selected_decode`` (at those of ``dsv32-share32``) against
+   their references, compiled (``interpret=False``), each under a
+   written tolerance;
 2. trainer — the ``TrainJob`` of ``examples/train_gpt.py`` through
    ``launch.detect_env`` + ``runner.run_training`` over all local
    devices: 3 steps and a checkpoint, then a second run on the same
@@ -173,6 +175,52 @@ def kernel_checks(sm: Smoke) -> None:
            pool="x".join(map(str, pool.shape)), dtype="bf16",
            interpret=interpret, rel_err="%.2e" % err, tol=MLA_TOL)
     sm.check(err <= MLA_TOL, "mla_paged_decode error %g" % err)
+
+    # the sparse decode at the shapes of dsv32-share32.serve-long-8k: 16
+    # rows of up to 33,280 tokens, an indexer of 64 heads x 128 over a
+    # second pool of bfloat16 keys, the top 2048 gathered for 128 heads
+    b, j, di, h, top = (4, 2, 16, 4, 16) if sm.rehearsal \
+        else (16, 64, 128, 128, 2048)
+    layers, pages, per_seq = (2, 33, 8) if sm.rehearsal else (2, 1025, 260)
+    keys = jax.random.split(jax.random.PRNGKey(sm.seed + 3), 8)
+    q_idx = jax.random.normal(keys[0], (b, j, di), jnp.bfloat16)
+    weights = jax.random.normal(keys[1], (b, j), jnp.float32)
+    index_pool = jax.random.normal(keys[2], (layers, pages, bs, 128),
+                                   jnp.bfloat16)
+    q_lat = jax.random.normal(keys[3], (b, h, c), jnp.bfloat16)
+    q_rope = jax.random.normal(keys[4], (b, h, r), jnp.bfloat16)
+    pool = jax.random.normal(keys[5], (layers, pages, bs, width),
+                             jnp.bfloat16)
+    tables = jax.random.randint(keys[6], (b, per_seq), 0, pages - 1)
+    lens = jax.random.randint(keys[7], (b,), 1, per_seq * bs + 1
+                              ).at[0].set(per_seq * bs).at[1].set(top // 2)
+    scores = jax.jit(lambda *a: ap.dsa_index_scores(
+        *a, layer=1, interpret=interpret))(
+            q_idx, weights, index_pool, tables, lens)
+    want = jax.jit(ap._reference_index_scores)(
+        q_idx, weights, index_pool[1], tables, lens)
+    seen = jnp.isfinite(want)
+    sm.check(bool(jnp.all(jnp.isfinite(scores) == seen)),
+             "dsa_index_scores masks other positions than its reference")
+    err = rel_err(jnp.where(seen, scores, 0.0), jnp.where(seen, want, 0.0))
+    sm.say("kernel dsa_index_scores", q_idx="x".join(map(str, q_idx.shape)),
+           pool="x".join(map(str, index_pool.shape)), dtype="bf16",
+           interpret=interpret, rel_err="%.2e" % err, tol=PAGED_TOL)
+    sm.check(err <= PAGED_TOL, "dsa_index_scores error %g" % err)
+    chosen, count = jax.jit(lambda s, n: ap.select_rows(s, n, top))(
+        scores, lens)
+    sm.check(bool(jnp.all(count == jnp.minimum(lens, top))),
+             "select_rows counts %s" % (count,))
+    got = jax.jit(lambda *a: ap.mla_selected_decode(
+        *a, scale, layer=1, interpret=interpret))(
+            q_lat, q_rope, pool, tables, chosen, count)
+    want = jax.jit(lambda *a: ap._reference_mla_selected_decode(
+        *a, scale, layer=1))(q_lat, q_rope, pool, tables, chosen, count)
+    err = rel_err(got, want)
+    sm.say("kernel mla_selected_decode", rows=b, selected=int(count.max()),
+           longest=int(lens.max()), dtype="bf16", interpret=interpret,
+           rel_err="%.2e" % err, tol=MLA_TOL)
+    sm.check(err <= MLA_TOL, "mla_selected_decode error %g" % err)
 
 
 # ---------------------------------------------------------------------------

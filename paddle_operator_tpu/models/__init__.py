@@ -7,9 +7,12 @@
 * wide_and_deep / deepfm — PS-mode CTR models (deploy/examples/*.yaml)
 * axk1 — latent attention and sigmoid-routed experts beside a shared one,
   as one chip's share of a wide expert-parallel deployment (serving only)
+* dsv32 — axk1's stack with a learned sparse attention (an indexer with a
+  key cache of its own, decode over the selected rows) and group-limited
+  bias-corrected routing (serving only)
 
 All models are (init, apply) pure functions over dict pytrees, bf16 compute,
 built from `paddle_operator_tpu.ops.nn`.
 """
 
-from . import resnet, bert, gpt, wide_deep, deepfm, axk1  # noqa: F401
+from . import resnet, bert, gpt, wide_deep, deepfm, axk1, dsv32  # noqa: F401

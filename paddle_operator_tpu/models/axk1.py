@@ -167,22 +167,37 @@ def _mla_inputs(cfg, attn, z, positions, inv_freq):
     return q[..., :n], q_r, jnp.concatenate([c_kv, k_r], axis=-1)
 
 
+def _mla_queries(cfg, attn, z, positions, inv_freq, normed):
+    """What :func:`_stack` asks of a model's attention inputs: (what
+    ``attend`` is handed as queries, what the token leaves in the
+    cache). ``normed(dtype=...)`` is the layer's normed input again at
+    another precision than ``z``'s bfloat16, for a model that needs it
+    (``models.dsv32``'s indexer)."""
+    del normed
+    q_nope, q_r, rows = _mla_inputs(cfg, attn, z, positions, inv_freq)
+    return (q_nope, q_r), rows
+
+
 def _stack(cfg: Dict[str, Any], params: Dict, x: jnp.ndarray,
            positions: jnp.ndarray, live: jnp.ndarray, attend: Callable,
-           carry: Any):
+           carry: Any, inputs: Callable = _mla_queries):
     """Every layer over x [T, D] (float32): the dense layer, then the expert
-    layers as one scan. ``attend(index, attn, q_nope, q_r, rows, carry) ->
-    (context [T, H, V], carry)`` is where prefill and decode differ; the
-    rest of a layer is written here once. Returns (x, carry, the rows
-    every layer cached [L, T, C + R], the expert layers' counters)."""
+    layers as one scan. ``inputs(cfg, attn, z, positions, inv_freq, normed)
+    -> (queries, rows)`` is where the models differ (``models.dsv32`` adds
+    its indexer's queries and keys); ``attend(index, attn, queries, rows,
+    carry) -> (context [T, H, V], carry)`` is where prefill and decode
+    differ; the rest of a layer is written here once. Returns (x, carry,
+    the rows every layer cached [L, T, C + R] (a tree of such where
+    ``rows`` is one), the expert layers' counters)."""
     inv_freq, _ = _rotary(cfg)
     eps = cfg["rms_norm_eps"]
 
     def layer(index, p, x, carry, ffn):
         z = nn.rmsnorm(p["norm1"], x, eps)
-        q_nope, q_r, rows = _mla_inputs(cfg, p["attn"], z, positions,
-                                        inv_freq)
-        ctx, carry = attend(index, p["attn"], q_nope, q_r, rows, carry)
+        queries, rows = inputs(
+            cfg, p["attn"], z, positions, inv_freq,
+            functools.partial(nn.rmsnorm, p["norm1"], x, eps))
+        ctx, carry = attend(index, p["attn"], queries, rows, carry)
         # the residual stream stays float32: rounded to bfloat16 after
         # every layer it moved the routers' inputs enough to flip their
         # eighth choice several times more often than the matmuls'
@@ -208,7 +223,9 @@ def _stack(cfg: Dict[str, Any], params: Dict, x: jnp.ndarray,
         return moe_share_apply(
             dict(p["moe"], **routed), z, cfg["held_experts"],
             cfg["experts_per_token"], cfg["routed_scaling_factor"],
-            live=live, layer=index - cfg["dense_layers"])
+            live=live, layer=index - cfg["dense_layers"],
+            n_group=cfg.get("n_group", 1),
+            topk_group=cfg.get("topk_group", 1), bias=p["moe"].get("bias"))
 
     x, carry, rows0, _ = layer(0, params["dense"], x, carry, dense_ffn)
 
@@ -223,8 +240,9 @@ def _stack(cfg: Dict[str, Any], params: Dict, x: jnp.ndarray,
     (x, carry), (rows, counters) = jax.lax.scan(
         body, (x, carry),
         (jnp.arange(1, el + 1, dtype=jnp.int32), sliced))
-    return (x, carry, jnp.concatenate([rows0[None], rows], axis=0),
-            {"moe." + k: jnp.sum(v) for k, v in counters.items()})
+    return (x, carry, jax.tree_util.tree_map(
+        lambda first, rest: jnp.concatenate([first[None], rest], axis=0),
+        rows0, rows), {"moe." + k: jnp.sum(v) for k, v in counters.items()})
 
 
 def _next_token(cfg, params, x):
@@ -235,10 +253,11 @@ def _next_token(cfg, params, x):
     return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
-def _attend_prefill(cfg, index, attn, q_nope, q_r, rows, carry):
+def _attend_prefill(cfg, index, attn, queries, rows, carry):
     """Causal attention over a whole prompt, keys and values rebuilt from
     the latent rows, ``PREFILL_QUERY_BLOCK`` queries at a time."""
     del index
+    q_nope, q_r = queries
     _, scale = _rotary(cfg)
     c = cfg["kv_lora_rank"]
     s = rows.shape[0]
@@ -273,7 +292,8 @@ def _attend_prefill(cfg, index, attn, q_nope, q_r, rows, carry):
 def prefill(config: Optional[dict], params: Dict, ids: jnp.ndarray,
             length: jnp.ndarray):
     """ids [1, S] zero-padded, length [] -> (the first sampled token [],
-    the rows to cache [L, S, C + R]; rows past ``length`` are padding)."""
+    the rows to cache, one array a pool: ([L, S, C + R],); rows past
+    ``length`` are padding)."""
     cfg = _config(config)
     s = ids.shape[1]
     x = jnp.take(params["embed"]["table"], ids[0], axis=0
@@ -282,31 +302,39 @@ def prefill(config: Optional[dict], params: Dict, ids: jnp.ndarray,
         cfg, params, x, jnp.arange(s), jnp.arange(s) < length,
         functools.partial(_attend_prefill, cfg), None)
     _, token = _next_token(cfg, params, x[length - 1][None])
-    return token[0], rows
+    return token[0], (rows,)
 
 
-def decode(config: Optional[dict], params: Dict, pool: jnp.ndarray,
-           tokens: jnp.ndarray, positions: jnp.ndarray,
-           tables: jnp.ndarray, lens: jnp.ndarray, live: jnp.ndarray,
-           attn_impl: str = "paged", block_size: int = 128,
-           dummy_page: int = 0, with_logits: bool = False):
-    """One token for every row of the batch through the latent cache:
-    ``pool`` [L, P, bs, W] (updated where each row's new token lies and
-    handed back), tokens / positions / lens [B], tables [B, T], live [B]
-    -> (next tokens [B], pool, counters)."""
-    from ..ops.attention_pallas import (
-        _reference_mla_paged_decode, mla_paged_decode)
-
-    cfg = _config(config)
-    _, scale = _rotary(cfg)
+def _write_targets(positions, tables, lens, live, block_size, dummy_page):
+    """Where a decode step's new rows go: (page [B], slot in it [B], the
+    lengths once they are written [B])."""
     gathered = jnp.take_along_axis(
         tables, (positions // block_size)[:, None], axis=1)[:, 0]
     # pad rows write into the dummy page, which no table names
     blocks = jnp.where(live, gathered, dummy_page)
     slots = jnp.where(live, positions % block_size, 0)
-    new_lens = lens + 1
+    return blocks, slots, lens + 1
 
-    def attend(index, attn, q_nope, q_r, rows, pool):
+
+def decode(config: Optional[dict], params: Dict, pools: Tuple,
+           tokens: jnp.ndarray, positions: jnp.ndarray,
+           tables: jnp.ndarray, lens: jnp.ndarray, live: jnp.ndarray,
+           attn_impl: str = "paged", block_size: int = 128,
+           dummy_page: int = 0, with_logits: bool = False):
+    """One token for every row of the batch through the latent cache:
+    ``pools`` = (the pool [L, P, bs, W],), updated where each row's new
+    token lies and handed back; tokens / positions / lens [B], tables
+    [B, T], live [B] -> (next tokens [B], pools, counters)."""
+    from ..ops.attention_pallas import (
+        _reference_mla_paged_decode, mla_paged_decode)
+
+    cfg = _config(config)
+    _, scale = _rotary(cfg)
+    blocks, slots, new_lens = _write_targets(
+        positions, tables, lens, live, block_size, dummy_page)
+
+    def attend(index, attn, queries, rows, pool):
+        q_nope, q_r = queries
         width = pool.shape[-1]
         rows = jnp.pad(rows, ((0, 0), (0, width - rows.shape[-1])))
         pool = pool.at[index, blocks, slots].set(rows.astype(pool.dtype))
@@ -324,11 +352,11 @@ def decode(config: Optional[dict], params: Dict, pool: jnp.ndarray,
     x = jnp.take(params["embed"]["table"], tokens, axis=0
                  ).astype(jnp.float32)
     x, pool, _, counters = _stack(cfg, params, x, positions, live, attend,
-                                  pool)
+                                  pools[0])
     logits, out = _next_token(cfg, params, x)
     if with_logits:
-        return out, pool, counters, logits
-    return out, pool, counters
+        return out, (pool,), counters, logits
+    return out, (pool,), counters
 
 
 # -- what the serving engine asks of a model's module -----------------------
@@ -352,7 +380,7 @@ def serve_cache(config: dict, num_blocks: int, block_size: int):
     cfg = _config(config)
     return LatentKvCache(
         num_blocks, block_size, layers=cfg["layers"],
-        width=cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"])
+        widths=(cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"],))
 
 
 def serve_prefill(config: dict, pad: int) -> Callable:

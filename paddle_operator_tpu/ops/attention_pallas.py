@@ -789,3 +789,219 @@ def mla_paged_decode(q_lat, q_rope, pages, block_tables, seq_lens,
         name="mla_paged_decode",
     )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q, pages)
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention (DeepSeek-V3.2): index scores, selected decode
+# ---------------------------------------------------------------------------
+#
+# A lightning indexer scores every cached token for the new query,
+#     I_s = sum_j w_j relu(q_j . k_s),
+# against keys of its own (one 128-wide row a token and layer, a second
+# pool behind the latent cache's block table), the ``top`` largest are
+# selected, and the latent attention above runs over the selected rows
+# alone. ``dsa_index_scores`` is the first (a Mosaic kernel over the
+# paged index keys); ``select_rows`` the second (plain XLA:
+# ``lax.top_k``, whose ties go to the lower position, then the chosen
+# positions in ascending order, so that a row no longer than ``top``
+# attends exactly as the dense kernel would); ``mla_selected_decode``
+# the third: the selected rows fetched through the block table, then
+# ``mla_paged_decode`` over them as pages of their own.
+
+#: index-key pages one grid cell of ``dsa_index_scores`` scores: a cell
+#: costs about 0.4 us whatever it does, and a row of 33k tokens has 260
+INDEX_PAGES_PER_CELL = 8
+
+
+def _reference_index_scores(q_idx, weights, pages, block_tables, seq_lens):
+    """Gather-then-einsum reference: q_idx [B,J,Di], weights [B,J]
+    float32, pages [P,bs,W] with W >= Di, block_tables [B,T], seq_lens
+    [B] -> [B, T*bs] float32, ``-inf`` at and past each row's length."""
+    b, _, width = q_idx.shape
+    bs = pages.shape[1]
+    t = block_tables.shape[1]
+    keys = jnp.take(pages[..., :width], block_tables, axis=0).reshape(
+        b, t * bs, width)
+    dots = jnp.einsum("bjd,bkd->bjk", q_idx.astype(pages.dtype), keys,
+                      preferred_element_type=jnp.float32)
+    scores = jnp.sum(jax.nn.relu(dots) * weights[:, :, None], axis=1)
+    valid = jnp.arange(t * bs)[None, :] < seq_lens[:, None]
+    return jnp.where(valid, scores, -jnp.inf)
+
+
+def _index_scores_kernel(seq_lens_ref, tables_ref, layer_ref, q_ref, w_ref,
+                         *refs, block_size, operand_dtype):
+    del tables_ref, layer_ref          # read by the index maps
+    page_refs, o_ref = refs[:-1], refs[-1]
+    b = pl.program_id(0)
+    t = pl.program_id(1)
+    for k, page_ref in enumerate(page_refs):
+        first = (t * len(page_refs) + k) * block_size
+
+        @pl.when(first < seq_lens_ref[b])
+        def _page():
+            dots = jax.lax.dot_general(
+                q_ref[0].astype(operand_dtype),
+                page_ref[0, 0].astype(operand_dtype),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [J, bs]
+            scores = jnp.sum(jnp.maximum(dots, 0.0) * w_ref[0], axis=0,
+                             keepdims=True)                  # [1, bs]
+            pos = first + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+            o_ref[0, k] = jnp.where(pos < seq_lens_ref[b], scores, -jnp.inf)
+
+        @pl.when(first >= seq_lens_ref[b])
+        def _past():
+            o_ref[0, k] = jnp.full(o_ref.shape[2:], -jnp.inf, o_ref.dtype)
+
+
+def dsa_index_scores(q_idx, weights, pages, block_tables, seq_lens,
+                     layer=None, interpret: bool = False):
+    """The indexer's score of every cached token for one new query a
+    sequence: ``I[b, s] = sum_j weights[b, j] relu(q_idx[b, j] .
+    key[b, s])`` in float32 (operands in the pages' type, sums in
+    float32).
+
+    q_idx ``[B, J, Di]``, weights ``[B, J]`` float32, pages ``[P, bs,
+    Di]`` — or ``[L, P, bs, Di]`` with ``layer`` an int32 scalar, as
+    :func:`mla_paged_decode` takes its pool — block_tables ``[B, T]``,
+    seq_lens ``[B]``. Returns ``[B, T * bs]`` float32, ``-inf`` at and
+    past each row's length. A grid cell scores ``INDEX_PAGES_PER_CELL``
+    pages; pages past a row's last live one repeat its index (nothing
+    is fetched for them) and are written ``-inf``. Inference only."""
+    b, heads, width = q_idx.shape
+    if pages.ndim == 3:
+        pages, layer = pages[None], 0
+    elif layer is None:
+        raise ValueError("pages %r hold several layers: say which"
+                         % (pages.shape,))
+    _, _, block_size, page_width = pages.shape
+    if page_width < width or weights.shape != (b, heads):
+        raise ValueError(
+            "index pages %r do not match q_idx %r / weights %r"
+            % (pages.shape, q_idx.shape, weights.shape))
+    if block_tables.shape[0] != b or seq_lens.shape != (b,):
+        raise ValueError(
+            "block_tables %r / seq_lens %r do not cover batch %d"
+            % (block_tables.shape, seq_lens.shape, b))
+    # the cache rounds a key up to whole 128-lane tiles, as the latent rows
+    q_idx = jnp.pad(q_idx, ((0, 0), (0, 0), (0, page_width - width)))
+    width = page_width
+    pages_per_seq = block_tables.shape[1]
+    per_cell = min(INDEX_PAGES_PER_CELL, pages_per_seq)
+    cells = -(-pages_per_seq // per_cell)
+
+    def q_index(bi, ti, lens_ref, tables_ref, layer_ref):
+        return (bi, 0, 0)
+
+    def page_index(k):
+        def index(bi, ti, lens_ref, tables_ref, layer_ref):
+            last = jnp.maximum(lens_ref[bi] - 1, 0) // block_size
+            return (layer_ref[0],
+                    tables_ref[bi, jnp.minimum(ti * per_cell + k, last)],
+                    0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, cells),
+        in_specs=[pl.BlockSpec((1, heads, width), q_index),
+                  pl.BlockSpec((1, heads, 1), q_index)]
+        + [pl.BlockSpec((1, 1, block_size, width), page_index(k))
+           for k in range(per_cell)],
+        out_specs=pl.BlockSpec(
+            (1, per_cell, 1, block_size),
+            lambda bi, ti, lens_ref, tables_ref, layer_ref: (bi, ti, 0, 0)),
+    )
+    scores = pl.pallas_call(
+        functools.partial(_index_scores_kernel, block_size=block_size,
+                          operand_dtype=jnp.float32 if interpret
+                          else pages.dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(
+            (b, cells * per_cell, 1, block_size), jnp.float32),
+        interpret=interpret,
+        name="dsa_index_scores",
+    )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q_idx.astype(pages.dtype),
+      weights.astype(jnp.float32)[:, :, None], *([pages] * per_cell))
+    return scores.reshape(b, cells * per_cell * block_size)[
+        :, :pages_per_seq * block_size]
+
+
+def select_rows(scores, seq_lens, top: int):
+    """scores [B, N] float32 (``-inf`` past each row's ``seq_lens``) ->
+    (the positions of each row's ``top`` largest scores in ascending
+    order [B, min(top, N)], how many of them are live [B] =
+    ``min(seq_lens, top)``). Equal scores go to the lower position
+    (``lax.top_k``'s rule); slots past the count name position 0."""
+    top = min(top, scores.shape[1])
+    _, chosen = jax.lax.top_k(scores, top)
+    count = jnp.minimum(seq_lens, top).astype(jnp.int32)
+    live = jnp.arange(top)[None, :] < count[:, None]
+    chosen = jnp.sort(jnp.where(live, chosen, scores.shape[1]), axis=-1)
+    return jnp.where(live, chosen, 0).astype(jnp.int32), count
+
+
+def _selected_rows(pages, layer, block_tables, chosen):
+    """The cached rows at positions ``chosen`` [B, K] of each sequence,
+    found through its block table: one gather of K rows a sequence out
+    of the layer's pool ``pages[layer]``, which is never sliced out."""
+    layers, total, block_size, width = pages.shape
+    # table[b, chosen // bs] as a compare-and-sum over the table's columns:
+    # a gather of single int32 elements costs the chip 6 ns each, 0.19 ms
+    # for 16 x 2048 of them, more than the rows they point at
+    page = jnp.sum(jnp.where(
+        (chosen // block_size)[:, :, None]
+        == jnp.arange(block_tables.shape[1], dtype=chosen.dtype),
+        block_tables[:, None, :], 0), axis=-1)
+    flat = (jnp.asarray(layer, jnp.int32) * total + page) * block_size \
+        + chosen % block_size
+    return jnp.take(pages.reshape(layers * total * block_size, width),
+                    flat, axis=0)                            # [B, K, W]
+
+
+def _reference_mla_selected_decode(q_lat, q_rope, pages, block_tables,
+                                   chosen, count, scale, layer=0):
+    """Gather-then-einsum reference of :func:`mla_selected_decode` in
+    float32."""
+    if pages.ndim == 3:
+        pages, layer = pages[None], 0
+    c = q_lat.shape[-1]
+    width = c + q_rope.shape[-1]
+    rows = _selected_rows(pages, layer, block_tables, chosen)[
+        ..., :width].astype(jnp.float32)
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(jnp.float32)
+    s = jnp.einsum("bhw,bkw->bhk", q, rows,
+                   precision=jax.lax.Precision.HIGHEST) * scale
+    valid = jnp.arange(chosen.shape[1])[None, :] < count[:, None]
+    p = jax.nn.softmax(jnp.where(valid[:, None, :], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhk,bkc->bhc", p, rows[..., :c],
+                      precision=jax.lax.Precision.HIGHEST
+                      ).astype(q_lat.dtype)
+
+
+def mla_selected_decode(q_lat, q_rope, pages, block_tables, chosen, count,
+                        scale: float, layer=None, interpret: bool = False):
+    """Absorbed latent-attention decode over SELECTED rows of a paged
+    latent cache: q_lat / q_rope / pages / block_tables / layer as
+    :func:`mla_paged_decode` takes them, ``chosen`` ``[B, K]`` the
+    positions each sequence attends to (its first ``count[b]`` entries;
+    :func:`select_rows` makes both). Reads ``K`` rows a sequence, never
+    the sequence's other pages: the rows are fetched through the block
+    table by one XLA gather and handed to the ``mla_paged_decode``
+    kernel as pages of their own. Returns ``[B, H, C]``."""
+    if pages.ndim == 3:
+        pages, layer = pages[None], 0
+    elif layer is None:
+        raise ValueError("pages %r hold several layers: say which"
+                         % (pages.shape,))
+    b, k = chosen.shape
+    block_size, width = pages.shape[2:]
+    per_seq = -(-k // block_size)
+    chosen = jnp.pad(chosen, ((0, 0), (0, per_seq * block_size - k)))
+    rows = _selected_rows(pages, layer, block_tables, chosen)
+    return mla_paged_decode(
+        q_lat, q_rope, rows.reshape(b * per_seq, block_size, width),
+        jnp.arange(b * per_seq, dtype=jnp.int32).reshape(b, per_seq),
+        count, scale, interpret=interpret)

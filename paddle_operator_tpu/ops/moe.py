@@ -396,9 +396,26 @@ def moe_apply_fused(params, x, capacity_factor: float = 1.25,
 # one chip's share of a wide expert layer (serving)
 # ---------------------------------------------------------------------------
 
+def _grouped_choice(scores, top_k: int, n_group: int, topk_group: int,
+                    bias):
+    """scores [T, R] float32 -> the ids [T, k] of the top-k of ``scores +
+    bias`` inside the ``topk_group`` groups whose two best sum highest."""
+    t, routed = scores.shape
+    choose = scores if bias is None else scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        grouped = choose.reshape(t, n_group, routed // n_group)
+        best_two, _ = jax.lax.top_k(grouped, 2)
+        _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+        keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+        choose = jnp.where(keep[:, :, None], grouped, -jnp.inf
+                           ).reshape(t, routed)
+    return jax.lax.top_k(choose, top_k)[1]
+
+
 def moe_share_apply(params, z, held, top_k: int, scale: float = 1.0,
                     live=None, layer=None, dtype=jnp.bfloat16,
-                    block: int = 256):
+                    block: int = 256, n_group: int = 1, topk_group: int = 1,
+                    bias=None):
     """The part of an expert layer that THIS chip computes when the
     layer's routed experts are divided over several chips: it is told
     which experts it holds, routes every token over ALL of them, and adds
@@ -419,7 +436,12 @@ def moe_share_apply(params, z, held, top_k: int, scale: float = 1.0,
 
     Routing (sigmoid scoring, plain top-k, gates normalised over the
     chosen and scaled): ``s = sigmoid(z W_r)`` in float32, ``I = top_k(s)``,
-    ``g_i = scale * s_i / sum_{j in I} s_j``. The pairs routed to a held
+    ``g_i = scale * s_i / sum_{j in I} s_j``. Told ``bias`` [R] (float32)
+    and ``n_group`` > 1 it chooses as DeepSeek-V3's ``noaux_tc`` does: on
+    ``s' = s + bias``, a group's score the sum of its two largest ``s'``
+    (``n_group`` equal runs of experts), the ``topk_group`` best groups
+    kept, ``I`` the top-k of ``s'`` inside them; the gates stay those of
+    ``s``. The pairs routed to a held
     expert are sorted by expert; each expert then multiplies its own
     contiguous group, ``block`` rows at a time, in a loop whose trip
     count is the group's size — so the work follows the pairs that are
@@ -437,7 +459,11 @@ def moe_share_apply(params, z, held, top_k: int, scale: float = 1.0,
     scores = jax.nn.sigmoid(jnp.matmul(
         z.astype(jnp.float32), params["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    top_s, top_i = jax.lax.top_k(scores, top_k)                    # [T, k]
+    if bias is None and n_group == 1:
+        top_s, top_i = jax.lax.top_k(scores, top_k)                # [T, k]
+    else:
+        top_i = _grouped_choice(scores, top_k, n_group, topk_group, bias)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
     gates = scale * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
     # expert id -> its slot among the held, g for "held elsewhere"
     slot_of = [g] * routed

@@ -12,7 +12,8 @@ MODEL'S MODULE (``model=``, default :mod:`..models.gpt`), which offers:
 * ``serve_cache(config, num_blocks, block_size)`` — the array half of
   the cache behind a :class:`.kv_cache.KvBlockAllocator`
   (:class:`.kv_cache.PagedKvCache`: K and V pages per head;
-  :class:`.kv_cache.LatentKvCache`: one compressed row a token);
+  :class:`.kv_cache.LatentKvCache`: a tuple of pools behind the one
+  block table, one compressed row a token in each);
 * ``serve_buckets(config, prompt_pad)`` — the padded prompt lengths
   prefill compiles for (a prompt takes the shortest that holds it);
 * ``serve_prefill(config, pad)`` -> ``f(params, ids [1, pad], length) ->
@@ -25,12 +26,16 @@ MODEL'S MODULE (``model=``, default :mod:`..models.gpt`), which offers:
   attention through the model's paged kernel (``attn="paged"``) or its
   gather-einsum reference (``attn="reference"``, which the tests
   compare token for token). ``counters`` are int32 scalars the engine
-  banks under their names (``moe.pairs_here``, ``moe.experts_hit``).
+  banks under their names (``moe.pairs_here``, ``moe.experts_hit``,
+  ``dsa.rows_live``, ``dsa.rows_selected``).
 
 Models served: :mod:`..models.gpt` (float32; no expert configuration:
-its Switch layer drops tokens over capacity and has no decode path) and
+its Switch layer drops tokens over capacity and has no decode path),
 :mod:`..models.axk1` (bfloat16; latent attention through a latent page
-cache, an expert layer that computes the experts this chip holds).
+cache, an expert layer that computes the experts this chip holds) and
+:mod:`..models.dsv32` (that stack with an indexer whose keys have a pool
+of their own, decode over the selected rows only, a prefill that walks
+its prompt in chunks inside one program a bucket).
 
 Both steps compile through :func:`..compile_cache.cached_jit`, as a
 training worker's step does, so a replica takes them from whichever rung

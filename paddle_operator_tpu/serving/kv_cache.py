@@ -15,8 +15,8 @@ scenario and ``make race`` exercise. :class:`PagedKvCache` is the array
 half: the ``[num_blocks, block_size, heads, head_dim]`` K/V pages per
 layer that :func:`..ops.attention_pallas.paged_decode_attention`
 consumes. :class:`LatentKvCache` is the array half for latent
-attention: ONE compressed row a token and layer for all heads, behind
-the same allocator. The engine asks either for ``pools()`` /
+attention: ONE compressed row a token and layer for all heads (a
+tuple of pools, one a width it is told), behind the same allocator. The engine asks either for ``pools()`` /
 ``set_pools()`` (what the decode step takes and hands back; the decode
 step itself writes each new token's rows), ``write_rows()`` (a
 prefill's rows: in both caches one jitted program a padded prompt
@@ -32,7 +32,7 @@ detector both enforce it).
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 
 def _prompt_pages(cache: Any, seq_id: str, n: int, padded: int) -> Any:
@@ -305,62 +305,68 @@ class PagedKvCache:
 
 
 class LatentKvCache:
-    """The array half for latent attention (``models.axk1``): what a
-    token leaves behind in a layer is one row ``[c_kv | k_rope]`` shared
-    by every head, so there is one pool and no separate values.
+    """The array half for latent attention (``models.axk1``,
+    ``models.dsv32``): what a token leaves behind in a layer is one row
+    ``[c_kv | k_rope]`` shared by every head, so there are no separate
+    values; ``models.dsv32`` leaves its indexer's key beside it.
 
-    The pool is ONE array ``[layers, num_blocks + 1, block_size, W]``
-    (the expert layers are one scan, which indexes it by layer; the last
-    page is the pad rows' target as in :class:`PagedKvCache`), ``W`` the
-    row's width rounded up to whole 128-lane tiles: the chip's tiled
-    layout of a row-major page holds that many lanes anyway, and a minor
-    axis that is no multiple of 128 makes XLA lay the pool out
-    token-minor and copy it whole before every kernel call. It answers to
-    the names the paged cache has: ``k_pages`` is the list holding it,
-    ``v_pages`` an empty list. A prefill lands through one jitted,
-    donating scatter (:meth:`write_rows`), and the decode step updates
-    the pool in place (``donate_pools``): an undonated copy of a pool
-    sized to fill the chip does not fit beside it.
+    ``widths`` names the rows a token leaves, and there is one pool a
+    width behind the one allocator and block table (a token's page and
+    slot are the same in each): ``pools()`` is their tuple and a
+    prefill's rows one array a pool. A pool is ONE array ``[layers,
+    num_blocks + 1, block_size, W]`` (the expert layers are one scan,
+    which indexes it by layer; the last page is the pad rows' target as
+    in :class:`PagedKvCache`), ``W`` the row's width rounded up to whole
+    128-lane tiles: the chip's tiled layout of a row-major page holds
+    that many lanes anyway, and a minor axis that is no multiple of 128
+    makes XLA lay the pool out token-minor and copy it whole before
+    every kernel call. It answers to the names the paged cache has:
+    ``k_pages`` is the list of the pools, ``v_pages`` an empty list. A
+    prefill lands through one jitted, donating scatter
+    (:meth:`write_rows`), and the decode step updates the pools in
+    place (``donate_pools``): an undonated copy of a pool sized to fill
+    the chip does not fit beside it.
     """
 
     donate_pools = True
     LANES = 128
 
     def __init__(self, num_blocks: int, block_size: int, layers: int,
-                 width: int, dtype: Any = None) -> None:
+                 widths: Tuple[int, ...], dtype: Any = None) -> None:
         import jax.numpy as jnp
 
         self.allocator = KvBlockAllocator(num_blocks, block_size)
         self.layers = layers
-        self.width = width
+        self.widths = tuple(widths)
         self.dummy_page = num_blocks
-        stored = -(-width // self.LANES) * self.LANES
         self.k_pages = [jnp.zeros(
-            (layers, num_blocks + 1, block_size, stored),
-            dtype or jnp.bfloat16)]
+            (layers, num_blocks + 1, block_size,
+             -(-w // self.LANES) * self.LANES), dtype or jnp.bfloat16)
+            for w in self.widths]
         self.v_pages: List[Any] = []
         self._scatter: Optional[Any] = None
 
-    def pools(self) -> Any:
-        return self.k_pages[0]
+    def pools(self) -> Tuple[Any, ...]:
+        return tuple(self.k_pages)
 
-    def set_pools(self, pools: Any) -> None:
-        self.k_pages = [pools]
+    def set_pools(self, pools: Tuple[Any, ...]) -> None:
+        self.k_pages = list(pools)
 
-    def write_rows(self, seq_id: str, rows: Any, n: int) -> None:
-        """A prefill's rows ``[layers, pad, width]`` (the first ``n`` the
-        prompt's) into the sequence's pages: one program a padded length,
-        whole pages of every layer at once. Pages past the prompt's last
-        go to the dummy page; the last page's slots past ``n`` take
-        padding, which ``seq_lens`` masks until decode overwrites it.
+    def write_rows(self, seq_id: str, rows: Tuple[Any, ...], n: int) -> None:
+        """A prefill's rows, one array ``[layers, pad, width]`` a pool
+        (the first ``n`` the prompt's), into the sequence's pages: one
+        program a padded length, whole pages of every layer and pool at
+        once. Pages past the prompt's last go to the dummy page; the
+        last page's slots past ``n`` take padding, which ``seq_lens``
+        masks until decode overwrites it.
         (A scatter row by row makes XLA copy the whole pool into a layout
         of its own first: 3 GB of temporaries for the benchmark's pool.)"""
         import jax
         import jax.numpy as jnp
 
-        blocks = _prompt_pages(self, seq_id, n, rows.shape[1])
+        blocks = _prompt_pages(self, seq_id, n, rows[0].shape[1])
         if self._scatter is None:
-            def scatter(pool, rows, blocks):
+            def paged(pool, rows, blocks):
                 layers, _, size, stored = pool.shape
                 rows = jnp.pad(rows, ((0, 0),
                                       (0, blocks.shape[0] * size
@@ -369,6 +375,9 @@ class LatentKvCache:
                 return pool.at[:, blocks].set(rows.astype(pool.dtype).reshape(
                     layers, blocks.shape[0], size, stored))
 
+            def scatter(pools, rows, blocks):
+                return [paged(p, r, blocks) for p, r in zip(pools, rows)]
+
             self._scatter = jax.jit(scatter, donate_argnums=(0,))
-        self.k_pages = [self._scatter(self.k_pages[0], rows,
-                                      jnp.asarray(blocks))]
+        self.k_pages = self._scatter(self.k_pages, list(rows),
+                                     jnp.asarray(blocks))

@@ -74,7 +74,7 @@ def test_prefill_then_decode_through_the_latent_cache_gives_the_references_logit
     full forward over everything the row has seen."""
     cfg = family.program_config(TINY)
     bs, blocks, batch = 8, 24, 4
-    cache = LatentKvCache(blocks, bs, layers=3, width=24)
+    cache = LatentKvCache(blocks, bs, layers=3, widths=(24,))
     rnd = np.random.RandomState(0)
     prompts = [list(rnd.randint(0, 512, size=n)) for n in (5, 16, 23)]
     seqs = []

@@ -132,6 +132,35 @@ def test_mla_paged_decode_compiles_for_v5e_without_copying_its_pool(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
+def test_sparse_decode_compiles_for_v5e_without_copying_its_pools(v5e):
+    """At the shapes of ``dsv32-share32.serve-long-8k``: 16 rows of up to
+    260 pages, an indexer of 64 heads x 128 over a six-layer pool of
+    bfloat16 keys, the top 2048 of 33,280 scores, their latent rows
+    fetched through the block table for 128 heads. Two Mosaic calls; the
+    gather reads the flat pool where it lies (the two pools are 4.9 GB:
+    a copy of either would show)."""
+    sh = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def sparse(q_idx, w, keys, ql, qr, latent, tables, lens, layer):
+        scores = ap.dsa_index_scores(q_idx, w, keys, tables, lens,
+                                     layer=layer)
+        chosen, count = ap.select_rows(scores, lens, 2048)
+        return ap.mla_selected_decode(ql, qr, latent, tables, chosen, count,
+                                      0.1, layer=layer)
+
+    compiled = jax.jit(sparse).lower(
+        _sds((16, 64, 128), jnp.bfloat16, sh), _sds((16, 64), jnp.float32, sh),
+        _sds((6, 4161, 128, 128), jnp.bfloat16, sh),
+        _sds((16, 128, 512), jnp.bfloat16, sh),
+        _sds((16, 128, 64), jnp.bfloat16, sh),
+        _sds((6, 4161, 128, 640), jnp.bfloat16, sh),
+        _sds((16, 260), jnp.int32, sh), _sds((16,), jnp.int32, sh),
+        _sds((), jnp.int32, sh)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
+
+
 def test_paged_cache_prefill_write_compiles_for_v5e_in_place(v5e):
     """At the shapes of ``gpt2-small.serve-steady``: 12 layers' K and V
     pools of 192 + 1 pages of 128 slots (76 MB each, 1.82 GB in all) and
