@@ -14,6 +14,19 @@ first tokens, over a tail of the same mix: starting and stopping the
 profiler stalls this loop for seconds, and inside the window that read
 as a 90th percentile of 3540 ms against 454 ms (my chip runs, PR 23).
 So the host-clock metrics of a traced run are those of an untraced one.
+
+The profiler is outside the loop's clock too. ``stop_trace`` costs
+seconds in proportion to what was captured, and a faster server puts
+more steps into the same ``trace_span_s``: 5.0 s for 85 steps (22 MB),
+30.8 s for about 660 (101 MB; builder's chip runs, PR 31, quoted in
+ISSUE 32). Counted on the loop's clock, the second outran ``drain_s``:
+the loop gave up with the tail's requests in flight and a sound run
+read ``unfinished_requests`` 4. So the clock that arrivals come due
+on, and that the loop sleeps and gives up by, is wall time LESS the
+seconds spent inside ``trace.start()`` and ``trace.stop()``: a stall
+of the profiler neither eats the drain allowance nor turns the tail's
+remaining arrivals into one burst. Every stamp that is matched against
+the trace or the program's spans stays on the raw clock.
 """
 
 from __future__ import annotations
@@ -33,7 +46,16 @@ from benchmark.harness.compiles import CompileCounter
 from benchmark.harness.loader import Cell, load_part
 from benchmark.harness.tracing import TraceWindow
 
-ANNOTATIONS = ("batcher.step", "queue.submit", "engine.step_fn")
+# the benchmark's own annotations, then the engine's spans inside
+# ``engine.step_fn``, innermost last: an idle gap is booked to the phase
+# that covers it
+ANNOTATIONS = ("batcher.step", "queue.submit", "engine.step_fn",
+               "serve.step",
+               "serve.prefill.build", "serve.prefill.dispatch",
+               "serve.prefill.scatter", "serve.prefill.wait",
+               "serve.decode.tables", "serve.decode.put",
+               "serve.decode.dispatch", "serve.decode.wait",
+               "serve.decode.readback")
 
 
 class Loop:
@@ -67,7 +89,9 @@ class Loop:
         """Offer ``arrivals`` on their schedule, then drain. Returns the
         raw stamps; nothing here is a metric yet. The profiler starts
         ``trace_after_s`` in, once the first ``first_tokens_of``
-        requests have their first token (or two seconds later)."""
+        requests have their first token (or two seconds later). ``now``
+        does not run while it starts or stops (the module's docstring);
+        the stamps returned are on the raw clock."""
         self.calls: List[Dict[str, Any]] = []
         alloc = self.engine.cache.allocator
         requests = [self.request_cls(
@@ -78,9 +102,10 @@ class Loop:
         sent, shed, samples = [], set(), []
         trace_at = [None, None]
         i, n = 0, len(arrivals)
+        in_profiler = 0.0
         t_open = self.clock()
         while True:
-            now = self.clock() - t_open
+            now = self.clock() - t_open - in_profiler
             while i < n and arrivals[i].due_s <= now:
                 with jax.profiler.TraceAnnotation("queue.submit"):
                     accepted, dropped = self.queue.submit(requests[i])
@@ -103,12 +128,16 @@ class Loop:
                         now >= trace_after_s + 2.0 or all(
                             token_times[r.request_id]
                             for r in requests[:first_tokens_of])):
+                    t_call = self.clock()
                     trace.start()
                     trace_at[0] = self.clock()
+                    in_profiler += trace_at[0] - t_call
                 elif trace.active and \
                         self.clock() - trace_at[0] >= trace_span_s:
+                    t_call = self.clock()
                     trace.stop()
                     trace_at[1] = self.clock()
+                    in_profiler += trace_at[1] - t_call
             self._stepped = None
             with jax.profiler.TraceAnnotation("batcher.step"):
                 self.batcher.step(self.engine_step)
@@ -151,8 +180,10 @@ def warm_up(loop: Loop, arrivals: List[schedule.Arrival], vocab: int,
             seed: int) -> int:
     """Every shape the window will use and no other: one prefill of each
     distinct prompt length of THIS schedule, and the decode step with
-    every row of the batch in use (the engine reads each row's token
-    back by its index). Returns the requests it served."""
+    every row of the batch in use (one program whatever is live: the
+    engine sends a step's inputs in one ``device_put`` and reads all
+    rows' tokens back in one ``device_get``). Returns the requests it
+    served."""
     rnd = random.Random(seed ^ 0x5EED)
     lengths = sorted({len(a.prompt) for a in arrivals})
     rows = loop.engine.max_batch

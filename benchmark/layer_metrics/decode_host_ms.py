@@ -1,7 +1,8 @@
 """Layer: engine. Median, over the window's decode-only steps, of the
 host's phases of one decode step as the engine's own spans bank them:
-block tables, the five transfers, the dispatch and the per-row
-read-back (the wait for the device is ``decode_wait_ms``)."""
+block tables filled in numpy, ONE ``device_put`` of the step's inputs,
+the dispatch and ONE ``device_get`` of tokens and counters (since
+PR 29; the wait for the device is ``decode_wait_ms``)."""
 
 from benchmark.harness.program_spans import decode_only_steps, median_ms
 

@@ -272,6 +272,19 @@ def test_the_familys_byte_and_operation_counts_by_hand():
     assert short["bytes"] == 6 * 1000 * (256 + 1152)
 
 
+#: what a later PR of the ``benchmark`` kind, the only kind that may,
+#: has changed of those files since, and the bound it tightened (PR 32:
+#: the traced serving loop's clock, three docstrings, the lint's pattern)
+CHANGED_BY_A_BENCHMARK_PR = {
+    "benchmark/drivers/serve.py",
+    "benchmark/layer_metrics/decode_host_ms.py",
+    "benchmark/layer_metrics/prefill_scatter_ms.py",
+    "tests/benchmark/test_cellbench_harness.py",
+    "tests/benchmark/test_cellbench_lint.py",
+}
+BOUND_SET_BY_A_BENCHMARK_PR = {"token_gap_p95_ms": 0.05}
+
+
 #: sha256 of every file ``BENCHMARK.json``'s ``paths`` held at the
 #: parent commit (d0b961a) that this PR could have edited: it edited none
 def test_no_file_the_benchmark_had_was_edited():
@@ -286,6 +299,8 @@ def test_no_file_the_benchmark_had_was_edited():
         pytest.skip("the parent commit is not in this checkout")
     for row in listed.stdout.strip().splitlines():
         meta, path = row.split("\t")
+        if path in CHANGED_BY_A_BENCHMARK_PR:
+            continue
         blob = meta.split()[2]
         with open(os.path.join(ROOT, path), "rb") as fh:
             data = fh.read()
@@ -309,6 +324,8 @@ def test_benchmark_json_gained_entries_only():
         assert new[key] == old[key]
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
         for was, now in zip(old[group], new[group]):
+            if was["name"] in BOUND_SET_BY_A_BENCHMARK_PR:
+                was["bound"] = BOUND_SET_BY_A_BENCHMARK_PR[was["name"]]
             lists = {k for k in was if k == "workloads"}
             assert {k: v for k, v in was.items() if k not in lists} \
                 == {k: v for k, v in now.items() if k not in lists}

@@ -139,6 +139,85 @@ def test_a_traced_serve_run_traces_after_its_window(copy, monkeypatch,
     assert 1.0 <= wall[0] < 3.5
 
 
+class _SlowProfiler(_NoProfiler):
+    """Takes seconds to start and to stop, as the real one does: its
+    stop costs in proportion to what the span captured."""
+
+    def __init__(self, start_s, stop_s):
+        super().__init__()
+        self.start_s, self.stop_s = start_s, stop_s
+
+    def start(self):
+        time.sleep(self.start_s)
+        super().start()
+
+    def stop(self):
+        time.sleep(self.stop_s)
+        super().stop()
+
+
+@pytest.mark.parametrize("start_s,stop_s", [(0.0, 3.4), (1.7, 1.7)])
+def test_a_profiler_slower_than_the_drain_leaves_no_request_unfinished(
+        tmp_path, monkeypatch, capsys, start_s, stop_s):
+    """The loop's clock does not run inside the profiler's calls. The
+    tail goes on for two seconds past the span and ``drain_s`` is one
+    more, so a profiler that takes 3.4 s comes back past the loop's
+    deadline on the wall: counted there, the loop gives up with the
+    tail's requests unsent or in flight, and a sound server reads
+    ``unfinished_requests`` above 0 and pages still held (PR 31)."""
+    from benchmark.harness import tracing
+
+    root = tiny.make_copy(tmp_path)
+    path = os.path.join(root, "benchmark", "traffic", "tiny-serve.json")
+    traffic = json.load(open(path))
+    traffic["drain_s"] = 1.0
+    json.dump(traffic, open(path, "w"))
+    made = []
+    monkeypatch.setattr(
+        tracing, "TraceWindow",
+        lambda: made.append(_SlowProfiler(start_s, stop_s)) or made[-1])
+    cell = loader.load_cell("tiny-gpt.tiny-serve", root=root)
+    say = result.say_factory(" platform=cpu DRY RUN")
+    line = cli.run_cell(cell, SEED, 1.0, True, tiny.cpu_device(),
+                        tiny.CPU_PEAKS, say, time.perf_counter())
+    assert made and made[0].done
+    checks = {ln.split()[2]: ln for ln in capsys.readouterr().out.splitlines()
+              if ln.startswith("CELLBENCH check")}
+    for name in ("unfinished_requests", "pool_blocks_left",
+                 "pool_sequences_left"):
+        assert "value=0 " in checks[name] and "FAILED" not in checks[name]
+    assert line["correct"] is True
+    assert line["attempted"] == 60 and line["failed"] == 0
+
+
+def test_an_idle_gap_is_booked_to_the_engines_phase_inside_the_step():
+    """``breakdown.idle_gaps`` names the innermost of the driver's
+    annotations that covers a gap: a phase of the engine's step where
+    there is one, and each of those names is a span the engine banks."""
+    from benchmark.drivers import serve
+    from benchmark.harness import xplane
+    from benchmark.harness.xplane import Event
+
+    host = {"python3": [Event("batcher.step", 0.0, 10.0),
+                        Event("engine.step_fn", 1.0, 9.0),
+                        Event("serve.step", 1.1, 8.9),
+                        Event("serve.decode.put", 2.0, 3.0),
+                        Event("serve.decode.wait", 4.0, 8.0),
+                        Event("PjRtExecute", 4.1, 4.2)]}
+    gaps = [(2.4, 2.6), (4.12, 4.18), (5.0, 6.0), (8.5, 8.75), (9.2, 9.5)]
+    got = dict(xplane.attribute_gaps(gaps, host, prefer=serve.ANNOTATIONS))
+    assert got == {"serve.decode.put": pytest.approx(0.2),
+                   "serve.decode.wait": pytest.approx(1.06),
+                   "serve.step": pytest.approx(0.25),
+                   "batcher.step": pytest.approx(0.3)}
+    with open(os.path.join(ROOT, "paddle_operator_tpu", "serving",
+                           "engine.py")) as fh:
+        engine = fh.read()
+    spans = [n for n in serve.ANNOTATIONS if n.startswith("serve.")]
+    assert len(spans) == 10
+    assert all('"%s"' % n in engine for n in spans)
+
+
 @pytest.mark.parametrize("key", ["param_dtype", "cache_dtype"])
 def test_a_server_that_stores_in_bfloat16_is_not_correct(tmp_path, capsys,
                                                          key):

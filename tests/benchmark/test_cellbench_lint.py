@@ -17,8 +17,8 @@ from benchmark.harness import loader
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj|_dim$|_rank$|"
-                   r"head_size|n_embd|n_inner|expansion|per_tok)")
+WIDTH = re.compile(r"(hidden_size|hidden_dim|intermediate|latent|state|proj|"
+                   r"_dim$|_rank$|head_size|n_embd|n_inner|expansion|per_tok)")
 
 
 @pytest.fixture(scope="module")
@@ -111,6 +111,18 @@ def test_cells_configurations_and_metrics_hang_together(spec):
         assert "setup_s" in ends and len(ends) >= 2, cell
         assert any(reported_in(m, cell) and m["moves"] in ends
                    for m in spec["per_layer"]), cell
+
+
+@pytest.mark.parametrize("key,is_width", [
+    ("hidden_size", True), ("hidden_dim", True),
+    ("moe_intermediate_size", True), ("kv_lora_rank", True),
+    ("num_experts_per_tok", True),
+    # a depth cut has to name this key: ``hidden`` alone refused it
+    ("num_hidden_layers", False), ("n_routed_experts", False),
+    ("vocab_size", False),
+])
+def test_reduced_may_name_a_depth_and_never_a_width(key, is_width):
+    assert bool(WIDTH.search(key)) is is_width
 
 
 def test_every_named_file_is_there_and_says_what_it_must(spec):
