@@ -6,18 +6,20 @@ width of ``models/gpt.BASE_CONFIG`` (GPT-2 small: 768 wide, 12 layers,
 12 heads, vocabulary 50304, 1024 positions; random weights from a seed):
 
 1. kernel checks — ``flash_attention`` forward and gradients,
-   ``paged_decode_attention``, ``mla_paged_decode`` (at the shapes of
-   the ``axk1-share16`` cell) and ``dsa_index_scores`` + ``select_rows``
-   + ``mla_selected_decode`` (at those of ``dsv32-share32``) against
-   their references, compiled (``interpret=False``), each under a
-   written tolerance;
+   ``paged_decode_attention`` (over the stacked pools of the
+   ``gpt2-small.serve-steady`` cell), ``mla_paged_decode`` (at the
+   shapes of the ``axk1-share16`` cell) and ``dsa_index_scores`` +
+   ``select_rows`` + ``mla_selected_decode`` (at those of
+   ``dsv32-share32``) against their references, compiled
+   (``interpret=False``), each under a written tolerance;
 2. trainer — the ``TrainJob`` of ``examples/train_gpt.py`` through
    ``launch.detect_env`` + ``runner.run_training`` over all local
    devices: 3 steps and a checkpoint, then a second run on the same
    directory that resumes at step 3 and takes 2 more;
 3. server — ``ServingEngine`` with its defaults (``attn="paged"``) behind
    a ``RequestQueue`` + ``ContinuousBatcher``: 8 requests, half of them
-   admitted into a batch that is already decoding;
+   admitted into a batch that is already decoding; then the decode step
+   compiled at the cell's shapes, which must copy no page pool;
 4. compile-cache report — where the cache lives, and that no cached
    executable was refused and no AOT lowering fell back to plain jit.
 
@@ -130,26 +132,31 @@ def kernel_checks(sm: Smoke) -> None:
     sm.check(fwd <= FLASH_FWD_TOL, "flash_attention fwd error %g" % fwd)
     sm.check(gerr <= FLASH_GRAD_TOL, "flash_attention grad error %g" % gerr)
 
-    # paged decode at the engine's own shapes (ServingEngine defaults)
-    b, h, d, bs = (4, 4, 32, 16) if sm.rehearsal else (8, 12, 64, 16)
-    pages, per_seq = (33, 8) if sm.rehearsal else (257, 64)
+    # paged decode over the stacked pools of gpt2-small.serve-steady: 32
+    # rows x 12 heads of 64 over f32[12, 193, 128, 768] a side, read in
+    # place; a row of one token, one that fills its last page, a pad row
+    b, h, d, bs = (4, 4, 32, 16) if sm.rehearsal else (32, 12, 64, 128)
+    layers, pages, per_seq = (2, 33, 8) if sm.rehearsal else (12, 193, 8)
     keys = jax.random.split(jax.random.PRNGKey(sm.seed + 1), 5)
     q = jax.random.normal(keys[0], (b, h, d), jnp.float32)
-    kp = jax.random.normal(keys[1], (pages, bs, h, d), jnp.float32)
-    vp = jax.random.normal(keys[2], (pages, bs, h, d), jnp.float32)
+    kp = jax.random.normal(keys[1], (layers, pages, bs, h * d), jnp.float32)
+    vp = jax.random.normal(keys[2], (layers, pages, bs, h * d), jnp.float32)
     tables = jax.random.randint(keys[3], (b, per_seq), 0, pages - 1)
-    lens = jax.random.randint(keys[4], (b,), 1, per_seq * bs + 1)
-    got = jax.jit(lambda *a: ap.paged_decode_attention(
-        *a, interpret=interpret))(q, kp, vp, tables, lens)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda *a: ap._reference_paged_decode(
-            *a, 1.0 / math.sqrt(d)))(q, kp, vp, tables, lens)
-    err = rel_err(got, want)
-    sm.say("kernel paged_decode_attention",
-           q="x".join(map(str, q.shape)),
-           pages="x".join(map(str, kp.shape)), dtype="f32",
-           interpret=interpret, rel_err="%.2e" % err, tol=PAGED_TOL)
-    sm.check(err <= PAGED_TOL, "paged_decode_attention error %g" % err)
+    lens = jax.random.randint(keys[4], (b,), 1, per_seq * bs + 1
+                              ).at[0].set(1).at[1].set(2 * bs).at[2].set(0)
+    for layer in (0, layers - 1):
+        got = jax.jit(lambda *a: ap.paged_decode_attention(
+            *a, layer, interpret=interpret))(q, kp, vp, tables, lens)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda *a: ap._reference_paged_decode(
+                *a, 1.0 / math.sqrt(d), layer))(q, kp, vp, tables, lens)
+        err = rel_err(got, want)
+        sm.say("kernel paged_decode_attention",
+               q="x".join(map(str, q.shape)),
+               pool="x".join(map(str, kp.shape)), layer=layer, dtype="f32",
+               interpret=interpret, rel_err="%.2e" % err, tol=PAGED_TOL)
+        sm.check(err <= PAGED_TOL, "paged_decode_attention error %g" % err)
+    del kp, vp
 
     # latent paged decode at the shapes of axk1-share16.serve-decode-1k:
     # 64 rows x 64 heads over bfloat16 pages of 128 rows [512 | 64 | pad]
@@ -424,13 +431,55 @@ def server_leg(sm: Smoke) -> None:
              "KV pool not empty afterwards: %r" % stats)
     sm.check(engine.cache.allocator.check() == [],
              "allocator audit: %r" % engine.cache.allocator.check())
-    kernels = engine._decode_fn.as_text().count(
-        'custom_call_target="tpu_custom_call"')
-    sm.say("server decode step", mosaic_calls=kernels, attn=engine.attn)
+    del engine
+    decode_copies_no_pool(sm, params, cfg)
+
+
+def decode_copies_no_pool(sm: Smoke, params, cfg) -> None:
+    """The decode step at the shapes of ``gpt2-small.serve-steady`` (32
+    rows, two donated pools f32[12, 193, 128, 768]), compiled for this
+    device: one Mosaic call a layer, no ``copy`` or ``transpose`` yields
+    an array of a pool's shape and the step's temporaries are no pool's
+    size, so the whole-pool copies of the per-layer layout (25 of a 29
+    ms step) cannot come back unseen."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_operator_tpu.models import gpt
+
+    b, bs, blocks = (4, 16, 32) if sm.rehearsal else (32, 128, 192)
+    pool = jax.ShapeDtypeStruct(
+        (cfg["layers"], blocks + 1, bs, cfg["hidden"]), jnp.float32)
+    row = jax.ShapeDtypeStruct((b,), jnp.int32)
+    compiled = jax.jit(
+        gpt.serve_decode(cfg, "paged", bs, blocks), donate_argnums=(1,)
+    ).lower(params, (pool, pool), row, row,
+            jax.ShapeDtypeStruct((b, cfg["max_seq"] // bs), jnp.int32), row,
+            jax.ShapeDtypeStruct((b,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    shape = "f32[%s]" % ",".join(map(str, pool.shape))
+    moved = re.findall(r"= %s\S* ((?:copy|transpose)[\w-]*)\("
+                       % re.escape(shape), text)
+    pool_bytes = math.prod(pool.shape) * 4
+    mem = compiled.memory_analysis()
+    sm.say("server decode program", mosaic_calls=kernels, pool=shape,
+           pool_bytes=pool_bytes, pool_copies=moved,
+           temp_bytes=mem.temp_size_in_bytes,
+           alias_bytes=mem.alias_size_in_bytes)
     if not sm.rehearsal:
         sm.check(kernels == cfg["layers"],
                  "decode step holds %d Mosaic calls, want one per layer"
                  % kernels)
+        sm.check(moved == [], "the decode step copies a pool: %r" % moved)
+        sm.check(mem.temp_size_in_bytes < pool_bytes // 8,
+                 "the decode step's temporaries are %d bytes"
+                 % mem.temp_size_in_bytes)
+        sm.check(mem.alias_size_in_bytes >= 2 * pool_bytes,
+                 "the decode step aliases %d bytes of its pools"
+                 % mem.alias_size_in_bytes)
 
 
 # ---------------------------------------------------------------------------

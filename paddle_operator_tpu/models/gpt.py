@@ -183,8 +183,9 @@ def synthetic_batch(key, batch_size: int, seq_len: int = 256,
 # block tables, page memory and the spans; the layer stack, the cache's
 # layout and the two step functions come from the model's module
 # (``models.axk1`` has the same five). These are the bodies the engine
-# held inline before it took a second model, unchanged: float32
-# throughout, one padded prompt length, K and V pages per head.
+# held inline before it took a second model: float32 throughout, one
+# padded prompt length, one K and one V page pool for all layers, a
+# token's heads side by side in a row (``serving.kv_cache.PagedKvCache``).
 
 def _rope_rows(x: jnp.ndarray, positions: jnp.ndarray,
                base: float = 10000.0) -> jnp.ndarray:
@@ -227,7 +228,9 @@ def serve_buckets(config: dict, prompt_pad: int):
 
 
 def serve_cache(config: dict, num_blocks: int, block_size: int):
-    """K and V pages per layer and head, float32."""
+    """One K and one V pool ``[layers, num_blocks + 1, block_size,
+    heads * head_dim]``, float32, which the decode step updates in
+    place."""
     from ..serving.kv_cache import PagedKvCache
 
     if config.get("moe_experts"):
@@ -247,10 +250,11 @@ def serve_prefill(config: dict, pad: int):
 
     def prefill(params, ids: jnp.ndarray, length: jnp.ndarray):
         """ids [1, pad] zero-padded, length [] int32 -> (first
-        sampled token [] int32, ([k per layer], [v per layer])) with
-        k/v shaped [pad, H, Dh] (callers slice to the real length).
-        Plain causal attention — prefill sees the whole prompt, so
-        the training-style full-sequence path is exactly right."""
+        sampled token [] int32, (k, v)) with k/v shaped [layers, pad,
+        H * Dh], a token's row as the cache stores it (its
+        ``write_rows`` takes the first ``length``). Plain causal
+        attention — prefill sees the whole prompt, so the
+        training-style full-sequence path is exactly right."""
         x = nn.embedding(params["embed"]["tok"], ids, jnp.float32)
         positions = jnp.arange(pad)[None, :]
         cmask = jnp.tril(jnp.ones((pad, pad), bool))[None, None]
@@ -260,8 +264,8 @@ def serve_prefill(config: dict, pad: int):
             q, k, v = _qkv(layer, h)
             q = _rope_rows(q, positions)
             k = _rope_rows(k, positions)
-            ks.append(k[0])
-            vs.append(v[0])
+            ks.append(k[0].reshape(pad, -1))
+            vs.append(v[0].reshape(pad, -1))
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) \
                 / math.sqrt(q.shape[-1])
             scores = jnp.where(cmask, scores, -1e30)
@@ -275,7 +279,8 @@ def serve_prefill(config: dict, pad: int):
         last = x[0, length - 1]
         logits = nn.dense(params["lm_head"], last[None],
                           dtype=jnp.float32)[0]
-        return jnp.argmax(logits).astype(jnp.int32), (ks, vs)
+        return (jnp.argmax(logits).astype(jnp.int32),
+                (jnp.stack(ks), jnp.stack(vs)))
 
     return prefill
 
@@ -284,19 +289,56 @@ def serve_decode(config: dict, attn: str, block_size: int, dummy_page: int):
     del config
     import math
 
+    from ..ops.attention_pallas import (
+        _reference_paged_decode, paged_decode_attention,
+    )
+
     bs, dummy = block_size, dummy_page
+
+    @jax.jit
+    def block(layer, li, x, k_pages, v_pages, pos2, blocks, slots, tables,
+              new_lens):
+        """One layer of the step, ``li`` its index in the pools. Jitted
+        so that the step traces and lowers it ONCE for all its layers
+        (they differ in nothing but their weights and ``li``): a
+        donating step is traced in every process, the compile ladder's
+        AOT rung refusing it, and twelve lowerings of the layer and its
+        kernel were 1.8 s of the benchmark's set-up (chip runs,
+        PR 33)."""
+        def stored(rows):               # [B, 1, H, D] -> the cache's row
+            rows = rows.reshape(rows.shape[0], -1).astype(k_pages.dtype)
+            return jnp.pad(rows, ((0, 0), (0, k_pages.shape[-1]
+                                           - rows.shape[1])))
+
+        h = nn.layernorm(layer["ln1"], x, dtype=jnp.float32)
+        q, k, v = _qkv(layer, h)
+        q = _rope_rows(q, pos2)
+        k = _rope_rows(k, pos2)
+        k_pages = k_pages.at[li, blocks, slots].set(stored(k))
+        v_pages = v_pages.at[li, blocks, slots].set(stored(v))
+        if attn == "paged":
+            ctx = paged_decode_attention(
+                q[:, 0], k_pages, v_pages, tables, new_lens, li,
+                interpret=jax.default_backend() != "tpu")
+        else:
+            ctx = _reference_paged_decode(
+                q[:, 0], k_pages, v_pages, tables, new_lens,
+                1.0 / math.sqrt(q.shape[-1]), li)
+        y = jnp.einsum("bhd,hdo->bo", ctx.astype(jnp.float32),
+                       layer["attn"]["o"]["kernel"]) \
+            + layer["attn"]["o"]["bias"]
+        return _ffn(layer, x + y[:, None]), k_pages, v_pages
 
     def decode(params, pools, tokens: jnp.ndarray, positions: jnp.ndarray,
                tables: jnp.ndarray, lens: jnp.ndarray, live: jnp.ndarray):
-        """One token for every row: pools = (k_pages, v_pages), tokens
-        [B] int32 (each row's last sampled token), positions [B] (its
-        0-based index), tables [B, T], lens [B] (live cache tokens
-        BEFORE this step), live [B] bool (False = pad row). Returns
-        (next tokens [B], (new k_pages, v_pages), no counters)."""
-        from ..ops.attention_pallas import (
-            _reference_paged_decode, paged_decode_attention,
-        )
-
+        """One token for every row: pools = (K, V), the cache's two
+        stacked pools ``[layers, pages, bs, W]``, which the engine
+        donates; tokens [B] int32 (each row's last sampled token),
+        positions [B] (its 0-based index), tables [B, T], lens [B]
+        (live cache tokens BEFORE this step), live [B] bool (False =
+        pad row). Every layer writes its new rows into the two arrays
+        where they lie and attends over them there; the SAME two come
+        back: (next tokens [B], (K, V), no counters)."""
         k_pages, v_pages = pools
         x = nn.embedding(params["embed"]["tok"], tokens[:, None],
                          jnp.float32)                       # [B,1,D]
@@ -308,33 +350,16 @@ def serve_decode(config: dict, attn: str, block_size: int, dummy_page: int):
         # and no live block table can reference it
         blocks = jnp.where(live, gathered, dummy)
         slots = jnp.where(live, positions % bs, 0)
-        new_lens = lens + 1
-        new_k, new_v = [], []
+        # a pad row attends to nothing: the kernel skips its pages
+        new_lens = jnp.where(live, lens + 1, 0)
         for li, layer in enumerate(params["layers"]):
-            h = nn.layernorm(layer["ln1"], x, dtype=jnp.float32)
-            q, k, v = _qkv(layer, h)
-            q = _rope_rows(q, pos2)
-            k = _rope_rows(k, pos2)
-            kp = k_pages[li].at[blocks, slots].set(k[:, 0])
-            vp = v_pages[li].at[blocks, slots].set(v[:, 0])
-            new_k.append(kp)
-            new_v.append(vp)
-            if attn == "paged":
-                ctx = paged_decode_attention(
-                    q[:, 0], kp, vp, tables, new_lens,
-                    interpret=jax.default_backend() != "tpu")
-            else:
-                ctx = _reference_paged_decode(
-                    q[:, 0], kp, vp, tables, new_lens,
-                    1.0 / math.sqrt(q.shape[-1]))
-            y = jnp.einsum("bhd,hdo->bo", ctx.astype(jnp.float32),
-                           layer["attn"]["o"]["kernel"]) \
-                + layer["attn"]["o"]["bias"]
-            x = _ffn(layer, x + y[:, None])
+            x, k_pages, v_pages = block(
+                layer, jnp.int32(li), x, k_pages, v_pages, pos2, blocks,
+                slots, tables, new_lens)
         x = nn.layernorm(params["final_ln"], x, dtype=jnp.float32)
         logits = nn.dense(params["lm_head"], x[:, 0],
                           dtype=jnp.float32)               # [B,V]
         return (jnp.argmax(logits, -1).astype(jnp.int32),
-                (new_k, new_v), {})
+                (k_pages, v_pages), {})
 
     return decode

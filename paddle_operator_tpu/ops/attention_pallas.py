@@ -492,52 +492,86 @@ def _check_blocks(q_shape, block_q, block_k):
 #
 # Serving decode is the inverse workload of training prefill: ONE query
 # token per sequence against a KV history scattered across fixed-size
-# cache pages (serving/kv_cache.py — the vLLM layout). The kernel grid is
-# (batch, page): the page axis is the fast, sequential one, so the online
-# softmax accumulates across a sequence's pages in fp32 VMEM scratch (the
-# same revisited-output-block pattern as _dkv_kernel) and writes the
-# context row once on the last page. Block tables and sequence lengths
-# ride in as scalar prefetch (pltpu.PrefetchScalarGridSpec), so the page
-# index_map can dereference the table BEFORE the body runs — the DMA for
-# page t of sequence b fetches k_pages[table[b, t]] directly; no gather
-# materializes.
+# cache pages (serving/kv_cache.py — the vLLM layout). The pools are the
+# cache's own arrays, one a side for every layer: ``[L, P, bs, W]``, a
+# token's row the heads side by side (head h in lanes ``h * D .. (h + 1)
+# * D``) and ``W`` that width rounded up to whole 128-lane tiles, so a
+# page is lane-dense and row-major as XLA holds it and the kernel reads
+# it where it lies: nothing slices, reshapes or transposes a pool
+# outside. The kernel grid is (batch, page): the page axis is the fast,
+# sequential one, so the online softmax accumulates across a sequence's
+# pages in fp32 VMEM scratch (the same revisited-output-block pattern as
+# _dkv_kernel) and writes the context row once on the last page. Block
+# tables, sequence lengths and the layer ride in as scalar prefetch
+# (pltpu.PrefetchScalarGridSpec), so the page index_map can dereference
+# the table BEFORE the body runs — the DMA for page t of sequence b
+# fetches pool[layer, table[b, t]] directly; no gather materializes.
+# Pages past a sequence's last live one repeat that page's index, so the
+# pipeline fetches nothing new for them, and their body is skipped; and
+# the grid itself ends at the last live row and the longest row's last
+# page (its bounds are computed from ``seq_lens`` each call): a cell with
+# nothing to read still costs 0.2 us, and a decode batch is mostly pad
+# rows and short rows (32 x 8 cells a layer took 0.84 ms a step at five
+# live rows, the cells that read something 0.34: chip runs, PR 33).
+
+HEAD_LANES = 128      # a page's scores: tokens on sublanes, heads on lanes
 
 
 def _reference_paged_decode(q, k_pages, v_pages, block_tables, seq_lens,
-                            scale):
-    """Gather-then-einsum reference: q [B,H,D], pages [P,bs,H,D],
-    block_tables [B,T] int32, seq_lens [B] int32 -> [B,H,D]. fp32
-    softmax, identical math to the kernel up to summation order."""
-    bs = k_pages.shape[1]
+                            scale, layer=0):
+    """Gather-then-einsum reference: q [B,H,D], pools [L,P,bs,W] with
+    W >= H*D, block_tables [B,T] int32, seq_lens [B] int32 -> [B,H,D]
+    (zeros for a row of length 0). fp32 softmax, identical math to the
+    kernel up to summation order."""
+    bs = k_pages.shape[2]
     b, h, d = q.shape
     t = block_tables.shape[1]
-    # [B, T, bs, H, D] -> [B, T*bs, H, D]
-    k = jnp.take(k_pages, block_tables, axis=0).reshape(b, t * bs, h, d)
-    v = jnp.take(v_pages, block_tables, axis=0).reshape(b, t * bs, h, d)
-    s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
+
+    def rows(pool):                     # [B, T, bs, W] -> [B, T*bs, H, D]
+        return pool[layer, block_tables][..., :h * d].reshape(
+            b, t * bs, h, d).astype(jnp.float32)
+
+    k, v = rows(k_pages), rows(v_pages)
+    s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32), k) * scale
     valid = jnp.arange(t * bs)[None, :] < seq_lens[:, None]     # [B, T*bs]
     s = jnp.where(valid[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhk,bkhd->bhd", p, v.astype(jnp.float32)
-                      ).astype(q.dtype)
+    out = jnp.einsum("bhk,bkhd->bhd", p, v)
+    return jnp.where(seq_lens[:, None, None] > 0, out, 0.0).astype(q.dtype)
 
 
-def _paged_decode_kernel(seq_lens_ref, tables_ref, q_ref, k_ref, v_ref,
-                         o_ref, acc_ref, m_ref, l_ref, *, scale,
-                         block_size, pages_per_seq):
+def _onehot_dot(x, onehot, contract):
+    """float32 ``x`` times a 0/1 matrix on the MXU at float32 precision:
+    Mosaic's default rounds both operands to bfloat16 (3e-3 of the
+    result, read on the chip), and the passes that ``HIGHEST`` adds
+    cost this kernel nothing measurable, its pages being read from HBM
+    the while."""
+    return jax.lax.dot_general(
+        x, onehot, (((1,), (contract,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _paged_decode_kernel(seq_lens_ref, tables_ref, layer_ref, q_ref,
+                         head_of_ref, k_ref, v_ref, zeros_ref, o_ref,
+                         acc_ref, m_ref, l_ref, *, block_size):
     """One (sequence, page) cell: score the query row against this page's
     tokens, fold into the running online softmax held in scratch.
 
-    Score and context are multiply-and-reduce on the VPU, not matmuls: a
-    single query row per head gives the MXU nothing to tile (M = 1), and
-    the batched ``dot_general`` this replaces — heads as a non-leading
-    batch dimension, no free lhs dimension — does not lower for the TPU
-    at all (Mosaic rejects its dimension numbers). Every intermediate
-    keeps the page's own layout: tokens major, heads on sublanes,
-    head_dim on lanes."""
+    A page is ``[bs, W]``, every head of a token side by side on the
+    lanes. The products ``k * q`` are taken on the VPU in float32; a
+    head's score is the sum over ITS lanes, which is a matmul with the
+    0/1 matrix ``head_of`` ``[W, 128]`` (lane w belongs to head w // D):
+    scores ``[bs, 128]``, tokens on sublanes and heads on lanes, so the
+    softmax's max and sum run down the sublanes. The probabilities go
+    back to the lanes of their heads through the same matrix
+    (``[bs, 128] x [W, 128]^T``), with the running correction as rows of
+    the same product, and weigh the value page on the VPU."""
+    del tables_ref, layer_ref          # read by the index maps
+    del zeros_ref                      # what o_ref starts as
     b = pl.program_id(0)
     t = pl.program_id(1)
+    n = seq_lens_ref[b]
 
     @pl.when(t == 0)
     def _init():
@@ -545,43 +579,60 @@ def _paged_decode_kernel(seq_lens_ref, tables_ref, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale             # [H, D]
-    k = k_ref[0].astype(jnp.float32)                     # [bs, H, D]
-    v = v_ref[0].astype(jnp.float32)
-    s = jnp.sum(k * q[None], axis=-1, keepdims=True)     # [bs, H, 1]
-    pos = t * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    s = jnp.where(pos < seq_lens_ref[b], s, NEG_INF)
-    m_prev = m_ref[...]                                  # [H, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-    p = jnp.exp(s - m_new[None])                         # [bs, H, 1]
-    correction = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * correction + jnp.sum(p, axis=0)
-    acc_ref[...] = acc_ref[...] * correction + jnp.sum(p * v, axis=0)
-    m_ref[...] = m_new
+    @pl.when(t * block_size < n)
+    def _page():
+        head_of = head_of_ref[...]                           # [W, 128]
+        k = k_ref[0, 0].astype(jnp.float32)                  # [bs, W]
+        v = v_ref[0, 0].astype(jnp.float32)
+        s = _onehot_dot(k * q_ref[0], head_of, 0)
+        pos = t * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        s = jnp.where(pos < n, s, NEG_INF)                   # [bs, 128]
+        m_prev = m_ref[...]                                  # [1, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * correction \
+            + jnp.sum(p, axis=0, keepdims=True)
+        m_ref[...] = m_new
+        wide = _onehot_dot(
+            jnp.concatenate(
+                [p, jnp.broadcast_to(correction, (8, HEAD_LANES))], axis=0),
+            head_of, 1)                                      # [bs + 8, W]
+        acc_ref[...] = acc_ref[...] * wide[block_size:block_size + 1] \
+            + jnp.sum(wide[:block_size] * v, axis=0, keepdims=True)
 
-    @pl.when(t == pages_per_seq - 1)
+    @pl.when(t == pl.num_programs(1) - 1)
     def _write():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        l = l_ref[...]
+        inverse = jnp.broadcast_to(1.0 / jnp.where(l > 0.0, l, 1.0),
+                                   (8, HEAD_LANES))
+        o_ref[0] = (acc_ref[...] * _onehot_dot(
+            inverse, head_of_ref[...], 1)[:1]).astype(o_ref.dtype)
 
 
 def supports_paged(q_shape, block_size: int) -> bool:
     """Kernel applicability for decode: [B, H, D] single-token queries,
-    lane-friendly head_dim, sublane-aligned page size."""
+    a head a lane of the score tile, sublane-aligned page size."""
     if len(q_shape) != 3:
         return False
-    _, _, d = q_shape
-    return d in (64, 128, 256) and block_size % 8 == 0
+    _, h, _ = q_shape
+    return h <= HEAD_LANES and block_size % 8 == 0
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
-                           scale=None, interpret: bool = False):
+                           layer, scale=None, interpret: bool = False):
     """Single-token decode attention over a paged KV cache.
 
     q: ``[B, H, D]`` (one new query token per sequence) — k_pages /
-    v_pages: ``[P, bs, H, D]`` page pools — block_tables: ``[B, T]``
-    int32 page ids per sequence (entries past the sequence's pages may
-    be any valid id; their tokens are masked by ``seq_lens``) —
-    seq_lens: ``[B]`` int32 tokens live in each sequence's cache.
+    v_pages: the cache's stacked pools ``[L, P, bs, W]``, ``W >= H * D``
+    a multiple of 128 (``serving.kv_cache.PagedKvCache``), read in place
+    — block_tables: ``[B, T]`` int32 page ids per sequence (entries past
+    the sequence's pages may be any valid id; their tokens are masked by
+    ``seq_lens``) — seq_lens: ``[B]`` int32 tokens live in each
+    sequence's cache; a row of length 0 (a pad row) reads nothing and
+    gets zeros — layer: an int32 scalar, traced or not, that picks the
+    layer's pages without slicing them out.
     Returns the attention context ``[B, H, D]``.
 
     Inference-only by design (no VJP): decode never backpropagates.
@@ -590,11 +641,15 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
     reference — the equivalence tests pin it).
     """
     b, h, d = q.shape
-    p_total, block_size, kh, kd = k_pages.shape
-    if (kh, kd) != (h, d) or v_pages.shape != k_pages.shape:
+    if k_pages.ndim != 4 or v_pages.shape != k_pages.shape:
         raise ValueError(
-            "page pools %r/%r do not match q heads/dim %r"
-            % (k_pages.shape, v_pages.shape, (h, d)))
+            "page pools %r/%r are not one stacked [L, P, bs, W] a side"
+            % (k_pages.shape, v_pages.shape))
+    _, _, block_size, width = k_pages.shape
+    if width < h * d or width % MIN_BLOCK or h > HEAD_LANES:
+        raise ValueError(
+            "page pools %r do not hold q's %d heads of %d on whole lanes"
+            % (k_pages.shape, h, d))
     if block_tables.shape[0] != b or seq_lens.shape != (b,):
         raise ValueError(
             "block_tables %r / seq_lens %r do not cover batch %d"
@@ -602,41 +657,53 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     pages_per_seq = block_tables.shape[1]
-    grid = (b, pages_per_seq)
+    scaled = jnp.pad((q.astype(jnp.float32) * scale).reshape(b, 1, h * d),
+                     ((0, 0), (0, 0), (0, width - h * d)))
+    lane = jnp.arange(width)[:, None]
+    head_of = ((lane // d == jnp.arange(HEAD_LANES)[None, :])
+               & (lane < h * d)).astype(jnp.float32)
 
-    def q_index(bi, ti, seq_lens_ref, tables_ref):
+    def q_index(bi, ti, lens_ref, tables_ref, layer_ref):
         return (bi, 0, 0)
 
-    def page_index(bi, ti, seq_lens_ref, tables_ref):
+    def page_index(bi, ti, lens_ref, tables_ref, layer_ref):
         # the scalar-prefetch dereference: page t of sequence b IS
-        # pages[table[b, t]] — the whole point of the layout
-        return (tables_ref[bi, ti], 0, 0, 0)
+        # pool[layer, table[b, t]] — the whole point of the layout
+        last = jnp.maximum(lens_ref[bi] - 1, 0) // block_size
+        return (layer_ref[0], tables_ref[bi, jnp.minimum(ti, last)], 0, 0)
 
+    seq_lens = seq_lens.astype(jnp.int32)
+    rows = jnp.max(jnp.where(seq_lens > 0, jnp.arange(1, b + 1), 1))
+    pages = jnp.clip(-(-jnp.max(seq_lens) // block_size), 1, pages_per_seq)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
+        num_scalar_prefetch=3,
+        grid=(rows, pages),
         in_specs=[
-            pl.BlockSpec((1, h, d), q_index),
-            pl.BlockSpec((1, block_size, h, d), page_index),
-            pl.BlockSpec((1, block_size, h, d), page_index),
+            pl.BlockSpec((1, 1, width), q_index),
+            pl.BlockSpec((width, HEAD_LANES), lambda *_: (0, 0)),
+            pl.BlockSpec((1, 1, block_size, width), page_index),
+            pl.BlockSpec((1, 1, block_size, width), page_index),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, h, d), q_index),
+        out_specs=pl.BlockSpec((1, 1, width), q_index),
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),   # ctx accumulator
-            pltpu.VMEM((h, 1), jnp.float32),   # running max
-            pltpu.VMEM((h, 1), jnp.float32),   # running denom
+            pltpu.VMEM((1, width), jnp.float32),        # ctx accumulator
+            pltpu.VMEM((1, HEAD_LANES), jnp.float32),   # running max
+            pltpu.VMEM((1, HEAD_LANES), jnp.float32),   # running denom
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale,
-                          block_size=block_size,
-                          pages_per_seq=pages_per_seq),
+    out = pl.pallas_call(
+        functools.partial(_paged_decode_kernel, block_size=block_size),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, width), q.dtype),
+        # rows past the grid keep the zeros handed in
+        input_output_aliases={7: 0},
         interpret=interpret,
         name="paged_decode",
-    )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, k_pages, v_pages)
+    )(seq_lens, block_tables.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), scaled, head_of, k_pages,
+      v_pages, jnp.zeros((b, 1, width), q.dtype))
+    return out[:, 0, :h * d].reshape(b, h, d)
 
 
 # ---------------------------------------------------------------------------

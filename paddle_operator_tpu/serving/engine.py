@@ -11,9 +11,11 @@ MODEL'S MODULE (``model=``, default :mod:`..models.gpt`), which offers:
 
 * ``serve_cache(config, num_blocks, block_size)`` — the array half of
   the cache behind a :class:`.kv_cache.KvBlockAllocator`
-  (:class:`.kv_cache.PagedKvCache`: K and V pages per head;
+  (:class:`.kv_cache.PagedKvCache`: one K and one V pool for all
+  layers, a token's heads side by side in a row;
   :class:`.kv_cache.LatentKvCache`: a tuple of pools behind the one
-  block table, one compressed row a token in each);
+  block table, one compressed row a token in each). Either hands the
+  decode step its pools donated: the step updates them where they lie;
 * ``serve_buckets(config, prompt_pad)`` — the padded prompt lengths
   prefill compiles for (a prompt takes the shortest that holds it);
 * ``serve_prefill(config, pad)`` -> ``f(params, ids [1, pad], length) ->

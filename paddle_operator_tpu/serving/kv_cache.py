@@ -12,17 +12,18 @@ block.
 :class:`KvBlockAllocator` is the bookkeeping half (pure Python, no
 arrays): alloc/append/free with conservation invariants the chaos
 scenario and ``make race`` exercise. :class:`PagedKvCache` is the array
-half: the ``[num_blocks, block_size, heads, head_dim]`` K/V pages per
-layer that :func:`..ops.attention_pallas.paged_decode_attention`
-consumes. :class:`LatentKvCache` is the array half for latent
-attention: ONE compressed row a token and layer for all heads (a
-tuple of pools, one a width it is told), behind the same allocator. The engine asks either for ``pools()`` /
-``set_pools()`` (what the decode step takes and hands back; the decode
-step itself writes each new token's rows), ``write_rows()`` (a
-prefill's rows: in both caches one jitted program a padded prompt
-length that takes the pools donated and writes whole pages in place)
-and ``donate_pools`` (whether the decode step may overwrite the pools
-it is handed).
+half: one K and one V pool for every layer, ``[layers, num_blocks + 1,
+block_size, heads * head_dim]``, the layout
+:func:`..ops.attention_pallas.paged_decode_attention` reads in place.
+:class:`LatentKvCache` is the array half for latent attention: ONE
+compressed row a token and layer for all heads (a tuple of pools, one a
+width it is told), behind the same allocator. The engine asks either
+for ``pools()`` / ``set_pools()`` (what the decode step takes and hands
+back; the decode step itself writes each new token's rows),
+``write_rows()`` (a prefill's rows: in both caches one jitted program a
+padded prompt length that takes the pools donated and writes whole
+pages in place) and ``donate_pools`` (whether the decode step may
+overwrite the pools it is handed: both say yes).
 
 Thread safety: every allocator field is owned by ``_lock`` (declared in
 analysis/guards.py — the static OPS9xx passes and the runtime race
@@ -46,6 +47,33 @@ def _prompt_pages(cache: Any, seq_id: str, n: int, padded: int) -> Any:
     live = -(-n // size)
     blocks[:live] = cache.allocator.block_table(seq_id)[:live]
     return blocks
+
+
+LANES = 128
+
+
+def _stored(width: int) -> int:
+    """A row's width rounded up to whole 128-lane tiles: the chip's
+    tiled layout of a row-major page holds that many lanes anyway, and a
+    minor axis that is no multiple of 128 makes XLA lay the pool out
+    token-minor and copy it whole before every kernel call."""
+    return -(-width // LANES) * LANES
+
+
+def _write_pages(pool: Any, rows: Any, blocks: Any) -> Any:
+    """``rows`` ``[layers, pad, width]`` as whole pages ``blocks`` of
+    every layer of ``pool`` ``[layers, pages, size, stored]`` at once.
+    (A scatter row by row makes XLA copy the whole pool into a layout
+    of its own first: 3 GB of temporaries for a pool that fills the
+    chip.)"""
+    import jax.numpy as jnp
+
+    layers, _, size, stored = pool.shape
+    rows = jnp.pad(rows, ((0, 0),
+                          (0, blocks.shape[0] * size - rows.shape[1]),
+                          (0, stored - rows.shape[2])))
+    return pool.at[:, blocks].set(rows.astype(pool.dtype).reshape(
+        layers, blocks.shape[0], size, stored))
 
 
 class KvCacheFull(Exception):
@@ -234,17 +262,27 @@ class KvBlockAllocator:
 
 
 class PagedKvCache:
-    """The array half: per-layer K/V pages shaped
-    ``[num_blocks, block_size, heads, head_dim]`` plus an allocator.
+    """The array half: ONE key pool and ONE value pool for all layers,
+    each ``[layers, num_blocks + 1, block_size, W]`` plus an allocator.
+    A token's row is its heads side by side, ``W`` = ``heads *
+    head_dim`` rounded up to whole 128-lane tiles (:func:`_stored`):
+    row-major and lane-dense, which is both how XLA holds the array and
+    what the decode kernel reads, so no step copies or transposes a
+    pool. ``k_pages`` / ``v_pages`` are lists of the one array each
+    (the names and the list :class:`LatentKvCache` answers to).
 
-    The arrays are immutable and live wherever JAX puts them (HBM on
-    TPU): a prefill's rows land through one jitted program that takes
-    the pools donated and hands back the same buffers with the prompt's
-    pages written (:meth:`write_rows`); the decode step writes its new
-    token's rows itself and hands back copies (``donate_pools``).
+    The arrays live wherever JAX puts them (HBM on TPU) and are updated
+    where they lie: a prefill's rows land through one jitted program
+    that takes the pools donated and hands back the same buffers with
+    the prompt's pages written (:meth:`write_rows`); the decode step
+    takes them donated too (``donate_pools``), writes its new token's
+    rows and hands the same two buffers back, so whoever held the
+    arrays before a step holds deleted ones after it.
     Single-engine-thread by design — the batcher serializes model steps —
     so only the ALLOCATOR is locked.
     """
+
+    donate_pools = True
 
     def __init__(self, num_blocks: int, block_size: int, layers: int,
                  heads: int, head_dim: int, dtype: Any = None) -> None:
@@ -257,51 +295,39 @@ class PagedKvCache:
         # their (garbage) k/v SOMEWHERE, and it must be a page no live
         # sequence can own or a pad row's write could race a real one.
         self.dummy_page = num_blocks
-        shape = (num_blocks + 1, block_size, heads, head_dim)
+        shape = (layers, num_blocks + 1, block_size,
+                 _stored(heads * head_dim))
         dtype = dtype or jnp.float32
-        self.k_pages = [jnp.zeros(shape, dtype) for _ in range(layers)]
-        self.v_pages = [jnp.zeros(shape, dtype) for _ in range(layers)]
+        self.k_pages = [jnp.zeros(shape, dtype)]
+        self.v_pages = [jnp.zeros(shape, dtype)]
         self._write: Optional[Any] = None
 
-    #: the decode step copies the pools it updates (ROADMAP Queue 1)
-    donate_pools = False
+    def pools(self) -> Tuple[Any, Any]:
+        return self.k_pages[0], self.v_pages[0]
 
-    def pools(self) -> Any:
-        return list(self.k_pages), list(self.v_pages)
+    def set_pools(self, pools: Tuple[Any, Any]) -> None:
+        self.k_pages, self.v_pages = [pools[0]], [pools[1]]
 
-    def set_pools(self, pools: Any) -> None:
-        self.k_pages, self.v_pages = list(pools[0]), list(pools[1])
-
-    def write_rows(self, seq_id: str, rows: Any, n: int) -> None:
-        """A prefill's keys and values (per layer ``[pad, H, D]``, the
-        first ``n`` rows the prompt's) into the sequence's pages: one
-        program a padded length, whole pages of every layer's K and V
-        at once into the donated pools. Pages past the prompt's last go
-        to the dummy page; the last page's slots past ``n`` take
-        padding, which ``seq_lens`` masks until decode overwrites it."""
+    def write_rows(self, seq_id: str, rows: Tuple[Any, Any], n: int) -> None:
+        """A prefill's keys and values (each ``[layers, pad, heads *
+        head_dim]``, the first ``n`` rows the prompt's) into the
+        sequence's pages: one program a padded length, whole pages of
+        every layer's K and V at once into the donated pools. Pages past
+        the prompt's last go to the dummy page; the last page's slots
+        past ``n`` take padding, which ``seq_lens`` masks until decode
+        overwrites it."""
         import jax
         import jax.numpy as jnp
 
-        ks, vs = rows
-        blocks = _prompt_pages(self, seq_id, n, ks[0].shape[0])
+        blocks = _prompt_pages(self, seq_id, n, rows[0].shape[1])
         if self._write is None:
-            def write(k_pages, v_pages, ks, vs, blocks):
-                def paged(pool, rows):
-                    size = pool.shape[1]
-                    rows = jnp.pad(
-                        rows, ((0, blocks.shape[0] * size - rows.shape[0]),
-                               (0, 0), (0, 0)))
-                    return pool.at[blocks].set(
-                        rows.astype(pool.dtype).reshape(
-                            (blocks.shape[0],) + pool.shape[1:]))
+            def write(pools, rows, blocks):
+                return tuple(_write_pages(p, r, blocks)
+                             for p, r in zip(pools, rows))
 
-                return ([paged(p, r) for p, r in zip(k_pages, ks)],
-                        [paged(p, r) for p, r in zip(v_pages, vs)])
-
-            self._write = jax.jit(write, donate_argnums=(0, 1))
-        self.k_pages, self.v_pages = self._write(
-            self.k_pages, self.v_pages, list(ks), list(vs),
-            jnp.asarray(blocks))
+            self._write = jax.jit(write, donate_argnums=(0,))
+        self.set_pools(self._write(self.pools(), tuple(rows),
+                                   jnp.asarray(blocks)))
 
 
 class LatentKvCache:
@@ -317,10 +343,8 @@ class LatentKvCache:
     num_blocks + 1, block_size, W]`` (the expert layers are one scan,
     which indexes it by layer; the last page is the pad rows' target as
     in :class:`PagedKvCache`), ``W`` the row's width rounded up to whole
-    128-lane tiles: the chip's tiled layout of a row-major page holds
-    that many lanes anyway, and a minor axis that is no multiple of 128
-    makes XLA lay the pool out token-minor and copy it whole before
-    every kernel call. It answers to the names the paged cache has:
+    128-lane tiles (:func:`_stored`). It answers to the names the paged
+    cache has:
     ``k_pages`` is the list of the pools, ``v_pages`` an empty list. A
     prefill lands through one jitted, donating scatter
     (:meth:`write_rows`), and the decode step updates the pools in
@@ -329,7 +353,6 @@ class LatentKvCache:
     """
 
     donate_pools = True
-    LANES = 128
 
     def __init__(self, num_blocks: int, block_size: int, layers: int,
                  widths: Tuple[int, ...], dtype: Any = None) -> None:
@@ -340,9 +363,8 @@ class LatentKvCache:
         self.widths = tuple(widths)
         self.dummy_page = num_blocks
         self.k_pages = [jnp.zeros(
-            (layers, num_blocks + 1, block_size,
-             -(-w // self.LANES) * self.LANES), dtype or jnp.bfloat16)
-            for w in self.widths]
+            (layers, num_blocks + 1, block_size, _stored(w)),
+            dtype or jnp.bfloat16) for w in self.widths]
         self.v_pages: List[Any] = []
         self._scatter: Optional[Any] = None
 
@@ -358,25 +380,15 @@ class LatentKvCache:
         program a padded length, whole pages of every layer and pool at
         once. Pages past the prompt's last go to the dummy page; the
         last page's slots past ``n`` take padding, which ``seq_lens``
-        masks until decode overwrites it.
-        (A scatter row by row makes XLA copy the whole pool into a layout
-        of its own first: 3 GB of temporaries for the benchmark's pool.)"""
+        masks until decode overwrites it."""
         import jax
         import jax.numpy as jnp
 
         blocks = _prompt_pages(self, seq_id, n, rows[0].shape[1])
         if self._scatter is None:
-            def paged(pool, rows, blocks):
-                layers, _, size, stored = pool.shape
-                rows = jnp.pad(rows, ((0, 0),
-                                      (0, blocks.shape[0] * size
-                                       - rows.shape[1]),
-                                      (0, stored - rows.shape[2])))
-                return pool.at[:, blocks].set(rows.astype(pool.dtype).reshape(
-                    layers, blocks.shape[0], size, stored))
-
             def scatter(pools, rows, blocks):
-                return [paged(p, r, blocks) for p, r in zip(pools, rows)]
+                return [_write_pages(p, r, blocks)
+                        for p, r in zip(pools, rows)]
 
             self._scatter = jax.jit(scatter, donate_argnums=(0,))
         self.k_pages = self._scatter(self.k_pages, list(rows),

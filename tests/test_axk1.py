@@ -325,27 +325,35 @@ def test_padding_rows_route_nowhere_and_layers_index_stacked_kernels():
 # -- the other model the engine serves ------------------------------------
 
 #: sha256 of the lowered text of GPT's serving programs at
-#: gpt.TINY_CONFIG (max_batch 2, prompt_pad 16, 8 pages of 8) AT THE
-#: PARENT COMMIT (PR 25, dcb9952), where the engine held them inline,
-#: less the names of ``main``'s results (``jax.result_info``: the two
-#: lists of pages are now one tuple). jax 0.9.0.
+#: gpt.TINY_CONFIG (max_batch 2, prompt_pad 16, 8 pages of 8) AS PR 33
+#: LEFT THEM, less the names of ``main``'s results
+#: (``jax.result_info``). Until PR 33 these were the texts of PR 25
+#: (dcb9952), where the engine held the programs inline; PR 33 meant to
+#: change them and re-pinned all four: the decode step takes the cache's
+#: two stacked pools donated, writes a token's rows with one scatter a
+#: pool and layer and reads them through ``paged_decode`` (or its
+#: reference) by layer index; the prefill hands its rows as the cache
+#: stores them, ``[layers, pad, heads * head_dim]`` a side. jax 0.9.0.
 PARENT_GPT_PROGRAMS = {
     ("paged", "serve-prefill"):
-        "ad7015a8549b0af2dc813e06c644e8e52a7ae9577c6f3873a08aee8baa7aba6f",
+        "9b5d75b9d22cb9a64417fc244e230ae91394b477f005c1e66e258ffee6d72de8",
     ("paged", "serve-decode"):
-        "7b1fdc7adf95e512d168efec88d9a1d389828d551c11838e3ede41c18619c32f",
+        "de986b99f7c8693562190d231156d977e57cc504e7cfe90c1d2900817ab8fbdd",
     ("reference", "serve-prefill"):
-        "ad7015a8549b0af2dc813e06c644e8e52a7ae9577c6f3873a08aee8baa7aba6f",
+        "9b5d75b9d22cb9a64417fc244e230ae91394b477f005c1e66e258ffee6d72de8",
     ("reference", "serve-decode"):
-        "6106b4bfe902b6ddcf7328adcb887e7a47c403338edd6133d0ffc28e6780df16",
+        "1a29599106b9177ba7d00f722d5c6846ce474b9b1e76c40398f8091821ffa41d",
 }
 
 
 @pytest.mark.parametrize("attn", ["paged", "reference"])
 def test_gpts_serve_programs_lower_to_the_parents_text(attn, monkeypatch):
-    """Behind the model interface GPT's prefill and decode are the
-    programs the engine held inline before: same operations, same
-    operands, no donation. So ``gpt2-small.serve-steady`` cannot move."""
+    """GPT's prefill and decode lower to the pinned texts: a PR that does
+    not mean to change them sees here that ``gpt2-small.serve-steady``
+    runs its parent's programs (as ``axk1``'s and ``dsv32``'s pins say
+    of their cells), and one that means to re-pins them and says why.
+    The decode step's two pools, and nothing else, are donated: the
+    sign, on the CPU, that the step updates the cache where it lies."""
     if jax.__version__ != "0.9.0":
         pytest.skip("the parent's text was lowered by jax 0.9.0")
     lowered = {}
@@ -367,8 +375,11 @@ def test_gpts_serve_programs_lower_to_the_parents_text(attn, monkeypatch):
     for _ in range(2):
         (token, _), = engine.step_fn([req])
         req.generated.append(token)
+    donated = {label: len(re.findall(r"jax\.buffer_donor|tf\.aliasing_output",
+                                     text))
+               for label, text in lowered.items()}
+    assert donated == {"serve-prefill": 0, "serve-decode": 2}
     for label in ("serve-prefill", "serve-decode"):
-        assert "jax.buffer_donor" not in lowered[label]
         assert hashlib.sha256(lowered[label].encode()).hexdigest() \
             == PARENT_GPT_PROGRAMS[attn, label], label
 

@@ -37,8 +37,10 @@ from paddle_operator_tpu.parallel import (
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: ServingEngine defaults at gpt.BASE_CONFIG: 8 rows, 12 heads of 64,
-#: 256 + 1 pages of 16 slots, 1024 // 16 pages per sequence
-ENGINE = dict(b=8, h=12, d=64, bs=16, pages=257, per_seq=64)
+#: 12 layers' 256 + 1 pages of 16 slots, 1024 // 16 pages per sequence
+ENGINE = dict(b=8, h=12, d=64, bs=16, layers=12, pages=257, per_seq=64)
+#: ``gpt2-small.serve-steady``: 32 rows, 192 + 1 pages of 128 slots
+CELL = dict(b=32, h=12, d=64, bs=128, layers=12, pages=193, per_seq=8)
 #: chip_smoke's kernel check, and the trainer's attention (batch 16)
 FLASH_SHAPES = [(2, 12, 1024, 64), (16, 12, 1024, 64)]
 
@@ -47,13 +49,14 @@ def _sds(shape, dtype, sharding=None):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _paged_args(dtype, sharding=None):
-    e = ENGINE
-    pool = (e["pages"], e["bs"], e["h"], e["d"])
+def _paged_args(dtype, sharding=None, e=ENGINE):
+    """q, the two stacked pools, tables, lengths and the layer."""
+    pool = (e["layers"], e["pages"], e["bs"], e["h"] * e["d"])
     return (_sds((e["b"], e["h"], e["d"]), dtype, sharding),
             _sds(pool, dtype, sharding), _sds(pool, dtype, sharding),
             _sds((e["b"], e["per_seq"]), jnp.int32, sharding),
-            _sds((e["b"],), jnp.int32, sharding))
+            _sds((e["b"],), jnp.int32, sharding),
+            _sds((), jnp.int32, sharding))
 
 
 def _flash_loss(q, k, v):
@@ -97,12 +100,17 @@ def v5e():
     return topo.devices
 
 
+@pytest.mark.parametrize("shapes", [ENGINE, CELL], ids=["engine", "cell"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_compiles_for_v5e(v5e, dtype):
+def test_paged_decode_compiles_for_v5e(v5e, dtype, shapes):
+    """The kernel reads the stacked pools where they lie: no temporary
+    of a pool's size (a pool of the cell is 0.91 GB), which a slice, a
+    reshape or a transpose of one outside the kernel would be."""
     sh = jax.sharding.SingleDeviceSharding(v5e[0])
     compiled = jax.jit(ap.paged_decode_attention).lower(
-        *_paged_args(dtype, sh)).compile()
+        *_paged_args(dtype, sh, shapes)).compile()
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 def test_flash_fwd_bwd_compiles_for_v5e(v5e):
@@ -162,43 +170,80 @@ def test_sparse_decode_compiles_for_v5e_without_copying_its_pools(v5e):
 
 
 def test_paged_cache_prefill_write_compiles_for_v5e_in_place(v5e):
-    """At the shapes of ``gpt2-small.serve-steady``: 12 layers' K and V
-    pools of 192 + 1 pages of 128 slots (76 MB each, 1.82 GB in all) and
-    a prefill's rows padded to 512. The one write program updates every
-    donated pool where it lies: no temporary of a pool's size, every
+    """At the shapes of ``gpt2-small.serve-steady``: the K and the V pool
+    of 12 layers x (192 + 1) pages of 128 slots (0.91 GB each) and a
+    prefill's rows padded to 512. The one write program updates both
+    donated pools where they lie: no temporary of a pool's size, every
     pool's bytes aliased to a result."""
     from paddle_operator_tpu.serving.kv_cache import PagedKvCache
 
     cache = PagedKvCache(2, 8, layers=1, heads=2, head_dim=8)
     cache.allocator.alloc_sequence("s", 3)
-    tiny = [jnp.zeros((8, 2, 8))]
+    tiny = jnp.zeros((1, 8, 16))
     cache.write_rows("s", (tiny, tiny), 3)   # builds the jitted write
     sh = jax.sharding.SingleDeviceSharding(v5e[0])
-    pools = [_sds((193, 128, 12, 64), jnp.float32, sh)] * 12
-    rows = [_sds((512, 12, 64), jnp.float32, sh)] * 12
+    pool = _sds((12, 193, 128, 768), jnp.float32, sh)
+    rows = _sds((12, 512, 768), jnp.float32, sh)
     compiled = cache._write.lower(
-        pools, pools, rows, rows, _sds((4,), jnp.int32, sh)).compile()
-    pool_bytes = 193 * 128 * 12 * 64 * 4
+        (pool, pool), (rows, rows), _sds((4,), jnp.int32, sh)).compile()
+    pool_bytes = 12 * 193 * 128 * 768 * 4
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < pool_bytes
-    assert mem.alias_size_in_bytes >= 24 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 8
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
 
 
-def test_paged_decode_matches_reference_interpreted():
-    """The repaired kernel (multiply-and-reduce, no batched matmul)
-    against the gather-einsum reference at the engine's head shape."""
+def test_gpts_decode_step_compiles_for_v5e_without_copying_a_pool(
+        v5e, monkeypatch):
+    """``gpt2-small.serve-steady``'s decode step as the engine jits it
+    (pools donated, the kernel compiled, not interpreted), for a
+    described v5e: both pools are aliased to the step's results, its
+    temporaries are no pool's size (before the stacked layout: a copy
+    and a transpose of each of 24 pools, 2.86 GB), the 24 row writes are
+    scatters in place and no other operation yields a pool."""
+    from paddle_operator_tpu.models import gpt
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sh = jax.sharding.SingleDeviceSharding(v5e[0])
+    cfg, c = dict(gpt.BASE_CONFIG), CELL
+    params = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, sh),
+        jax.eval_shape(lambda: gpt.init(jax.random.PRNGKey(0), cfg)))
+    _, pool, _, tables, row, _ = _paged_args(jnp.float32, sh, c)
+    decode = gpt.serve_decode(cfg, "paged", c["bs"], c["pages"] - 1)
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, (pool, pool), row, row, tables, row,
+        _sds(row.shape, jnp.bool_, sh)).compile()
+    pool_bytes = int(np.prod(pool.shape)) * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 8
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 12
+    yields_a_pool = re.findall(
+        r"= f32\[12,193,128,768\]\S* ([\w-]+)\(", text)
+    assert set(yields_a_pool) <= {"parameter", "scatter", "fusion",
+                                  "get-tuple-element", "bitcast"}
+    assert yields_a_pool.count("scatter") == 24
+
+
+@pytest.mark.parametrize("layer", [0, 5, 11])
+def test_paged_decode_matches_reference_interpreted(layer):
+    """The kernel (products on the VPU, a head's sum over its lanes as a
+    0/1 matmul) against the gather-einsum reference at the engine's head
+    shape, the first, a middle and the last layer of the stack."""
     e = dict(ENGINE, pages=33, per_seq=8)
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
-    pool = (e["pages"], e["bs"], e["h"], e["d"])
+    pool = (e["layers"], e["pages"], e["bs"], e["h"] * e["d"])
     q = jax.random.normal(ks[0], (e["b"], e["h"], e["d"]))
     kp, vp = jax.random.normal(ks[1], pool), jax.random.normal(ks[2], pool)
     tables = jax.random.randint(ks[3], (e["b"], e["per_seq"]), 0,
                                 e["pages"] - 1)
     lens = jax.random.randint(ks[4], (e["b"],), 1,
                               e["per_seq"] * e["bs"] + 1)
-    got = ap.paged_decode_attention(q, kp, vp, tables, lens, interpret=True)
+    got = ap.paged_decode_attention(q, kp, vp, tables, lens, layer,
+                                    interpret=True)
     want = ap._reference_paged_decode(q, kp, vp, tables, lens,
-                                      1.0 / np.sqrt(e["d"]))
+                                      1.0 / np.sqrt(e["d"]), layer)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 

@@ -464,31 +464,104 @@ def test_validate_serving_rejects_unknown_shed_policy_and_elastic():
 # data plane (jax): kernel equivalence + the engine golden test
 # ---------------------------------------------------------------------------
 
-def test_paged_decode_interpret_matches_reference():
+def test_supports_paged_names_the_kernels_shapes():
+    from paddle_operator_tpu.ops.attention_pallas import supports_paged
+
+    assert supports_paged((3, 2, 64), 8)
+    assert supports_paged((3, 12, 48), 8)        # heads sit side by side
+    assert not supports_paged((3, 129, 8), 8)    # a head a score lane
+    assert not supports_paged((3, 2, 64), 6)     # sublane-hostile page
+    assert not supports_paged((3, 1, 2, 64), 8)
+
+
+_STACK = dict(b=4, h=2, d=64, bs=8, layers=3, pages=16, t=4)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_decode_interpret_matches_reference(layer, dtype):
+    """The kernel reads page ``[layer, table[b, t]]`` of the stacked
+    pools in place: the first, a middle and the last layer, float32 and
+    bfloat16 pages; a row of length 1, one that fills its last page to
+    the last slot, a ragged one, and a pad row (length 0: zeros)."""
     import jax
     import jax.numpy as jnp
 
     from paddle_operator_tpu.ops.attention_pallas import (
-        _reference_paged_decode, paged_decode_attention, supports_paged)
+        _reference_paged_decode, paged_decode_attention)
 
-    b, h, d, bs, pages, t = 3, 2, 64, 8, 16, 4
-    assert supports_paged((b, h, d), bs)
-    assert not supports_paged((b, h, 48), bs)    # lane-hostile head_dim
-    assert not supports_paged((b, h, d), 6)      # sublane-hostile page
-
+    c = _STACK
+    b, h, d, bs = c["b"], c["h"], c["d"], c["bs"]
+    pool = (c["layers"], c["pages"], bs, h * d)
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(keys[0], (b, h, d), jnp.float32)
-    k_pages = jax.random.normal(keys[1], (pages, bs, h, d), jnp.float32)
-    v_pages = jax.random.normal(keys[2], (pages, bs, h, d), jnp.float32)
+    k_pages = jax.random.normal(keys[1], pool, jnp.float32).astype(dtype)
+    v_pages = jax.random.normal(keys[2], pool, jnp.float32).astype(dtype)
     # ragged: each row its own depth, tables deliberately non-contiguous
-    tables = jnp.asarray([[1, 5, 9, 13], [2, 6, 10, 14], [3, 7, 11, 0]],
-                         jnp.int32)
-    lens = jnp.asarray([5, 16, 23], jnp.int32)
+    tables = jnp.asarray([[1, 5, 9, 13], [2, 6, 10, 14], [3, 7, 11, 0],
+                          [0, 0, 0, 0]], jnp.int32)
+    lens = jnp.asarray([1, 2 * bs, 23, 0], jnp.int32)
     scale = 1.0 / (d ** 0.5)
-    ref = _reference_paged_decode(q, k_pages, v_pages, tables, lens, scale)
-    out = paged_decode_attention(q, k_pages, v_pages, tables, lens,
+    ref = _reference_paged_decode(q, k_pages, v_pages, tables, lens, scale,
+                                  layer)
+    out = paged_decode_attention(q, k_pages, v_pages, tables, lens, layer,
+                                 interpret=True)
+    assert out.shape == (b, h, d) and out.dtype == q.dtype
+    assert jnp.max(jnp.abs(out - ref)) < 1e-5
+    assert not jnp.any(out[3])
+    # the layer picks its pages: another layer's answer is another one
+    other = _reference_paged_decode(q, k_pages, v_pages, tables, lens,
+                                    scale, (layer + 1) % c["layers"])
+    assert jnp.max(jnp.abs(out - other)) > 1e-2
+
+
+@pytest.mark.parametrize("lens", [[5, 16, 9], [0, 5, 0], [0, 0, 0]],
+                         ids=["ragged", "one-live-row", "no-live-row"])
+def test_paged_decode_pads_rows_narrower_than_a_lane_tile(lens):
+    """Heads whose rows do not fill whole 128-lane tiles (4 heads of 16:
+    64 of 128 lanes): the cache pads the row, the kernel reads the
+    padded pool and leaves the padding out. The kernel's grid ends at
+    the last live row and the longest row's last page: an empty row
+    inside it, the rows past it and a batch with no live row read
+    zeros."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_operator_tpu.ops.attention_pallas import (
+        _reference_paged_decode, paged_decode_attention)
+
+    b, h, d, bs = 3, 4, 16, 8
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(keys[0], (b, h, d), jnp.float32)
+    # the padding lanes hold what must not be read
+    k_pages = jax.random.normal(keys[1], (2, 9, bs, 128), jnp.float32)
+    v_pages = jax.random.normal(keys[2], (2, 9, bs, 128), jnp.float32)
+    tables = jnp.asarray([[1, 5], [2, 6], [3, 7]], jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    ref = _reference_paged_decode(q, k_pages, v_pages, tables, lens, 0.25,
+                                  1)
+    out = paged_decode_attention(q, k_pages, v_pages, tables, lens, 1,
                                  interpret=True)
     assert jnp.max(jnp.abs(out - ref)) < 1e-5
+    assert not jnp.any(jnp.where((lens == 0)[:, None, None], out, 0.0))
+
+
+def test_paged_decode_refuses_pools_it_cannot_read_in_place():
+    import jax.numpy as jnp
+
+    from paddle_operator_tpu.ops.attention_pallas import (
+        paged_decode_attention)
+
+    q = jnp.zeros((3, 4, 16))
+    pool = jnp.zeros((2, 9, 8, 128))
+    tables, lens = jnp.zeros((3, 2), jnp.int32), jnp.ones((3,), jnp.int32)
+    with pytest.raises(ValueError, match="stacked"):      # a layer's own
+        paged_decode_attention(q, pool[0], pool[0], tables, lens, 0)
+    with pytest.raises(ValueError, match="whole lanes"):  # half a tile
+        paged_decode_attention(q, pool[..., :64], pool[..., :64], tables,
+                               lens, 0)
+    with pytest.raises(ValueError, match="cover batch"):
+        paged_decode_attention(q, pool, pool, tables[:2], lens, 0)
 
 
 def _greedy_full_forward(params, prompt, budget):
@@ -557,10 +630,11 @@ _WRITE_BS, _WRITE_PAD = 4, 16
 @pytest.mark.parametrize(
     "n", [1, _WRITE_BS - 1, _WRITE_BS, _WRITE_BS + 1, _WRITE_PAD])
 def test_paged_cache_write_rows_lands_whole_pages(n):
-    """A prefill's padded rows land in the sequence's pages (slots < n
-    hold the prompt's), and nothing else moves: not an earlier
-    sequence's pages, not the sequence's reserved pages past the
-    prompt's last live one, not a free page."""
+    """A prefill's padded rows land in the sequence's pages of every
+    layer (slots < n hold the prompt's), and nothing else moves: not an
+    earlier sequence's pages, not the sequence's reserved pages past the
+    prompt's last live one, not a free page. The pools handed in are
+    donated: the cache holds the new ones."""
     import jax
     import numpy as np
 
@@ -568,11 +642,14 @@ def test_paged_cache_write_rows_lands_whole_pages(n):
 
     bs, pad, layers, heads, dim = _WRITE_BS, _WRITE_PAD, 2, 2, 8
     cache = PagedKvCache(12, bs, layers, heads, dim)
+    # rows of 16 stored as one whole lane tile
+    assert [p.shape for p in cache.k_pages + cache.v_pages] \
+        == [(layers, 13, bs, 128)] * 2 and cache.donate_pools
 
     def rows(seed):
-        keys = jax.random.split(jax.random.PRNGKey(seed), 2 * layers)
-        draw = [jax.random.normal(k, (pad, heads, dim)) for k in keys]
-        return draw[:layers], draw[layers:]
+        keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+        return tuple(jax.random.normal(k, (layers, pad, heads * dim))
+                     for k in keys)
 
     # an earlier sequence whose table is not contiguous with the next's
     cache.allocator.alloc_sequence("hole", bs)
@@ -584,28 +661,30 @@ def test_paged_cache_write_rows_lands_whole_pages(n):
     # prompt n, with a generation budget that reserves pages past it
     table = cache.allocator.alloc_sequence("second", pad + 2 * bs,
                                            live_tokens=n)
-    before = [[np.asarray(p) for p in pool]
-              for pool in (cache.k_pages, cache.v_pages)]
+    handed = cache.pools()
+    # copies: a view of a buffer would keep it from being donated
+    before = [np.array(pool) for pool in handed]
     second_rows = rows(2)
     cache.write_rows("second", second_rows, n)
+    assert all(pool.is_deleted() for pool in handed)
 
     live = -(-n // bs)
     untouched = [b for b in range(cache.dummy_page)
                  if b not in table[:live]]
     assert set(first) | set(table[live:]) <= set(untouched)
     for pool, was, mine, theirs in zip(
-            (cache.k_pages, cache.v_pages), before, second_rows,
-            first_rows):
-        for layer in range(layers):
-            now = np.asarray(pool[layer])
-            got = now[table[:live]].reshape(live * bs, heads, dim)
-            np.testing.assert_array_equal(got[:n],
-                                          np.asarray(mine[layer])[:n])
-            np.testing.assert_array_equal(
-                now[first[:2]].reshape(2 * bs, heads, dim)[:bs + 2],
-                np.asarray(theirs[layer])[:bs + 2])
-            np.testing.assert_array_equal(now[untouched],
-                                          was[layer][untouched])
+            cache.pools(), before, second_rows, first_rows):
+        now = np.asarray(pool)
+        assert not now[..., heads * dim:].any()      # the lanes' padding
+        now = now[..., :heads * dim]
+        got = now[:, table[:live]].reshape(layers, live * bs, heads * dim)
+        np.testing.assert_array_equal(got[:, :n], np.asarray(mine)[:, :n])
+        np.testing.assert_array_equal(
+            now[:, first[:2]].reshape(
+                layers, 2 * bs, heads * dim)[:, :bs + 2],
+            np.asarray(theirs)[:, :bs + 2])
+        np.testing.assert_array_equal(
+            now[:, untouched], was[:, untouched, :, :heads * dim])
 
 
 def test_paged_cache_write_rows_is_one_program_a_padded_length(caplog):
@@ -676,6 +755,40 @@ def _decode_steps(eng, steps):
     for req in reqs:
         eng.retire(req)
     return out[1:]
+
+
+@pytest.mark.parametrize("attn", ["reference", "paged"])
+def test_a_decode_step_updates_its_donated_pools_in_one_slot_a_row(attn):
+    """The decode step takes the cache's two pools donated and hands the
+    same two back: what was handed in is deleted afterwards, and of
+    their contents one slot of every layer has changed for the row that
+    decoded (and the dummy page, the pad rows' target); a prefilled
+    sequence that sat the step out, every free page and every other
+    slot of the row's own pages are bit-equal."""
+    import numpy as np
+
+    eng = _tiny_engine("gpt", attn, "slot")
+    bs = eng.cache.allocator.block_size
+    stepping = Request("a", prompt=list(range(1, 12)), max_new_tokens=4)
+    waiting = Request("b", prompt=[7, 8, 9], max_new_tokens=4)
+    assert eng.admit(stepping) and eng.admit(waiting)
+    for req, (token, _) in zip((stepping, waiting),
+                               eng.step_fn([stepping, waiting])):
+        req.generated.append(token)
+    handed = eng.cache.pools()
+    assert [p.shape for p in handed] == [(2, 33, bs, 128)] * 2
+    before = [np.array(p) for p in handed]          # copies, not views
+    pos = eng.cache.allocator.seq_len("a")
+    eng.step_fn([stepping])
+    assert all(p.is_deleted() for p in handed)
+    page = eng.cache.allocator.block_table("a")[pos // bs]
+    assert page not in eng.cache.allocator.block_table("b")
+    for was, pool in zip(before, eng.cache.pools()):
+        changed = (np.asarray(pool) != was).any(-1)          # [L, P, bs]
+        assert changed[:, page, pos % bs].all()
+        changed[:, page, pos % bs] = False
+        changed[:, eng.cache.dummy_page, 0] = False
+        assert not changed.any()
 
 
 class _FetchedWhole:
@@ -826,7 +939,8 @@ def test_engine_reuses_pages_with_stale_rows_past_a_shorter_prompt():
     # LIFO free list: the short prompt's first page is one the long
     # sequence filled, and its slots past the prompt's are not zeros
     assert tables["short"][0] in tables["long"]
-    assert np.asarray(eng.cache.k_pages[0])[tables["short"][0], 3:].any()
+    assert np.asarray(eng.cache.k_pages[0])[
+        0, tables["short"][0], 3:].any()
     for req in (long_req, short_req):
         assert req.generated == _greedy_full_forward(
             params, req.prompt, req.max_new_tokens)
