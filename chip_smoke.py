@@ -7,7 +7,9 @@ width of ``models/gpt.BASE_CONFIG`` (GPT-2 small: 768 wide, 12 layers,
 
 1. kernel checks — ``flash_attention`` forward and gradients,
    ``paged_decode_attention`` (over the stacked pools of the
-   ``gpt2-small.serve-steady`` cell), ``mla_paged_decode`` (at the
+   ``gpt2-small.serve-steady`` cell, float32, and of the
+   ``evabyte-pp4.serve-bytes-8k`` cell, bfloat16 pages of 32 heads x
+   128), ``mla_paged_decode`` (at the
    shapes of the ``axk1-share16`` cell) and ``dsa_index_scores`` +
    ``select_rows`` + ``mla_selected_decode`` (at those of
    ``dsv32-share32``) against their references, compiled
@@ -60,6 +62,7 @@ FLASH_FWD_TOL = 2e-2      # max |out - ref| / max |ref|
 FLASH_GRAD_TOL = 4e-2
 MLA_TOL = 2e-2        # bfloat16 pages, probabilities and result
 PAGED_TOL = 1e-4
+EVA_PAGED_TOL = 2e-2  # bfloat16 pages, query and probabilities; f32 sums
 
 
 class SmokeFailure(Exception):
@@ -132,31 +135,48 @@ def kernel_checks(sm: Smoke) -> None:
     sm.check(fwd <= FLASH_FWD_TOL, "flash_attention fwd error %g" % fwd)
     sm.check(gerr <= FLASH_GRAD_TOL, "flash_attention grad error %g" % gerr)
 
-    # paged decode over the stacked pools of gpt2-small.serve-steady: 32
-    # rows x 12 heads of 64 over f32[12, 193, 128, 768] a side, read in
-    # place; a row of one token, one that fills its last page, a pad row
-    b, h, d, bs = (4, 4, 32, 16) if sm.rehearsal else (32, 12, 64, 128)
-    layers, pages, per_seq = (2, 33, 8) if sm.rehearsal else (12, 193, 8)
-    keys = jax.random.split(jax.random.PRNGKey(sm.seed + 1), 5)
-    q = jax.random.normal(keys[0], (b, h, d), jnp.float32)
-    kp = jax.random.normal(keys[1], (layers, pages, bs, h * d), jnp.float32)
-    vp = jax.random.normal(keys[2], (layers, pages, bs, h * d), jnp.float32)
-    tables = jax.random.randint(keys[3], (b, per_seq), 0, pages - 1)
-    lens = jax.random.randint(keys[4], (b,), 1, per_seq * bs + 1
-                              ).at[0].set(1).at[1].set(2 * bs).at[2].set(0)
-    for layer in (0, layers - 1):
-        got = jax.jit(lambda *a: ap.paged_decode_attention(
-            *a, layer, interpret=interpret))(q, kp, vp, tables, lens)
-        with jax.default_matmul_precision("highest"):
-            want = jax.jit(lambda *a: ap._reference_paged_decode(
-                *a, 1.0 / math.sqrt(d), layer))(q, kp, vp, tables, lens)
-        err = rel_err(got, want)
-        sm.say("kernel paged_decode_attention",
-               q="x".join(map(str, q.shape)),
-               pool="x".join(map(str, kp.shape)), layer=layer, dtype="f32",
-               interpret=interpret, rel_err="%.2e" % err, tol=PAGED_TOL)
-        sm.check(err <= PAGED_TOL, "paged_decode_attention error %g" % err)
-    del kp, vp
+    # paged decode, one kernel at its two users' shapes, the pools read
+    # in place. gpt2-small.serve-steady: 32 rows x 12 heads of 64 over
+    # f32[12, 193, 128, 768] a side (a row of one token, one that fills
+    # its last page, a pad row). evabyte-pp4.serve-bytes-8k: 16 rows x
+    # 32 heads of 128 over bf16[8, 385, 128, 4096] a side, a row's table
+    # its closed windows' summary pages then its window's pages, 24 at
+    # most (a row of one row, one whose 24 pages are all live, one that
+    # ends inside a page, a pad row); the pages are the MXU's operands as
+    # they lie, so the query and the probabilities are rounded to
+    # bfloat16 (2^-9 each) where the reference at ``highest`` keeps them
+    # float32, and the sums are float32: EVA_PAGED_TOL
+    small = (4, 4, 32, 16, 2, 33, 8)
+    for dtype, tol, seed, shape, pinned in (
+            (jnp.float32, PAGED_TOL, 1, (32, 12, 64, 128, 12, 193, 8),
+             lambda bs, per_seq: (1, 2 * bs, 0)),
+            (jnp.bfloat16, EVA_PAGED_TOL, 5, (16, 32, 128, 128, 8, 385, 24),
+             lambda bs, per_seq: (1, per_seq * bs, 0, 5 * bs + 3))):
+        b, h, d, bs, layers, pages, per_seq = small if sm.rehearsal else shape
+        keys = jax.random.split(jax.random.PRNGKey(sm.seed + seed), 5)
+        q = jax.random.normal(keys[0], (b, h, d), jnp.float32)
+        kp, vp = (jax.random.normal(k, (layers, pages, bs, h * d), dtype)
+                  for k in keys[1:3])
+        tables = jax.random.randint(keys[3], (b, per_seq), 0, pages - 1)
+        lens = jax.random.randint(keys[4], (b,), 1, per_seq * bs + 1)
+        for row, n in enumerate(pinned(bs, per_seq)):
+            lens = lens.at[row].set(n)
+        for layer in (0, layers - 1):
+            got = jax.jit(lambda *a: ap.paged_decode_attention(
+                *a, layer, interpret=interpret))(q, kp, vp, tables, lens)
+            with jax.default_matmul_precision("highest"):
+                want = jax.jit(lambda *a: ap._reference_paged_decode(
+                    *a, 1.0 / math.sqrt(d), layer))(q, kp, vp, tables, lens)
+            err = rel_err(got, want)
+            sm.say("kernel paged_decode_attention",
+                   q="x".join(map(str, q.shape)),
+                   pool="x".join(map(str, kp.shape)), layer=layer,
+                   dtype=jnp.dtype(dtype).name, interpret=interpret,
+                   rel_err="%.2e" % err, tol=tol)
+            sm.check(err <= tol, "paged_decode_attention (%s pages) error %g"
+                     % (jnp.dtype(dtype).name, err))
+            sm.check(not bool(jnp.any(got[2])), "a pad row read something")
+        del kp, vp
 
     # latent paged decode at the shapes of axk1-share16.serve-decode-1k:
     # 64 rows x 64 heads over bfloat16 pages of 128 rows [512 | 64 | pad]

@@ -514,7 +514,8 @@ def _check_blocks(q_shape, block_q, block_k):
 # rows and short rows (32 x 8 cells a layer took 0.84 ms a step at five
 # live rows, the cells that read something 0.34: chip runs, PR 33).
 
-HEAD_LANES = 128      # a page's scores: tokens on sublanes, heads on lanes
+MAX_HEADS = 128       # a page's scores: heads on sublanes, tokens on lanes
+HEAD_ROWS = 16        # the heads' rows come in whole bfloat16 sublane tiles
 
 
 def _reference_paged_decode(q, k_pages, v_pages, block_tables, seq_lens,
@@ -540,33 +541,27 @@ def _reference_paged_decode(q, k_pages, v_pages, block_tables, seq_lens,
     return jnp.where(seq_lens[:, None, None] > 0, out, 0.0).astype(q.dtype)
 
 
-def _onehot_dot(x, onehot, contract):
-    """float32 ``x`` times a 0/1 matrix on the MXU at float32 precision:
-    Mosaic's default rounds both operands to bfloat16 (3e-3 of the
-    result, read on the chip), and the passes that ``HIGHEST`` adds
-    cost this kernel nothing measurable, its pages being read from HBM
-    the while."""
-    return jax.lax.dot_general(
-        x, onehot, (((1,), (contract,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)
-
-
 def _paged_decode_kernel(seq_lens_ref, tables_ref, layer_ref, q_ref,
                          head_of_ref, k_ref, v_ref, zeros_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, block_size):
-    """One (sequence, page) cell: score the query row against this page's
+                         acc_ref, m_ref, l_ref, *, scale, block_size,
+                         operand_dtype, precision):
+    """One (sequence, page) cell: score the query against this page's
     tokens, fold into the running online softmax held in scratch.
 
     A page is ``[bs, W]``, every head of a token side by side on the
-    lanes. The products ``k * q`` are taken on the VPU in float32; a
-    head's score is the sum over ITS lanes, which is a matmul with the
-    0/1 matrix ``head_of`` ``[W, 128]`` (lane w belongs to head w // D):
-    scores ``[bs, 128]``, tokens on sublanes and heads on lanes, so the
-    softmax's max and sum run down the sublanes. The probabilities go
-    back to the lanes of their heads through the same matrix
-    (``[bs, 128] x [W, 128]^T``), with the running correction as rows of
-    the same product, and weigh the value page on the VPU."""
+    lanes, in the type the cache stores. ``q_ref`` holds the query ONCE
+    A HEAD, ``[HP, W]``: row h is head h's query on that head's own
+    lanes and zero elsewhere, so ``q . page^T`` on the MXU is
+    ``[HP, bs]``, a head's scores against every token of the page
+    (heads on sublanes, tokens on lanes: the softmax's max and sum run
+    along the lanes), with the page as the MXU reads it, unconverted.
+    ``p . page_v`` ``[HP, W]`` then weighs the whole value page for every
+    head; row h is wanted on head h's lanes only, which the 0/1 mask
+    ``head_of`` ``[HP, W]`` picks when the last page folds the rows
+    into the one context row. Sums, the maximum and the exponentials
+    are float32; the operands are the page's type (float32 pages at
+    ``HIGHEST``: Mosaic's default rounds float32 operands to bfloat16,
+    3e-3 of the result, read on the chip)."""
     del tables_ref, layer_ref          # read by the index maps
     del zeros_ref                      # what o_ref starts as
     b = pl.program_id(0)
@@ -581,43 +576,41 @@ def _paged_decode_kernel(seq_lens_ref, tables_ref, layer_ref, q_ref,
 
     @pl.when(t * block_size < n)
     def _page():
-        head_of = head_of_ref[...]                           # [W, 128]
-        k = k_ref[0, 0].astype(jnp.float32)                  # [bs, W]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = _onehot_dot(k * q_ref[0], head_of, 0)
+        q = q_ref[0].astype(operand_dtype)                   # [HP, W]
+        k = k_ref[0, 0].astype(operand_dtype)                # [bs, W]
+        v = v_ref[0, 0].astype(operand_dtype)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32) * scale      # [HP, bs]
         pos = t * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        s = jnp.where(pos < n, s, NEG_INF)                   # [bs, 128]
-        m_prev = m_ref[...]                                  # [1, 128]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            jnp.int32, s.shape, 1)
+        s = jnp.where(pos < n, s, NEG_INF)
+        m_prev = m_ref[...]                                  # [HP, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         correction = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * correction \
-            + jnp.sum(p, axis=0, keepdims=True)
+            + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_new
-        wide = _onehot_dot(
-            jnp.concatenate(
-                [p, jnp.broadcast_to(correction, (8, HEAD_LANES))], axis=0),
-            head_of, 1)                                      # [bs + 8, W]
-        acc_ref[...] = acc_ref[...] * wide[block_size:block_size + 1] \
-            + jnp.sum(wide[:block_size] * v, axis=0, keepdims=True)
+        acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
+            p.astype(operand_dtype), v, (((1,), (0,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32)
 
     @pl.when(t == pl.num_programs(1) - 1)
     def _write():
         l = l_ref[...]
-        inverse = jnp.broadcast_to(1.0 / jnp.where(l > 0.0, l, 1.0),
-                                   (8, HEAD_LANES))
-        o_ref[0] = (acc_ref[...] * _onehot_dot(
-            inverse, head_of_ref[...], 1)[:1]).astype(o_ref.dtype)
+        ctx = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)      # [HP, W]
+        o_ref[0] = jnp.sum(ctx * head_of_ref[...], axis=0,
+                           keepdims=True).astype(o_ref.dtype)
 
 
 def supports_paged(q_shape, block_size: int) -> bool:
     """Kernel applicability for decode: [B, H, D] single-token queries,
-    a head a lane of the score tile, sublane-aligned page size."""
+    a head a row of one score tile, sublane-aligned page size."""
     if len(q_shape) != 3:
         return False
     _, h, _ = q_shape
-    return h <= HEAD_LANES and block_size % 8 == 0
+    return h <= MAX_HEADS and block_size % 8 == 0
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
@@ -627,13 +620,21 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
     q: ``[B, H, D]`` (one new query token per sequence) — k_pages /
     v_pages: the cache's stacked pools ``[L, P, bs, W]``, ``W >= H * D``
     a multiple of 128 (``serving.kv_cache.PagedKvCache``), read in place
-    — block_tables: ``[B, T]`` int32 page ids per sequence (entries past
-    the sequence's pages may be any valid id; their tokens are masked by
-    ``seq_lens``) — seq_lens: ``[B]`` int32 tokens live in each
-    sequence's cache; a row of length 0 (a pad row) reads nothing and
-    gets zeros — layer: an int32 scalar, traced or not, that picks the
-    layer's pages without slicing them out.
+    and in the type they are stored in: float32 pages are multiplied at
+    float32 precision, bfloat16 pages as bfloat16 operands (the query
+    and the probabilities rounded to it) with float32 sums
+    — block_tables: ``[B, T]`` int32 page ids per sequence, in the
+    order their rows are attended over (entries past the sequence's
+    pages may be any valid id; their tokens are masked by ``seq_lens``)
+    — seq_lens: ``[B]`` int32 rows live in each sequence's table; a row
+    of length 0 (a pad row) reads nothing and gets zeros — layer: an
+    int32 scalar, traced or not, that picks the layer's pages without
+    slicing them out.
     Returns the attention context ``[B, H, D]``.
+
+    One softmax over whatever rows the table lists: GPT's table lists a
+    sequence's tokens; ``models.evabyte``'s lists the summary pages of
+    its closed windows, then its open window's pages.
 
     Inference-only by design (no VJP): decode never backpropagates.
     Numerics match :func:`_reference_paged_decode` to fp32 online-softmax
@@ -646,7 +647,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
             "page pools %r/%r are not one stacked [L, P, bs, W] a side"
             % (k_pages.shape, v_pages.shape))
     _, _, block_size, width = k_pages.shape
-    if width < h * d or width % MIN_BLOCK or h > HEAD_LANES:
+    if width < h * d or width % MIN_BLOCK or h > MAX_HEADS:
         raise ValueError(
             "page pools %r do not hold q's %d heads of %d on whole lanes"
             % (k_pages.shape, h, d))
@@ -657,11 +658,16 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     pages_per_seq = block_tables.shape[1]
-    scaled = jnp.pad((q.astype(jnp.float32) * scale).reshape(b, 1, h * d),
-                     ((0, 0), (0, 0), (0, width - h * d)))
-    lane = jnp.arange(width)[:, None]
-    head_of = ((lane // d == jnp.arange(HEAD_LANES)[None, :])
-               & (lane < h * d)).astype(jnp.float32)
+    head_rows = -(-h // HEAD_ROWS) * HEAD_ROWS
+    lane = jnp.arange(width)[None, :]
+    head_of = ((lane // d == jnp.arange(head_rows)[:, None])
+               & (lane < h * d)).astype(jnp.float32)         # [HP, W]
+    flat = jnp.pad(q.astype(jnp.float32).reshape(b, 1, h * d),
+                   ((0, 0), (0, 0), (0, width - h * d)))
+    # head h's query on head h's lanes, in the type the MXU is fed (the
+    # scores are scaled after the product, in float32)
+    spread = (flat * head_of[None]).astype(k_pages.dtype)    # [B, HP, W]
+    exact = k_pages.dtype == jnp.float32 or interpret
 
     def q_index(bi, ti, lens_ref, tables_ref, layer_ref):
         return (bi, 0, 0)
@@ -679,21 +685,26 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
         num_scalar_prefetch=3,
         grid=(rows, pages),
         in_specs=[
-            pl.BlockSpec((1, 1, width), q_index),
-            pl.BlockSpec((width, HEAD_LANES), lambda *_: (0, 0)),
+            pl.BlockSpec((1, head_rows, width), q_index),
+            pl.BlockSpec((head_rows, width), lambda *_: (0, 0)),
             pl.BlockSpec((1, 1, block_size, width), page_index),
             pl.BlockSpec((1, 1, block_size, width), page_index),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, 1, width), q_index),
         scratch_shapes=[
-            pltpu.VMEM((1, width), jnp.float32),        # ctx accumulator
-            pltpu.VMEM((1, HEAD_LANES), jnp.float32),   # running max
-            pltpu.VMEM((1, HEAD_LANES), jnp.float32),   # running denom
+            pltpu.VMEM((head_rows, width), jnp.float32),    # ctx accumulator
+            pltpu.VMEM((head_rows, 1), jnp.float32),        # running max
+            pltpu.VMEM((head_rows, 1), jnp.float32),        # running denom
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, block_size=block_size),
+        functools.partial(
+            _paged_decode_kernel, scale=scale, block_size=block_size,
+            # the MXU takes the pages' own type; the CPU the interpreter
+            # runs on has no bfloat16 dot
+            operand_dtype=jnp.float32 if exact else k_pages.dtype,
+            precision=jax.lax.Precision.HIGHEST if exact else None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, width), q.dtype),
         # rows past the grid keep the zeros handed in
@@ -701,7 +712,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
         interpret=interpret,
         name="paged_decode",
     )(seq_lens, block_tables.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), scaled, head_of, k_pages,
+      jnp.asarray(layer, jnp.int32).reshape(1), spread, head_of, k_pages,
       v_pages, jnp.zeros((b, 1, width), q.dtype))
     return out[:, 0, :h * d].reshape(b, h, d)
 

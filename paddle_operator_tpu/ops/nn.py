@@ -149,12 +149,16 @@ def layernorm(params, x, eps: float = 1e-6, dtype=jnp.bfloat16):
     return y.astype(dtype)
 
 
-def rmsnorm(scale, x, eps: float = 1e-6, dtype=jnp.bfloat16):
+def rmsnorm(scale, x, eps: float = 1e-6, dtype=jnp.bfloat16,
+            unit_offset: bool = False):
     """Root-mean-square norm over the last axis, no mean and no bias:
-    ``x / sqrt(mean(x^2) + eps) * scale``, computed in float32."""
+    ``x / sqrt(mean(x^2) + eps) * scale``, computed in float32. With
+    ``unit_offset`` the learned vector is the gain's distance from one,
+    ``* (1 + scale)`` (EvaByte's ``norm_add_unit_offset``)."""
     xf = x.astype(jnp.float32)
     y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(dtype)
+    gain = scale.astype(jnp.float32)
+    return (y * (1.0 + gain if unit_offset else gain)).astype(dtype)
 
 
 # ---------------------------------------------------------------------------

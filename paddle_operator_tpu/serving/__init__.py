@@ -42,12 +42,14 @@ from .controller import (  # noqa: F401
 )
 from .kv_cache import (  # noqa: F401
     KvBlockAllocator, KvCacheFull, LatentKvCache, PagedKvCache,
+    WindowKvCache,
 )
 from .metrics import ServeMetrics  # noqa: F401
 
 __all__ = [
     "ANNOT_DESIRED_REPLICAS", "ContinuousBatcher", "KvBlockAllocator",
-    "KvCacheFull", "LatentKvCache", "PagedKvCache", "Request",
+    "KvCacheFull", "LatentKvCache", "PagedKvCache", "WindowKvCache",
+    "Request",
     "RequestQueue", "SERVING_DEFAULTS", "SHED_POLICIES", "ScaleDecision",
     "ServeMetrics", "ServingAutoscaler", "ServingEngine",
     "apply_desired_replicas", "serving_config", "serving_replicas",

@@ -4,18 +4,30 @@ Training runs the full sequence through the model every step; serving
 must not: after the prompt is processed once (**prefill**), each new
 token needs only its OWN query row against the cache of everything
 before it (**decode**). The engine owns that split and everything around
-it that is not the model: admission and the page reservation, slots,
-block tables, page memory, the two compiled steps and their spans. The
-layer stack, the cache's layout and the two step functions come from the
-MODEL'S MODULE (``model=``, default :mod:`..models.gpt`), which offers:
+it that is not the model: admission, slots, the two compiled steps and
+their spans. The layer stack, the cache and the two step functions come
+from the MODEL'S MODULE (``model=``, default :mod:`..models.gpt`), which
+offers:
 
-* ``serve_cache(config, num_blocks, block_size)`` — the array half of
-  the cache behind a :class:`.kv_cache.KvBlockAllocator`
+* ``serve_cache(config, num_blocks, block_size)`` — the cache: page
+  memory behind a :class:`.kv_cache.KvBlockAllocator`
   (:class:`.kv_cache.PagedKvCache`: one K and one V pool for all
   layers, a token's heads side by side in a row;
   :class:`.kv_cache.LatentKvCache`: a tuple of pools behind the one
-  block table, one compressed row a token in each). Either hands the
-  decode step its pools donated: the step updates them where they lie;
+  block table, one compressed row a token in each;
+  :class:`.kv_cache.WindowKvCache`: the paged pools holding an exact
+  window's rows beside one summary row a chunk of the windows before
+  it). Each hands the decode step its pools donated: the step updates
+  them where they lie. WHAT A SEQUENCE KEEPS FOR ITS TOKENS IS THE
+  CACHE'S TO SAY, and the engine asks it instead of computing:
+  ``pages_for(tokens)`` (the pages a budget reserves, which is what
+  ``allocator.alloc_sequence`` takes), ``table_width(max_seq)`` (the
+  decode table's columns) and, for every row of a decode step,
+  ``decode_row(seq_id)`` -> (the token's position, the pages its
+  attention reads in order, the rows live in them: the new row's
+  slot). A row a token, the first pages and the tokens so far for the
+  paged and the latent cache; window arithmetic for the third. Nothing
+  here branches on which cache or which model it holds;
 * ``serve_buckets(config, prompt_pad)`` — the padded prompt lengths
   prefill compiles for (a prompt takes the shortest that holds it);
 * ``serve_prefill(config, pad)`` -> ``f(params, ids [1, pad], length) ->
@@ -29,7 +41,8 @@ MODEL'S MODULE (``model=``, default :mod:`..models.gpt`), which offers:
   gather-einsum reference (``attn="reference"``, which the tests
   compare token for token). ``counters`` are int32 scalars the engine
   banks under their names (``moe.pairs_here``, ``moe.experts_hit``,
-  ``dsa.rows_live``, ``dsa.rows_selected``).
+  ``dsa.rows_live``, ``dsa.rows_selected``, ``eva.rows_read``,
+  ``eva.tokens_live``, ``eva.windows_closed``).
 
 Models served: :mod:`..models.gpt` (float32; no expert configuration:
 its Switch layer drops tokens over capacity and has no decode path),
@@ -37,7 +50,12 @@ its Switch layer drops tokens over capacity and has no decode path),
 cache, an expert layer that computes the experts this chip holds) and
 :mod:`..models.dsv32` (that stack with an indexer whose keys have a pool
 of their own, decode over the selected rows only, a prefill that walks
-its prompt in chunks inside one program a bucket).
+its prompt in chunks inside one program a bucket) and
+:mod:`..models.evabyte` (bfloat16; a byte-level decoder whose attention
+reads an exact window and pooled summaries of every earlier chunk out
+of GPT's pools through GPT's decode kernel; a window closes inside the
+compiled decode step; a prefill that walks its prompt a window at a time
+and caches the open window and the summaries only).
 
 Both steps compile through :func:`..compile_cache.cached_jit`, as a
 training worker's step does, so a replica takes them from whichever rung
@@ -97,9 +115,10 @@ class ServingEngine:
         self.attn = attn
         self.eos_id = eos_id
         self.label = label
-        #: pages one sequence may span — the decode block-table width
-        self.pages_per_seq = -(-config["max_seq"] // block_size)
         self.cache = model.serve_cache(self.config, num_blocks, block_size)
+        #: the decode block-table's width: the pages one sequence's
+        #: attention may read, which its cache knows
+        self.pages_per_seq = self.cache.table_width(config["max_seq"])
         #: the padded prompt lengths, ascending, and the program of each
         self.buckets = tuple(model.serve_buckets(self.config, prompt_pad))
         self._prefilled: Dict[str, bool] = {}
@@ -237,7 +256,7 @@ class ServingEngine:
         with timed("serve.prefill.dispatch", request_id=rid, bucket=pad):
             token, rows = self._prefill_fns[pad](self.params, ids, length)
         with timed("serve.prefill.scatter", request_id=rid,
-                   pages=-(-n // self.cache.allocator.block_size)):
+                   pages=self.cache.pages_for(n)):
             self.cache.write_rows(rid, rows, n)
         with timed("serve.prefill.wait", request_id=rid):
             return int(token)
@@ -247,17 +266,18 @@ class ServingEngine:
             self._decode_fn = self._build_decode()
         timed = self.times.timed
         with timed("serve.decode.tables"):
-            alloc, b = self.cache.allocator, self.max_batch
+            b = self.max_batch
             # filled on the host; pad rows stay zero and not live
             tokens, positions, lens = np.zeros((3, b), np.int32)
             tables = np.zeros((b, self.pages_per_seq), np.int32)
             live = np.arange(b) < len(rows)
             for i, req in enumerate(rows):
-                sid = req.request_id
                 tokens[i] = req.generated[-1]
-                lens[i] = alloc.seq_len(sid)
-                positions[i] = alloc.advance(sid)  # == lens[i], slot reserved
-                table = alloc.block_table(sid)
+                # the cache's answer: where the token stands, the pages
+                # its attention reads in order, the rows live in them
+                # (the new row's slot, which this call reserves)
+                positions[i], table, lens[i] = self.cache.decode_row(
+                    req.request_id)
                 tables[i, :len(table)] = table
         with timed("serve.decode.put"):
             # host -> device, once a step: one transfer call for the five

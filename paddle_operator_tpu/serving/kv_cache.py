@@ -17,13 +17,29 @@ block_size, heads * head_dim]``, the layout
 :func:`..ops.attention_pallas.paged_decode_attention` reads in place.
 :class:`LatentKvCache` is the array half for latent attention: ONE
 compressed row a token and layer for all heads (a tuple of pools, one a
-width it is told), behind the same allocator. The engine asks either
-for ``pools()`` / ``set_pools()`` (what the decode step takes and hands
-back; the decode step itself writes each new token's rows),
-``write_rows()`` (a prefill's rows: in both caches one jitted program a
-padded prompt length that takes the pools donated and writes whole
-pages in place) and ``donate_pools`` (whether the decode step may
-overwrite the pools it is handed: both say yes).
+width it is told), behind the same allocator.
+:class:`WindowKvCache` is :class:`PagedKvCache` for a model whose
+sequences keep an exact WINDOW of their newest positions beside one
+pooled SUMMARY row for every chunk of the windows before it
+(``models.evabyte``): the same two pools hold both kinds of row, and a
+sequence's pages are its window's, written over each time a window
+closes, and one summary page a closed window.
+
+What a sequence keeps for its tokens is the cache's to say, and the
+engine asks instead of computing it: ``pages_for(tokens)`` (the pages a
+budget reserves: the allocator's arithmetic), ``table_width(max_seq)``
+(the decode step's table columns) and ``decode_row(seq_id)`` (for a
+row of the next decode step: its position, the pages its attention
+reads IN ORDER, and how many rows of them are live; the new row is
+written at that row of the table). One row a token, the first pages,
+the tokens so far for the paged and the latent cache (:class:`_RowAToken`);
+window arithmetic for :class:`WindowKvCache`. Beside them ``pools()`` /
+``set_pools()`` (what the decode step takes and hands back; the decode
+step itself writes each new token's rows), ``write_rows()`` (a
+prefill's rows: one jitted program a padded prompt length that takes
+the pools donated and writes whole pages in place) and ``donate_pools``
+(whether the decode step may overwrite the pools it is handed: all say
+yes).
 
 Thread safety: every allocator field is owned by ``_lock`` (declared in
 analysis/guards.py — the static OPS9xx passes and the runtime race
@@ -33,7 +49,7 @@ detector both enforce it).
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 
 def _prompt_pages(cache: Any, seq_id: str, n: int, padded: int) -> Any:
@@ -87,18 +103,31 @@ class KvBlockAllocator:
 
     * every block is either in the free list or in exactly one
       sequence's table — no leak, no double-own;
-    * ``len(table) * block_size >= seq_len`` and
-      ``(len(table) - 1) * block_size < seq_len`` — tables are exactly
-      as long as the tokens need, never longer;
-    * fragmentation is only ever tail slack:
-      ``waste == Σ (len(table) * block_size - seq_len)``.
+    * ``len(table) == pages_for(reserved tokens)`` — tables are exactly
+      as long as the budget needs, never longer;
+    * fragmentation is only ever slack:
+      ``waste == Σ (len(table) * block_size - rows_for(seq_len))``.
+
+    ``pages_for(tokens)`` and ``rows_for(tokens, budget)`` are the
+    cache's answers (how many pages a budget of ``tokens`` reserves;
+    how many ROWS of them are filled once ``tokens`` positions of a
+    sequence with that budget are written). Left out, a token is a row:
+    ``ceil(tokens / block_size)`` pages, ``tokens`` rows.
+    :class:`WindowKvCache` hands its own: its pages are written over
+    and hold fewer rows than the sequence has tokens.
     """
 
-    def __init__(self, num_blocks: int, block_size: int) -> None:
+    def __init__(self, num_blocks: int, block_size: int,
+                 pages_for: Optional[Callable[[int], int]] = None,
+                 rows_for: Optional[Callable[[int, int], int]] = None
+                 ) -> None:
         if num_blocks <= 0 or block_size <= 0:
             raise ValueError("num_blocks and block_size must be positive")
         self.num_blocks = num_blocks
         self.block_size = block_size
+        self.pages_for = pages_for or (
+            lambda tokens: -(-tokens // block_size))
+        self.rows_for = rows_for or (lambda tokens, budget: tokens)
         self._lock = threading.Lock()
         # LIFO free list: a just-freed (hot) block is reused first
         self._free: List[int] = list(range(num_blocks - 1, -1, -1))
@@ -127,7 +156,7 @@ class KvBlockAllocator:
         if not 0 < live <= num_tokens:
             raise ValueError("live_tokens %r outside (0, %d]"
                              % (live_tokens, num_tokens))
-        need = -(-num_tokens // self.block_size)
+        need = self.pages_for(num_tokens)
         with self._lock:
             if seq_id in self._tables:
                 raise ValueError("sequence %r already allocated" % seq_id)
@@ -164,7 +193,8 @@ class KvBlockAllocator:
         Returns the newly allocated block id when the token crossed a
         block boundary, else None. Raises :class:`KvCacheFull` (sequence
         unchanged) on exhaustion. The incremental-growth counterpart of
-        the up-front reservation: callers pick one style per sequence."""
+        the up-front reservation: callers pick one style per sequence
+        (a token a row: not for a cache that hands its own arithmetic)."""
         with self._lock:
             if seq_id not in self._tables:
                 raise KeyError("unknown sequence %r" % seq_id)
@@ -214,12 +244,13 @@ class KvBlockAllocator:
             return sorted(self._tables)
 
     def stats(self) -> Dict[str, int]:
-        """Pool occupancy + fragmentation: ``waste_slots`` is the tail
-        slack (allocated-but-unfilled token slots), the ONLY internal
-        fragmentation paging permits."""
+        """Pool occupancy + fragmentation, in ROWS of cache:
+        ``waste_slots`` is the slack (allocated-but-unfilled rows), the
+        ONLY internal fragmentation paging permits."""
         with self._lock:
             used = self.num_blocks - len(self._free)
-            waste = sum(len(t) * self.block_size - self._lens[s]
+            waste = sum(len(t) * self.block_size
+                        - self.rows_for(self._lens[s], self._reserved[s])
                         for s, t in self._tables.items())
             reserved_slack = sum(self._reserved[s] - self._lens[s]
                                  for s in self._tables)
@@ -240,7 +271,7 @@ class KvBlockAllocator:
             owned: List[int] = []
             for seq, table in self._tables.items():
                 owned.extend(table)
-                need = -(-self._reserved[seq] // self.block_size)
+                need = self.pages_for(self._reserved[seq])
                 if len(table) != need:
                     errs.append(
                         "seq %r: %d block(s) for %d reserved slot(s), "
@@ -261,7 +292,28 @@ class KvBlockAllocator:
         return errs
 
 
-class PagedKvCache:
+class _RowAToken:
+    """What the engine asks of a cache whose sequences keep one row a
+    token: ``ceil(tokens / block_size)`` pages, attended in the order
+    they were reserved, as many rows live as tokens written."""
+
+    allocator: KvBlockAllocator
+
+    def pages_for(self, tokens: int) -> int:
+        return self.allocator.pages_for(tokens)
+
+    def table_width(self, max_seq: int) -> int:
+        return self.pages_for(max_seq)
+
+    def decode_row(self, seq_id: str) -> Tuple[int, List[int], int]:
+        """(the next token's position — its slot is reserved by this
+        call —, the sequence's pages, the rows live before it)."""
+        alloc = self.allocator
+        live = alloc.seq_len(seq_id)
+        return alloc.advance(seq_id), alloc.block_table(seq_id), live
+
+
+class PagedKvCache(_RowAToken):
     """The array half: ONE key pool and ONE value pool for all layers,
     each ``[layers, num_blocks + 1, block_size, W]`` plus an allocator.
     A token's row is its heads side by side, ``W`` = ``heads *
@@ -319,7 +371,13 @@ class PagedKvCache:
         import jax
         import jax.numpy as jnp
 
-        blocks = _prompt_pages(self, seq_id, n, rows[0].shape[1])
+        self._write_blocks(rows, _prompt_pages(self, seq_id, n,
+                                               rows[0].shape[1]))
+
+    def _write_blocks(self, rows: Tuple[Any, Any], blocks: Any) -> None:
+        import jax
+        import jax.numpy as jnp
+
         if self._write is None:
             def write(pools, rows, blocks):
                 return tuple(_write_pages(p, r, blocks)
@@ -330,7 +388,101 @@ class PagedKvCache:
                                    jnp.asarray(blocks)))
 
 
-class LatentKvCache:
+class WindowKvCache(PagedKvCache):
+    """:class:`PagedKvCache` for attention that keeps an exact window
+    beside pooled summaries (``models.evabyte``): of a sequence's
+    positions ``0 .. i`` the pools hold the rows of ``i``'s own window
+    (``window`` positions, the last of them ``i``) and, for every window
+    before it, ONE row for each of its chunks of ``chunk`` positions.
+    ``window // chunk == block_size``: a closed window's summaries are
+    exactly one page.
+
+    A budget of T tokens reserves ``min(window // block_size, ceil(T /
+    block_size))`` WINDOW pages, written over each time a window closes,
+    and ``(T - 1) // window`` SUMMARY pages, one for every window that
+    closes before the budget's last position: the first and the last
+    entries of the allocator's table. A decode row's table names in
+    column 0 the summary page of its OPEN window (the dummy page where
+    the budget ends inside that window), to which the decode step writes
+    each chunk's row as the chunk's last position lands, and from column
+    1 on the pages its attention reads, in this order: the summary pages
+    of the closed windows, then the window pages. When a window closes
+    its summary page moves from column 0 among the attended ones and the
+    window's pages start again at row 0: nothing is copied.
+    """
+
+    def __init__(self, num_blocks: int, block_size: int, layers: int,
+                 heads: int, head_dim: int, window: int, chunk: int,
+                 dtype: Any = None) -> None:
+        if window % block_size or window // chunk != block_size:
+            raise ValueError(
+                "a window of %d in chunks of %d does not fill pages of %d "
+                "rows with rows and one page with summaries"
+                % (window, chunk, block_size))
+        self.window, self.chunk = window, chunk
+        super().__init__(num_blocks, block_size, layers, heads, head_dim,
+                         dtype)
+        self.allocator = KvBlockAllocator(num_blocks, block_size,
+                                          self.pages_for, self.rows_for)
+
+    def pages_for(self, tokens: int) -> int:
+        size = self.allocator.block_size
+        return min(self.window // size, -(-tokens // size)) \
+            + (tokens - 1) // self.window
+
+    def rows_for(self, tokens: int, budget: int) -> int:
+        """Rows filled once ``tokens`` positions are written: the open
+        window's, and a summary for every whole chunk that has a page."""
+        kept = (budget - 1) // self.window * self.allocator.block_size
+        return tokens % self.window + min(tokens // self.chunk, kept)
+
+    def table_width(self, max_seq: int) -> int:
+        return 1 + (max_seq - 1) // self.window \
+            + self.window // self.allocator.block_size
+
+    def _split(self, seq_id: str) -> Tuple[List[int], List[int]]:
+        """(the sequence's window pages, its summary pages): a budget
+        that reserves a summary page reserves a whole window first."""
+        table = self.allocator.block_table(seq_id)
+        pages = min(len(table), self.window // self.allocator.block_size)
+        return table[:pages], table[pages:]
+
+    def decode_row(self, seq_id: str) -> Tuple[int, List[int], int]:
+        """(the next token's position i, the table of the class's
+        docstring, the rows live in its attended pages before it:
+        ``block_size`` a closed window and ``i % window`` of its own)."""
+        position = self.allocator.advance(seq_id)
+        pages, summaries = self._split(seq_id)
+        closed = position // self.window
+        return (position,
+                (summaries[closed:closed + 1] or [self.dummy_page])
+                + summaries[:closed] + pages,
+                closed * self.allocator.block_size + position % self.window)
+
+    def write_rows(self, seq_id: str, rows: Tuple[Any, Any], n: int) -> None:
+        """A prefill's keys and values, each ``[layers, pad // chunk +
+        window, heads * head_dim]``: one summary row for every chunk of
+        the padded prompt, then the rows of the window that is open
+        after ``n`` positions (``n % window`` of them live). Whole
+        pages into the donated pools, one program a padded length:
+        summary pages that hold a whole chunk of the prompt and have a
+        page reserved, window pages that hold a live row; every other
+        page of ``rows`` goes to the dummy page."""
+        import numpy as np
+
+        size = self.allocator.block_size
+        pages, summaries = self._split(seq_id)
+        first = (rows[0].shape[1] - self.window) // size
+        blocks = np.full((first + self.window // size,), self.dummy_page,
+                         np.int32)
+        keep = min(-(-(n // self.chunk) // size), len(summaries), first)
+        blocks[:keep] = summaries[:keep]
+        keep = min(-(-(n % self.window) // size), len(pages))
+        blocks[first:first + keep] = pages[:keep]
+        self._write_blocks(rows, blocks)
+
+
+class LatentKvCache(_RowAToken):
     """The array half for latent attention (``models.axk1``,
     ``models.dsv32``): what a token leaves behind in a layer is one row
     ``[c_kv | k_rope]`` shared by every head, so there are no separate

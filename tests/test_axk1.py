@@ -333,12 +333,19 @@ def test_padding_rows_route_nowhere_and_layers_index_stacked_kernels():
 #: two stacked pools donated, writes a token's rows with one scatter a
 #: pool and layer and reads them through ``paged_decode`` (or its
 #: reference) by layer index; the prefill hands its rows as the cache
-#: stores them, ``[layers, pad, heads * head_dim]`` a side. jax 0.9.0.
+#: stores them, ``[layers, pad, heads * head_dim]`` a side. PR 36
+#: re-pinned ONE, the paged decode step: ``paged_decode_attention`` took
+#: a second user (``models.evabyte``: bfloat16 pages of 32 heads x 128)
+#: and one form for both — the query spread a head a row and two MXU
+#: products a page in the pages' own type, where PR 33's multiplied on
+#: the VPU and summed through two 0/1 matmuls — so the kernel's text
+#: inside GPT's step changed; the prefill and the reference step did
+#: not. jax 0.9.0.
 PARENT_GPT_PROGRAMS = {
     ("paged", "serve-prefill"):
         "9b5d75b9d22cb9a64417fc244e230ae91394b477f005c1e66e258ffee6d72de8",
     ("paged", "serve-decode"):
-        "de986b99f7c8693562190d231156d977e57cc504e7cfe90c1d2900817ab8fbdd",
+        "1e2cb07ca1559ca94f6f6f43673e4d3aa8a0417fad865ed153caf2d0ba863838",
     ("reference", "serve-prefill"):
         "9b5d75b9d22cb9a64417fc244e230ae91394b477f005c1e66e258ffee6d72de8",
     ("reference", "serve-decode"):

@@ -507,7 +507,12 @@ def test_paged_decode_interpret_matches_reference(layer, dtype):
     out = paged_decode_attention(q, k_pages, v_pages, tables, lens, layer,
                                  interpret=True)
     assert out.shape == (b, h, d) and out.dtype == q.dtype
-    assert jnp.max(jnp.abs(out - ref)) < 1e-5
+    # float32 pages are multiplied at float32 precision; bfloat16 pages
+    # are the MXU's operands as they lie, so the query is rounded to
+    # bfloat16 too (2^-9 of each product; the sums stay float32) where
+    # the reference keeps it float32: 4e-3 read here (since PR 36)
+    assert jnp.max(jnp.abs(out - ref)) < (1e-5 if dtype == "float32"
+                                          else 8e-3)
     assert not jnp.any(out[3])
     # the layer picks its pages: another layer's answer is another one
     other = _reference_paged_decode(q, k_pages, v_pages, tables, lens,
