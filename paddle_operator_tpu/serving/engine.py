@@ -68,11 +68,19 @@ a bucket per engine config. Sampling is greedy argmax: serving replicas
 must be deterministic so the paged-vs-reference tests and the chaos
 replays can compare token ids exactly.
 
-A step crosses the host-device boundary once each way: what the host
-hands a compiled step is filled into numpy arrays and sent by ONE
-``jax.device_put`` of the tuple; a decode step's tokens and counters
-come back by ONE ``jax.device_get``. No eager ``jnp`` program runs in
-``step_fn``: only the compiled steps and the cache's page write.
+A decode step crosses the host-device boundary as ONE array each way.
+In: one numpy ``int32[max_batch, 4 + pages_per_seq]`` (a row's token,
+its position, the rows live, whether the row is live, then its block
+table), sent by ONE ``jax.device_put``; the compiled step slices the
+columns back out for the model's hook.
+Out: one ``int32[max_batch + counters]``, every row's token and then the
+hook's counters in the order of their sorted names (read once when the
+step is built), whose copy home is asked for AT DISPATCH
+(``copy_to_host_async``), so it follows the program on the device's
+queue and the read-back finds the bytes here. A prefill's padded prompt
+and its length go by one ``jax.device_put`` of the pair. No eager
+``jnp`` program runs in ``step_fn``: only the compiled steps and the
+cache's page write.
 """
 
 from __future__ import annotations
@@ -81,6 +89,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..utils.trace import StageTimes, export_stage_times
@@ -124,6 +133,9 @@ class ServingEngine:
         self._prefilled: Dict[str, bool] = {}
         self._prefill_fns: Dict[int, Callable[..., Any]] = {}
         self._decode_fn = None
+        #: the names of the decode hook's counters, sorted: the order
+        #: they follow the tokens in, in what a decode step hands back
+        self._counters: Tuple[str, ...] = ()
         #: this engine's spans (utils.trace): one ``serve.step`` per
         #: step_fn call with its phases inside, ``serve.admit`` per
         #: reservation; always on, bounded. Exported under the engine's
@@ -190,20 +202,32 @@ class ServingEngine:
         decode = self.model.serve_decode(self.config, attn, bs,
                                          self.cache.dummy_page)
 
-        def serve_decode(*args: Any) -> Any:
-            # ``jit_serve_decode`` on ``XLA Modules``: what the
-            # benchmark's ``decode_device_ms`` looks for
-            with jax.named_scope("serve_decode"):
-                return decode(*args)
-
         b = self.max_batch
         pools = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             self.cache.pools())
         row = jax.ShapeDtypeStruct((b,), np.int32)
-        ex = (self.params, pools, row, row,
-              jax.ShapeDtypeStruct((b, self.pages_per_seq), np.int32),
-              row, jax.ShapeDtypeStruct((b,), np.bool_))
+        # what the hook counts is static for a model and a config: asked
+        # of its shapes here, not learnt from tracing (the compile
+        # cache's memo can hand this engine a step it never traced)
+        _, _, counted = jax.eval_shape(
+            decode, self.params, pools, row, row,
+            jax.ShapeDtypeStruct((b, self.pages_per_seq), np.int32),
+            row, jax.ShapeDtypeStruct((b,), np.bool_))
+        names = self._counters = tuple(sorted(counted))
+
+        def serve_decode(params: Any, pools: Any, packed: Any) -> Any:
+            # ``jit_serve_decode`` on ``XLA Modules``: what the
+            # benchmark's ``decode_device_ms`` looks for
+            with jax.named_scope("serve_decode"):
+                tokens, pools, counters = decode(
+                    params, pools, packed[:, 0], packed[:, 1],
+                    packed[:, 4:], packed[:, 2], packed[:, 3] != 0)
+                return jnp.concatenate(
+                    [tokens] + [counters[n][None] for n in names]), pools
+
+        ex = (self.params, pools,
+              jax.ShapeDtypeStruct((b, 4 + self.pages_per_seq), np.int32))
         return compile_cache.cached_jit(
             serve_decode, ex,
             config=dict(self.config, attn=attn, max_batch=b,
@@ -266,36 +290,39 @@ class ServingEngine:
             self._decode_fn = self._build_decode()
         timed = self.times.timed
         with timed("serve.decode.tables"):
-            b = self.max_batch
-            # filled on the host; pad rows stay zero and not live
-            tokens, positions, lens = np.zeros((3, b), np.int32)
-            tables = np.zeros((b, self.pages_per_seq), np.int32)
-            live = np.arange(b) < len(rows)
+            # one array for the whole step, filled on the host: a row's
+            # token, position, rows live, live flag, then its table;
+            # pad rows stay zero and not live
+            packed = np.zeros((self.max_batch, 4 + self.pages_per_seq),
+                              np.int32)
             for i, req in enumerate(rows):
-                tokens[i] = req.generated[-1]
                 # the cache's answer: where the token stands, the pages
                 # its attention reads in order, the rows live in them
                 # (the new row's slot, which this call reserves)
-                positions[i], table, lens[i] = self.cache.decode_row(
+                position, table, lens = self.cache.decode_row(
                     req.request_id)
-                tables[i, :len(table)] = table
+                packed[i, :4] = req.generated[-1], position, lens, 1
+                packed[i, 4:4 + len(table)] = table
         with timed("serve.decode.put"):
-            # host -> device, once a step: one transfer call for the five
-            args = jax.device_put((tokens, positions, tables, lens, live))
+            # host -> device, once a step: one transfer of one array
+            packed = jax.device_put(packed)
         with timed("serve.decode.dispatch"):
-            out, pools, counters = self._decode_fn(
-                self.params, self.cache.pools(), *args)
+            out, pools = self._decode_fn(self.params, self.cache.pools(),
+                                         packed)
             self.cache.set_pools(pools)
+            # device -> host, once a step, asked for now: the copy
+            # follows the program on the device's queue
+            out.copy_to_host_async()
         with timed("serve.decode.wait"):
-            # the device's part of the step: the read-back finds it done
+            # the device's part of the step
             jax.block_until_ready(out)
         with timed("serve.decode.readback"):
-            # device -> host, once a step: every row's token and the
-            # model's counters (a model that counts nothing hands none)
-            out, counters = jax.device_get((out, counters))
+            # every row's token, then the model's counters (a model that
+            # counts nothing hands none): the bytes are here already
+            out = np.asarray(out).tolist()
             # banked as samples whose VALUE is the count (a stage's
             # total is then the count's, its calls the steps')
             now = time.perf_counter()
-            for name, value in counters.items():
+            for name, value in zip(self._counters, out[self.max_batch:]):
                 self.times.add(name, float(value), start=now)
-            return out[:len(rows)].tolist()
+            return out[:len(rows)]

@@ -340,16 +340,23 @@ def test_padding_rows_route_nowhere_and_layers_index_stacked_kernels():
 #: products a page in the pages' own type, where PR 33's multiplied on
 #: the VPU and summed through two 0/1 matmuls — so the kernel's text
 #: inside GPT's step changed; the prefill and the reference step did
-#: not. jax 0.9.0.
+#: not. PR 38 re-pinned the TWO decode steps: the engine hands a step ONE
+#: ``int32[max_batch, 4 + pages_per_seq]`` where it handed five arrays,
+#: and takes ONE ``int32[max_batch + counters]`` back beside the pools.
+#: With the names of the values normalised, the parent's text and this
+#: differ in ``main``'s signature and in 13 lines at its head (five
+#: slices, four reshapes, the ``!= 0`` of the live column); the blocks,
+#: the scatters and the kernel are the parent's line for line. The
+#: prefill is untouched. jax 0.9.0.
 PARENT_GPT_PROGRAMS = {
     ("paged", "serve-prefill"):
         "9b5d75b9d22cb9a64417fc244e230ae91394b477f005c1e66e258ffee6d72de8",
     ("paged", "serve-decode"):
-        "1e2cb07ca1559ca94f6f6f43673e4d3aa8a0417fad865ed153caf2d0ba863838",
+        "e2f7bd616772ef818c2a29c981353f2ac4c76d0b7c8d7a526c605251f4688325",
     ("reference", "serve-prefill"):
         "9b5d75b9d22cb9a64417fc244e230ae91394b477f005c1e66e258ffee6d72de8",
     ("reference", "serve-decode"):
-        "1a29599106b9177ba7d00f722d5c6846ce474b9b1e76c40398f8091821ffa41d",
+        "cd26debe61137f9108707be0153a218f350769e71ea65a3e98163faff80e51e4",
 }
 
 

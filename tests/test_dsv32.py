@@ -536,17 +536,27 @@ def test_one_group_and_no_bias_is_plain_top_k():
 
 #: sha256 of the lowered text of ``models.axk1``'s serving programs at
 #: ``axk1.TINY_CONFIG`` with ``max_seq`` 64 (max_batch 2, prompt_pad 16,
-#: 8 pages of 8) AT THE PARENT COMMIT (PR 29, d0b961a), less the names of
-#: ``main``'s results. jax 0.9.0.
+#: 8 pages of 8), less the names of ``main``'s results: the prefill's AS
+#: PR 29 LEFT IT (d0b961a), the decode steps' AS PR 38 LEFT THEM. PR 38
+#: meant to change the two decode steps and re-pinned them: the engine
+#: hands a step ONE ``int32[max_batch, 4 + pages_per_seq]`` where it
+#: handed five arrays, and takes ONE ``int32[max_batch + counters]``
+#: back beside the pool where it took the tokens and two scalars. With
+#: the names of the values normalised, the parent's text and this differ
+#: in ``main``'s signature, 13 lines at its head (five slices, four
+#: reshapes, the ``!= 0`` of the live column) and 3 at its end (the two
+#: counters broadcast to ``[1]`` and one concatenate); the stack, the
+#: expert layer and the kernel are the parent's line for line.
+#: jax 0.9.0.
 PARENT_AXK1_PROGRAMS = {
     ("paged", "serve-prefill"):
         "20f13cff0bfcdf261ff8099a5127d57cf8084fb06ebcfcb4cfc1ea69f7594c8e",
     ("paged", "serve-decode"):
-        "f529d934037020a4b3efe71258effa14be57d3a951ea5ec898088ef0652a8b9e",
+        "4beb04a521e9036720caf0e85c1656e689f310c03c2a4cbde1316d31d1f7c1b5",
     ("reference", "serve-prefill"):
         "20f13cff0bfcdf261ff8099a5127d57cf8084fb06ebcfcb4cfc1ea69f7594c8e",
     ("reference", "serve-decode"):
-        "30c67a06d0f3a07017e170f69d954f47140f4b00b51bab3d3807785c423d8b43",
+        "51f299203a1d8fe3f4fe3e5600593a9c95a04125ca993e8e50e67b568ebc7749",
 }
 
 

@@ -18,6 +18,8 @@ Shared-state holders are wrapped with the declared guard specs so
 ``make race`` asserts the lock contracts on these exact paths.
 """
 
+import functools
+
 import pytest
 
 from paddle_operator_tpu.analysis import guards
@@ -727,14 +729,16 @@ def test_paged_cache_write_rows_is_one_program_a_padded_length(caplog):
 
 def _tiny_engine(model, attn, label, prompt_pad=16):
     """An engine over ``model`` ("gpt": its decode step returns no
-    counters; "axk1": it counts its expert pairs) at the model's tiny
+    counters; "axk1": it counts its expert pairs; "dsv32": those and its
+    selected rows; "evabyte": its window's) at the model's tiny
     configuration."""
+    import importlib
+
     import jax
 
-    from paddle_operator_tpu.models import axk1, gpt
     from paddle_operator_tpu.serving.engine import ServingEngine
 
-    module = {"gpt": gpt, "axk1": axk1}[model]
+    module = importlib.import_module("paddle_operator_tpu.models." + model)
     cfg = dict(module.TINY_CONFIG)
     return ServingEngine(module.init(jax.random.PRNGKey(0), cfg), cfg,
                          max_batch=4, prompt_pad=prompt_pad, num_blocks=32,
@@ -796,21 +800,28 @@ def test_a_decode_step_updates_its_donated_pools_in_one_slot_a_row(attn):
         assert not changed.any()
 
 
-class _FetchedWhole:
-    """A decode step's tokens as the host may touch them: waited for
-    and fetched whole (``__array__`` is what ``jax.device_get`` calls),
-    never indexed or converted element by element."""
+class _PackedResult:
+    """A decode step's one result beside the pools as the host may touch
+    it: its copy home asked for, waited for and fetched whole
+    (``__array__`` is what ``np.asarray`` calls), each logged; never
+    indexed or converted element by element."""
 
-    def __init__(self, array):
-        self._array = array
+    def __init__(self, array, log):
+        self._array, self._log = array, log
+
+    def copy_to_host_async(self):
+        self._log.append("asked")
+        self._array.copy_to_host_async()
 
     def block_until_ready(self):
+        self._log.append("waited")
         self._array.block_until_ready()
         return self
 
     def __array__(self, *args, **kwargs):
         import numpy as np
 
+        self._log.append("fetched")
         return np.asarray(self._array)
 
     def _touched(self, *args):
@@ -821,11 +832,18 @@ class _FetchedWhole:
 
 @pytest.mark.parametrize("model", ["gpt", "axk1"])
 def test_a_decode_step_crosses_back_in_one_device_get(model, monkeypatch):
-    """Whatever the model returns beside its tokens, the engine reads a
-    decode step back by ONE ``jax.device_get`` of tokens and counters,
-    banks the counters it got (GPT: none) and hands on Python ints: the
-    tokens of an engine on the reference attention path."""
+    """Whatever the model counts beside its tokens, a decode step
+    crosses the boundary as ONE array each way: the compiled step is
+    handed one ``int32[max_batch, 4 + pages_per_seq]`` beside params and
+    pools, sent by the step's one ``jax.device_put``, and hands ONE
+    ``int32[max_batch + counters]`` back beside the pools, whose copy
+    home is asked for before it is waited for and which is fetched
+    once (never through ``jax.device_get``). The engine banks the
+    hook's counters under their names (GPT: none), one sample a step,
+    and hands on Python ints: the tokens of an engine on the reference
+    attention path."""
     import jax
+    import numpy as np
 
     steps = 3
     want = _decode_steps(_tiny_engine(model, "reference", "want"), steps)
@@ -833,32 +851,160 @@ def test_a_decode_step_crosses_back_in_one_device_get(model, monkeypatch):
     eng = _tiny_engine(model, "paged", "once")
     _decode_steps(eng, 1)                    # builds both steps
     eng.times.reset()
-    decode_fn, counted = eng._decode_fn, set()
+    names = {"gpt": set(),
+             "axk1": {"moe.pairs_here", "moe.experts_hit"}}[model]
+    decode_fn, touched = eng._decode_fn, []
 
-    def guarded(*args):
-        out, pools, counters = decode_fn(*args)
-        counted.update(counters)
-        return _FetchedWhole(out), pools, counters
+    def guarded(params, pools, *handed):
+        packed, = jax.tree_util.tree_leaves(handed)
+        assert isinstance(packed, jax.Array)
+        assert (packed.shape, packed.dtype) \
+            == ((eng.max_batch, 4 + eng.pages_per_seq), np.int32)
+        out, pools = decode_fn(params, pools, packed)
+        assert (out.shape, out.dtype) \
+            == ((eng.max_batch + len(names),), np.int32)
+        touched.append([])
+        return _PackedResult(out, touched[-1]), pools
 
     eng._decode_fn = guarded
-    gets = []
-    real_get = jax.device_get
+    crossings = {"device_get": 0, "device_put": 0}
+    for name in crossings:
+        def counting(x, _name=name, _real=getattr(jax, name)):
+            crossings[_name] += 1
+            return _real(x)
 
-    def device_get(x):
-        gets.append(x)
-        return real_get(x)
-
-    monkeypatch.setattr(jax, "device_get", device_get)
+        monkeypatch.setattr(jax, name, counting)
     got = _decode_steps(eng, steps)
-    assert len(gets) == steps
+    assert touched == [["asked", "waited", "fetched"]] * steps
+    # (a put a prefill, in the first step, and one a decode step)
+    assert crossings == {"device_get": 0,
+                         "device_put": len(_BOUNDARY_PROMPTS) + steps}
     assert got == want
     assert all(type(t) is int for row in got for t in row)
     # the counters' samples, one a step, and no other stage beside the spans
-    assert counted == ({"moe.pairs_here", "moe.experts_hit"}
-                       if model == "axk1" else set())
     banked = {k: v["count"] for k, v in eng.times.summary().items()
               if not k.startswith("serve.")}
-    assert banked == {name: steps for name in counted}
+    assert banked == {name: steps for name in names}
+
+
+@functools.lru_cache(maxsize=None)
+def _packed_steps(model):
+    """Three requests through a tiny engine of ``model`` with four rows,
+    joining one a step and retiring at budgets of their own (the second
+    passes position 32, where ``evabyte`` closes a window). Every decode
+    step is also run as the MODEL'S HOOK on the five separate arrays,
+    over a copy of the pools the step was handed. One record a decode
+    step: the array handed, what the packed step and the hook returned
+    (tokens, counters, pools), the pools before, what the engine handed
+    on; and the engine, for what it banked."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    eng = _tiny_engine(model, "reference", "packed", prompt_pad=64)
+    hook = jax.jit(eng.model.serve_decode(
+        eng.config, eng.attn, eng.cache.allocator.block_size,
+        eng.cache.dummy_page))
+    step, records = eng._build_decode(), []
+
+    def host(tree):
+        return [np.array(a) for a in jax.tree_util.tree_leaves(tree)]
+
+    def spy(params, pools, handed):
+        kept = jax.tree_util.tree_map(jnp.copy, pools)      # donated below
+        before, packed = host(pools), np.asarray(handed)
+        out, pools = step(params, pools, handed)
+        tokens, hook_pools, counters = hook(
+            params, kept, packed[:, 0], packed[:, 1], packed[:, 4:],
+            packed[:, 2], packed[:, 3].astype(bool))
+        records.append({
+            "packed": packed, "out": np.asarray(out).tolist(),
+            "hook_tokens": np.asarray(tokens).tolist(),
+            "hook_counters": {k: int(v) for k, v in counters.items()},
+            "before": before, "pools": host(pools),
+            "hook_pools": host(hook_pools)})
+        return out, pools
+
+    eng._decode_fn = spy
+    reqs = [Request("p%d" % i, prompt=[(7 * j + i) % 61 + 1
+                                       for j in range(n)],
+                    max_new_tokens=budget)
+            for i, (n, budget) in enumerate(zip((3, 30, 41), (5, 6, 4)))]
+    active = []
+    while reqs or active:
+        if reqs:
+            active.append(reqs.pop(0))
+            assert eng.admit(active[-1])
+        decoding = [r.request_id for r in active if r.generated]
+        seen = len(records)
+        tokens = [t for t, _ in eng.step_fn(active)]
+        for req, token in zip(active, tokens):
+            req.generated.append(token)
+        if decoding:
+            record, = records[seen:]
+            record["handed_on"] = [r.generated[-1] for r in active
+                                   if r.request_id in decoding]
+        for req in [r for r in active
+                    if len(r.generated) == r.max_new_tokens]:
+            active.remove(req)
+            eng.retire(req)
+    assert eng.cache.allocator.check() == []
+    return eng, records
+
+
+@pytest.mark.parametrize("check", ["tokens", "counters", "pad-rows"])
+@pytest.mark.parametrize("model", ["gpt", "axk1", "dsv32", "evabyte"])
+def test_the_packed_decode_step_is_the_models_hook(model, check):
+    """The step the engine compiles around a model's ``serve_decode``
+    (one array in, one out) against the hook itself on the five arrays,
+    over steps of 1, 2, 3, 3, 2 and 1 live rows of 4. ``tokens``: the same
+    token in every row of every step, and the live rows' are what the
+    engine handed on. ``counters``: what follows the tokens is the
+    hook's counters in the order of their sorted names, and what the
+    engine banked is those values, one sample a step. ``pad-rows``: the
+    rows past the live ones are zero (not live) in what the step is
+    handed, the step left every page alone but the live rows' own and
+    the dummy page, and the pools are bit for bit the hook's."""
+    import numpy as np
+
+    eng, records = _packed_steps(model)
+    b = eng.max_batch
+    live = [int(r["packed"][:, 3].sum()) for r in records]
+    assert live == [1, 2, 3, 3, 2, 1]
+    if check == "tokens":
+        for n, r in zip(live, records):
+            assert r["out"][:b] == r["hook_tokens"]
+            assert r["handed_on"] == r["out"][:n]
+    elif check == "counters":
+        names = sorted(records[0]["hook_counters"])
+        assert names == {
+            "gpt": [], "axk1": ["moe.experts_hit", "moe.pairs_here"],
+            "dsv32": ["dsa.rows_live", "dsa.rows_selected",
+                      "moe.experts_hit", "moe.pairs_here"],
+            "evabyte": ["eva.rows_read", "eva.tokens_live",
+                        "eva.windows_closed"]}[model]
+        for r in records:
+            assert r["out"][b:] == [r["hook_counters"][k] for k in names]
+        banked = {k for k in eng.times.summary() if not k.startswith("serve.")}
+        assert banked == set(names)
+        for k in names:
+            assert [s.seconds for s in eng.times.samples(k)] \
+                == [float(r["hook_counters"][k]) for r in records]
+        if model == "evabyte":
+            assert sum(r["hook_counters"]["eva.windows_closed"]
+                       for r in records) == 1
+    else:
+        dummy = eng.cache.dummy_page
+        for n, r in zip(live, records):
+            packed = r["packed"]
+            assert (packed[:n, 3] == 1).all() and not packed[n:].any()
+            own = set(packed[:n, 4:].ravel().tolist()) | {dummy}
+            for was, now, hooks in zip(r["before"], r["pools"],
+                                       r["hook_pools"]):
+                changed = (now != was).any(axis=(0, 2, 3))     # [pages]
+                assert changed.any()
+                assert set(np.nonzero(changed)[0].tolist()) <= own
+                assert now.tobytes() == hooks.tobytes()
 
 
 @pytest.mark.parametrize("model, pad", [("gpt", 48), ("axk1", 40)])
