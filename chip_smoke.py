@@ -395,18 +395,24 @@ def server_leg(sm: Smoke) -> None:
     import jax
 
     from paddle_operator_tpu.models import gpt
+    from paddle_operator_tpu.obs import parse_exposition
     from paddle_operator_tpu.serving.batching import (
         ContinuousBatcher, Request, RequestQueue)
     from paddle_operator_tpu.serving.engine import ServingEngine
+    from paddle_operator_tpu.serving.metrics import ServeMetrics
 
     cfg = dict(gpt.TINY_CONFIG if sm.rehearsal else gpt.BASE_CONFIG)
     new_tokens = 8 if sm.rehearsal else 32
     params = gpt.init(jax.random.PRNGKey(sm.seed), cfg)
     engine = ServingEngine(params, cfg)
     queue = RequestQueue(capacity=16)
-    batcher = ContinuousBatcher(queue, engine.max_batch,
+    metrics = ServeMetrics(job="default/smoke")
+    batcher = ContinuousBatcher(queue, engine.max_batch, metrics=metrics,
                                 on_admit=engine.admit,
                                 on_retire=engine.retire)
+    # what an operator scrapes: the scheduler's stages beside the engine's
+    metrics.add_stages(batcher.times)
+    metrics.add_stages(engine.times)
     rnd = random.Random(sm.seed)
     requests = [
         Request("req-%d" % i,
@@ -451,6 +457,22 @@ def server_leg(sm: Smoke) -> None:
              "KV pool not empty afterwards: %r" % stats)
     sm.check(engine.cache.allocator.check() == [],
              "allocator audit: %r" % engine.cache.allocator.check())
+    sched = batcher.times.summary()
+    sm.say("server_spans", iterations=sched["sched.step"]["count"],
+           sched_step_ms=sched["sched.step"]["mean_ms"],
+           serve_step_ms=engine.times.summary()["serve.step"]["mean_ms"],
+           between_ms=sched["sched.between"]["mean_ms"],
+           admitted=sched["sched.queue_wait"]["count"],
+           retired=sched["sched.retire"]["count"])
+    sm.check(sched["sched.step"]["count"] == iterations + 1
+             and sched["sched.retire"]["count"] == len(requests),
+             "the scheduler's spans disagree with the loop: %r" % sched)
+    block = metrics.metrics_block()
+    sm.check(parse_exposition(block + "\n") == []
+             and 'stage="sched.step"' in block
+             and 'stage="serve.step"' in block,
+             "the serving exposition lacks a scheduler's or an engine's "
+             "stage, or does not parse")
     del engine
     decode_copies_no_pool(sm, params, cfg)
 

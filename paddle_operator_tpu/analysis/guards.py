@@ -117,7 +117,7 @@ SPECS: Tuple[GuardSpec, ...] = (
     GuardSpec("paddle_operator_tpu.serving.autoscaler", "ServingAutoscaler",
               "_lock", ("_calm_streak", "_decisions")),
     GuardSpec("paddle_operator_tpu.serving.batching", "ContinuousBatcher",
-              "_lock", ("_active", "_counts")),
+              "_lock", ("_active", "_counts", "_left")),
     GuardSpec("paddle_operator_tpu.serving.batching", "RequestQueue",
               "_lock", ("_q", "_counts")),
     GuardSpec("paddle_operator_tpu.serving.kv_cache", "KvBlockAllocator",
@@ -126,7 +126,8 @@ SPECS: Tuple[GuardSpec, ...] = (
     GuardSpec("paddle_operator_tpu.serving.metrics", "ServeMetrics",
               "_lock",
               ("_requests", "_tokens", "_queue_depth", "_replicas",
-               "_hist", "_hist_sum", "_hist_count", "_pending_slo")),
+               "_hist", "_hist_sum", "_hist_count", "_pending_slo",
+               "_stages")),
 )
 
 

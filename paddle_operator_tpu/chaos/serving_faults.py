@@ -190,6 +190,7 @@ def run_serving_scenario(plan, injector: FaultInjector
                          shed_policy=cfg["shed_policy"], clock=clock)
     fleet_store: set = set()
     replicas: List[_Replica] = []
+    schedulers: List = []      # every replica's spans, preempted ones too
     submitted = 0
 
     def make_step(repl: _Replica):
@@ -214,6 +215,9 @@ def run_serving_scenario(plan, injector: FaultInjector
         bring_up.counter += 1
         repl = _Replica(name, queue, clock, metrics, fleet_store, tick)
         replicas.append(repl)
+        # the gang's exposition sums every replica's sched.* stages
+        metrics.add_stages(repl.batcher.times)
+        schedulers.append(repl.batcher.times)
         if repl.warm:
             injector.record("serve_warm_start")
             ledger.charge("default", "serve", "restore", RESTORE_CHARGE_S)
@@ -341,6 +345,15 @@ def run_serving_scenario(plan, injector: FaultInjector
         violations.append(
             "queue shed counters disagree with metrics: %r vs %r"
             % (qc, mcounts))
+
+    # the schedulers' own spans tell the same story as the metrics: one
+    # sched.retire a completed request, over every replica that ever ran
+    retired = sum(t.summary().get("sched.retire", {}).get("count", 0)
+                  for t in schedulers)
+    if retired != completed:
+        violations.append(
+            "the schedulers' spans disagree with the metrics: %d "
+            "sched.retire for %d completed" % (retired, completed))
 
     for repl in replicas:
         errs = repl.allocator.check()

@@ -26,6 +26,37 @@ The pieces:
   deterministic fake step while production wires
   :meth:`.engine.ServingEngine.step_fn`.
 
+The batcher times itself in an accumulator of its own
+(``ContinuousBatcher.times``, a :class:`..utils.trace.StageTimes`
+exported under ``"sched"``; always on, bounded, as the engine's):
+
+========================  ==============================================
+``sched.step``            one iteration, whole (``active``, ``admitted``,
+                          ``retired``). Its span id is the iteration's:
+                          everything below and the engine's
+                          ``serve.admit`` / ``serve.step`` carry it
+``sched.admit``           one request from the queue's pop to its slot or
+                          its way back (``request_id``, ``outcome`` =
+                          ``admitted`` | ``deferred`` | ``error``,
+                          ``depth``: the queue left behind)
+``sched.queue_wait``      ``t_admitted - t_arrival`` on the batcher's
+                          clock, banked where the request leaves the
+                          queue (``request_id``)
+``sched.retire``          ``on_retire`` + ``metrics.observe_request`` of
+                          one finished request (``request_id``,
+                          ``tokens``)
+``sched.between``         from the previous iteration's return to this
+                          one's entry, where that return left sequences
+                          in flight (``in_flight``): the CALLER's time,
+                          which every live row's token gap holds
+``sched.empty``           the same stretch where nothing was in flight:
+                          the replica stood idle for want of requests
+========================  ==============================================
+
+``sched.between`` / ``sched.empty`` plus the ``sched.step`` that follows
+tile the time from one return to the next with no remainder: the stamps
+are the ``sched.step`` spans' own.
+
 Thread safety: queue and batcher state are each owned by their ``_lock``
 (declared in analysis/guards.py); the engine step itself runs outside
 the batcher lock — it is model compute, not shared state.
@@ -34,12 +65,14 @@ the batcher lock — it is model compute, not shared state.
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 # the canonical vocabulary lives in the API layer so the webhook/CRD can
 # validate serving specs without importing the jax-backed data plane
 from ..api.types import SERVING_SHED_POLICIES as SHED_POLICIES
+from ..utils.trace import StageTimes, export_stage_times
 
 
 @dataclass
@@ -78,8 +111,6 @@ class RequestQueue:
         if shed_policy not in SHED_POLICIES:
             raise ValueError("shed_policy must be one of %s, got %r"
                              % ("|".join(SHED_POLICIES), shed_policy))
-        import time
-
         self.capacity = capacity
         self.shed_policy = shed_policy
         self._clock = clock or time.monotonic
@@ -153,11 +184,10 @@ class ContinuousBatcher:
                  clock: Optional[Callable[[], float]] = None,
                  metrics: Optional[Any] = None,
                  on_admit: Optional[Callable[[Request], bool]] = None,
-                 on_retire: Optional[Callable[[Request], None]] = None) -> None:
+                 on_retire: Optional[Callable[[Request], None]] = None,
+                 label: str = "sched") -> None:
         if max_batch <= 0:
             raise ValueError("max_batch must be positive")
-        import time
-
         self.queue = queue
         self.max_batch = max_batch
         self.metrics = metrics
@@ -168,22 +198,45 @@ class ContinuousBatcher:
         self._active: List[Request] = []
         self._counts: Dict[str, int] = {"completed": 0, "admit_deferred": 0,
                                         "preempted": 0, "iterations": 0}
+        #: where the last iteration (or a preemption since) ended on
+        #: ``time.perf_counter()`` and how many sequences it left in
+        #: flight; None until the first iteration has returned
+        self._left: Optional[Tuple[float, int]] = None
+        #: this scheduler's spans (the module's docstring); hand it to
+        #: ``ServeMetrics(stages=...)`` beside the engine's
+        self.times = export_stage_times(label, StageTimes())
 
     # -- scheduling ------------------------------------------------------
 
-    def _admit(self) -> None:
-        """Fill free slots from the queue head. ``on_admit`` returning
-        False (KV pool exhausted) defers the request — it goes back to
-        the FRONT so admission order is preserved."""
+    def _admit(self, span: Optional[int] = None) -> int:
+        """Fill free slots from the queue head; returns how many
+        requests took one. ``on_admit`` returning False (KV pool
+        exhausted) defers the request — it goes back to the FRONT so
+        admission order is preserved."""
+        admitted = 0
         while True:
             with self._lock:
                 if len(self._active) >= self.max_batch:
-                    return
+                    return admitted
             req = self.queue.pop()
             if req is None:
-                return
+                return admitted
             try:
-                admitted = self.on_admit is None or self.on_admit(req)
+                # the outcome stands at "error" until the hook has
+                # answered: an exception banks it on its way out
+                with self.times.timed(
+                        "sched.admit", request_id=req.request_id,
+                        outcome="error", depth=self.queue.depth()) as timed:
+                    ok = self.on_admit is None or self.on_admit(req)
+                    timed.attrs["outcome"] = "admitted" if ok else "deferred"
+                    if ok:
+                        req.t_admitted = self._clock()
+                        with self._lock:
+                            self._active.append(req)
+                    else:
+                        self.queue.requeue_front([req])
+                        with self._lock:
+                            self._counts["admit_deferred"] += 1
             except BaseException:
                 # the popped slot must not vanish with the exception:
                 # retire it as an engine error so request conservation
@@ -194,25 +247,54 @@ class ContinuousBatcher:
                     self._counts["admit_error"] = (
                         self._counts.get("admit_error", 0) + 1)
                 raise
-            if not admitted:
-                self.queue.requeue_front([req])
-                with self._lock:
-                    self._counts["admit_deferred"] += 1
-                return
-            req.t_admitted = self._clock()
-            with self._lock:
-                self._active.append(req)
+            if not ok:
+                return admitted
+            admitted += 1
+            self.times.add("sched.queue_wait",
+                           req.t_admitted - req.t_arrival, span=span,
+                           request_id=req.request_id)
 
     def step(self, engine_step: Callable[[List[Request]],
                                          List[Tuple[int, bool]]]) -> int:
         """One scheduler iteration: admit, run the engine step, retire.
         Returns how many sequences are still in flight."""
-        self._admit()
+        timed = self.times.timed("sched.step", active=0, admitted=0,
+                                 retired=0)
+        try:
+            with timed:
+                with self._lock:
+                    left = self._left
+                self._bank_stretch(left, timed.t0, timed.span)
+                return self._iterate(engine_step, timed)
+        finally:
+            with self._lock:
+                self._left = (timed.t0 + timed.seconds, len(self._active))
+
+    def _bank_stretch(self, left: Optional[Tuple[float, int]], until: float,
+                      span: Optional[int] = None) -> None:
+        """What lay between the last return (``left``) and ``until``:
+        the caller's time while sequences waited, or an empty
+        replica's."""
+        if left is None:
+            return
+        since, in_flight = left
+        if in_flight:
+            self.times.add("sched.between", until - since, start=since,
+                           span=span, in_flight=in_flight)
+        else:
+            self.times.add("sched.empty", until - since, start=since,
+                           span=span)
+
+    def _iterate(self, engine_step: Callable[[List[Request]],
+                                             List[Tuple[int, bool]]],
+                 timed: Any) -> int:
+        timed.attrs["admitted"] = self._admit(timed.span)
         with self._lock:
             active = list(self._active)
             self._counts["iterations"] += 1
         if not active:
             return 0
+        timed.attrs["active"] = len(active)
         results = engine_step(active)
         if len(results) != len(active):
             raise RuntimeError(
@@ -232,11 +314,14 @@ class ContinuousBatcher:
             for req in finished:
                 self._active.remove(req)
                 self._counts["completed"] += 1
+        timed.attrs["retired"] = len(finished)
         for req in finished:
-            if self.on_retire is not None:
-                self.on_retire(req)
-            if self.metrics is not None:
-                self.metrics.observe_request(req, outcome="ok")
+            with self.times.timed("sched.retire", request_id=req.request_id,
+                                  tokens=len(req.generated)):
+                if self.on_retire is not None:
+                    self.on_retire(req)
+                if self.metrics is not None:
+                    self.metrics.observe_request(req, outcome="ok")
         with self._lock:
             return len(self._active)
 
@@ -247,10 +332,15 @@ class ContinuousBatcher:
         pulled out of the batch (its partial generation is discarded —
         the paged cache dies with the replica) and handed to the caller
         to requeue or shed. Nothing is silently lost."""
+        now = time.perf_counter()
         with self._lock:
             victims = list(self._active)
             self._active = []
             self._counts["preempted"] += len(victims)
+            left, self._left = self._left, (now, 0)
+        # the stretch since the last return ends here, and what follows
+        # it is an empty replica's
+        self._bank_stretch(left, now)
         for req in victims:
             req.generated = []
             req.t_admitted = req.t_first_token = req.t_done = 0.0

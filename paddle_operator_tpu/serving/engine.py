@@ -40,9 +40,10 @@ offers:
   attention through the model's paged kernel (``attn="paged"``) or its
   gather-einsum reference (``attn="reference"``, which the tests
   compare token for token). ``counters`` are int32 scalars the engine
-  banks under their names (``moe.pairs_here``, ``moe.experts_hit``,
-  ``dsa.rows_live``, ``dsa.rows_selected``, ``eva.rows_read``,
-  ``eva.tokens_live``, ``eva.windows_closed``).
+  banks as counts (``StageTimes.count``) under their names
+  (``moe.pairs_here``, ``moe.experts_hit``, ``dsa.rows_live``,
+  ``dsa.rows_selected``, ``eva.rows_read``, ``eva.tokens_live``,
+  ``eva.windows_closed``).
 
 Models served: :mod:`..models.gpt` (float32; no expert configuration:
 its Switch layer drops tokens over capacity and has no decode path),
@@ -138,9 +139,11 @@ class ServingEngine:
         self._counters: Tuple[str, ...] = ()
         #: this engine's spans (utils.trace): one ``serve.step`` per
         #: step_fn call with its phases inside, ``serve.admit`` per
-        #: reservation; always on, bounded. Exported under the engine's
-        #: label for who reads in the same process; hand it to
-        #: ``ServeMetrics(stages=...)`` for the ``tpujob_serve_stage_*``
+        #: reservation, and what a decode step counted as counters;
+        #: always on, bounded. Exported under the engine's label for
+        #: who reads in the same process; hand it to
+        #: ``ServeMetrics(stages=...)``, beside the batcher's, for the
+        #: ``tpujob_serve_stage_*`` and ``tpujob_serve_step_count_*``
         #: families
         self.times = export_stage_times(label, StageTimes())
 
@@ -320,9 +323,9 @@ class ServingEngine:
             # every row's token, then the model's counters (a model that
             # counts nothing hands none): the bytes are here already
             out = np.asarray(out).tolist()
-            # banked as samples whose VALUE is the count (a stage's
-            # total is then the count's, its calls the steps')
+            # banked as counts, apart from the stages: one sample a
+            # step, stamped where its tokens were read back
             now = time.perf_counter()
             for name, value in zip(self._counters, out[self.max_batch:]):
-                self.times.add(name, float(value), start=now)
+                self.times.count(name, value, start=now)
             return out[:len(rows)]

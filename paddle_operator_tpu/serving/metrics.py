@@ -24,7 +24,7 @@ Two integrations ride along:
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Tuple, Union
 
 from ..k8s.runtime import escape_label_value
 from ..obs.exposition import format_float
@@ -51,23 +51,60 @@ _HIST_FAMILIES = (
      "histogram"),
 )
 
+#: (family, help, key of a ``StageTimes.summary()`` row, type): the
+#: seconds the scheduler (``sched.*``) and the engine (``serve.*``)
+#: spent a stage. A key in ``ms`` is exported in seconds
+_STAGE_FAMILIES = (
+    ("tpujob_serve_stage_seconds_total",
+     "Host wall-clock accumulated per stage of the serving loop (the "
+     "scheduler's sched.* and the engine's serve.* spans).",
+     "ms", "counter"),
+    ("tpujob_serve_stage_calls_total",
+     "Times each stage of the serving loop was entered.",
+     "count", "counter"),
+    ("tpujob_serve_stage_max_seconds",
+     "Longest single sample of each stage of the serving loop.",
+     "max_ms", "gauge"),
+)
+
+#: (family, help, key of a ``StageTimes.counts()`` row, type): what the
+#: decode steps counted, in counts and never in seconds
+_COUNTER_FAMILIES = (
+    ("tpujob_serve_step_counter_total",
+     "What the decode steps counted, summed (moe.pairs_here, "
+     "dsa.rows_selected, eva.rows_read ...).", "total", "counter"),
+    ("tpujob_serve_step_counter_steps_total",
+     "Decode steps that banked each counter.", "steps", "counter"),
+    ("tpujob_serve_step_counter_max",
+     "Largest count of one decode step.", "max", "gauge"),
+)
+
 
 class ServeMetrics:
     """Counters + histograms for one serving gang (a job's replicas).
 
     ``ledger``/``namespace``/``name`` wire the optional goodput-ledger
     charge: each completed request's queue wait lands as ``sched_wait``
-    badput against that job. ``stages`` is the engine's span accumulator
-    (``ServingEngine.times``): where the host's time inside the serving
-    step goes, exported as ``tpujob_serve_stage_*`` at every scrape.
+    badput against that job. ``stages`` is one span accumulator or
+    several (``ContinuousBatcher.times`` and ``ServingEngine.times``;
+    a gang's further replicas through :meth:`add_stages`): where the
+    host's time in the serving loop goes, exported as
+    ``tpujob_serve_stage_*`` at every scrape (a stage that several hold
+    is summed), and what the decode steps counted as
+    ``tpujob_serve_step_counter_*``.
     """
 
     def __init__(self, job: str = "default/serve",
                  ledger: Optional[Any] = None,
                  namespace: str = "", name: str = "",
-                 stages: Optional[StageTimes] = None) -> None:
+                 stages: Union[None, StageTimes,
+                               Iterable[StageTimes]] = None) -> None:
         self.job = job
-        self._stages = stages
+        if stages is None:
+            stages = ()
+        elif isinstance(stages, StageTimes):
+            stages = (stages,)
+        self._stages: List[StageTimes] = list(stages)
         self._ledger = ledger
         self._ns = namespace
         self._name = name
@@ -120,6 +157,12 @@ class ServeMetrics:
         self._hist_sum[which] = self._hist_sum.get(which, 0.0) + seconds
         self._hist_count[which] = self._hist_count.get(which, 0) + 1
 
+    def add_stages(self, times: StageTimes) -> None:
+        """One more accumulator to export: a replica that joined the
+        gang after this was built."""
+        with self._lock:
+            self._stages.append(times)
+
     def set_queue_depth(self, depth: int) -> None:
         with self._lock:
             self._queue_depth = int(depth)
@@ -158,6 +201,7 @@ class ServeMetrics:
             hist = {k: list(v) for k, v in self._hist.items()}
             hist_sum = dict(self._hist_sum)
             hist_count = dict(self._hist_count)
+            held = list(self._stages)
         job = esc(self.job)
         lines: List[str] = []
         lines.append("# HELP tpujob_serve_requests_total Requests leaving "
@@ -197,29 +241,36 @@ class ServeMetrics:
                          % (fam, job, hist_sum.get(which, 0.0)))
             lines.append('%s_count{job="%s"} %d'
                          % (fam, job, hist_count.get(which, 0)))
-        stages = self._stages.summary() if self._stages is not None else {}
-        if stages:
-            lines.append("# HELP tpujob_serve_stage_seconds_total Host "
-                         "wall-clock accumulated per stage of the serving "
-                         "step (the engine's serve.* spans).")
-            lines.append("# TYPE tpujob_serve_stage_seconds_total counter")
-            for stage in sorted(stages):
-                lines.append(
-                    'tpujob_serve_stage_seconds_total{job="%s",stage="%s"} '
-                    '%.6f' % (job, esc(stage), stages[stage]["ms"] / 1e3))
-            lines.append("# HELP tpujob_serve_stage_calls_total Times each "
-                         "stage of the serving step was entered.")
-            lines.append("# TYPE tpujob_serve_stage_calls_total counter")
-            for stage in sorted(stages):
-                lines.append(
-                    'tpujob_serve_stage_calls_total{job="%s",stage="%s"} %d'
-                    % (job, esc(stage), stages[stage]["count"]))
-            lines.append("# HELP tpujob_serve_stage_max_seconds Longest "
-                         "single sample of each stage of the serving step.")
-            lines.append("# TYPE tpujob_serve_stage_max_seconds gauge")
-            for stage in sorted(stages):
-                lines.append(
-                    'tpujob_serve_stage_max_seconds{job="%s",stage="%s"} '
-                    '%.6f' % (job, esc(stage),
-                              stages[stage]["max_ms"] / 1e3))
+        for families, label, rows in (
+                (_STAGE_FAMILIES, "stage",
+                 _merged(t.summary() for t in held)),
+                (_COUNTER_FAMILIES, "counter",
+                 _merged(t.counts() for t in held))):
+            if not rows:
+                continue
+            for fam, help_text, key, mtype in families:
+                lines.append("# HELP %s %s" % (fam, help_text))
+                lines.append("# TYPE %s %s" % (fam, mtype))
+                for name in sorted(rows):
+                    value = rows[name][key]
+                    lines.append('%s{job="%s",%s="%s"} %s' % (
+                        fam, job, label, esc(name),
+                        "%.6f" % (value / 1e3) if key.endswith("ms")
+                        else "%d" % value))
         return "\n".join(lines)
+
+
+def _merged(tables: Iterable[Dict[str, Dict[str, float]]]
+            ) -> Dict[str, Dict[str, float]]:
+    """The rows of several accumulators as one table: a name that
+    several hold (a gang's replicas all bank ``sched.step``) has its
+    totals summed and its largest sample the largest of theirs. (A
+    ``mean_ms`` does not survive that and is not exported.)"""
+    out: Dict[str, Dict[str, float]] = {}
+    for table in tables:
+        for name, row in table.items():
+            into = out.setdefault(name, dict.fromkeys(row, 0))
+            for key, value in row.items():
+                into[key] = max(into[key], value) \
+                    if key.startswith("max") else into[key] + value
+    return out

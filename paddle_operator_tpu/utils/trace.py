@@ -319,6 +319,12 @@ class Sample(NamedTuple):
     span: Optional[int]
     attrs: Dict[str, Any]
 
+    @property
+    def value(self) -> float:
+        """What a counter's sample holds where a span's holds its
+        seconds (:meth:`StageTimes.count`)."""
+        return self.seconds
+
 
 def _quantile(sorted_vals: List[float], q: float) -> float:
     if not sorted_vals:
@@ -376,6 +382,32 @@ class _Timed:
                        **self.attrs)
 
 
+class _Bank:
+    """Per name a total, the samples banked, the largest and a bounded
+    ring of the newest. ``StageTimes`` holds one for its stages and one
+    for its counters, both under its lock."""
+
+    __slots__ = ("total", "count", "max", "ring")
+
+    def __init__(self) -> None:
+        self.total: Dict[str, float] = {}
+        self.count: Dict[str, int] = {}
+        self.max: Dict[str, float] = {}
+        self.ring: Dict[str, Deque[Sample]] = {}
+
+    def add(self, name: str, sample: Sample) -> None:
+        ring = self.ring.get(name)
+        if ring is None:
+            ring = self.ring[name] = deque(maxlen=RING_DEPTH)
+            self.total[name] = self.count[name] = 0
+            self.max[name] = sample.seconds
+        self.total[name] += sample.seconds
+        self.count[name] += 1
+        if sample.seconds > self.max[name]:
+            self.max[name] = sample.seconds
+        ring.append(sample)
+
+
 class StageTimes:
     """Thread-safe accumulator of per-stage host time: the program's one
     span mechanism.
@@ -392,31 +424,35 @@ class StageTimes:
     ``summary()`` is the breakdown ``run_training`` reports;
     ``timed()`` also enters a ``jax.profiler.TraceAnnotation`` of the
     stage's name, so a device trace shows the same spans on its clock.
+
+    What a step COUNTED (pairs of token and expert, rows read) is
+    banked beside the stages and apart from them (``count()``): the
+    same rings, cut by ``samples()`` the same way, but a counter is in
+    ``counts()`` and never in ``summary()``, whose every number is
+    seconds.
     """
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
-        self._total: Dict[str, float] = {}
-        self._count: Dict[str, int] = {}
-        self._max: Dict[str, float] = {}
-        self._ring: Dict[str, Deque[Sample]] = {}
+        self._stages = _Bank()
+        self._counters = _Bank()
 
     def add(self, stage: str, seconds: float, start: Optional[float] = None,
             span: Optional[int] = None, **attrs: Any) -> None:
         if start is None:
             start = time.perf_counter() - seconds
-        sample = Sample(start, seconds, span, attrs)
         with self._lock:
-            ring = self._ring.get(stage)
-            if ring is None:
-                ring = self._ring[stage] = deque(maxlen=RING_DEPTH)
-                self._total[stage], self._count[stage] = 0.0, 0
-                self._max[stage] = seconds
-            self._total[stage] += seconds
-            self._count[stage] += 1
-            if seconds > self._max[stage]:
-                self._max[stage] = seconds
-            ring.append(sample)
+            self._stages.add(stage, Sample(start, seconds, span, attrs))
+
+    def count(self, name: str, value: float,
+              start: Optional[float] = None) -> None:
+        """Bank what one step counted under ``name``: one sample a step
+        whose ``value`` is the count, stamped ``start`` (now, where the
+        caller has no stamp of the step's own)."""
+        if start is None:
+            start = time.perf_counter()
+        with self._lock:
+            self._counters.add(name, Sample(start, value, None, {}))
 
     def timed(self, stage: str, span: Optional[int] = None,
               **attrs: Any) -> _Timed:
@@ -427,27 +463,45 @@ class StageTimes:
         return _Timed(self, stage, span, attrs)
 
     def summary(self) -> Dict[str, Dict[str, float]]:
+        """Every STAGE's seconds (a counter is in ``counts()``)."""
         with self._lock:
+            bank = self._stages
             return {
                 stage: {
-                    "ms": round(self._total[stage] * 1e3, 3),
-                    "count": self._count[stage],
+                    "ms": round(bank.total[stage] * 1e3, 3),
+                    "count": bank.count[stage],
                     "mean_ms": round(
-                        self._total[stage] * 1e3 / self._count[stage], 3),
-                    "max_ms": round(self._max[stage] * 1e3, 3),
+                        bank.total[stage] * 1e3 / bank.count[stage], 3),
+                    "max_ms": round(bank.max[stage] * 1e3, 3),
                 }
-                for stage in sorted(self._total)
+                for stage in sorted(bank.total)
             }
+
+    def counts(self) -> Dict[str, Dict[str, float]]:
+        """Every COUNTER's total, the steps that banked it and the
+        largest count of one step."""
+        with self._lock:
+            bank = self._counters
+            return {name: {"total": bank.total[name],
+                           "steps": bank.count[name],
+                           "max": bank.max[name]}
+                    for name in sorted(bank.total)}
 
     def samples(self, stage: str, since: Optional[float] = None,
                 until: Optional[float] = None) -> List[Sample]:
         """The ring's samples of ``stage`` that lie wholly inside
-        ``[since, until]`` on ``time.perf_counter()``, oldest first."""
+        ``[since, until]`` on ``time.perf_counter()``, oldest first. A
+        counter of that name is found the same way and cut by its
+        stamp alone: its value is no length of time."""
         with self._lock:
-            ring = list(self._ring.get(stage, ()))
+            ring = self._stages.ring.get(stage)
+            counted = ring is None
+            ring = list(self._counters.ring.get(stage, ())
+                        if counted else ring)
         return [s for s in ring
                 if (since is None or s.start >= since)
-                and (until is None or s.start + s.seconds <= until)]
+                and (until is None
+                     or s.start + (0 if counted else s.seconds) <= until)]
 
     def by_span(self, stages: Iterable[str], since: Optional[float] = None,
                 until: Optional[float] = None
@@ -463,7 +517,7 @@ class StageTimes:
 
     def _newest(self, stage: str, n: int) -> List[float]:
         with self._lock:
-            ring = self._ring.get(stage, ())
+            ring = self._stages.ring.get(stage, ())
             return [s.seconds for s in itertools.islice(reversed(ring), n)]
 
     def stats(self, stage: str) -> Dict[str, float]:
@@ -486,7 +540,7 @@ class StageTimes:
         running median of those before it, or None where it does not
         (``STALL_FACTOR``). One comparison for an ordinary sample."""
         with self._lock:
-            ring = self._ring.get(stage)
+            ring = self._stages.ring.get(stage)
             if not ring or ring[-1].seconds <= STALL_FLOOR_S \
                     or len(ring) <= STALL_MIN_SAMPLES:
                 return None
@@ -499,10 +553,8 @@ class StageTimes:
 
     def reset(self) -> None:
         with self._lock:
-            self._total.clear()
-            self._count.clear()
-            self._max.clear()
-            self._ring.clear()
+            self._stages = _Bank()
+            self._counters = _Bank()
 
 
 _exported_lock = threading.Lock()

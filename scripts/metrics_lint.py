@@ -398,10 +398,14 @@ def selftest_serving_text() -> str:
     from paddle_operator_tpu.serving.metrics import OUTCOMES
     from paddle_operator_tpu.utils.trace import StageTimes
 
-    stages = StageTimes()
+    stages, sched = StageTimes(), StageTimes()
     stages.add("serve.decode.wait", 0.031)
     stages.add('serve.evil"stage\\x', 0.002)
-    m = ServeMetrics(job='default/evil"serve\\x', stages=stages)
+    stages.count("moe.pairs_here", 24)
+    stages.count('moe.evil"counter\\x', 3)
+    sched.add("sched.step", 0.033)
+    sched.add("sched.empty", 1.5)
+    m = ServeMetrics(job='default/evil"serve\\x', stages=(sched, stages))
     ok = Request("r0", prompt=[1, 2, 3], max_new_tokens=4)
     ok.t_arrival, ok.t_admitted = 0.0, 0.25
     ok.t_first_token, ok.t_done = 0.5, 1.1
@@ -422,8 +426,16 @@ def selftest_serving_text() -> str:
                 "tpujob_serve_tpot_seconds",
                 "tpujob_serve_stage_seconds_total",
                 "tpujob_serve_stage_calls_total",
-                "tpujob_serve_stage_max_seconds"):
+                "tpujob_serve_stage_max_seconds",
+                "tpujob_serve_step_counter_total",
+                "tpujob_serve_step_counter_steps_total",
+                "tpujob_serve_step_counter_max"):
         assert "# TYPE %s" % fam in text, "serving selftest lost %s" % fam
+    assert 'stage="sched.empty"' in text and 'stage="serve.decode.wait"' \
+        in text, "a scheduler's or an engine's stage fell out"
+    assert not any("seconds" in line and "moe." in line
+                   for line in text.splitlines()), \
+        "a step's counter is exported under a family of seconds"
     assert 'outcome="shed_overflow"} 1' in text, \
         "an outcome label fell out of the requests counter"
     assert 'job="default/evil\\"serve\\\\x"' in text, \

@@ -152,10 +152,11 @@ def test_the_engine_serves_it_through_step_fn(params):
                 < 0.3
         engine.retire(req)
     assert engine.cache.allocator.check() == []
-    stages = engine.times.summary()
+    counts = engine.times.counts()
     # five decode steps banked their counters beside the spans
-    assert stages["moe.pairs_here"]["count"] == 5
-    assert stages["moe.experts_hit"]["count"] == 5
+    assert counts["moe.pairs_here"]["steps"] == 5
+    assert counts["moe.experts_hit"]["steps"] == 5
+    assert not set(counts) & set(engine.times.summary())
     bucket = engine.times.samples("serve.prefill.dispatch")[0].attrs["bucket"]
     assert bucket == 32
 

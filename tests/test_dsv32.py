@@ -172,11 +172,11 @@ def test_the_engine_serves_it_token_for_token_on_both_attention_paths(
                 gaps = [float(jnp.max(logits[lo + j]) - logits[lo + j, t])
                         for j, t in enumerate(req.generated)]
                 assert max(gaps) < 1.0 and sorted(gaps)[4] < 0.2, gaps
-            stages = engine.times.summary()
+            counts = engine.times.counts()
             # seven decode steps banked their counters beside the spans
             for name in ("dsa.rows_live", "dsa.rows_selected",
                          "moe.pairs_here", "moe.experts_hit"):
-                assert stages[name]["count"] == 7, name
+                assert counts[name]["steps"] == 7, name
             build = engine.times.samples("serve.prefill.build")
             assert sorted(s.attrs["prompt_len"] for s in build) \
                 == [9, 40, 64]

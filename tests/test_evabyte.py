@@ -256,8 +256,8 @@ def test_the_paged_kernel_and_the_gather_serve_the_same_bytes(params):
             engine.retire(r)
         assert alloc.stats()["blocks_used"] == 0
         assert alloc.stats()["sequences"] == 0 and alloc.check() == []
-        counts = engine.times.summary()
-        assert counts["eva.windows_closed"]["count"] == 29
+        counts = engine.times.counts()
+        assert counts["eva.windows_closed"]["steps"] == 29
     assert served["paged"] == served["reference"]
     assert len(served["paged"][0]) == 30 and len(served["paged"][1]) == 9
 

@@ -881,10 +881,11 @@ def test_a_decode_step_crosses_back_in_one_device_get(model, monkeypatch):
                          "device_put": len(_BOUNDARY_PROMPTS) + steps}
     assert got == want
     assert all(type(t) is int for row in got for t in row)
-    # the counters' samples, one a step, and no other stage beside the spans
-    banked = {k: v["count"] for k, v in eng.times.summary().items()
-              if not k.startswith("serve.")}
-    assert banked == {name: steps for name in names}
+    # the counters' samples, one a step, apart from the stages, which are
+    # the spans and nothing else
+    assert {k: v["steps"] for k, v in eng.times.counts().items()} \
+        == {name: steps for name in names}
+    assert all(k.startswith("serve.") for k in eng.times.summary())
 
 
 @functools.lru_cache(maxsize=None)
@@ -985,8 +986,8 @@ def test_the_packed_decode_step_is_the_models_hook(model, check):
                         "eva.windows_closed"]}[model]
         for r in records:
             assert r["out"][b:] == [r["hook_counters"][k] for k in names]
-        banked = {k for k in eng.times.summary() if not k.startswith("serve.")}
-        assert banked == set(names)
+        assert set(eng.times.counts()) == set(names)
+        assert all(k.startswith("serve.") for k in eng.times.summary())
         for k in names:
             assert [s.seconds for s in eng.times.samples(k)] \
                 == [float(r["hook_counters"][k]) for r in records]

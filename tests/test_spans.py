@@ -121,6 +121,41 @@ def test_an_owner_exports_its_accumulator_under_a_label():
     assert trace.stage_times("test-spans-label") is theirs is not mine
 
 
+def test_a_counter_is_a_count_and_never_a_stages_seconds(monkeypatch):
+    """What a step counted is banked apart from the stages: found by
+    ``samples()`` exactly as a stage is, totalled by ``counts()``, and
+    in no number of ``summary()``, whose every number is seconds."""
+    monkeypatch.setattr(trace, "RING_DEPTH", 4)
+    times = StageTimes()
+    times.add("serve.decode.wait", 0.002, start=9.9)
+    for step, pairs in enumerate((7, 12, 0, 9, 3)):
+        times.count("moe.pairs_here", pairs, start=10.0 + step)
+    assert set(times.summary()) == {"serve.decode.wait"}
+    assert times.counts() == {
+        "moe.pairs_here": {"total": 31, "steps": 5, "max": 12}}
+    assert type(times.counts()["moe.pairs_here"]["total"]) is int
+    kept = times.samples("moe.pairs_here")          # the ring is bounded
+    assert [(s.start, s.value) for s in kept] \
+        == [(11.0, 12), (12.0, 0), (13.0, 9), (14.0, 3)]
+    # the benchmark's readers take ``seconds`` and ``start``, as before
+    assert [s.seconds for s in times.samples("moe.pairs_here", since=12.0)
+            if s.start <= 13.0] == [0, 9]
+    # a count is cut by its stamp: its value is no length of time
+    assert [s.value for s in times.samples("moe.pairs_here", 11.0, 13.0)] \
+        == [12, 0, 9]
+    assert all(s.span is None and s.attrs == {} for s in kept)
+    # a running statistic is a stage's, not a counter's
+    assert times.stats("moe.pairs_here") == {}
+    assert times.excess("moe.pairs_here") is None
+    # with no stamp of the step's own a count is stamped now
+    before = time.perf_counter()
+    times.count("eva.rows_read", 40)
+    assert before <= times.samples("eva.rows_read")[0].start \
+        <= time.perf_counter()
+    times.reset()
+    assert times.counts() == {} and times.samples("moe.pairs_here") == []
+
+
 def test_timed_enters_a_trace_annotation_of_the_stages_name(monkeypatch):
     seen = []
 
@@ -171,7 +206,7 @@ def _serve(prompts, budgets, engine=None):
             for i, (p, n) in enumerate(zip(prompts, budgets))]
     queue = RequestQueue(capacity=8)
     batcher = ContinuousBatcher(queue, max_batch=4, on_admit=engine.admit,
-                                on_retire=engine.retire)
+                                on_retire=engine.retire, label="test-sched")
     for r in reqs:
         queue.submit(r)
     for _ in range(64):
@@ -222,10 +257,271 @@ def test_the_engines_phases_lie_inside_their_step_one_after_another():
             for x in times.samples("serve.prefill.scatter")] == [1, 2, 1]
     assert [x.attrs["prompt_len"]
             for x in times.samples("serve.prefill.build")] == [3, 9, 1]
-    # the reservation is the engine's too, before the step and in none
+    # the reservation is the engine's too, before its step, and carries
+    # the id of the iteration that admitted it: the first one's, as the
+    # step that prefilled the three
     admits = times.samples("serve.admit")
     assert [x.attrs["request_id"] for x in admits] == ["s0", "s1", "s2"]
-    assert all(x.span not in rows for x in admits)
+    assert {x.span for x in admits} == {steps[0].span}
+    assert all(x.start + x.seconds <= steps[0].start for x in admits)
+
+
+# ---------------------------------------------------------------------------
+# the scheduler
+# ---------------------------------------------------------------------------
+
+def test_an_iteration_holds_its_admissions_its_engine_step_and_its_retirements():
+    """``sched.step`` is one iteration of the batcher: the admissions
+    (with the engine's reservation inside each), the engine's step and
+    the retirements lie inside it in that order and carry its span id,
+    across the two accumulators."""
+    prompts = [[5, 99, 7], [11, 3, 250, 42, 8, 9, 9, 9, 9], [1023]]
+    _, eng = _serve(prompts, [3, 3, 3])
+    eng.times.reset()
+    reqs, _ = _serve(prompts, [6, 4, 8], engine=eng)
+    sched = trace.stage_times("test-sched")     # the second batcher's own
+    assert sched is not eng.times
+    its = sched.samples("sched.step")
+    steps = eng.times.samples("serve.step")
+    assert len(its) == len(steps) == 8
+    assert len({it.span for it in its}) == 8
+    assert [it.attrs for it in its] == [
+        {"active": 3, "admitted": 3, "retired": 0}] + [
+        {"active": 3, "admitted": 0, "retired": 0}] * 2 + [
+        {"active": 3, "admitted": 0, "retired": 1},     # s1's budget of 4
+        {"active": 2, "admitted": 0, "retired": 0},
+        {"active": 2, "admitted": 0, "retired": 1},     # s0's of 6
+        {"active": 1, "admitted": 0, "retired": 0},
+        {"active": 1, "admitted": 0, "retired": 1}]
+    end = lambda x: x.start + x.seconds
+    for it, step in zip(its, steps):
+        inside = sorted(
+            [(x, stage) for times, stages in (
+                (sched, ("sched.admit", "sched.retire")),
+                (eng.times, ("serve.admit", "serve.step")))
+             for stage in stages for x in times.samples(stage)
+             if x.span == it.span], key=lambda pair: pair[0].start)
+        assert (step, "serve.step") in inside
+        assert it.start <= inside[0][0].start
+        assert end(inside[-1][0]) <= end(it)
+        assert [stage for _, stage in inside] \
+            == ["sched.admit", "serve.admit"] * it.attrs["admitted"] \
+            + ["serve.step"] + ["sched.retire"] * it.attrs["retired"]
+        # a reservation lies inside the admission that asked for it
+        # (below); nothing else overlaps
+        flat = [x for x, stage in inside if stage != "serve.admit"]
+        for x, y in zip(flat, flat[1:]):
+            assert end(x) <= y.start + 1e-9
+    admits = sched.samples("sched.admit")
+    assert [(x.attrs["request_id"], x.attrs["outcome"], x.attrs["depth"])
+            for x in admits] == [("s0", "admitted", 2), ("s1", "admitted", 1),
+                                 ("s2", "admitted", 0)]
+    for outer, inner in zip(admits, eng.times.samples("serve.admit")):
+        assert outer.start <= inner.start and end(inner) <= end(outer)
+    assert [(x.attrs["request_id"], x.attrs["tokens"])
+            for x in sched.samples("sched.retire")] \
+        == [("s1", 4), ("s0", 6), ("s2", 8)]
+    assert [x.attrs["request_id"] for x in sched.samples("sched.queue_wait")] \
+        == ["s0", "s1", "s2"]
+    assert {x.span for x in sched.samples("sched.queue_wait")} \
+        == {its[0].span}
+
+
+class _Ticks:
+    """A clock for the batcher and the queue that moves when told."""
+
+    def __init__(self, now=100.0):
+        self.now = now
+
+    def __call__(self):
+        return self.now
+
+
+def _fake_server(max_batch=2, on_admit=None, clock=None, label="test-fake"):
+    """Queue + batcher over a step that hands every live sequence one
+    token: no model, so a test says exactly what each iteration holds."""
+    from paddle_operator_tpu.serving.batching import (
+        ContinuousBatcher, RequestQueue)
+
+    queue = RequestQueue(capacity=8, clock=clock)
+    batcher = ContinuousBatcher(queue, max_batch, clock=clock,
+                                on_admit=on_admit, label=label)
+    return queue, batcher, lambda active: [(7, False)] * len(active)
+
+
+def _req(rid, budget):
+    from paddle_operator_tpu.serving.batching import Request
+
+    return Request(rid, prompt=[1, 2, 3], max_new_tokens=budget)
+
+
+def test_queue_wait_is_admission_less_arrival_on_the_batchers_clock():
+    ticks = _Ticks()
+    queue, batcher, step = _fake_server(max_batch=1, clock=ticks)
+    a, b = _req("a", 2), _req("b", 1)
+    queue.submit(a)
+    ticks.now += 0.25
+    queue.submit(b)
+    ticks.now += 0.5
+    before = time.perf_counter()
+    batcher.step(step)                  # a leaves the queue; b has no slot
+    ticks.now += 2.0
+    batcher.step(step)                  # a's last token
+    batcher.step(step)                  # b leaves it
+    waits = batcher.times.samples("sched.queue_wait")
+    assert [(w.attrs["request_id"], w.seconds) for w in waits] \
+        == [("a", 0.75), ("b", 2.5)]
+    assert waits[0].seconds == a.t_admitted - a.t_arrival
+    assert waits[1].seconds == b.t_admitted - b.t_arrival
+    # stamped where the request left the queue, reaching back its wait
+    assert before <= waits[0].start + waits[0].seconds <= time.perf_counter()
+    # each belongs to the iteration that admitted it
+    its = batcher.times.samples("sched.step")
+    assert [w.span for w in waits] == [its[0].span, its[2].span]
+    assert trace.stage_times("test-fake") is batcher.times
+
+
+def test_between_and_empty_split_on_what_the_last_return_left(
+        clock, monkeypatch):
+    """The caller's time between two iterations is ``sched.between``
+    where the earlier one left sequences in flight and ``sched.empty``
+    where it left none; with the iterations themselves they cover the
+    wall time from the first entry to the last return, with no
+    remainder: the stamps are the ``sched.step`` spans' own."""
+    from paddle_operator_tpu.serving import batching
+
+    monkeypatch.setattr(batching, "time", clock)
+    queue, batcher, step = _fake_server()
+    times = batcher.times
+    batcher.step(step)                  # nothing to run: banked all the same
+    assert times.summary()["sched.step"]["count"] == 1
+    assert not {"sched.between", "sched.empty"} & set(times.summary())
+    clock.advance(0.5)                  # the replica stands empty
+    queue.submit(_req("a", 3))
+    queue.submit(_req("b", 1))
+    assert batcher.step(step) == 1      # both admitted, b done
+    clock.advance(0.25)                 # the caller, while a waits
+    assert batcher.step(step) == 1
+    clock.advance(0.125)
+    assert batcher.step(step) == 0      # a done
+    clock.advance(2.0)                  # empty again
+    assert batcher.step(step) == 0
+    its = times.samples("sched.step")
+    assert [it.attrs["active"] for it in its] == [0, 2, 1, 1, 0]
+    between = times.samples("sched.between")
+    empty = times.samples("sched.empty")
+    assert [s.attrs for s in between] == [{"in_flight": 1}] * 2
+    assert [s.attrs for s in empty] == [{}, {}]
+    # the clock moves a millisecond a reading: a stretch is what the
+    # test advanced plus the readings between the two stamps
+    assert [round(s.seconds, 2) for s in between] == [0.25, 0.13]
+    assert [round(s.seconds, 1) for s in empty] == [0.5, 2.0]
+    # each lies before the iteration whose id it carries, end to start
+    stretches = sorted(between + empty, key=lambda s: s.start)
+    assert [s.span for s in stretches] == [it.span for it in its[1:]]
+    for before, s, it in zip(its, stretches, its[1:]):
+        assert s.start == before.start + before.seconds
+        assert s.start + s.seconds == pytest.approx(it.start, abs=1e-12)
+    covered = sum(s.seconds for s in its + stretches)
+    wall = its[-1].start + its[-1].seconds - its[0].start
+    assert covered == pytest.approx(wall, abs=1e-9)
+
+
+def test_a_deferred_admission_says_so_and_the_request_keeps_its_place():
+    full = {"now": True}
+    queue, batcher, step = _fake_server(
+        on_admit=lambda req: not (full["now"] and req.request_id == "b"))
+    for rid in ("a", "b", "c"):
+        queue.submit(_req(rid, 2))
+    batcher.step(step)
+    admits = batcher.times.samples("sched.admit")
+    assert [(x.attrs["request_id"], x.attrs["outcome"], x.attrs["depth"])
+            for x in admits] == [("a", "admitted", 2), ("b", "deferred", 1)]
+    it, = batcher.times.samples("sched.step")
+    assert it.attrs == {"active": 1, "admitted": 1, "retired": 0}
+    assert batcher.counts()["admit_deferred"] == 1
+    # b was never admitted: no wait is banked for it yet, and it still
+    # stands ahead of c
+    assert [w.attrs["request_id"]
+            for w in batcher.times.samples("sched.queue_wait")] == ["a"]
+    full["now"] = False
+    batcher.step(step)
+    assert batcher.active_ids() == ["b"] and queue.depth() == 1
+    assert [x.attrs["request_id"]
+            for x in batcher.times.samples("sched.admit")] \
+        == ["a", "b", "b"]
+    batcher.step(step)
+    assert [w.attrs["request_id"]
+            for w in batcher.times.samples("sched.queue_wait")] \
+        == ["a", "b", "c"]
+
+
+def test_an_admission_that_raises_is_banked_as_an_error():
+    def on_admit(req):
+        raise ValueError("too long")
+
+    queue, batcher, step = _fake_server(on_admit=on_admit)
+    queue.submit(_req("a", 2))
+    with pytest.raises(ValueError):
+        batcher.step(step)
+    x, = batcher.times.samples("sched.admit")
+    assert (x.attrs["request_id"], x.attrs["outcome"]) == ("a", "error")
+    # the iteration that raised is banked too, and the next stretch is
+    # an empty replica's, from where it ended
+    it, = batcher.times.samples("sched.step")
+    assert x.span == it.span and batcher.counts()["admit_error"] == 1
+    batcher.step(step)
+    gap, = batcher.times.samples("sched.empty")
+    assert gap.start == it.start + it.seconds
+
+
+@pytest.mark.parametrize("how", ["drain", "preempt"])
+def test_disruption_leaves_the_accumulator_consistent(how, clock,
+                                                      monkeypatch):
+    from paddle_operator_tpu.serving import batching
+
+    monkeypatch.setattr(batching, "time", clock)
+    queue, batcher, step = _fake_server()
+    times = batcher.times
+    for rid, budget in (("a", 3), ("b", 2), ("c", 2)):
+        queue.submit(_req(rid, budget))
+    assert batcher.step(step) == 2
+    clock.advance(0.25)
+    if how == "drain":
+        # runs to empty without admitting: c stays queued, every
+        # iteration is banked, none admits
+        assert batcher.drain(step) == 2
+        its = times.samples("sched.step")
+        assert [it.attrs for it in its[1:]] == [
+            {"active": 2, "admitted": 0, "retired": 1},
+            {"active": 1, "admitted": 0, "retired": 1}]
+        assert queue.depth() == 1
+        assert [x.attrs["request_id"]
+                for x in times.samples("sched.retire")] == ["b", "a"]
+        assert len(times.samples("sched.between")) == 2
+        assert "sched.empty" not in times.summary()
+    else:
+        victims = batcher.preempt()
+        assert [r.request_id for r in victims] == ["a", "b"]
+        # nobody finished: nothing retired, and the stretch since the
+        # last return ended where the sequences were pulled out
+        assert "sched.retire" not in times.summary()
+        gap, = times.samples("sched.between")
+        assert gap.attrs == {"in_flight": 2}
+        assert round(gap.seconds, 2) == 0.25
+    clock.advance(1.0)
+    assert batcher.step(step) == 1          # c, on an empty replica
+    its = times.samples("sched.step")
+    empty, = times.samples("sched.empty")
+    assert round(empty.seconds, 1) == 1.0
+    assert empty.start + empty.seconds == pytest.approx(its[-1].start,
+                                                        abs=1e-12)
+    stretches = times.samples("sched.between") + [empty]
+    covered = sum(s.seconds for s in its + stretches)
+    wall = its[-1].start + its[-1].seconds - its[0].start
+    assert covered == pytest.approx(wall, abs=1e-9)
+    assert [x.attrs["request_id"]
+            for x in times.samples("sched.admit")] == ["a", "b", "c"]
 
 
 def test_serve_metrics_export_the_engines_stages():
@@ -238,6 +534,7 @@ def test_serve_metrics_export_the_engines_stages():
     assert "tpujob_serve_stage" not in ServeMetrics().metrics_block()
     block = ServeMetrics(job="default/serve",
                          stages=eng.times).metrics_block()
+    assert "tpujob_serve_step_counter" not in block    # GPT counts nothing
     summary = eng.times.summary()
     assert set(PHASES) | {"serve.step", "serve.admit"} == set(summary)
     for stage, row in summary.items():
@@ -248,6 +545,55 @@ def test_serve_metrics_export_the_engines_stages():
         assert ('tpujob_serve_stage_max_seconds{job="default/serve",'
                 'stage="%s"} %.6f' % (stage, row["max_ms"] / 1e3)) in block
     assert parse_exposition(block + "\n") == []   # the strict parser
+
+
+def test_an_operator_gets_the_schedulers_stages_and_counts_as_counts():
+    """``ServeMetrics(stages=...)`` takes the batcher's accumulator and
+    the engine's: ``sched.*`` beside ``serve.*`` under the stage
+    families, and what the steps counted under a family of counts, in
+    no family of seconds. A gang's further replicas join by
+    ``add_stages`` and a stage they share is summed."""
+    import re
+
+    from paddle_operator_tpu.obs import parse_exposition
+    from paddle_operator_tpu.serving import ServeMetrics
+
+    _, eng = _serve([[5, 99, 7], [1023]], [3, 2])
+    sched = trace.stage_times("test-sched")
+    for pairs in (7, 12):
+        eng.times.count("moe.pairs_here", pairs)
+    m = ServeMetrics(job="default/serve", stages=(sched, eng.times))
+    block = m.metrics_block()
+    assert parse_exposition(block + "\n") == []   # the strict parser
+    staged = set(re.findall(
+        r'tpujob_serve_stage_calls_total\{job="default/serve",'
+        r'stage="([^"]+)"\}', block))
+    assert staged == set(sched.summary()) | set(eng.times.summary())
+    assert {"sched.step", "sched.admit", "sched.queue_wait", "sched.retire",
+            "sched.between", "serve.step", "serve.admit"} <= staged
+    assert "moe.pairs_here" not in staged
+    assert not any("seconds" in line for line in block.splitlines()
+                   if "moe.pairs_here" in line)
+    for family, value in (("total", 19), ("steps_total", 2), ("max", 12)):
+        assert ('tpujob_serve_step_counter_%s{job="default/serve",'
+                'counter="moe.pairs_here"} %d' % (family, value)) in block
+    # a second replica's scheduler: the gang's iterations add up, the
+    # longest stays the longest
+    queue, other, step = _fake_server(label="test-fake-2")
+    queue.submit(_req("z", 2))
+    while other.step(step):
+        pass
+    m.add_stages(other.times)
+    block = m.metrics_block()
+    assert parse_exposition(block + "\n") == []
+    mine, theirs = sched.summary()["sched.step"], \
+        other.times.summary()["sched.step"]
+    assert ('tpujob_serve_stage_calls_total{job="default/serve",'
+            'stage="sched.step"} %d' % (mine["count"] + theirs["count"])) \
+        in block
+    assert ('tpujob_serve_stage_max_seconds{job="default/serve",'
+            'stage="sched.step"} %.6f'
+            % (max(mine["max_ms"], theirs["max_ms"]) / 1e3)) in block
 
 
 def test_the_wait_before_the_read_back_changes_no_token(monkeypatch):
