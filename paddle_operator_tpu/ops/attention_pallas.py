@@ -10,9 +10,11 @@ forward kernel also emits the per-row log-sum-exp; the backward runs two
 Pallas kernels (a dQ pass over q-tiles and a dK/dV pass over kv-tiles) that
 recompute P from the saved LSE tile-by-tile — O(S·D) memory end to end, never
 materialising the S×S score matrix. ``causal=True`` fuses the triangular mask
-into the loop bounds of all three kernels (skipped tiles, ~2x FLOPs saved).
-Falls back to the einsum path automatically off-TPU or for shapes that don't
-tile (see ``supports``).
+into the tile ranges of all three kernels (tiles above the diagonal are never
+computed, ~2x FLOPs saved; only tiles the diagonal crosses build the mask).
+bfloat16 inputs are multiplied as bfloat16 with float32 sums and float32
+softmax statistics; float32 inputs in float32. Falls back to the einsum path
+automatically off-TPU or for shapes that don't tile (see ``supports``).
 """
 
 from __future__ import annotations
@@ -26,11 +28,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-# lse/delta are lane-replicated to this width: TPU blocks must have a
-# 128-multiple (or full-dim) minor axis, so per-row vectors are stored as
-# [rows, 128] with the value broadcast across lanes (the layout the
-# official jax.experimental.pallas TPU flash kernel uses for l/m).
+# tiles are multiples of this: a per-row vector (lse, delta) is kept as a
+# ROW ``[1, S]`` with the rows along the lanes, and a TPU block's minor
+# axis must be a 128-multiple (or the whole dimension)
 MIN_BLOCK = 128
+# a kernel whose grid cells compute at most this many tiles between them
+# is written out as straight-line code, one branch a grid position: static
+# slices, and no loop boundary between two tiles' MXU work. At [768, 1024,
+# 64] bfloat16, tiles of 512 (3 tiles) against the same kernels looping:
+# forward 4.11 -> 2.07 ms, dQ 3.72 -> 2.69, dK/dV 4.80 -> 3.83; at S = 2048
+# (10 tiles) the written-out form is the one the sweep ran. The code grows
+# with the square of the sequence: at S = 4096 (36 tiles) forward and dQ
+# still gain (4.51 -> 2.67, 4.49 -> 3.77) but dK/dV, four products a
+# tile, falls off a cliff (6.20 -> 21.55 ms). Chip runs, PR 40 (v5e).
+MAX_UNROLLED_TILES = 16
 
 
 def _reference_attention(q, k, v, scale, causal=False):
@@ -44,208 +55,288 @@ def _reference_attention(q, k, v, scale, causal=False):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
-                seq_len, causal):
-    """One (batch·head, q-tile) cell: stream KV tiles, online softmax.
+def _operand_dtype(dtype):
+    """What the flash kernels multiply in: bfloat16 inputs go into the
+    MXU as they arrive (float32 sums), anything else in float32."""
+    return jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32
 
-    Causal: KV tiles strictly above the diagonal are skipped entirely (the
-    fori_loop trip count is data-independent but grid-position-dependent, so
-    late q-tiles do proportionally less work — ~2x FLOP saving overall); the
-    tiles straddling the diagonal get an in-tile triangular mask.
-    """
-    q = q_ref[0].astype(jnp.float32) * scale            # [block_q, d]
-    block_q, head_dim = q.shape
-    qi = pl.program_id(1)
-    q_start = qi * block_q
 
-    def body(i, carry):
-        acc, m_prev, l_prev = carry
-        k_start = i * block_k
-        k_tile = k_ref[0, pl.ds(k_start, block_k), :].astype(jnp.float32)
-        v_tile = v_ref[0, pl.ds(k_start, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(                         # [block_q, block_k]
-            q, k_tile, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)       # [block_q, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                           # [block_q, block_k]
-        correction = jnp.exp(m_prev - m_new)
-        l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * correction + jax.lax.dot_general(
-            p, v_tile, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return acc, m_new, l_new
+def _scale_folds(scale, operand) -> bool:
+    """Whether ``q`` may carry ``scale`` once a q-tile: in float32 always
+    (the forward always did), in bfloat16 only where the product is exact,
+    which is a power of two (1/8 at head width 64). Otherwise the float32
+    scores are scaled."""
+    return operand == jnp.float32 or math.frexp(scale)[0] == 0.5
 
-    if causal:
-        # tiles with k_start > q_end contribute nothing — skip them
-        n_steps = (q_start + block_q + block_k - 1) // block_k
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))      # a @ b.T
+_NN = ((1,), (0,))      # a @ b
+
+
+def _scores(rows, cols, q_start, k_start, scale, masked, rows_are_q=True):
+    """One tile of scaled scores in float32, ``rows @ cols.T``: queries
+    down the rows and keys along the lanes, or (``rows_are_q`` False)
+    the transposed tile. ``scale`` is None where ``q`` carries it.
+    ``masked`` is static: only a tile the diagonal crosses builds and
+    applies the triangle."""
+    s = _dot(rows, cols, _NT)
+    if scale is not None:
+        s = s * scale
+    if masked:
+        q_axis = 0 if rows_are_q else 1
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                   1 - q_axis)
+        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    return s
+
+
+def _tile_at(ref, i, block, axis):
+    """Tile ``i`` of ``ref[0]`` along ``axis`` (1 or 2); a static slice
+    where ``i`` is a Python int."""
+    start = i * block if isinstance(i, int) else pl.multiple_of(i * block,
+                                                                block)
+    index = (0, pl.ds(start, block), slice(None)) if axis == 1 else (
+        0, slice(None), pl.ds(start, block))
+    return start, ref[index]
+
+
+def _over_live_tiles(run, cell, n_cells, spans, unroll):
+    """Call ``run(cell, loop)`` for this grid cell; ``loop(step, carry)``
+    runs ``step(i, carry, masked)`` over the tiles the cell computes, in
+    ascending order. ``spans(cell)`` lists them as ``(lo, hi, masked)``
+    ranges. ``unroll``: one ``pl.when`` branch a grid position, its tiles
+    written out with static indices; else ``fori_loop`` ranges whose
+    bounds follow the grid position."""
+    def loops(step, carry, at=cell):
+        for lo, hi, masked in spans(at):
+            body = functools.partial(step, masked=masked)
+            if isinstance(at, int):
+                for i in range(lo, hi):
+                    carry = body(i, carry)
+            else:
+                carry = jax.lax.fori_loop(lo, hi, body, carry)
+        return carry
+
+    if not unroll:
+        run(cell, loops)
+    elif n_cells == 1 or spans(0) == spans(n_cells - 1):   # every cell alike
+        run(cell, functools.partial(loops, at=0))
     else:
-        n_steps = seq_len // block_k
-    acc = jnp.zeros((block_q, head_dim), jnp.float32)
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, n_steps, body, (acc, m0, l0))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (block_q, MIN_BLOCK))
+        for t in range(n_cells):
+            pl.when(cell == t)(functools.partial(
+                run, t, functools.partial(loops, at=t)))
+
+
+def _kv_spans(block_q, block_k, seq_len, causal):
+    """The KV tiles q-tile ``qi`` sees: those wholly below the diagonal
+    unmasked, then those it crosses; tiles above it are never computed
+    (about half the square). Not causal: all of them, unmasked."""
+    def spans(qi):
+        if not causal:
+            return [(0, seq_len // block_k, False)]
+        q_start = qi * block_q
+        n_free = q_start // block_k      # k_start + block_k - 1 <= q_start
+        n_live = (q_start + block_q + block_k - 1) // block_k
+        return [(0, n_free, False), (n_free, n_live, True)]
+    return spans
+
+
+def _q_spans(block_q, block_k, seq_len, causal):
+    """The q-tiles KV tile ``ki`` is seen by: those the diagonal crosses,
+    then those wholly below it."""
+    def spans(ki):
+        n_q = seq_len // block_q
+        if not causal:
+            return [(0, n_q, False)]
+        k_start = ki * block_k
+        first = k_start // block_q       # q_start + block_q - 1 >= k_start
+        n_crossed = (k_start + block_k + block_q - 1) // block_q
+        return [(first, n_crossed, True), (n_crossed, n_q, False)]
+    return spans
+
+
+def _as_row(col):
+    """``[n, 1]`` float32 -> ``[1, n]``: the rows move onto the lanes."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], MIN_BLOCK)))[:1]
+
+
+def _as_col(row):
+    """``[1, n]`` float32 -> ``[n, MIN_BLOCK]``, lane-replicated."""
+    return jnp.transpose(jnp.broadcast_to(row, (MIN_BLOCK, row.shape[1])))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
+                seq_len, causal, unroll):
+    """One (batch·head, q-tile) cell: stream KV tiles, online softmax.
+    Statistics, ``exp`` and the accumulator are float32; ``p`` is rounded
+    to the operand type for ``p v``, as the einsum path rounds it."""
+    operand = _operand_dtype(q_ref.dtype)
+    block_q, head_dim = q_ref.shape[1:]
+
+    def run(qi, loop):
+        q_start = qi * block_q
+        q, score_scale = q_ref[0].astype(operand), scale
+        if _scale_folds(scale, operand):
+            q, score_scale = q * scale, None
+
+        def step(i, carry, masked):
+            acc, m_prev, l_prev = carry
+            k_start, k_tile = _tile_at(k_ref, i, block_k, 1)
+            v_tile = _tile_at(v_ref, i, block_k, 1)[1]
+            s = _scores(q, k_tile.astype(operand), q_start, k_start,
+                        score_scale, masked)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)                       # [block_q, block_k]
+            correction = jnp.exp(m_prev - m_new)
+            l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * correction + _dot(
+                p.astype(operand), v_tile.astype(operand), _NN)
+            return acc, m_new, l_new
+
+        acc = jnp.zeros((block_q, head_dim), jnp.float32)
+        m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((block_q, 1), jnp.float32)
+        acc, m, l = loop(step, (acc, m0, l0))
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0] = _as_row(m + jnp.log(l))
+
+    _over_live_tiles(run, pl.program_id(1), seq_len // block_q,
+                     _kv_spans(block_q, block_k, seq_len, causal), unroll)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               scale, block_k, seq_len, causal):
+               scale, block_k, seq_len, causal, unroll):
     """dQ pass, one (batch·head, q-tile) cell: stream KV tiles.
 
     dS_ij = P_ij * (dO_i·V_j - delta_i);  dQ_i = scale * Σ_j dS_ij K_j
     with P recomputed from the saved log-sum-exp — no S×S residency.
+    ``dS`` is rounded to the operand type for ``dS k``; the sum is float32.
     """
-    q = q_ref[0].astype(jnp.float32)                     # [block_q, d]
-    do = do_ref[0].astype(jnp.float32)                   # [block_q, d]
-    block_q, head_dim = q.shape
-    qi = pl.program_id(1)
-    q_start = qi * block_q
-    # lane-replicated [block_q, MIN_BLOCK] -> tiled to [block_q, block_k]
-    # so the subtraction below stays lane-aligned (no sub-128 slicing)
-    reps = block_k // MIN_BLOCK
-    lse = jnp.tile(lse_ref[0], (1, reps))
-    delta = jnp.tile(delta_ref[0], (1, reps))
+    operand = _operand_dtype(q_ref.dtype)
+    block_q, head_dim = q_ref.shape[1:]
 
-    def body(i, dq):
-        k_start = i * block_k
-        k_tile = k_ref[0, pl.ds(k_start, block_k), :].astype(jnp.float32)
-        v_tile = v_ref[0, pl.ds(k_start, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_tile, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)                             # [block_q, block_k]
-        dov = jax.lax.dot_general(                       # dO·V^T
-            do, v_tile, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dov - delta)
-        return dq + jax.lax.dot_general(
-            ds, k_tile, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def run(qi, loop):
+        q_start = qi * block_q
+        q, score_scale = q_ref[0].astype(operand), scale
+        do = do_ref[0].astype(operand)                   # [block_q, d]
+        if _scale_folds(scale, operand):
+            q, score_scale = q * scale, None
+        # rows [1, block_q] -> lane-replicated columns, tiled to the
+        # width of a score tile so the subtraction stays lane-aligned
+        reps = (1, block_k // MIN_BLOCK)
+        lse = jnp.tile(_as_col(lse_ref[0]), reps)        # [block_q, block_k]
+        delta = jnp.tile(_as_col(delta_ref[0]), reps)
 
-    if causal:
-        n_steps = (q_start + block_q + block_k - 1) // block_k
-    else:
-        n_steps = seq_len // block_k
-    dq = jax.lax.fori_loop(
-        0, n_steps, body, jnp.zeros((block_q, head_dim), jnp.float32))
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+        def step(i, dq, masked):
+            k_start, k_tile = _tile_at(k_ref, i, block_k, 1)
+            k_tile = k_tile.astype(operand)
+            v_tile = _tile_at(v_ref, i, block_k, 1)[1].astype(operand)
+            s = _scores(q, k_tile, q_start, k_start, score_scale, masked)
+            p = jnp.exp(s - lse)
+            ds = p * (_dot(do, v_tile, _NT) - delta)     # dO·V^T
+            return dq + _dot(ds.astype(operand), k_tile, _NN)
+
+        dq = loop(step, jnp.zeros((block_q, head_dim), jnp.float32))
+        dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+
+    _over_live_tiles(run, pl.program_id(1), seq_len // block_q,
+                     _kv_spans(block_q, block_k, seq_len, causal), unroll)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, scale, n_q_tiles,
-                causal):
-    """dK/dV pass over a (batch·head, kv-tile, q-tile) grid.
+                dk_ref, dv_ref, *, scale, block_q, seq_len, causal, unroll):
+    """dK/dV pass, one (batch·head, kv-tile) cell: stream q-tiles.
 
-    dV_j = Σ_i P_ij dO_i;  dK_j = scale · Σ_i dS_ij Q_i. The q-tile axis is
-    the FASTEST grid axis, so the dk/dv output blocks (indexed by kv-tile
-    only) are revisited consecutively: partial sums accumulate in fp32 VMEM
-    scratch and are written back once on the last q-tile — the canonical
-    Pallas-TPU accumulation pattern. Causal: q-tiles strictly above the
-    diagonal contribute nothing and are skipped via pl.when.
+    dV_j = Σ_i P_ij dO_i;  dK_j = scale · Σ_i dS_ij Q_i. The tile is
+    computed TRANSPOSED from the start (``S^T = K Q^T``, keys down the
+    rows), so ``P^T dO`` and ``dS^T Q`` are plain products with no
+    transposed operand, and ``lse`` / ``delta`` are read as the rows
+    they are stored as, broadcast down the sublanes.
     """
-    k = k_ref[0].astype(jnp.float32)                     # [block_k, d]
-    v = v_ref[0].astype(jnp.float32)                     # [block_k, d]
-    block_k, head_dim = k.shape
-    block_q = q_ref.shape[1]
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    k_start = ki * block_k
-    q_start = qi * block_q
+    operand = _operand_dtype(q_ref.dtype)
+    block_k, head_dim = k_ref.shape[1:]
+    folds = _scale_folds(scale, operand)
 
-    @pl.when(qi == 0)
-    def _zero():
-        dk_acc[...] = jnp.zeros((block_k, head_dim), jnp.float32)
-        dv_acc[...] = jnp.zeros((block_k, head_dim), jnp.float32)
+    def run(ki, loop):
+        k_start = ki * block_k
+        k = k_ref[0].astype(operand)                     # [block_k, d]
+        v = v_ref[0].astype(operand)
 
-    live = (q_start + block_q - 1 >= k_start) if causal else (qi >= 0)
+        def step(i, carry, masked):
+            dk, dv = carry
+            q_start, q_tile = _tile_at(q_ref, i, block_q, 1)
+            q_tile = q_tile.astype(operand)
+            do_tile = _tile_at(do_ref, i, block_q, 1)[1].astype(operand)
+            if folds:   # dK = Σ dS^T (scale · Q): q carries it both times
+                q_tile = q_tile * scale
+            s_t = _scores(k, q_tile, q_start, k_start,
+                          None if folds else scale, masked,
+                          rows_are_q=False)              # [block_k, block_q]
+            p_t = jnp.exp(s_t - _tile_at(lse_ref, i, block_q, 2)[1])
+            dv = dv + _dot(p_t.astype(operand), do_tile, _NN)   # P^T dO
+            ds_t = p_t * (_dot(v, do_tile, _NT)
+                          - _tile_at(delta_ref, i, block_q, 2)[1])
+            dk = dk + _dot(ds_t.astype(operand), q_tile, _NN)   # dS^T Q
+            return dk, dv
 
-    @pl.when(live)
-    def _accumulate():
-        q_tile = q_ref[0].astype(jnp.float32)            # [block_q, d]
-        do_tile = do_ref[0].astype(jnp.float32)
-        reps = block_k // MIN_BLOCK
-        lse = jnp.tile(lse_ref[0], (1, reps))            # [block_q, block_k]
-        delta = jnp.tile(delta_ref[0], (1, reps))
-        s = jax.lax.dot_general(                         # [block_q, block_k]
-            q_tile, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)                             # [block_q, block_k]
-        dv_acc[...] += jax.lax.dot_general(              # P^T dO
-            p, do_tile, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dov = jax.lax.dot_general(
-            do_tile, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dov - delta)
-        dk_acc[...] += jax.lax.dot_general(              # dS^T Q
-            ds, q_tile, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        zeros = jnp.zeros((block_k, head_dim), jnp.float32)
+        dk, dv = loop(step, (zeros, zeros))
+        dk_ref[0] = (dk if folds else dk * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
 
-    @pl.when(qi == n_q_tiles - 1)
-    def _write():
-        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+    _over_live_tiles(run, pl.program_id(1), seq_len // block_k,
+                     _q_spans(block_q, block_k, seq_len, causal), unroll)
+
+
+def _unrolls(seq_len, block_q, block_k, causal) -> bool:
+    return _tile_counts(seq_len, block_q, block_k,
+                        causal)[0] <= MAX_UNROLLED_TILES
+
+
+# block index maps of a (batch·head, tile) grid: the tile of a [S, D]
+# operand, the whole of one, the tile's 128-lane blocks of a [1, S] row
+def _tile_index(bh, i):
+    return (bh, i, 0)
+
+
+def _whole_index(bh, i):
+    return (bh, 0, 0)
+
+
+def _row_index(bh, i):
+    return (bh, 0, i)
 
 
 def _flash_fwd(q, k, v, scale, block_q, block_k, interpret, causal):
+    """-> (out ``[b, h, s, d]``, lse ``[b·h, 1, s]`` float32)."""
     b, h, s, d = q.shape
-    grid = (b * h, s // block_q)
-
-    def qo_index(bh, qi):
-        return (bh, qi, 0)
-
-    def kv_index(bh, qi):
-        return (bh, 0, 0)
-
     q3 = q.reshape(b * h, s, d)
     k3 = k.reshape(b * h, s, d)
     v3 = v.reshape(b * h, s, d)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
-                          seq_len=s, causal=causal),
-        grid=grid,
+                          seq_len=s, causal=causal,
+                          unroll=_unrolls(s, block_q, block_k, causal)),
+        grid=(b * h, s // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), qo_index),
-            pl.BlockSpec((1, s, d), kv_index),
-            pl.BlockSpec((1, s, d), kv_index),
+            pl.BlockSpec((1, block_q, d), _tile_index),
+            pl.BlockSpec((1, s, d), _whole_index),
+            pl.BlockSpec((1, s, d), _whole_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), qo_index),
-            pl.BlockSpec((1, block_q, MIN_BLOCK), qo_index),
+            pl.BlockSpec((1, block_q, d), _tile_index),
+            pl.BlockSpec((1, 1, block_q), _row_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-            # lane-replicated lse (see MIN_BLOCK comment at top)
-            jax.ShapeDtypeStruct((b * h, s, MIN_BLOCK), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -262,67 +353,52 @@ def _flash_bwd(q, k, v, out, lse, g, scale, block_q, block_k, interpret,
     b, h, s, d = q.shape
     q3, k3, v3 = (x.reshape(b * h, s, d) for x in (q, k, v))
     do3 = g.reshape(b * h, s, d)
-    # delta_i = Σ_d dO_i O_i — O(S·D) rowwise reduce, fused by XLA;
-    # lane-replicated like the lse so kernel reads stay 128-aligned
+    # delta_i = Σ_d dO_i O_i — O(S·D) rowwise reduce, fused by XLA; a row
+    # like the lse
     delta = jnp.sum(do3.astype(jnp.float32)
                     * out.reshape(b * h, s, d).astype(jnp.float32), axis=-1)
     if lse_cotangent is not None:
         delta = delta - lse_cotangent.reshape(b * h, s).astype(jnp.float32)
-    delta = jnp.broadcast_to(delta[..., None], (b * h, s, MIN_BLOCK))
-
-    def qo_index(bh, qi):
-        return (bh, qi, 0)
-
-    def full_index(bh, qi):
-        return (bh, 0, 0)
+    delta = delta[:, None, :]
+    unroll = _unrolls(s, block_q, block_k, causal)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, block_k=block_k,
-                          seq_len=s, causal=causal),
+                          seq_len=s, causal=causal, unroll=unroll),
         grid=(b * h, s // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), qo_index),
-            pl.BlockSpec((1, s, d), full_index),
-            pl.BlockSpec((1, s, d), full_index),
-            pl.BlockSpec((1, block_q, d), qo_index),
-            pl.BlockSpec((1, block_q, MIN_BLOCK), qo_index),
-            pl.BlockSpec((1, block_q, MIN_BLOCK), qo_index),
+            pl.BlockSpec((1, block_q, d), _tile_index),
+            pl.BlockSpec((1, s, d), _whole_index),
+            pl.BlockSpec((1, s, d), _whole_index),
+            pl.BlockSpec((1, block_q, d), _tile_index),
+            pl.BlockSpec((1, 1, block_q), _row_index),
+            pl.BlockSpec((1, 1, block_q), _row_index),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), qo_index),
+        out_specs=pl.BlockSpec((1, block_q, d), _tile_index),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         interpret=interpret,
         name="flash_dq",
     )(q3, k3, v3, do3, lse, delta)
 
-    def dkv_q_index(bh, ki, qi):
-        return (bh, qi, 0)
-
-    def dkv_kv_index(bh, ki, qi):
-        return (bh, ki, 0)
-
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale,
-                          n_q_tiles=s // block_q, causal=causal),
-        grid=(b * h, s // block_k, s // block_q),
+        functools.partial(_dkv_kernel, scale=scale, block_q=block_q,
+                          seq_len=s, causal=causal, unroll=unroll),
+        grid=(b * h, s // block_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), dkv_q_index),
-            pl.BlockSpec((1, block_k, d), dkv_kv_index),
-            pl.BlockSpec((1, block_k, d), dkv_kv_index),
-            pl.BlockSpec((1, block_q, d), dkv_q_index),
-            pl.BlockSpec((1, block_q, MIN_BLOCK), dkv_q_index),
-            pl.BlockSpec((1, block_q, MIN_BLOCK), dkv_q_index),
+            pl.BlockSpec((1, s, d), _whole_index),
+            pl.BlockSpec((1, block_k, d), _tile_index),
+            pl.BlockSpec((1, block_k, d), _tile_index),
+            pl.BlockSpec((1, s, d), _whole_index),
+            pl.BlockSpec((1, 1, s), _whole_index),
+            pl.BlockSpec((1, 1, s), _whole_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), dkv_kv_index),
-            pl.BlockSpec((1, block_k, d), dkv_kv_index),
+            pl.BlockSpec((1, block_k, d), _tile_index),
+            pl.BlockSpec((1, block_k, d), _tile_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, s, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
         name="flash_dkv",
@@ -356,15 +432,14 @@ _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 def _flash_attention_lse(q, k, v, scale, block_q, block_k, interpret, causal):
     out, lse = _flash_fwd(q, k, v, scale, block_q, block_k, interpret, causal)
     b, h, s, d = q.shape
-    return out, lse.reshape(b, h, s, MIN_BLOCK)[..., 0]
+    return out, lse.reshape(b, h, s)
 
 
 def _flash_attention_lse_fwd(q, k, v, scale, block_q, block_k, interpret,
                              causal):
     out, lse = _flash_fwd(q, k, v, scale, block_q, block_k, interpret, causal)
     b, h, s, d = q.shape
-    lse_row = lse.reshape(b, h, s, MIN_BLOCK)[..., 0]
-    return (out, lse_row), (q, k, v, out, lse)
+    return (out, lse.reshape(b, h, s)), (q, k, v, out, lse)
 
 
 def _flash_attention_lse_bwd(scale, block_q, block_k, interpret, causal,
@@ -385,15 +460,10 @@ def flash_attention_lse(q, k, v, scale=None, block_q: int = None,
     log-sum-exp ([B, H, S], fp32) — the quantity that lets independently
     computed attention blocks be merged exactly (ring/blockwise
     composition): out = Σ_b softmax-weight(lse_b) · out_b. Differentiable
-    in both outputs. Block sizes auto-size like :func:`flash_attention`
-    (512-max since round 3, previously always 128) — pin
-    ``block_q=block_k=128`` near the VMEM ceiling or for the old
-    tile-level numerics."""
+    in both outputs. Tiles as in :func:`flash_attention`."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    block_q = block_q or _auto_block(q.shape[2], "q")
-    block_k = block_k or _auto_block(q.shape[2], "k")
-    _check_blocks(q.shape, block_q, block_k)
+    block_q, block_k = _flash_plan(q, block_q, block_k, causal)
     return _flash_attention_lse(q, k, v, scale, block_q, block_k, interpret,
                                 causal)
 
@@ -411,19 +481,53 @@ def flash_attention(q, k, v, scale=None, block_q: int = None,
                     causal: bool = False):
     """q,k,v: [B, H, S, D] → [B, H, S, D]. Differentiable.
 
-    ``block_q``/``block_k`` default to auto-sizing (512 when the sequence
-    divides by it, else 256/128) — since round 3; earlier revisions always
-    used 128. Larger tiles are ~1.9x faster fwd+bwd at S≥4k but hold
-    ~4x the VMEM per tile and change tile-level accumulation order
-    (bit-exactness vs the 128 tiling is not preserved). Callers near the
-    VMEM ceiling, or needing the old numerics, should pin
-    ``block_q=block_k=128`` explicitly."""
+    ``block_q``/``block_k`` default to the rule of :func:`_auto_block`
+    (512 where the sequence divides by it, else 256 / 128). The tiling
+    sets the order the sums accumulate in, so results are not bit-equal
+    across tilings. bfloat16 inputs are multiplied as bfloat16 (``p`` and
+    ``dS`` rounded to it) with float32 sums; float32 inputs in float32."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    block_q = block_q or _auto_block(q.shape[2], "q")
-    block_k = block_k or _auto_block(q.shape[2], "k")
-    _check_blocks(q.shape, block_q, block_k)
+    block_q, block_k = _flash_plan(q, block_q, block_k, causal)
     return _flash_attention(q, k, v, scale, block_q, block_k, interpret, causal)
+
+
+def _tile_counts(seq, block_q, block_k, causal):
+    """(tiles the kernels compute, tiles among them that pay the mask)."""
+    spans = _kv_spans(block_q, block_k, seq, causal)
+    live = masked = 0
+    for qi in range(seq // block_q):
+        for lo, hi, pays_mask in spans(qi):
+            live += hi - lo
+            masked += (hi - lo) * pays_mask
+    return live, masked
+
+
+_plans_seen = set()
+
+
+def _flash_plan(q, block_q, block_k, causal):
+    """The tiles one flash call runs with — the caller's, else the rule's
+    (:func:`_auto_block`) — checked against the shape. Both entry points
+    take their tiles from here, and a traced call says once what was
+    chosen: event ``flash.plan`` (``operand`` is what the kernels multiply
+    in; ``tiles_masked / tiles_live`` the share of tiles that pay the
+    causal mask). A trace-time fact: nothing is added to the step."""
+    from ..utils.trace import tracer
+
+    seq, head_dim = q.shape[2], q.shape[3]
+    operand = jnp.dtype(_operand_dtype(q.dtype)).name
+    block_q = block_q or _auto_block(seq, "q")
+    block_k = block_k or _auto_block(seq, "k")
+    _check_blocks(q.shape, block_q, block_k)
+    plan = (seq, head_dim, operand, block_q, block_k, bool(causal))
+    if tracer().enabled and plan not in _plans_seen:
+        _plans_seen.add(plan)
+        live, masked = _tile_counts(seq, block_q, block_k, causal)
+        tracer().event("flash.plan", seq=seq, head_dim=head_dim,
+                       operand=operand, block_q=block_q, block_k=block_k,
+                       tiles_live=live, tiles_masked=masked)
+    return block_q, block_k
 
 
 _warned_overrides = set()
@@ -443,11 +547,28 @@ def _warn_block_override_once(which, env, seq):
 
 
 def _auto_block(seq: int, which: str = "q") -> int:
-    """Largest tile of the ladder that divides the sequence, 512 first:
-    bigger tiles feed the MXU [512,128]x[128,512] matmuls and amortize
-    the online-softmax loop, and VMEM pressure grows beyond that. No
-    sweep of the tile has run in a cell of the benchmark (ROADMAP Queue 1
-    item 3b). Falls back down the ladder for short sequences.
+    """Largest tile of the ladder that divides the sequence, 512 first,
+    for ``block_q`` and ``block_k`` alike. The rule rests on a sweep of
+    every pair of 256 / 512 / 1024 / 2048 on one v5e chip (PR 40; causal,
+    bfloat16, 50.3 M elements a side a call: ``[768, 1024, 64]``,
+    ``[384, 2048, 64]``, ``[384, 1024, 128]``, ``[192, 2048, 128]``;
+    2 x forward + dQ + dK/dV in ms):
+
+    ==========  =========  =========  ===========  ========
+    S, D        512 x 512  256 x 256  1024 x 1024  next best
+    ==========  =========  =========  ===========  ========
+    1024, 64        10.67      13.92        12.17  12.17 (1024 x 1024)
+    2048, 64        16.83      20.04        18.28  18.28 (1024 x 1024)
+    1024, 128        5.14       6.66         6.13   6.13 (1024 x 1024)
+    2048, 128        8.18       9.76         9.18   9.08 (256 x 512)
+    ==========  =========  =========  ===========  ========
+
+    so head width and operand type do not move the choice and the rule
+    reads the sequence alone: a smaller tile computes less of the square
+    above the diagonal but pays the online-softmax bookkeeping once more a
+    row, a larger one the reverse, and a side of 2048 is the slowest
+    where it fits the fast memory at all (9 of its 14 pairs did not).
+    256 and 128 are what is left where 512 does not divide.
 
     ``TPUJOB_FLASH_BLOCK_Q`` / ``TPUJOB_FLASH_BLOCK_K`` override the
     auto choice fleet-wide (still subject to divisibility)."""
@@ -472,8 +593,8 @@ def _auto_block(seq: int, which: str = "q") -> int:
 
 def _check_blocks(q_shape, block_q, block_k):
     if block_q % MIN_BLOCK or block_k % MIN_BLOCK:
-        # the lane-replicated lse/delta layout tiles by MIN_BLOCK; smaller
-        # blocks would silently produce zero-width tiles in the backward
+        # a tile's lse/delta are 128-lane blocks of a row: smaller tiles
+        # have no block to read
         raise ValueError(
             "block_q/block_k must be multiples of %d, got %d/%d"
             % (MIN_BLOCK, block_q, block_k))
@@ -500,8 +621,8 @@ def _check_blocks(q_shape, block_q, block_k):
 # it where it lies: nothing slices, reshapes or transposes a pool
 # outside. The kernel grid is (batch, page): the page axis is the fast,
 # sequential one, so the online softmax accumulates across a sequence's
-# pages in fp32 VMEM scratch (the same revisited-output-block pattern as
-# _dkv_kernel) and writes the context row once on the last page. Block
+# pages in fp32 VMEM scratch (the output block is revisited across the
+# page axis) and writes the context row once on the last page. Block
 # tables, sequence lengths and the layer ride in as scalar prefetch
 # (pltpu.PrefetchScalarGridSpec), so the page index_map can dereference
 # the table BEFORE the body runs — the DMA for page t of sequence b
