@@ -10,6 +10,9 @@
 * dsv32 — axk1's stack with a learned sparse attention (an indexer with a
   key cache of its own, decode over the selected rows) and group-limited
   bias-corrected routing (serving only)
+* ouro — a stack of layers run several times over with the same weights,
+  a key-value cache of its own for every loop step, an exit gate
+  (serving only; imported where it is served, as evabyte is)
 
 All models are (init, apply) pure functions over dict pytrees, bf16 compute,
 built from `paddle_operator_tpu.ops.nn`.

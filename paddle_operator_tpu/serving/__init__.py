@@ -13,7 +13,8 @@ is finally cheap enough to build. The plane has three layers:
   pods scale with zero new pod-lifecycle code;
 * **data plane** (:mod:`.batching`, :mod:`.kv_cache`, :mod:`.engine`) —
   a continuous-batching engine over a model's module (:mod:`..models.gpt`,
-  :mod:`..models.axk1`): a request queue
+  :mod:`..models.axk1`, :mod:`..models.dsv32`, :mod:`..models.evabyte`,
+  :mod:`..models.ouro`): a request queue
   with admission / load-shedding, iteration-level scheduling that admits
   new sequences into in-flight batches, and a paged KV-cache (block-table
   allocator + the ``paged_decode_attention`` Pallas kernel in

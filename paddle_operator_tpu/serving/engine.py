@@ -43,7 +43,8 @@ offers:
   banks as counts (``StageTimes.count``) under their names
   (``moe.pairs_here``, ``moe.experts_hit``, ``dsa.rows_live``,
   ``dsa.rows_selected``, ``eva.rows_read``, ``eva.tokens_live``,
-  ``eva.windows_closed``).
+  ``eva.windows_closed``, ``loop.layer_passes``, ``loop.rows_live``,
+  ``loop.rows_read``, ``loop.exit_steps``).
 
 Models served: :mod:`..models.gpt` (float32; no expert configuration:
 its Switch layer drops tokens over capacity and has no decode path),
@@ -56,7 +57,12 @@ its prompt in chunks inside one program a bucket) and
 reads an exact window and pooled summaries of every earlier chunk out
 of GPT's pools through GPT's decode kernel; a window closes inside the
 compiled decode step; a prefill that walks its prompt a window at a time
-and caches the open window and the summaries only).
+and caches the open window and the summaries only) and
+:mod:`..models.ouro` (bfloat16; a stack of layers run several times over
+with the same weights, every loop step's keys and values in cache
+layers of its own — the paged cache is handed loop steps x layers and
+the model indexes it —, GPT's decode kernel once a layer and loop step,
+an exit gate that chooses which loop step's output the head reads).
 
 Both steps compile through :func:`..compile_cache.cached_jit`, as a
 training worker's step does, so a replica takes them from whichever rung

@@ -12,8 +12,10 @@ block.
 :class:`KvBlockAllocator` is the bookkeeping half (pure Python, no
 arrays): alloc/append/free with conservation invariants the chaos
 scenario and ``make race`` exercise. :class:`PagedKvCache` is the array
-half: one K and one V pool for every layer, ``[layers, num_blocks + 1,
-block_size, heads * head_dim]``, the layout
+half: one K and one V pool for every layer of the CACHE (a model's
+layers, or loop steps x layers where a stack is run several times
+over), ``[layers, num_blocks + 1, block_size, heads * head_dim]``, the
+layout
 :func:`..ops.attention_pallas.paged_decode_attention` reads in place.
 :class:`LatentKvCache` is the array half for latent attention: ONE
 compressed row a token and layer for all heads (a tuple of pools, one a
@@ -323,6 +325,12 @@ class PagedKvCache(_RowAToken):
     pool. ``k_pages`` / ``v_pages`` are lists of the one array each
     (the names and the list :class:`LatentKvCache` answers to).
 
+    ``layers`` counts the CACHE's layers, which need not be the
+    weights': a model that runs its stack several times over and keeps
+    every loop step's rows apart (``models.ouro``) hands loop steps x
+    layers and indexes the pools by ``step * layers + layer`` itself.
+    Nothing here or in the engine reads a model's layer count.
+
     The arrays live wherever JAX puts them (HBM on TPU) and are updated
     where they lie: a prefill's rows land through one jitted program
     that takes the pools donated and hands back the same buffers with
@@ -368,9 +376,6 @@ class PagedKvCache(_RowAToken):
         the prompt's last go to the dummy page; the last page's slots
         past ``n`` take padding, which ``seq_lens`` masks until decode
         overwrites it."""
-        import jax
-        import jax.numpy as jnp
-
         self._write_blocks(rows, _prompt_pages(self, seq_id, n,
                                                rows[0].shape[1]))
 

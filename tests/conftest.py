@@ -65,11 +65,12 @@ if _LEAK_MODE:
 
 
 #: Tests under ``tests/benchmark/`` — files that only a PR of the
-#: ``benchmark`` kind may edit — whose assertion no later addition to the
-#: benchmark can meet, however it keeps the rules. They run and are
-#: expected to fail until such a PR repairs them; what they guard is
-#: asserted, in a form that later additions can meet too, by
-#: ``tests/benchmark/test_cellbench_evabyte.py``.
+#: ``benchmark`` kind may edit — whose assertion an addition to the
+#: benchmark that keeps the rules fails: each entry says which. They run
+#: and are expected to fail until such a PR repairs them; what they guard
+#: is asserted for every cell, in a form that later additions can meet
+#: too, by ``tests/benchmark/test_cellbench_evabyte.py`` and
+#: ``test_cellbench_ouro.py``.
 _OUTDATED = {
     "test_cellbench_dsv32.py::test_benchmark_json_gained_entries_only":
         "pins BENCHMARK.json to PR 30's additions alone: any cell, "
@@ -81,6 +82,25 @@ _OUTDATED = {
         "/ block_size pages, one row a token: a cache that keeps a window "
         "and summaries (PR 36, WindowKvCache) reserves 24 pages where "
         "that counts 144",
+    "test_cellbench_sched.py::"
+    "test_benchmark_json_gained_the_four_entries_only":
+        "compares every list of BENCHMARK.json entry for entry with PR "
+        "39's parent, the ``workloads`` lists of the metrics too: any cell "
+        "appended after PR 39 fails it (PR 41 appends "
+        "ouro-2.6b.serve-reason-1k to nineteen of them); "
+        "test_cellbench_ouro.py asserts appended-only against its own "
+        "parent",
+    "test_cellbench_evabyte.py::test_every_serving_mix_records_its_knee_"
+    "its_rate_and_a_pool_that_fits":
+        "asks every serving pool for every slot's longest request at "
+        "once, which a cell whose pool fits its slots still meets: "
+        "ouro-2.6b.serve-reason-1k (PR 41) is given 8 slots over 40 pages "
+        "by its issue, 201 MB a page, and eight slots' 80 pages are 16 GB "
+        "beside 5.3 GB of weights. test_cellbench_ouro.py::test_every_"
+        "serving_mix_records_its_knee_its_rate_and_a_pool_by_rule keeps "
+        "every clause for EVERY serving cell and lets a smaller pool "
+        "stand only where the family's byte count says the chip has no "
+        "room for the full one",
 }
 
 

@@ -231,6 +231,48 @@ def test_gpts_decode_step_compiles_for_v5e_without_copying_a_pool(
     assert yields_a_pool.count("scatter") == 24
 
 
+def test_the_looped_decode_step_compiles_for_v5e_copying_no_pool_and_no_weight(
+        v5e, monkeypatch):
+    """``ouro-2.6b.serve-reason-1k``'s decode step at the published sizes
+    (48 layers run four times over, 8 rows, both pools ``bf16[192, 41,
+    128, 2048]`` donated: 8.25 GB beside 5.34 GB of weights) for a
+    described v5e: ONE scan over the loop steps whose body holds the 48
+    layers — 48 Mosaic calls, each loop step's 96 row writes scatters in
+    place, both pools aliased to the results, temporaries a hundredth of
+    a pool, and no operation that copies, transposes or slices an array
+    of a weight's shape (a scan over stacked weights slices all seven of
+    a layer's kernels into fresh buffers: PERF.md, section 6, PR 41)."""
+    from paddle_operator_tpu.models import ouro
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sh = jax.sharding.SingleDeviceSharding(v5e[0])
+    cfg = dict(ouro.BASE_CONFIG, max_seq=1280)
+    params = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, sh),
+        jax.eval_shape(lambda: ouro.init(jax.random.PRNGKey(0), cfg)))
+    pool = _sds((192, 41, 128, 2048), jnp.bfloat16, sh)
+    row = _sds((8,), jnp.int32, sh)
+    decode = ouro.serve_decode(cfg, "paged", 128, 40)
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, (pool, pool), row, row, _sds((8, 10), jnp.int32, sh), row,
+        _sds((8,), jnp.bool_, sh)).compile()
+    pool_bytes = 192 * 41 * 128 * 2048 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 16
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 48
+    yields_a_pool = re.findall(
+        r"= bf16\[192,41,128,2048\]\S* ([\w-]+)\(", text)
+    assert set(yields_a_pool) <= {"parameter", "scatter", "fusion",
+                                  "get-tuple-element", "bitcast", "while",
+                                  "tuple"}
+    assert yields_a_pool.count("scatter") == 96
+    assert not re.findall(
+        r"= bf16\[(?:1,)?(?:2048,5632|5632,2048|2048,2048)\]\S* "
+        r"(?:copy|transpose|dynamic-slice)\(", text)
+
+
 @pytest.mark.parametrize("layer", [0, 5, 11])
 def test_paged_decode_matches_reference_interpreted(layer):
     """The kernel (products on the VPU, a head's sum over its lanes as a
