@@ -280,7 +280,7 @@ def trainer_leg(sm: Smoke) -> None:
     import train_gpt
 
     # the example's own job (batch 16, S=1024, remat, attn "auto",
-    # ce_chunk 1024, AdamW); only the run length and the log/checkpoint
+    # the chunked loss, AdamW); only the run length and the log/checkpoint
     # cadence are the smoke's
     if sm.rehearsal:
         job = train_gpt.build_job(total_steps=5, batch=4, seq=256,

@@ -41,7 +41,7 @@ def build_job(total_steps: int = STEPS, batch: int = BATCH, seq: int = SEQ,
 
     # stream tokens through the LM head (never materialize [B,S,V] fp32
     # logits — gigabytes at long context); 0 restores the dense path
-    ce_chunk = int(os.environ.get("TPUJOB_CE_CHUNK", "1024"))
+    ce_chunk = int(os.environ.get("TPUJOB_CE_CHUNK", "2048"))
 
     def loss_fn(p, b, mesh=None):
         attn = "auto"

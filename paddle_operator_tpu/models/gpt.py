@@ -132,10 +132,13 @@ def loss_fn(params, batch, train=True, dtype=jnp.bfloat16, remat: bool = False,
 
     ``ce_chunk > 0`` routes the LM head through
     :func:`ops.nn.chunked_lm_xent`: tokens stream through the head in
-    chunks under remat, so the ``[B, S, V]`` fp32 logits (gigabytes at
-    S=2k, V=50k — the dominant HBM cost of this loss) are never
-    materialized. Same loss/accuracy as the dense path up to fp32
-    summation order.
+    chunks, so the ``[B, S, V]`` fp32 logits (gigabytes at S=2k, V=50k —
+    the dominant HBM cost of this loss) are never materialized, and each
+    chunk's logits are computed ONCE: under ``jax.grad`` the one loop also
+    takes the head's and the hidden states' gradients, which wait as
+    float32 residuals (rows x D and D x V) for the backward to scale
+    them. Same loss/accuracy as the dense path up to fp32 summation
+    order.
     """
     if mesh is not None and attn_impl == "auto" \
             and jax.default_backend() == "tpu":

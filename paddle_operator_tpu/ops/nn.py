@@ -404,10 +404,11 @@ def _chunking(n: int, chunk: int) -> Tuple[int, int]:
     return -(-n // chunk), chunk
 
 
-def _lm_xent_sums(head_params, hidden, labels, mask, chunk, dtype):
-    """The local part of :func:`chunked_lm_xent`: ``(loss_sum, acc_sum,
-    mask_sum)`` over the rows it is handed, one ``jax.checkpoint``ed chunk
-    of tokens at a time under ``lax.scan``."""
+def _xent_chunks(head_params, hidden, labels, mask, chunk, dtype):
+    """What the loop of :func:`_lm_xent_sums` scans over and closes over,
+    differentiated or not: the rows as ``[n_chunks, chunk, ...]`` (hidden
+    states, labels, float32 mask; the last chunk padded with mask 0), the
+    head's kernel in ``dtype`` and its float32 bias or None."""
     d = hidden.shape[-1]
     flat_h = hidden.reshape(-1, d)
     flat_l = labels.reshape(-1)
@@ -421,35 +422,136 @@ def _lm_xent_sums(head_params, hidden, labels, mask, chunk, dtype):
             [flat_h, jnp.zeros((pad, d), flat_h.dtype)])
         flat_l = jnp.concatenate([flat_l, jnp.zeros((pad,), flat_l.dtype)])
         flat_m = jnp.concatenate([flat_m, jnp.zeros((pad,), jnp.float32)])
-    hc = flat_h.reshape(n_chunks, chunk, d)
-    lc = flat_l.reshape(n_chunks, chunk)
-    mc = flat_m.reshape(n_chunks, chunk)
+    rows = (flat_h.reshape(n_chunks, chunk, d),
+            flat_l.reshape(n_chunks, chunk),
+            flat_m.reshape(n_chunks, chunk))
+    bias = head_params.get("bias")
+    return rows, head_params["kernel"].astype(dtype), (
+        None if bias is None else bias.astype(jnp.float32))
 
-    @jax.checkpoint
-    def one_chunk(h, l, m):
-        # bf16 operands, fp32 MXU accumulation: full matmul speed with
-        # near-fp32 logits (plain bf16 output would round the logsumexp)
-        logits = jnp.matmul(
-            h.astype(dtype), head_params["kernel"].astype(dtype),
-            preferred_element_type=jnp.float32)
-        if "bias" in head_params:
-            logits = logits + head_params["bias"].astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)                 # [chunk]
-        picked = jnp.take_along_axis(
-            logits, l[:, None], axis=-1)[:, 0]                  # [chunk]
-        correct = (jnp.argmax(logits, axis=-1) == l)
-        loss_sum = jnp.sum((lse - picked) * m)
-        acc_sum = jnp.sum(correct.astype(jnp.float32) * m)
-        return loss_sum, acc_sum
+
+def _lse_and_argmax(logits):
+    """``(logsumexp, argmax)`` over the last axis of float32 ``logits``.
+    The sum of exponentials and the argmax are ONE ``lax.reduce`` with
+    three results, so the chip's compiler reads the logits once for both
+    (0.275 ms for a chunk's 206 MB; ``jax.nn.logsumexp`` beside
+    ``jnp.argmax`` is two such passes); the row maximum it takes in the
+    product's epilogue. Ties go to the lower column, as ``jnp.argmax``."""
+    top = jnp.max(logits, axis=-1)
+    top = jnp.where(jnp.isfinite(top), top, 0.0)
+    columns = lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+
+    def merge(a, b):
+        (sum_a, top_a, at_a), (sum_b, top_b, at_b) = a, b
+        keep = (top_a > top_b) | ((top_a == top_b) & (at_a < at_b))
+        return (sum_a + sum_b, jnp.where(keep, top_a, top_b),
+                jnp.where(keep, at_a, at_b))
+
+    sum_exp, _, argmax = lax.reduce(
+        (jnp.exp(logits - top[..., None]), logits, columns),
+        (jnp.float32(0), jnp.float32(-jnp.inf), jnp.int32(0)), merge,
+        (logits.ndim - 1,))
+    return top + jnp.log(sum_exp), argmax
+
+
+def _chunk_sums(w, bias, h, l, m):
+    """One chunk through the head: its float32 logits, their logsumexp,
+    and the chunk's masked sums of loss and of argmax hits."""
+    # bf16 operands, fp32 MXU accumulation: full matmul speed with
+    # near-fp32 logits (plain bf16 output would round the logsumexp)
+    logits = jnp.matmul(h.astype(w.dtype), w,
+                        preferred_element_type=jnp.float32)
+    if bias is not None:
+        logits = logits + bias
+    lse, argmax = _lse_and_argmax(logits)                       # [chunk]
+    picked = jnp.take_along_axis(logits, l[:, None], axis=-1)[:, 0]
+    return (logits, lse, jnp.sum((lse - picked) * m),
+            jnp.sum((argmax == l).astype(jnp.float32) * m))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _lm_xent_sums(head_params, hidden, labels, mask, chunk, dtype, where):
+    """The local part of :func:`chunked_lm_xent`: ``(loss_sum, acc_sum,
+    mask_sum)`` over the rows it is handed, one chunk of tokens at a time
+    under ``lax.scan``. ``where`` is the caller's words for the trace-time
+    log line, which ends in the form that was built. This is the plain
+    forward (evaluation, ``jax.eval_shape``): one logits product a chunk
+    and the three sums; differentiated, :func:`_lm_xent_sums_fwd` runs in
+    its place."""
+    rows, w, bias = _xent_chunks(
+        head_params, hidden, labels, mask, chunk, dtype)
+    log.info("chunked_lm_xent: %s, %d chunks of %d: the plain forward",
+             where, *rows[1].shape)
 
     def body(carry, xs):
-        loss_acc, acc_acc = carry
-        loss_sum, acc_sum = one_chunk(*xs)
-        return (loss_acc + loss_sum, acc_acc + acc_sum), None
+        _, _, loss_sum, acc_sum = _chunk_sums(w, bias, *xs)
+        return (carry[0] + loss_sum, carry[1] + acc_sum), None
 
-    (loss_sum, acc_sum), _ = jax.lax.scan(
-        body, (jnp.float32(0), jnp.float32(0)), (hc, lc, mc))
-    return loss_sum, acc_sum, jnp.sum(flat_m)
+    (loss_sum, acc_sum), _ = lax.scan(
+        body, (jnp.float32(0), jnp.float32(0)), rows)
+    return loss_sum, acc_sum, jnp.sum(rows[2])
+
+
+def _lm_xent_sums_fwd(head_params, hidden, labels, mask, chunk, dtype,
+                      where):
+    """What ``jax.grad`` runs: the same ONE loop, which also takes the
+    gradients. The loss is the last thing the forward computes, so a
+    chunk's ``d loss_sum / d logits = (softmax - onehot) * mask`` is known
+    while its logits are in hand, up to the cotangent of ``loss_sum``:
+    the chunk's ``dX = dlogits @ W^T`` is the scan's stacked output and
+    ``dW += h^T @ dlogits`` (``db += sum(dlogits)``) its float32 carry.
+    Three products a chunk, each with ``dtype`` operands and float32
+    accumulation; ``dlogits`` is rounded to ``dtype`` only where it enters
+    one. Residuals: ``dX`` (rows x D, float32) and ``dW`` (D x V,
+    float32), alive only from here to :func:`_lm_xent_sums_bwd`, which
+    follows at once in a train step."""
+    rows, w, bias = _xent_chunks(
+        head_params, hidden, labels, mask, chunk, dtype)
+    n_chunks, chunk = rows[1].shape
+    log.info("chunked_lm_xent: %s, %d chunks of %d: gradients taken in the "
+             "forward loop", where, n_chunks, chunk)
+    columns = lax.broadcasted_iota(labels.dtype, (1, w.shape[1]), 1)
+
+    def body(carry, xs):
+        loss_acc, acc_acc, dparams = carry
+        h, l, m = xs
+        logits, lse, loss_sum, acc_sum = _chunk_sums(w, bias, h, l, m)
+        dlogits = (jnp.exp(logits - lse[:, None])
+                   - (columns == l[:, None])) * m[:, None]
+        dl = dlogits.astype(dtype)
+        grads = {"kernel": dparams["kernel"] + lax.dot_general(
+            h.astype(dtype), dl, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)}
+        if bias is not None:
+            grads["bias"] = dparams["bias"] + jnp.sum(dlogits, axis=0)
+        dx = lax.dot_general(dl, w, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        return (loss_acc + loss_sum, acc_acc + acc_sum, grads), dx
+
+    zeros = {k: jnp.zeros(v.shape, jnp.float32)
+             for k, v in head_params.items()}
+    (loss_sum, acc_sum, dparams), dx = lax.scan(
+        body, (jnp.float32(0), jnp.float32(0), zeros), rows)
+    dx = dx.reshape(n_chunks * chunk, -1)[:math.prod(labels.shape)]
+    # head_params and hidden ride along for their dtypes alone: the
+    # backward reads no data of theirs
+    return (loss_sum, acc_sum, jnp.sum(rows[2])), (
+        head_params, hidden, dparams, dx.reshape(hidden.shape))
+
+
+def _lm_xent_sums_bwd(chunk, dtype, where, residuals, cotangents):
+    """No loop: the forward's ``dX`` and ``dW`` times the cotangent of
+    ``loss_sum``, in float32, each rounded once to its primal's dtype.
+    Labels and mask get none; the cotangents of ``acc_sum`` and
+    ``mask_sum`` are ignored (an argmax and a constant of the batch)."""
+    head_params, hidden, dparams, dx = residuals
+    g = cotangents[0].astype(jnp.float32)
+    return ({k: (v * g).astype(head_params[k].dtype)
+             for k, v in dparams.items()},
+            (dx * g).astype(hidden.dtype), None, None)
+
+
+_lm_xent_sums.defvjp(_lm_xent_sums_fwd, _lm_xent_sums_bwd)
 
 
 def chunked_lm_xent(head_params, hidden, labels, mask=None,
@@ -459,14 +561,21 @@ def chunked_lm_xent(head_params, hidden, labels, mask=None,
     full ``[tokens, vocab]`` logits tensor.
 
     The dense path stores fp32 logits plus their backward residuals: at
-    GPT scale (S=1024, V=50k) gigabytes of HBM a batch. Here tokens are
-    processed in ``chunk``-sized slices under ``jax.checkpoint``: the
-    forward keeps only per-token scalars (logsumexp, picked logit,
-    argmax-correct), and the backward recomputes each chunk's logits from
-    ``(hidden_chunk, W)`` — the same FLOPs-for-memory trade flash
-    attention makes for S^2 scores. Peak extra memory: O(chunk * vocab).
-    On the chip the two loops (forward, backward) are 191 ms = 25% of
-    GPT-2 small's 771 ms step at 64 x 1024 (PERF.md section 5).
+    GPT scale (S=1024, V=50k) gigabytes of HBM a batch. Here tokens go
+    through the head in ``chunk``-sized slices under ONE ``lax.scan``,
+    and a chunk's logits are computed once. Undifferentiated, a chunk
+    leaves three scalars behind. Differentiated, the same loop takes the
+    gradients while the logits are in hand (the loss is the forward's
+    last step, so ``d loss / d logits = (softmax - onehot) * mask`` is
+    known there up to the loss's own cotangent): three products a chunk
+    (logits, ``dX``, ``dW``) where a recomputing backward makes four in
+    two loops, and the backward is two scalings
+    (:func:`_lm_xent_sums_fwd`). Peak extra memory: O(chunk * vocab)
+    for the logits, plus the residuals ``dX`` (rows x D) and ``dW``
+    (D x V) in float32, which live from the end of the forward to the
+    start of the backward. On the chip the loop is about 114 ms of GPT-2
+    small's 618 ms step at 64 x 1024 in chunks of 2048, 129 of 637 in
+    chunks of 1024 (PERF.md section 5; 191 of 694 ms as two loops).
 
     ``mesh``: the mesh the caller's step is jitted over. The tokens are
     flattened batch-first and scanned chunk by chunk, so the scanned axis
@@ -475,8 +584,9 @@ def chunked_lm_xent(head_params, hidden, labels, mask=None,
     every device, forward and backward, and every device loops over the
     whole batch (PERF.md section 5: four chips gave one chip's
     throughput). So where ``mesh`` has ``batch_axis`` with a size > 1 that
-    divides the batch, the loops run per shard under ``shard_map``, manual
-    over ``batch_axis`` ONLY, and three scalars are ``psum``med; any other
+    divides the batch, the loop runs per shard under ``shard_map``, manual
+    over ``batch_axis`` ONLY, and three scalars are ``psum``med (its
+    transpose ``psum``s the head's gradient); any other
     axis (a ``tp``-sharded head kernel) stays with GSPMD. Otherwise the
     traced program is the unsharded one, operation for operation.
 
@@ -494,21 +604,18 @@ def chunked_lm_xent(head_params, hidden, labels, mask=None,
         if mask is None:
             mask = jnp.ones(labels.shape, jnp.float32)
         rows = P(batch_axis)
+        where = "%d shards over '%s'" % (shards, batch_axis)
 
         @functools.partial(
             jax.shard_map, mesh=mesh, in_specs=(P(), rows, rows, rows),
             out_specs=P(), axis_names={batch_axis}, check_vma=False)
         def sums(hp, h, l, m):
             return lax.psum(
-                _lm_xent_sums(hp, h, l, m, chunk, dtype), batch_axis)
+                _lm_xent_sums(hp, h, l, m, chunk, dtype, where), batch_axis)
 
         loss_sum, acc_sum, mask_sum = sums(head_params, hidden, labels, mask)
-        log.info("chunked_lm_xent: %d shards over '%s', %d chunks of %d a "
-                 "shard", shards, batch_axis,
-                 *_chunking(math.prod(labels.shape) // shards, chunk))
     else:
         loss_sum, acc_sum, mask_sum = _lm_xent_sums(
-            head_params, hidden, labels, mask, chunk, dtype)
-        log.info("chunked_lm_xent: unsharded")
+            head_params, hidden, labels, mask, chunk, dtype, "unsharded")
     denom = jnp.maximum(mask_sum, 1.0)
     return loss_sum / denom, acc_sum / denom

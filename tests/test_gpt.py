@@ -198,7 +198,7 @@ def test_remat_same_loss():
 
 
 def test_chunked_ce_matches_dense():
-    """ce_chunk streams tokens through the LM head under remat without
+    """ce_chunk streams tokens through the LM head chunk by chunk without
     materializing [B,S,V] logits; loss, accuracy AND gradients must match
     the dense path (fp32 summation order aside)."""
     import jax
@@ -232,6 +232,202 @@ def test_chunked_ce_matches_dense():
     m_d = gpt.loss_fn(params, batch)[1]
     m_c = gpt.loss_fn(params, batch, ce_chunk=24)[1]
     assert abs(float(m_d["accuracy"]) - float(m_c["accuracy"])) < 1e-5
+
+
+# ---------------------------------------------------------------------------
+# chunked LM-head loss in one pass: the gradients leave the forward loop
+# ---------------------------------------------------------------------------
+
+def _head_case(bias=False, rows=(3, 20), width=16, vocab=64, mask_zeros=True):
+    """A head, hidden states, labels and a mask for the loss alone."""
+    k = jax.random.split(jax.random.PRNGKey(3), 5)
+    head = {"kernel": 0.3 * jax.random.normal(k[0], (width, vocab))}
+    if bias:
+        head["bias"] = 0.1 * jax.random.normal(k[1], (vocab,))
+    hidden = jax.random.normal(k[2], rows + (width,))
+    labels = jax.random.randint(k[3], rows, 0, vocab)
+    mask = (jax.random.uniform(k[4], rows) > 0.3).astype(jnp.float32) \
+        if mask_zeros else None
+    return head, hidden, labels, mask
+
+
+def _dense_xent(head, hidden, labels, mask):
+    """The full ``[rows, vocab]`` float32 logits, loss and accuracy."""
+    logits = hidden @ head["kernel"] + head.get("bias", 0.0)
+    mask = jnp.ones(labels.shape) if mask is None else mask
+    picked = jnp.take_along_axis(
+        jax.nn.log_softmax(logits), labels[..., None], axis=-1)[..., 0]
+    hits = (jnp.argmax(logits, -1) == labels).astype(jnp.float32)
+    denom = jnp.maximum(mask.sum(), 1.0)
+    return -(picked * mask).sum() / denom, (hits * mask).sum() / denom
+
+
+ONE_PASS_CASES = {
+    "mask-with-zeros": (dict(), 20),
+    "chunk-not-dividing": (dict(mask_zeros=False), 7),    # 60 rows: 9 chunks
+    "head-with-bias": (dict(bias=True), 20),
+    "one-chunk": (dict(), 1024),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(ONE_PASS_CASES))
+def test_one_pass_ce_matches_dense(case, dtype):
+    """Loss, accuracy and every gradient leaf (kernel, bias, hidden) of
+    the one-pass loop against the dense float32 path: to 1e-5 with
+    float32 operands, at the chunked loss's tolerances with bfloat16."""
+    kwargs, chunk = ONE_PASS_CASES[case]
+    head, hidden, labels, mask = _head_case(**kwargs)
+    tol, loss_tol = (1e-5, 1e-5) if dtype == "float32" else (2e-2, 1e-3)
+
+    (l_d, a_d), g_d = jax.value_and_grad(
+        lambda hp, h: _dense_xent(hp, h, labels, mask),
+        argnums=(0, 1), has_aux=True)(head, hidden)
+    (l_c, a_c), g_c = jax.value_and_grad(
+        lambda hp, h: nn.chunked_lm_xent(
+            hp, h, labels, mask=mask, chunk=chunk, dtype=jnp.dtype(dtype)),
+        argnums=(0, 1), has_aux=True)(head, hidden)
+    # the undifferentiated call is another program: the plain forward
+    l_p, a_p = nn.chunked_lm_xent(
+        head, hidden, labels, mask=mask, chunk=chunk, dtype=jnp.dtype(dtype))
+
+    assert abs(float(l_d) - float(l_c)) < loss_tol, (float(l_d), float(l_c))
+    assert abs(float(l_c) - float(l_p)) < 1e-6
+    assert abs(float(a_d) - float(a_c)) < 1e-5
+    assert float(a_c) == float(a_p)
+    assert jax.tree_util.tree_structure(g_d) == \
+        jax.tree_util.tree_structure(g_c)
+    for a, b in zip(jax.tree_util.tree_leaves(g_d),
+                    jax.tree_util.tree_leaves(g_c)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=tol * float(jnp.abs(a).max()),
+                                   rtol=tol)
+
+
+@pytest.mark.parametrize("case", ["three-times", "two-micro-batches"])
+def test_one_pass_ce_scales_with_its_cotangent(case):
+    """The forward loop takes the gradients for a cotangent of 1; the
+    backward multiplies by the one it is handed."""
+    head, hidden, labels, mask = _head_case(bias=True)
+
+    def loss(hp, h, l=labels, m=mask):
+        return nn.chunked_lm_xent(hp, h, l, mask=m, chunk=16,
+                                  dtype=jnp.float32)[0]
+
+    g_1 = jax.grad(loss, argnums=(0, 1))(head, hidden)
+    if case == "three-times":
+        got = jax.grad(lambda hp, h: 3.0 * loss(hp, h),
+                       argnums=(0, 1))(head, hidden)
+        want = jax.tree_util.tree_map(lambda g: 3.0 * g, g_1)
+    else:
+        other = (hidden[::-1], labels[::-1], mask[::-1])
+        got = jax.grad(
+            lambda hp, h: loss(hp, h) + 0.5 * loss(hp, *other),
+            argnums=(0, 1))(head, hidden)
+        g_2 = jax.grad(loss)(head, *other)
+        want = (jax.tree_util.tree_map(lambda a, b: a + 0.5 * b, g_1[0], g_2),
+                g_1[1])
+    for a, b in zip(jax.tree_util.tree_leaves(want),
+                    jax.tree_util.tree_leaves(got)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-6, atol=1e-9)
+
+
+def _primitives(jaxpr, inside=None):
+    """Every equation of a jaxpr and of the jaxprs inside it, as
+    ``(primitive name, name of the enclosing scan or None)``."""
+    out = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        out.append((name, inside))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _primitives(
+                        sub, name if name == "scan" else inside)
+    return out
+
+
+@pytest.mark.parametrize("case", ["differentiated", "plain",
+                                  "differentiated-gpt"])
+def test_one_pass_ce_structure(case, caplog):
+    """``jax.grad`` of the chunked loss holds ONE scan with the three
+    products of a chunk in it (logits, dX, dW) and no product of the
+    head's outside it; undifferentiated it is one scan with the logits
+    product alone. The trace-time log line names the form."""
+    head, hidden, labels, mask = _head_case()
+
+    def loss(hp, h):
+        return nn.chunked_lm_xent(hp, h, labels, mask=mask, chunk=16)[0]
+
+    with caplog.at_level(logging.INFO, logger="tpujob.nn"):
+        if case == "differentiated":
+            jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+                head, hidden)
+        elif case == "plain":
+            jaxpr = jax.make_jaxpr(loss)(head, hidden)
+        else:
+            params, batch = _ce_case(4)
+            jaxpr = jax.make_jaxpr(jax.grad(
+                lambda p: gpt.loss_fn(p, batch, ce_chunk=24)[0]))(params)
+    prims = _primitives(jaxpr.jaxpr)
+    assert [p for p, _ in prims].count("scan") == 1
+    in_scan = [p for p, inside in prims if inside == "scan"]
+    products = 1 if case == "plain" else 3
+    assert in_scan.count("dot_general") == products, in_scan
+    if case != "differentiated-gpt":
+        assert [p for p, _ in prims].count("dot_general") == products
+    form = ("the plain forward" if case == "plain"
+            else "gradients taken in the forward loop")
+    assert "chunked_lm_xent: unsharded" in caplog.text
+    assert form in caplog.text
+    if case != "plain":
+        assert "the plain forward" not in caplog.text
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "minus-inf-columns"])
+def test_one_pass_ce_row_statistics(case):
+    """The one reduce that gives a chunk's sum of exponentials and its
+    argmax == ``jax.nn.logsumexp`` beside ``jnp.argmax``, ties to the
+    lower column included."""
+    logits = jax.random.normal(jax.random.PRNGKey(5), (6, 40)) * 4.0
+    if case == "ties":
+        logits = jnp.round(logits)          # many equal maxima in a row
+        logits = logits.at[0].set(0.0)      # a row all alike
+    elif case == "minus-inf-columns":
+        logits = logits.at[:, ::3].set(-jnp.inf)
+    lse, argmax = nn._lse_and_argmax(logits)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.nn.logsumexp(logits, axis=-1)),
+        rtol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(argmax), np.asarray(jnp.argmax(logits, axis=-1)))
+
+
+@pytest.mark.parametrize("wrt", ["hidden-alone", "mask", "labels"])
+def test_one_pass_ce_labels_and_mask_get_no_gradient(wrt):
+    head, hidden, labels, mask = _head_case()
+
+    def loss(hp, h, l, m):
+        return nn.chunked_lm_xent(hp, h, l, mask=m, chunk=16,
+                                  dtype=jnp.float32)[0]
+
+    if wrt == "hidden-alone":
+        got = jax.grad(loss, argnums=1)(head, hidden, labels, mask)
+        want = jax.grad(lambda h: _dense_xent(head, h, labels, mask)[0])(
+            hidden)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-6, rtol=1e-5)
+    elif wrt == "mask":
+        got = jax.grad(loss, argnums=3)(head, hidden, labels, mask)
+        assert got.shape == mask.shape and not np.asarray(got).any()
+    else:
+        got = jax.grad(loss, argnums=2, allow_int=True)(
+            head, hidden, labels, mask)
+        assert got.shape == labels.shape
+        assert got.dtype == jax.dtypes.float0
 
 
 # ---------------------------------------------------------------------------
