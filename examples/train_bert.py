@@ -16,9 +16,14 @@ STEPS = int(os.environ.get("TPUJOB_STEPS", "100"))
 
 
 def main():
+    # run_training hands the live mesh to a loss that declares the
+    # argument: the masked-LM head then packs and loops per dp shard
+    def loss_fn(p, b, mesh=None):
+        return bert.loss_fn(p, b, remat=True, mesh=mesh)
+
     job = TrainJob(
         init_params=lambda rng: bert.init(rng),
-        loss_fn=lambda p, b: bert.loss_fn(p, b, remat=True),
+        loss_fn=loss_fn,
         optimizer=optim.adamw(
             optim.cosine_schedule(1e-4, STEPS, STEPS // 10), weight_decay=0.01,
         ),

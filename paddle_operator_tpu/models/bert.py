@@ -112,36 +112,57 @@ def encode(params, input_ids, type_ids=None, attention_mask=None,
     return x, aux
 
 
-def mlm_logits(params, hidden, dtype=jnp.bfloat16):
+def _mlm_hidden(params, hidden, dtype):
+    """The masked-LM head up to its decoder: transform, GELU, LayerNorm."""
     y = nn.dense(params["mlm"]["transform"], hidden, dtype)
     y = nn.gelu(y)
-    y = nn.layernorm(params["mlm"]["ln"], y, dtype=dtype)
-    return nn.dense(params["mlm"]["decoder"], y, dtype=jnp.float32)
+    return nn.layernorm(params["mlm"]["ln"], y, dtype=dtype)
+
+
+def mlm_logits(params, hidden, dtype=jnp.bfloat16):
+    """Float32 logits of every position, for callers that want logits;
+    the loss does not come this way (:func:`loss_fn`)."""
+    return nn.dense(params["mlm"]["decoder"],
+                    _mlm_hidden(params, hidden, dtype), dtype=jnp.float32)
+
+
+# Rows a trip of the masked-LM loss's loop sends through the decoder: 62 MB of
+# float32 logits at 30,522 columns. Chosen on the chip by ``train_tokens_per_s``
+# of ``bert-base.train-512`` among 256 / 512 / 1024 / 2048 (PERF.md section 5):
+# a trip costs its rows plus one round trip of the float32 ``dW`` carry, and a
+# 15% mask over 16,384 rows fills five chunks of 512 with less padding than
+# three of 1024.
+MLM_CHUNK = 512
 
 
 def loss_fn(params, batch, train=True, dtype=jnp.bfloat16, remat: bool = False,
-            attn_impl: str = "auto", moe_aux_weight: float = 0.01):
+            attn_impl: str = "auto", moe_aux_weight: float = 0.01, mesh=None):
     """Masked-LM loss. batch = {input_ids, labels, [type_ids, attention_mask,
-    loss_mask]}; labels [B,S] with ignored positions marked by loss_mask=0."""
+    loss_mask]}; labels [B,S] with ignored positions marked by loss_mask=0.
+
+    Only rows whose ``loss_mask`` is not 0 go through the decoder
+    (:func:`ops.nn.masked_lm_xent`: packed to the front, ``MLM_CHUNK`` at
+    a time, in a loop as long as the mask needs; float32 logits over the
+    whole vocabulary for each such row). With no ``loss_mask`` every row
+    counts and every chunk runs. ``aux["head_rows_pct"]`` says how many
+    rows that was, of all.
+
+    ``mesh`` is the mesh the step is jitted over (``run_training`` hands
+    it to any loss function that declares the argument): the loss then
+    packs and loops per ``dp`` shard, as ``gpt.loss_fn``'s does.
+    """
     hidden, moe_aux = encode(
         params, batch["input_ids"], batch.get("type_ids"),
         batch.get("attention_mask"), dtype=dtype, remat=remat,
         attn_impl=attn_impl,
     )
-    logits = mlm_logits(params, hidden, dtype)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    labels = batch["labels"]
-    picked = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    mask = batch.get("loss_mask")
-    if mask is None:
-        mask = jnp.ones_like(labels, jnp.float32)
-    mask = mask.astype(jnp.float32)
-    loss = -jnp.sum(picked * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    loss, acc, head_rows_pct = nn.masked_lm_xent(
+        params["mlm"]["decoder"], _mlm_hidden(params, hidden, dtype),
+        batch["labels"], mask=batch.get("loss_mask"), chunk=MLM_CHUNK,
+        dtype=dtype, mesh=mesh)
     loss = loss + moe_aux_weight * moe_aux
-    acc = jnp.sum(
-        (jnp.argmax(logits, -1) == labels).astype(jnp.float32) * mask
-    ) / jnp.maximum(jnp.sum(mask), 1.0)
-    return loss, {"accuracy": acc, "moe_aux": moe_aux}
+    return loss, {"accuracy": acc, "moe_aux": moe_aux,
+                  "head_rows_pct": head_rows_pct}
 
 
 def synthetic_batch(key, batch_size: int, seq_len: int = 128,

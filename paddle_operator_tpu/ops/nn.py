@@ -469,6 +469,37 @@ def _chunk_sums(w, bias, h, l, m):
             jnp.sum((argmax == l).astype(jnp.float32) * m))
 
 
+def _chunk_grads(w, bias, columns, dtype, carry, xs):
+    """One chunk of a differentiated loop: :func:`_chunk_sums`, then the
+    chunk's ``d loss_sum / d logits = (softmax - onehot) * mask`` while
+    its logits are in hand (``columns``: the vocabulary's ids as a row, to
+    find the label's). ``carry`` is ``(loss_sum, acc_sum, {kernel,
+    [bias]} gradients in float32)`` and grows by the chunk's share; the
+    chunk's ``dX`` is returned beside it. ``dlogits`` is rounded to
+    ``dtype`` only where it enters a product."""
+    loss_acc, acc_acc, dparams = carry
+    h, l, m = xs
+    logits, lse, loss_sum, acc_sum = _chunk_sums(w, bias, h, l, m)
+    dlogits = (jnp.exp(logits - lse[:, None])
+               - (columns == l[:, None])) * m[:, None]
+    dl = dlogits.astype(dtype)
+    grads = {"kernel": dparams["kernel"] + lax.dot_general(
+        h.astype(dtype), dl, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)}
+    if bias is not None:
+        grads["bias"] = dparams["bias"] + jnp.sum(dlogits, axis=0)
+    dx = lax.dot_general(dl, w, (((1,), (1,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+    return (loss_acc + loss_sum, acc_acc + acc_sum, grads), dx
+
+
+def _zero_sums(head_params):
+    """What :func:`_chunk_grads` starts from."""
+    return (jnp.float32(0), jnp.float32(0),
+            {k: jnp.zeros(v.shape, jnp.float32)
+             for k, v in head_params.items()})
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _lm_xent_sums(head_params, hidden, labels, mask, chunk, dtype, where):
     """The local part of :func:`chunked_lm_xent`: ``(loss_sum, acc_sum,
@@ -511,27 +542,9 @@ def _lm_xent_sums_fwd(head_params, hidden, labels, mask, chunk, dtype,
     log.info("chunked_lm_xent: %s, %d chunks of %d: gradients taken in the "
              "forward loop", where, n_chunks, chunk)
     columns = lax.broadcasted_iota(labels.dtype, (1, w.shape[1]), 1)
-
-    def body(carry, xs):
-        loss_acc, acc_acc, dparams = carry
-        h, l, m = xs
-        logits, lse, loss_sum, acc_sum = _chunk_sums(w, bias, h, l, m)
-        dlogits = (jnp.exp(logits - lse[:, None])
-                   - (columns == l[:, None])) * m[:, None]
-        dl = dlogits.astype(dtype)
-        grads = {"kernel": dparams["kernel"] + lax.dot_general(
-            h.astype(dtype), dl, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)}
-        if bias is not None:
-            grads["bias"] = dparams["bias"] + jnp.sum(dlogits, axis=0)
-        dx = lax.dot_general(dl, w, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        return (loss_acc + loss_sum, acc_acc + acc_sum, grads), dx
-
-    zeros = {k: jnp.zeros(v.shape, jnp.float32)
-             for k, v in head_params.items()}
     (loss_sum, acc_sum, dparams), dx = lax.scan(
-        body, (jnp.float32(0), jnp.float32(0), zeros), rows)
+        functools.partial(_chunk_grads, w, bias, columns, dtype),
+        _zero_sums(head_params), rows)
     dx = dx.reshape(n_chunks * chunk, -1)[:math.prod(labels.shape)]
     # head_params and hidden ride along for their dtypes alone: the
     # backward reads no data of theirs
@@ -552,6 +565,112 @@ def _lm_xent_sums_bwd(chunk, dtype, where, residuals, cotangents):
 
 
 _lm_xent_sums.defvjp(_lm_xent_sums_fwd, _lm_xent_sums_bwd)
+
+
+def _packed_xent_chunks(head_params, hidden, labels, mask, chunk, dtype):
+    """:func:`_xent_chunks` over the rows in packed order, those with
+    ``mask != 0`` first and each kind in its own order (a stable sort of
+    the flags); beside it that order and the number of leading chunks
+    that hold a row with a loss, which the device computes. Labels and
+    mask ride the sort: gathering 16,384 scalars costs the chip as much
+    as gathering as many rows of 768 (0.12 ms each: PERF.md section 5)."""
+    n = math.prod(labels.shape)
+    flat_m = (jnp.ones((n,), jnp.float32) if mask is None
+              else mask.reshape(-1).astype(jnp.float32))
+    _, order, flat_l, flat_m = lax.sort(
+        (flat_m == 0, lax.iota(jnp.int32, n), labels.reshape(-1), flat_m),
+        num_keys=1, is_stable=True)
+    rows, w, bias = _xent_chunks(
+        head_params, hidden.reshape(n, -1)[order], flat_l, flat_m,
+        chunk, dtype)
+    chunk = rows[1].shape[1]
+    chunks_run = (jnp.count_nonzero(flat_m) + (chunk - 1)) // chunk
+    return rows, w, bias, order, chunks_run
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _masked_xent_sums(head_params, hidden, labels, mask, chunk, dtype,
+                      where):
+    """The local part of :func:`masked_lm_xent`: ``(loss_sum, acc_sum,
+    mask_sum, rows through the head)`` over the rows it is handed. This
+    is the plain forward, as :func:`_lm_xent_sums` is; differentiated,
+    :func:`_masked_xent_sums_fwd` runs in its place."""
+    rows, w, bias, _, chunks_run = _packed_xent_chunks(
+        head_params, hidden, labels, mask, chunk, dtype)
+    n_chunks, chunk = rows[1].shape
+    log.info("masked_lm_xent: %s, up to %d chunks of %d, as many as the "
+             "mask needs: the plain forward", where, n_chunks, chunk)
+
+    def body(i, carry):
+        _, _, loss_sum, acc_sum = _chunk_sums(
+            w, bias, *(r[i] for r in rows))
+        return carry[0] + loss_sum, carry[1] + acc_sum
+
+    loss_sum, acc_sum = lax.fori_loop(
+        0, chunks_run, body, (jnp.float32(0), jnp.float32(0)))
+    return (loss_sum, acc_sum, jnp.sum(rows[2]),
+            (chunks_run * chunk).astype(jnp.float32))
+
+
+def _masked_xent_sums_fwd(head_params, hidden, labels, mask, chunk, dtype,
+                          where):
+    """What ``jax.grad`` runs, as :func:`_lm_xent_sums_fwd`: the one loop
+    takes the gradients too (:func:`_chunk_grads`), so nothing is ever
+    differentiated THROUGH it and its length may be data. ``dX`` is
+    written chunk by chunk into zeros, in packed order, and gathered back
+    to the rows' own; :func:`_lm_xent_sums_bwd` scales it."""
+    rows, w, bias, order, chunks_run = _packed_xent_chunks(
+        head_params, hidden, labels, mask, chunk, dtype)
+    n_chunks, chunk = rows[1].shape
+    log.info("masked_lm_xent: %s, up to %d chunks of %d, as many as the "
+             "mask needs: gradients taken in the forward loop",
+             where, n_chunks, chunk)
+    columns = lax.broadcasted_iota(labels.dtype, (1, w.shape[1]), 1)
+
+    def body(i, carry):
+        sums, dx = carry
+        sums, dx_chunk = _chunk_grads(
+            w, bias, columns, dtype, sums, tuple(r[i] for r in rows))
+        return sums, dx.at[i].set(dx_chunk)
+
+    (loss_sum, acc_sum, dparams), dx = lax.fori_loop(
+        0, chunks_run, body,
+        (_zero_sums(head_params), jnp.zeros(rows[0].shape, jnp.float32)))
+    # the row that went to place order[j] comes back from place j
+    dx = dx.reshape(n_chunks * chunk, -1)[jnp.argsort(order)]
+    return ((loss_sum, acc_sum, jnp.sum(rows[2]),
+             (chunks_run * chunk).astype(jnp.float32)),
+            (head_params, hidden, dparams, dx.reshape(hidden.shape)))
+
+
+_masked_xent_sums.defvjp(_masked_xent_sums_fwd, _lm_xent_sums_bwd)
+
+
+def _sums_per_shard(local_sums, head_params, hidden, labels, mask, chunk,
+                    dtype, mesh, batch_axis):
+    """``local_sums`` (:func:`_lm_xent_sums` or :func:`_masked_xent_sums`)
+    over the whole batch, or, where ``mesh`` has ``batch_axis`` with a
+    size > 1 that divides the batch, per shard under ``shard_map``,
+    manual over ``batch_axis`` ONLY, its sums ``psum``med (the transpose
+    ``psum``s the head's gradient); any other axis (a ``tp``-sharded head
+    kernel) stays with GSPMD."""
+    shards = mesh.shape.get(batch_axis, 1) if mesh is not None else 1
+    if shards <= 1 or hidden.shape[0] % shards:
+        return local_sums(
+            head_params, hidden, labels, mask, chunk, dtype, "unsharded")
+    if mask is None:
+        mask = jnp.ones(labels.shape, jnp.float32)
+    rows = P(batch_axis)
+    where = "%d shards over '%s'" % (shards, batch_axis)
+
+    @functools.partial(
+        jax.shard_map, mesh=mesh, in_specs=(P(), rows, rows, rows),
+        out_specs=P(), axis_names={batch_axis}, check_vma=False)
+    def sums(hp, h, l, m):
+        return lax.psum(
+            local_sums(hp, h, l, m, chunk, dtype, where), batch_axis)
+
+    return sums(head_params, hidden, labels, mask)
 
 
 def chunked_lm_xent(head_params, hidden, labels, mask=None,
@@ -599,23 +718,49 @@ def chunked_lm_xent(head_params, hidden, labels, mask=None,
       (mean_loss fp32, accuracy fp32) over masked positions — matching
       ``softmax_cross_entropy`` + ``accuracy`` on the dense path.
     """
-    shards = mesh.shape.get(batch_axis, 1) if mesh is not None else 1
-    if shards > 1 and hidden.shape[0] % shards == 0:
-        if mask is None:
-            mask = jnp.ones(labels.shape, jnp.float32)
-        rows = P(batch_axis)
-        where = "%d shards over '%s'" % (shards, batch_axis)
-
-        @functools.partial(
-            jax.shard_map, mesh=mesh, in_specs=(P(), rows, rows, rows),
-            out_specs=P(), axis_names={batch_axis}, check_vma=False)
-        def sums(hp, h, l, m):
-            return lax.psum(
-                _lm_xent_sums(hp, h, l, m, chunk, dtype, where), batch_axis)
-
-        loss_sum, acc_sum, mask_sum = sums(head_params, hidden, labels, mask)
-    else:
-        loss_sum, acc_sum, mask_sum = _lm_xent_sums(
-            head_params, hidden, labels, mask, chunk, dtype, "unsharded")
+    loss_sum, acc_sum, mask_sum = _sums_per_shard(
+        _lm_xent_sums, head_params, hidden, labels, mask, chunk, dtype,
+        mesh, batch_axis)
     denom = jnp.maximum(mask_sum, 1.0)
     return loss_sum / denom, acc_sum / denom
+
+
+def masked_lm_xent(head_params, hidden, labels, mask=None,
+                   chunk: int = 1024, dtype=jnp.bfloat16,
+                   mesh=None, batch_axis: str = "dp"):
+    """Cross-entropy through a big-vocab LM head over the rows that carry
+    a loss ALONE: a masked-LM batch weighs 15% of its positions, and a
+    row of weight 0 adds 0 to the loss and to every gradient, so its
+    ``vocab`` float32 logits need not exist.
+
+    The rows are packed, those with ``mask != 0`` first and in their own
+    order, and go through the body of :func:`chunked_lm_xent`'s loops a
+    chunk at a time (a chunk's float32 logits computed once; under
+    ``jax.grad`` its ``dX``, ``dW`` and ``db`` taken while they are in
+    hand) in a loop of ``ceil(rows with a loss / chunk)`` iterations: a
+    number the device computes from the mask, a ``while`` on the chip.
+    There is no capacity and no dropped row: a mask of ones (or none)
+    runs every chunk, a mask of zeros none, and the sums are those of
+    :func:`chunked_lm_xent` over the same rows for any mask. Chunks the
+    loop never reaches leave zeros in ``dX``, which goes back to the
+    rows' own order by a gather (packing is a permutation).
+
+    This is :func:`chunked_lm_xent`'s sibling and not a mode of it: a
+    causal LM's every row carries a loss, and its static ``lax.scan`` is
+    what the chip's compiler schedules whole.
+
+    ``mesh``, ``batch_axis``: as :func:`chunked_lm_xent`. Each
+    ``batch_axis`` shard packs its own rows and loops as long as its own
+    mask needs; the sums are ``psum``med after the loops.
+
+    Returns ``(mean_loss, accuracy, head_rows_pct)``, float32: the first
+    two over masked positions as :func:`chunked_lm_xent`'s, the third
+    100 x the rows that went through the head (chunks run x chunk) over
+    the rows there are.
+    """
+    loss_sum, acc_sum, mask_sum, head_rows = _sums_per_shard(
+        _masked_xent_sums, head_params, hidden, labels, mask, chunk, dtype,
+        mesh, batch_axis)
+    denom = jnp.maximum(mask_sum, 1.0)
+    return (loss_sum / denom, acc_sum / denom,
+            100.0 * head_rows / math.prod(labels.shape))

@@ -654,7 +654,13 @@ def run_training(job: TrainJob, cfg: Optional[LaunchConfig] = None,
             # this boundary's step profile (usually ~0: deferred design)
             with times.timed("d2h"):
                 loss = float(host["loss"])
-            log.info("step %d loss=%.4f steps/s=%.2f", pstep, loss, rate)
+            # whatever else the loss's aux counts (accuracy, the share of
+            # rows a masked-LM head scored) follows the rate, scalars only
+            others = "".join(
+                " %s=%.6g" % (k, float(v)) for k, v in sorted(host.items())
+                if k != "loss" and np.ndim(v) == 0)
+            log.info("step %d loss=%.4f steps/s=%.2f%s",
+                     pstep, loss, rate, others)
             eps = rate * examples_per_step
             if examples_per_step > 0 and \
                     tput_watch.observe(eps) == "degraded":
