@@ -34,7 +34,11 @@ unchanged) and their fp8 storage (the index keys are bfloat16 rows).
 row's cached index keys, ``select_rows`` takes the top, and
 ``mla_selected_decode`` attends over those rows alone: a sequence of
 ``n`` tokens reads ``n`` index keys and ``min(n, index_topk)`` latent
-rows a layer. **Prefill masks**: it walks the prompt ``PREFILL_CHUNK``
+rows a layer. All three cost by the ROW of the batch, live or not, so a
+decode step runs them over the first ``r`` rows, ``r`` the smallest rung
+of ``DECODE_RUNGS`` (then the batch) that reaches the last live row:
+chosen on the device, inside the one compiled step (``decode``).
+**Prefill masks**: it walks the prompt ``PREFILL_CHUNK``
 tokens at a time through the whole stack inside one program (so nothing
 it holds grows with the prompt but the rows it hands back and one
 chunk's scores against them), and a chunk attends densely, key block by
@@ -89,6 +93,19 @@ TINY_CONFIG = dict(
 PREFILL_CHUNK = 1024
 #: prefill programs an engine compiles at most: prompt_pad / 8, 2/8, ...
 PREFILL_BUCKETS = 8
+#: row counts BELOW the batch that a decode step's sparse attention can
+#: run over (``_decode_rungs``); the batch's own is always the top rung.
+#: Every rung is a branch of the ONE decode program and is paid for at
+#: set-up, in Python: its two kernels traced and lowered once more. The
+#: rule: the longest ladder whose warm set-up costs at most 2.0 s over
+#: a program with none. Read on the chip machine (PERF.md section 6,
+#: PR 47; a batch of 16, under a measurement wrapper that doubles the
+#: differences): (4,) +0.58 s, (4, 8) +1.53 s, (2, 4, 8) +2.54 s;
+#: through benchmark/run.py itself (4, 8) reads +0.68 s, the longer
+#: ladder was not read again. A device step costs 15.0 / 15.9 / 17.6 /
+#: 22.6 ms at 2 / 4 / 8 / 16 rows: 4 is where the long-context cell's
+#: steps stand, 8 what its knee's 4-6 live rows take
+DECODE_RUNGS = (4, 8)
 
 
 def _config(config: Optional[dict]) -> Dict[str, Any]:
@@ -312,6 +329,43 @@ def prefill(config: Optional[dict], params: Dict, ids: jnp.ndarray,
 
 # -- decode -------------------------------------------------------------------
 
+def _decode_rungs(batch: int) -> Tuple[int, ...]:
+    """The row counts a decode step's sparse attention is built for,
+    ascending: the entries of ``DECODE_RUNGS`` below ``batch``, then
+    ``batch``."""
+    return tuple(r for r in DECODE_RUNGS if r < batch) + (batch,)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "rows", "top", "scale", "interpret"))
+def _sparse_rows(index, q_idx, w, q_lat, q_r, latent, keys, tables, lens, *,
+                 rows: int, top: int, scale: float, interpret: bool):
+    """The first ``rows`` rows of a batch through the paged sparse
+    attention of layer ``index``, handed back at the batch's size:
+    (context [B, H, C], count [B]), zeros past ``rows``; at ``rows`` =
+    the batch nothing is cut or padded. A function of its arguments
+    alone, jitted: a decode step calls each rung at two sites (the dense
+    layer, the scan's body) and is traced twice before it is lowered
+    (``engine._build_decode``'s ``eval_shape``, then the step), and all
+    of them share ONE trace and ONE lowered function a rung."""
+    from ..ops.attention_pallas import (
+        dsa_index_scores, mla_selected_decode, select_rows)
+
+    batch = q_idx.shape[0]
+    q_idx, w, q_lat, q_r, tables, lens = (
+        a if rows == batch else a[:rows]
+        for a in (q_idx, w, q_lat, q_r, tables, lens))
+    scores = dsa_index_scores(q_idx, w, keys, tables, lens, layer=index,
+                              interpret=interpret)
+    chosen, count = select_rows(scores, lens, top)
+    ctx = mla_selected_decode(q_lat, q_r, latent, tables, chosen, count,
+                              scale, layer=index, interpret=interpret)
+    if rows == batch:
+        return ctx, count
+    return (jnp.pad(ctx, ((0, batch - rows), (0, 0), (0, 0))),
+            jnp.pad(count, (0, batch - rows)))
+
+
 def decode(config: Optional[dict], params: Dict, pools: Tuple,
            tokens: jnp.ndarray, positions: jnp.ndarray,
            tables: jnp.ndarray, lens: jnp.ndarray, live: jnp.ndarray,
@@ -320,15 +374,31 @@ def decode(config: Optional[dict], params: Dict, pools: Tuple,
     """One token for every row of the batch through the two caches:
     ``pools`` = (latent [L, P, bs, W], index keys [L, P, bs, Wi]), both
     updated where each row's new token lies and handed back; the rest as
-    ``models.axk1.decode``. Counters: the expert layers' and
-    ``dsa.rows_live`` (cached tokens of the live rows, the new one
-    counted) / ``dsa.rows_selected`` (of those, the rows one layer's
-    attention weighed: the live rows' ``count`` as ``select_rows``
-    handed it to the last layer's kernel; the gather before the kernel
-    fetches ``index_topk`` slots for every row of the batch)."""
+    ``models.axk1.decode``.
+
+    With ``attn_impl="paged"`` a layer's sparse attention (index scores,
+    the top ``index_topk``, the gather, the attention over the gathered
+    rows) runs over rows ``[:r]`` of the batch, ``r`` the smallest of
+    ``_decode_rungs(batch)`` that is at least 1 + the index of the last
+    live row: one ``lax.switch`` a layer over ``_sparse_rows`` at each
+    rung, the top rung the whole batch as it always ran. It follows what
+    the step is handed (``live``), whatever order the rows are in (the
+    engine packs them to the front); rows past ``r`` are handed a
+    context of zeros: none is live, the expert layer masks them and
+    nobody reads their token. Everything else of the step, the cache
+    writes among it, stays at the batch's rows: it streams weights and
+    does not feel them. A batch with no rung below it builds no
+    conditional; ``attn_impl="reference"`` runs every row.
+
+    Counters: the expert layers' and ``dsa.rows_live`` (cached tokens of
+    the live rows, the new one counted) / ``dsa.rows_selected`` (of
+    those, the rows one layer's attention weighed: the live rows'
+    ``count`` as ``select_rows`` handed it to the last layer's kernel;
+    the gather before the kernel fetches ``index_topk`` slots for every
+    row of the rung) / ``dsa.rung_rows`` (the rung: the rows of the
+    batch the sparse attention ran over)."""
     from ..ops.attention_pallas import (
-        _reference_index_scores, _reference_mla_selected_decode,
-        dsa_index_scores, mla_selected_decode, select_rows)
+        _reference_index_scores, _reference_mla_selected_decode, select_rows)
 
     cfg = _config(config)
     _, scale = axk1._rotary(cfg)
@@ -336,6 +406,17 @@ def decode(config: Optional[dict], params: Dict, pools: Tuple,
     blocks, slots, new_lens = axk1._write_targets(
         positions, tables, lens, live, block_size, dummy_page)
     interpret = jax.default_backend() != "tpu"
+
+    batch = tokens.shape[0]
+    rungs = _decode_rungs(batch) if attn_impl == "paged" else (batch,)
+    # the rung: how many of the smaller ones end before the last live row
+    which = jnp.sum(
+        jnp.max(jnp.where(live, jnp.arange(1, batch + 1), 0))
+        > jnp.asarray(rungs[:-1], jnp.int32), dtype=jnp.int32)
+
+    # one branch a rung; a ladder of one is a plain call
+    branches = [functools.partial(_sparse_rows, rows=r, top=top, scale=scale,
+                                  interpret=interpret) for r in rungs]
 
     def attend(index, attn, queries, rows, carry):
         q_nope, q_r, q_idx, w = queries
@@ -348,12 +429,9 @@ def decode(config: Optional[dict], params: Dict, pools: Tuple,
         latent, keys = written(pools[0], rows[0]), written(pools[1], rows[1])
         q_lat = _mm("bhn,hnc->bhc", q_nope, attn["k_up"])
         if attn_impl == "paged":
-            scores = dsa_index_scores(q_idx, w, keys, tables, new_lens,
-                                      layer=index, interpret=interpret)
-            chosen, count = select_rows(scores, new_lens, top)
-            ctx = mla_selected_decode(
-                q_lat, q_r, latent, tables, chosen, count, scale,
-                layer=index, interpret=interpret)
+            ctx, count = jax.lax.switch(
+                which, branches, jnp.asarray(index, jnp.int32), q_idx, w,
+                q_lat, q_r, latent, keys, tables, new_lens)
         else:
             scores = _reference_index_scores(
                 q_idx, w, jax.lax.dynamic_index_in_dim(keys, index, 0, False),
@@ -373,7 +451,8 @@ def decode(config: Optional[dict], params: Dict, pools: Tuple,
         (*pools, jnp.zeros((), jnp.int32)), inputs=_dsa_inputs)
     counters = dict(counters, **{
         "dsa.rows_live": jnp.sum(jnp.where(live, new_lens, 0)),
-        "dsa.rows_selected": selected})
+        "dsa.rows_selected": selected,
+        "dsa.rung_rows": jnp.asarray(rungs, jnp.int32)[which]})
     logits, out = axk1._next_token(cfg, params, x)
     if with_logits:
         return out, tuple(pools), counters, logits

@@ -6,6 +6,7 @@ selection is live, against the benchmark's plain reference
 (``benchmark/reference/dsv32.py``: float32, not absorbed, dense scores
 masked to the selection, no cache; it imports nothing of the program)."""
 
+import functools
 import hashlib
 import os
 import re
@@ -185,6 +186,185 @@ def test_the_engine_serves_it_token_for_token_on_both_attention_paths(
         assert engine.cache.allocator.check() == []
         assert engine.cache.allocator.stats()["blocks_used"] == 0
     assert served["paged"] == served["reference"]
+
+
+# -- the rung: the sparse attention runs over the rows up to the last live --
+
+RUNG_BATCH = 16
+
+
+def _rung_cases():
+    """(rows live, the rung they take) for every rung of the ladder at
+    1, r - 1, r, r + 1 and all rows live, packed to the front; then live
+    rows that are NOT at the front."""
+    ladder = dsv32._decode_rungs(RUNG_BATCH)
+    cases = []
+    for r in ladder[:-1]:
+        for n in (1, r - 1, r, r + 1, RUNG_BATCH):
+            cases.append((tuple(range(n)), next(x for x in ladder if x >= n)))
+    cases.append(((0, 9), RUNG_BATCH))
+    cases.append(((2,), ladder[0]))
+    return cases
+
+
+@pytest.fixture(scope="module")
+def rung_steps(params):
+    """One decode step of 16 rows over pools of random rows (contexts of
+    20-60 tokens, so every row's selection of 16 is live), as three
+    programs of the same arguments: the paged step with its ladder, the
+    paged step with no rung below the batch (the parent's program), and
+    ``attn="reference"``."""
+    cfg = family.program_config(TINY)
+    bs, per_seq, b = 8, 8, RUNG_BATCH
+    pages = b * per_seq
+    rnd = np.random.RandomState(47)
+    ks = jax.random.split(jax.random.PRNGKey(47), 2)
+    pools = tuple(
+        jax.random.normal(k, (cfg["layers"], pages + 1, bs, 128),
+                          jnp.float32).astype(jnp.bfloat16) for k in ks)
+    lens = rnd.randint(20, 60, size=b).astype(np.int32)
+    tables = rnd.permutation(pages).reshape(b, per_seq).astype(np.int32)
+    tokens = rnd.randint(0, 512, size=b).astype(np.int32)
+
+    # name -> (attn, the ladder the step is traced under, at its first call)
+    kinds = {"ladder": ("paged", dsv32.DECODE_RUNGS), "whole": ("paged", ()),
+             "reference": ("reference", dsv32.DECODE_RUNGS)}
+    programs = {
+        name: jax.jit(functools.partial(
+            dsv32.decode, cfg, attn_impl=attn, block_size=bs,
+            dummy_page=pages, with_logits=True))
+        for name, (attn, _) in kinds.items()}
+
+    def step(name, rows):
+        live = np.zeros((b,), bool)
+        live[list(rows)] = True
+        # a row that is not live is handed zeros, as the engine packs them
+        keep = lambda a: jnp.asarray(np.where(live, a, 0))
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(dsv32, "DECODE_RUNGS", kinds[name][1])
+            return programs[name](
+                params, pools, keep(tokens), keep(lens),
+                jnp.asarray(np.where(live[:, None], tables, 0)), keep(lens),
+                jnp.asarray(live))
+    return step
+
+
+@pytest.mark.parametrize("rows,rung", _rung_cases())
+def test_the_sparse_attention_runs_over_the_rung_that_covers_the_live_rows(
+        rung_steps, rows, rung):
+    """Whatever rows are live, a live row's token is the reference
+    step's, its logits are bit for bit those of the step that runs all
+    16 rows, both pools' live pages and the counters of the live rows
+    are equal in all three, and ``dsa.rung_rows`` reads the smallest rung that
+    reaches the last live row."""
+    rows = list(rows)
+    tokens, pools, counters, logits = rung_steps("ladder", rows)
+    whole_tokens, whole_pools, whole_counters, whole_logits = rung_steps(
+        "whole", rows)
+    ref_tokens, ref_pools, ref_counters, _ = rung_steps("reference", rows)
+    assert np.asarray(tokens)[rows].tolist() \
+        == np.asarray(ref_tokens)[rows].tolist() \
+        == np.asarray(whole_tokens)[rows].tolist()
+    np.testing.assert_array_equal(np.asarray(logits)[rows],
+                                  np.asarray(whole_logits)[rows])
+    # every page a table names (the dummy page, the last, takes the rows
+    # that are not live: what such a row computes is nobody's business)
+    for got, whole, ref in zip(pools, whole_pools, ref_pools):
+        np.testing.assert_array_equal(np.asarray(got[:, :-1], np.float32),
+                                      np.asarray(whole[:, :-1], np.float32))
+        np.testing.assert_array_equal(np.asarray(got[:, :-1], np.float32),
+                                      np.asarray(ref[:, :-1], np.float32))
+    for name in ("dsa.rows_live", "dsa.rows_selected", "moe.pairs_here",
+                 "moe.experts_hit"):
+        assert int(counters[name]) == int(whole_counters[name]) \
+            == int(ref_counters[name]), name
+    assert int(counters["dsa.rows_selected"]) == 16 * len(rows)
+    assert int(counters["dsa.rung_rows"]) == rung
+    # the yardsticks run the whole batch
+    assert int(whole_counters["dsa.rung_rows"]) \
+        == int(ref_counters["dsa.rung_rows"]) == RUNG_BATCH
+
+
+def _conditionals(jaxpr):
+    """The branch counts of every ``cond`` in a jaxpr, loops and calls
+    looked into, kernels' bodies (their ``pl.when``) not."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            found.append(len(eqn.params["branches"]))
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += _conditionals(sub)
+    return found
+
+
+@pytest.mark.parametrize("batch", [16, 8, 4, 2])
+def test_a_decode_step_holds_one_conditional_a_site_with_a_branch_a_rung(
+        params, batch, monkeypatch):
+    """Two sites call the sparse attention (the dense layer, the scan's
+    body). A batch with rungs below it holds one conditional at each,
+    with as many branches as ``_decode_rungs`` says; a batch no larger
+    than the smallest rung holds none, and ``attn="reference"`` never
+    does. Lowered for the chip (the kernels as Mosaic calls: interpreted,
+    their ``pl.when`` would be conditionals of the text too)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = family.program_config(TINY)
+    rungs = dsv32._decode_rungs(batch)
+    assert rungs == tuple(r for r in dsv32.DECODE_RUNGS if r < batch) \
+        + (batch,)
+    row = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    pool = jax.ShapeDtypeStruct((cfg["layers"], 9, 8, 128), jnp.bfloat16)
+    args = (params, (pool, pool), row, row,
+            jax.ShapeDtypeStruct((batch, 4), jnp.int32), row,
+            jax.ShapeDtypeStruct((batch,), bool))
+    for attn in ("paged", "reference"):
+        traced = jax.jit(dsv32.serve_decode(cfg, attn, 8, 8)).trace(*args)
+        want = [len(rungs)] * 2 if attn == "paged" and len(rungs) > 1 else []
+        assert _conditionals(traced.jaxpr.jaxpr) == want, attn
+        text = traced.lower(lowering_platforms=("tpu",)).as_text()
+        assert text.count("stablehlo.case") == len(want), attn
+        # a rung is traced and lowered once, whichever site calls it
+        assert len(set(re.findall(r"func\.func private @(_sparse_rows\w*)",
+                                  text))) \
+            == (len(rungs) if attn == "paged" else 0), attn
+
+
+def test_an_engine_builds_one_decode_program_whatever_rows_are_live(
+        params, chunks_of_16):
+    """An engine of 16 rows stepped with 1, 3, 5, 9 and 16 of them live
+    (every rung of the ladder): ONE program is lowered and compiled, in
+    the first decode step, and the later steps lower and compile nothing
+    (counted as the benchmark counts a window's); the rung is among the
+    step's counters."""
+    from benchmark.harness.compiles import CompileCounter
+
+    cfg = family.program_config(TINY)
+    engine = ServingEngine(params, cfg, max_batch=16, prompt_pad=64,
+                           num_blocks=16 * 9, block_size=8, attn="paged",
+                           model=dsv32, label="serve-dsv32-rungs")
+    rnd = np.random.RandomState(3)
+    reqs = [Request("r%d" % i, [int(t) for t in rnd.randint(0, 512, size=n)],
+                    max_new_tokens=8)
+            for i, n in enumerate(rnd.randint(20, 60, size=16))]
+    assert all(engine.admit(r) for r in reqs)
+
+    def step(n):
+        for req, (token, _) in zip(reqs[:n], engine.step_fn(reqs[:n])):
+            req.generated.append(token)
+
+    step(16)                    # the prefills, no decode row yet
+    counter = CompileCounter.get()
+    counter.mark()
+    rung = []
+    for n in (1, 3, 5, 9, 16):
+        step(n)
+        # (programs lowered, programs compiled): the step, once
+        assert counter.mark() == ((1, 1) if n == 1 else (0, 0)), n
+        rung.append(int(engine.times.samples("dsa.rung_rows")[-1].seconds))
+    ladder = dsv32._decode_rungs(16)
+    assert rung == [next(x for x in ladder if x >= n)
+                    for n in (1, 3, 5, 9, 16)]
+    assert "dsa.rung_rows" in engine._counters
 
 
 def test_buckets_are_eighths_of_the_prompt_pad_in_whole_chunks():

@@ -980,7 +980,7 @@ def test_the_packed_decode_step_is_the_models_hook(model, check):
         names = sorted(records[0]["hook_counters"])
         assert names == {
             "gpt": [], "axk1": ["moe.experts_hit", "moe.pairs_here"],
-            "dsv32": ["dsa.rows_live", "dsa.rows_selected",
+            "dsv32": ["dsa.rows_live", "dsa.rows_selected", "dsa.rung_rows",
                       "moe.experts_hit", "moe.pairs_here"],
             "evabyte": ["eva.rows_read", "eva.tokens_live",
                         "eva.windows_closed"]}[model]
