@@ -24,6 +24,11 @@ STEPS = int(os.environ.get("TPUJOB_STEPS", "100"))
 SP = int(os.environ.get("TPUJOB_SP", "1"))
 MOE = int(os.environ.get("TPUJOB_MOE_EXPERTS", "0"))
 
+# Rows a trip of the chunked LM-head loss sends through the head, so that the
+# [B,S,V] float32 logits (gigabytes at long context) never exist. Chosen on
+# the chip by ``train_tokens_per_s`` of ``gpt2-small.train-1k`` (PR 42's sweep).
+CE_CHUNK = 2048
+
 
 def build_job(total_steps: int = STEPS, batch: int = BATCH, seq: int = SEQ,
               config: dict = gpt.BASE_CONFIG) -> TrainJob:
@@ -39,17 +44,13 @@ def build_job(total_steps: int = STEPS, batch: int = BATCH, seq: int = SEQ,
     if MOE:
         cfg.update(moe_experts=MOE, moe_every=2)
 
-    # stream tokens through the LM head (never materialize [B,S,V] fp32
-    # logits — gigabytes at long context); 0 restores the dense path
-    ce_chunk = int(os.environ.get("TPUJOB_CE_CHUNK", "2048"))
-
     def loss_fn(p, b, mesh=None):
         attn = "auto"
         if mesh is not None and SP > 1 and "sp" in mesh.shape:
             attn = functools.partial(
                 ring_attention, mesh=mesh, axis="sp", causal=True)
         return gpt.loss_fn(p, b, remat=True, attn_impl=attn,
-                           ce_chunk=ce_chunk, mesh=mesh)
+                           ce_chunk=CE_CHUNK, mesh=mesh)
 
     return TrainJob(
         init_params=lambda rng: gpt.init(rng, cfg),

@@ -42,6 +42,7 @@ HTTP I/O happens outside the lock.
 
 from __future__ import annotations
 
+import fcntl
 import itertools
 import json
 import logging
@@ -262,55 +263,93 @@ class ArtifactStore:
         payload = json.dumps({"holder": self._token,
                               "deadline": time.time() + self.lease_ttl_s}
                              ).encode()
-        for _ in range(2):
-            try:
-                os.makedirs(self.local_dir, exist_ok=True)
-                fd = os.open(path, os.O_CREAT | os.O_EXCL | os.O_WRONLY)
+        lock = None
+        try:
+            for _ in range(2):
                 try:
-                    os.write(fd, payload)
-                finally:
-                    os.close(fd)
-                return True
-            except FileExistsError:
-                if not self._local_lease_expired(path):
-                    return False
-                # the holder died (or wedged past its TTL): break the
-                # lease ATOMICALLY by renaming the inode aside — the
-                # source vanishes for every other breaker, so exactly
-                # one rename succeeds (a bare remove+create would let
-                # breaker B's remove delete the lease breaker A just
-                # freshly created — two "granted" holders)
-                stale = "%s.stale.%d.%d" % (path, os.getpid(),
-                                            next(_token_counter))
-                try:
-                    os.rename(path, stale)
-                except OSError:
-                    return False  # someone else broke it; they hold it
-                if not self._local_lease_expired(stale):
-                    # we stole a LIVE lease: our expired-check read the
-                    # dead holder's file, but a peer broke it and
-                    # created a fresh one before our rename landed —
-                    # restore it (os.link never overwrites, so an even
-                    # newer lease at path wins) and report "held"
+                    os.makedirs(self.local_dir, exist_ok=True)
+                    # the lease appears at its path WITH its deadline:
+                    # written under a name of our own and linked into
+                    # place (os.link never overwrites, so it arbitrates
+                    # as O_EXCL would). A lease created empty and filled
+                    # afterwards reads as torn, hence dead, to a peer
+                    # that looks in between, which then breaks a LIVE
+                    # lease and is granted it as well
+                    fresh = "%s.fresh.%d.%d" % (path, os.getpid(),
+                                                next(_token_counter))
+                    fd = os.open(fresh,
+                                 os.O_CREAT | os.O_EXCL | os.O_WRONLY)
                     try:
-                        os.link(stale, path)
+                        try:
+                            os.write(fd, payload)
+                        finally:
+                            os.close(fd)
+                        os.link(fresh, path)
+                    finally:
+                        os.remove(fresh)
+                    return True
+                except FileExistsError:
+                    if not self._local_lease_expired(path):
+                        return False
+                    if lock is None:
+                        # ONE breaker at a time, from here to our own
+                        # lease's link at the loop's top (the kernel
+                        # drops a dead breaker's flock). Unserialised, a
+                        # breaker whose expired-check read the dead
+                        # lease renames aside the LIVE one a faster peer
+                        # has put there since; it puts it back, but
+                        # while the path is empty a third acquirer links
+                        # in and two hold the lease. Where the volume
+                        # has no flock the checks below still stand
+                        lock = os.open(self.local_dir, os.O_RDONLY)
+                        try:
+                            fcntl.flock(lock, fcntl.LOCK_EX)
+                        except OSError:
+                            pass
+                        if not os.path.exists(path):
+                            continue  # released meanwhile: link at the top
+                        if not self._local_lease_expired(path):
+                            return False  # a peer broke it; they hold it
+                    # the holder died (or wedged past its TTL): break the
+                    # lease ATOMICALLY by renaming the inode aside — the
+                    # source vanishes for every other breaker, so exactly
+                    # one rename succeeds (a bare remove+create would let
+                    # breaker B's remove delete the lease breaker A just
+                    # freshly created — two "granted" holders)
+                    stale = "%s.stale.%d.%d" % (path, os.getpid(),
+                                                next(_token_counter))
+                    try:
+                        os.rename(path, stale)
                     except OSError:
-                        pass
+                        return False  # someone else broke it; they hold it
+                    if not self._local_lease_expired(stale):
+                        # we stole a LIVE lease: our expired-check read the
+                        # dead holder's file, but a peer broke it and
+                        # created a fresh one before our rename landed —
+                        # restore it (os.link never overwrites, so an even
+                        # newer lease at path wins) and report "held"
+                        try:
+                            os.link(stale, path)
+                        except OSError:
+                            pass
+                        try:
+                            os.remove(stale)
+                        except OSError:
+                            pass
+                        return False
+                    self._bump("lease_broken")
                     try:
                         os.remove(stale)
                     except OSError:
                         pass
-                    return False
-                self._bump("lease_broken")
-                try:
-                    os.remove(stale)
+                    # loop: retry the exclusive create (another FRESH
+                    # acquirer may still beat us — os.link arbitrates)
                 except OSError:
-                    pass
-                # loop: retry the exclusive create (another FRESH
-                # acquirer may still beat us — O_EXCL arbitrates)
-            except OSError:
-                return False  # unwritable store: no singleflight, no wedge
-        return False
+                    return False  # unwritable store: no singleflight, no wedge
+            return False
+        finally:
+            if lock is not None:
+                os.close(lock)
 
     @staticmethod
     def _local_lease_expired(path: str) -> bool:

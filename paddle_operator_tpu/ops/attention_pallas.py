@@ -517,8 +517,8 @@ def _flash_plan(q, block_q, block_k, causal):
 
     seq, head_dim = q.shape[2], q.shape[3]
     operand = jnp.dtype(_operand_dtype(q.dtype)).name
-    block_q = block_q or _auto_block(seq, "q")
-    block_k = block_k or _auto_block(seq, "k")
+    block_q = block_q or _auto_block(seq)
+    block_k = block_k or _auto_block(seq)
     _check_blocks(q.shape, block_q, block_k)
     plan = (seq, head_dim, operand, block_q, block_k, bool(causal))
     if tracer().enabled and plan not in _plans_seen:
@@ -530,23 +530,7 @@ def _flash_plan(q, block_q, block_k, causal):
     return block_q, block_k
 
 
-_warned_overrides = set()
-
-
-def _warn_block_override_once(which, env, seq):
-    key = (which, env, seq)
-    if key in _warned_overrides:
-        return
-    _warned_overrides.add(key)
-    import logging
-
-    logging.getLogger("tpujob.attention").warning(
-        "TPUJOB_FLASH_BLOCK_%s=%r ignored for seq=%d (must be a "
-        "%d-multiple that divides the sequence); using auto block",
-        which.upper(), env, seq, MIN_BLOCK)
-
-
-def _auto_block(seq: int, which: str = "q") -> int:
+def _auto_block(seq: int) -> int:
     """Largest tile of the ladder that divides the sequence, 512 first,
     for ``block_q`` and ``block_k`` alike. The rule rests on a sweep of
     every pair of 256 / 512 / 1024 / 2048 on one v5e chip (PR 40; causal,
@@ -568,23 +552,7 @@ def _auto_block(seq: int, which: str = "q") -> int:
     above the diagonal but pays the online-softmax bookkeeping once more a
     row, a larger one the reverse, and a side of 2048 is the slowest
     where it fits the fast memory at all (9 of its 14 pairs did not).
-    256 and 128 are what is left where 512 does not divide.
-
-    ``TPUJOB_FLASH_BLOCK_Q`` / ``TPUJOB_FLASH_BLOCK_K`` override the
-    auto choice fleet-wide (still subject to divisibility)."""
-    import os
-
-    env = os.environ.get("TPUJOB_FLASH_BLOCK_" + which.upper())
-    if env:
-        try:
-            b = int(env)
-        except ValueError:
-            b = -1
-        if b >= MIN_BLOCK and b % MIN_BLOCK == 0 and seq % b == 0:
-            return b
-        # a typo must not break training, but a silently-discarded
-        # override would make a deployed sweep config an invisible no-op
-        _warn_block_override_once(which, env, seq)
+    256 and 128 are what is left where 512 does not divide."""
     for b in (512, 256, 128):
         if seq % b == 0:
             return b

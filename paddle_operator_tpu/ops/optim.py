@@ -7,13 +7,10 @@ since params are plain pytrees.
 
 from __future__ import annotations
 
-import functools
 from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
 class Optimizer(NamedTuple):
@@ -269,145 +266,3 @@ def clip_by_global_norm(tree, max_norm: float):
     norm = global_norm(tree)
     scale = jnp.minimum(1.0, max_norm / (norm + 1e-6))
     return _tree_map(lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype), tree), norm
-
-
-# ---------------------------------------------------------------------------
-# fused optimizer update (Pallas)
-# ---------------------------------------------------------------------------
-
-# flattened parameter rows are tiled [rows, 128]; row blocks per kernel cell
-_FUSED_LANE = 128
-_FUSED_BLOCK_ROWS = 256
-
-
-def _fused_sgd_kernel(lr_ref, p_ref, g_ref, m_ref, wd_ref, pout_ref,
-                      mout_ref, *, momentum, weight_decay, nesterov):
-    """decay + momentum + parameter update, one fused pass over a
-    [block_rows, 128] tile. Mirrors sgd()'s per-leaf `upd` op-for-op (same
-    fp32 order) so the two paths are bit-identical."""
-    lr = lr_ref[0, 0]
-    g = g_ref[...].astype(jnp.float32)
-    p32 = p_ref[...].astype(jnp.float32)
-    m = m_ref[...].astype(jnp.float32)
-    if weight_decay:
-        g = g + (weight_decay * wd_ref[...]) * p32
-    m_new = momentum * m + g
-    d = g + momentum * m_new if nesterov else m_new
-    pout_ref[...] = (p32 - lr * d).astype(pout_ref.dtype)
-    mout_ref[...] = m_new
-
-
-def _flatten_rows(leaves, pad_rows):
-    """Concatenate leaves into one [rows, 128] tile-able buffer."""
-    flat = jnp.concatenate([l.reshape(-1) for l in leaves])
-    n = flat.shape[0]
-    cols = _FUSED_LANE
-    rows = -(-n // cols)
-    rows = -(-rows // pad_rows) * pad_rows
-    flat = jnp.pad(flat, (0, rows * cols - n))
-    return flat.reshape(rows, cols), n
-
-
-def _unflatten_rows(buf, n, shapes, sizes):
-    flat = buf.reshape(-1)[:n]
-    out, off = [], 0
-    for shape, size in zip(shapes, sizes):
-        out.append(flat[off:off + size].reshape(shape))
-        off += size
-    return out
-
-
-def fused_sgd(lr, momentum: float = 0.9, weight_decay: float = 0.0,
-              nesterov: bool = False, wd_mask=None,
-              block_rows: int = _FUSED_BLOCK_ROWS,
-              interpret: bool = False) -> Optimizer:
-    """SGD with the whole update — weight decay, momentum, parameter
-    write — fused into ONE Pallas kernel over the concatenated parameter
-    buffer, instead of a pytree of per-leaf elementwise ops (hundreds of
-    small HBM round trips for a ResNet). State layout matches :func:`sgd`
-    exactly (checkpoints are interchangeable) and numerics match op-for-op
-    — the compiler may fuse ``a·b + c`` chains (momentum accumulate,
-    decay, the parameter write) into FMAs the eager reference rounds
-    twice, so equivalence is within 1–2 ulp; the first-step momentum
-    (``0.9·0 + g``) is exact under either rounding and stays bitwise
-    equal (asserted by ``tests/test_fused_ops.py``).
-
-    Non-fp32 and mixed-dtype parameter trees fall back to the reference
-    update transparently: the fused path needs one homogeneous buffer,
-    and for low-precision params the reference's weak-typed
-    ``weight_decay * p`` rounds to the param dtype where the kernel
-    stays fp32 — a semantic difference, not rounding noise.
-    """
-    lr_fn = lr if callable(lr) else (lambda step: lr)
-    reference = sgd(lr, momentum=momentum, weight_decay=weight_decay,
-                    nesterov=nesterov, wd_mask=wd_mask)
-
-    def init(params):
-        return reference.init(params)
-
-    def update(grads, state, params):
-        import numpy as np
-
-        p_leaves, treedef = jax.tree_util.tree_flatten(params)
-        g_leaves = treedef.flatten_up_to(grads)
-        m_leaves = treedef.flatten_up_to(state["momentum"])
-        # fused path covers the fp32-master-params regime only: for
-        # low-precision params the reference rounds `weight_decay * p`
-        # to the param dtype (weak promotion) where the kernel would
-        # keep fp32 — a real numeric difference, not ulp noise — and a
-        # mixed tree cannot share one buffer at all. Both fall back.
-        if ({l.dtype for l in p_leaves} != {np.dtype(np.float32)}
-                or len({l.dtype for l in g_leaves}) != 1
-                or len({l.dtype for l in m_leaves}) != 1):
-            return reference.update(grads, state, params)
-
-        step = state["step"] + 1
-        lr_t = jnp.asarray(lr_fn(step), jnp.float32).reshape(1, 1)
-        shapes = [l.shape for l in p_leaves]
-        sizes = [int(np.prod(s)) if s else 1 for s in shapes]
-
-        pbuf, n = _flatten_rows(p_leaves, block_rows)
-        gbuf, _ = _flatten_rows(g_leaves, block_rows)
-        mbuf, _ = _flatten_rows(m_leaves, block_rows)
-        # per-element weight-decay flags: constant-folded (mask is static)
-        if wd_mask is not None:
-            flags = np.concatenate([
-                np.full(size, 1.0 if on else 0.0, np.float32)
-                for size, on in zip(
-                    sizes, jax.tree_util.tree_leaves(wd_mask))])
-        else:
-            flags = np.ones(sum(sizes), np.float32)
-        rows = pbuf.shape[0]
-        flags = np.pad(flags, (0, rows * _FUSED_LANE - flags.shape[0]))
-        wdbuf = jnp.asarray(flags.reshape(rows, _FUSED_LANE))
-
-        pout, mout = pl.pallas_call(
-            functools.partial(
-                _fused_sgd_kernel, momentum=momentum,
-                weight_decay=weight_decay, nesterov=nesterov),
-            grid=(rows // block_rows,),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec((block_rows, _FUSED_LANE), lambda r: (r, 0)),
-                pl.BlockSpec((block_rows, _FUSED_LANE), lambda r: (r, 0)),
-                pl.BlockSpec((block_rows, _FUSED_LANE), lambda r: (r, 0)),
-                pl.BlockSpec((block_rows, _FUSED_LANE), lambda r: (r, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((block_rows, _FUSED_LANE), lambda r: (r, 0)),
-                pl.BlockSpec((block_rows, _FUSED_LANE), lambda r: (r, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((rows, _FUSED_LANE),
-                                     p_leaves[0].dtype),
-                jax.ShapeDtypeStruct((rows, _FUSED_LANE), jnp.float32),
-            ],
-            interpret=interpret,
-        )(lr_t, pbuf, gbuf, mbuf, wdbuf)
-
-        new_params = treedef.unflatten(
-            _unflatten_rows(pout, n, shapes, sizes))
-        new_m = treedef.unflatten(_unflatten_rows(mout, n, shapes, sizes))
-        return new_params, {"step": step, "momentum": new_m}
-
-    return Optimizer(init, update)

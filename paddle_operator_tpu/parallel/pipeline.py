@@ -16,7 +16,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def stack_stage_params(per_stage_params):
@@ -98,10 +98,3 @@ def pipeline_apply(
     out = run(stage_params, xs)
     return out.reshape(batch, *x.shape[1:])
 
-
-def shard_stacked_params(stage_params, mesh: Mesh, axis: str = "pp"):
-    """Place stacked stage params with leading axis sharded over `axis`."""
-    return jax.tree_util.tree_map(
-        lambda leaf: jax.device_put(leaf, NamedSharding(mesh, P(axis))),
-        stage_params,
-    )

@@ -307,13 +307,3 @@ def _globalize_batches(step_fn, batch_sh, host_local):
     wrapped.compile_seconds = getattr(step_fn, "compile_seconds", 0.0)
     return wrapped
 
-
-def build_eval_step(loss_fn: Callable, mesh: Optional[Mesh] = None):
-    def evaluate(params, batch):
-        loss, aux = loss_fn(params, batch)
-        out = {"loss": loss}
-        if isinstance(aux, dict):
-            out.update({k: v for k, v in aux.items() if k != "stats"})
-        return out
-
-    return jax.jit(evaluate)

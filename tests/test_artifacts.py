@@ -241,6 +241,35 @@ class TestLeaseProtocol:
         for lease in grants:
             lease.release()
 
+    def test_a_lease_being_written_is_not_broken(self, local_store,
+                                                 monkeypatch):
+        """A peer that looks at the lease WHILE its holder writes the
+        deadline (from inside the holder's ``os.write``) must not find a
+        file it reads as torn, hence dead: it would rename a LIVE lease
+        aside, take it, and both would be granted."""
+        from paddle_operator_tpu.artifacts import store as store_module
+
+        first, second = self._store(local_store), self._store(local_store)
+        leases = {}
+        write = os.write
+
+        def write_with_a_peer_looking(fd, data):
+            if "second" not in leases:
+                leases["second"] = None      # the peer's own write: plain
+                leases["second"] = second.acquire_compile_lease(FP)
+            return write(fd, data)
+
+        monkeypatch.setattr(store_module.os, "write",
+                            write_with_a_peer_looking)
+        leases["first"] = first.acquire_compile_lease(FP)
+        assert sum(lease.granted for lease in leases.values()) == 1
+        assert first.stats()["lease_broken"] == 0
+        assert second.stats()["lease_broken"] == 0
+        assert second.lease_state(FP) == "held"
+        for lease in leases.values():
+            lease.release()
+        assert second.lease_state(FP) == "free"
+
     def test_wait_fetch_returns_on_publish(self, local_store):
         s = self._store(local_store)
         holder = self._store(local_store)

@@ -4,6 +4,7 @@ sequence-parallel integration, training convergence."""
 import functools
 import logging
 import math
+import os
 import re
 
 import jax
@@ -160,6 +161,32 @@ def test_moe_variant_trains():
     state, m = step(state, batch)
     assert np.isfinite(float(m["loss"]))
     assert float(m["moe_aux"]) > 0
+
+
+@pytest.mark.parametrize("config", ["TINY_CONFIG", "TINY_MOE_CONFIG"])
+def test_the_examples_job_trains_a_step_in_chunks_of_ce_chunk(
+        config, caplog, monkeypatch):
+    """``examples/train_gpt.build_job`` is what the three GPT cells train
+    through: one step of ITS loss, optimizer, rules and clip is finite,
+    and the head's loss ran in chunks of the example's ``CE_CHUNK`` rows
+    (9 x 255 rows: two chunks, the second padded)."""
+    monkeypatch.syspath_prepend(os.path.join(
+        os.path.dirname(os.path.dirname(__file__)), "examples"))
+    import train_gpt
+
+    job = train_gpt.build_job(
+        total_steps=10, batch=9, seq=256, config=getattr(gpt, config))
+    batch = job.make_batch(KEY, 0)
+    assert batch["input_ids"].shape == (9, 256)
+    step, state = build_train_step(
+        job.loss_fn, job.optimizer, job.init_params(KEY), batch,
+        rules=job.rules, grad_clip=job.grad_clip, cache=False)
+    with caplog.at_level(logging.INFO, logger="tpujob.nn"):
+        state, metrics = step(state, batch)
+    assert np.isfinite(float(metrics["loss"]))
+    assert (float(metrics["moe_aux"]) > 0) == (config == "TINY_MOE_CONFIG")
+    assert ("chunked_lm_xent: unsharded, 2 chunks of %d: gradients taken "
+            "in the forward loop" % train_gpt.CE_CHUNK) in caplog.text
 
 
 def test_runner_passes_mesh_to_loss_fn():

@@ -2,6 +2,7 @@
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from paddle_operator_tpu.models import bert, deepfm, resnet, wide_deep
@@ -113,6 +114,68 @@ def test_sgd_momentum_quadratic():
         grads = jax.grad(lambda pp: jnp.sum(pp["w"] ** 2))(p)
         p, state = opt.update(grads, state, p)
     assert float(jnp.abs(p["w"]).max()) < 0.05
+
+
+def _sgd_tree(mixed=False):
+    p = {"w": jax.random.normal(KEY, (300, 7), jnp.float32),
+         "b": jnp.ones((13,), jnp.bfloat16 if mixed else jnp.float32),
+         "scalar": jnp.asarray(2.0, jnp.float32)}
+    g = jax.tree_util.tree_map(lambda l: (l * 0.01 + 0.001).astype(l.dtype), p)
+    return p, g
+
+
+@pytest.mark.parametrize("case", [
+    "first-step", "three-steps", "nesterov", "callable-lr", "mixed-dtypes"])
+def test_sgd_matches_its_closed_form(case):
+    """``optim.sgd`` a leaf at a time == ``m <- mu m + g``, ``p <- p - lr d``
+    with ``d = m`` (``g + mu m`` under Nesterov) in numpy float64, within
+    4 ulp of the leaf's largest value in the leaf's own type."""
+    steps, nesterov, lr = {
+        "first-step": (1, False, 0.1), "three-steps": (3, False, 0.1),
+        "nesterov": (3, True, 0.1), "mixed-dtypes": (1, False, 0.1),
+        # the step the schedule is asked about counts from 1
+        "callable-lr": (3, False, lambda step: 0.1 / step),
+    }[case]
+    p, g = _sgd_tree(mixed=case == "mixed-dtypes")
+    opt = optim.sgd(lr, momentum=0.9, nesterov=nesterov)
+    state = opt.init(p)
+    as64 = lambda tree: {k: np.asarray(v, np.float64) for k, v in tree.items()}
+    ref_p, ref_g = as64(p), as64(g)
+    ref_m = {k: np.zeros_like(v) for k, v in ref_p.items()}
+    for step in range(1, steps + 1):
+        p, state = opt.update(g, state, p)
+        for k in ref_p:
+            ref_m[k] = 0.9 * ref_m[k] + ref_g[k]
+            d = ref_g[k] + 0.9 * ref_m[k] if nesterov else ref_m[k]
+            ref_p[k] = ref_p[k] - (lr(step) if callable(lr) else lr) * d
+    assert int(state["step"]) == steps
+    for k, ref in ref_p.items():
+        assert p[k].dtype == g[k].dtype, k
+        eps = float(jnp.finfo(p[k].dtype).eps)
+        for got, want in ((p[k], ref), (state["momentum"][k], ref_m[k])):
+            np.testing.assert_allclose(
+                np.asarray(got, np.float64), want, rtol=0,
+                atol=4 * eps * np.abs(want).max())
+    if case == "first-step":
+        # 0.9 * 0 + g is g under any rounding
+        for k in g:
+            assert (np.asarray(state["momentum"][k]) == np.asarray(g[k])).all()
+
+
+def test_sgd_state_is_laid_out_as_the_parameters():
+    """What a checkpoint holds of SGD: a step count and one momentum leaf
+    a parameter leaf, same tree, same shapes, before and after a step."""
+    p, g = _sgd_tree()
+    opt = optim.sgd(0.1, momentum=0.9)
+    state = opt.init(p)
+    assert set(state) == {"step", "momentum"}
+    assert state["step"].dtype == jnp.int32 and int(state["step"]) == 0
+    new_p, new_state = opt.update(g, state, p)
+    shapes = lambda tree: jax.tree_util.tree_map(
+        lambda l: (l.shape, l.dtype), tree)
+    assert shapes(state["momentum"]) == shapes(p) == shapes(new_p)
+    assert shapes(new_state["momentum"]) == shapes(p)
+    assert set(new_state) == {"step", "momentum"}
 
 
 def test_cosine_schedule_endpoints():

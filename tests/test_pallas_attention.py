@@ -70,14 +70,10 @@ def test_mha_flash_impl_matches_einsum():
     assert jnp.allclose(y_einsum, y_flash, atol=2e-4)
 
 
-def test_auto_block_selection_matches_small_blocks(monkeypatch):
+def test_auto_block_selection_matches_small_blocks():
     """Default (auto) block sizes must compute the same attention as
     explicit 128-blocks, and pick the 512 tile for long sequences."""
     from paddle_operator_tpu.ops.attention_pallas import _auto_block
-
-    # the env override must not leak into the auto assertions below
-    monkeypatch.delenv("TPUJOB_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("TPUJOB_FLASH_BLOCK_K", raising=False)
 
     assert _auto_block(4096) == 512
     assert _auto_block(512) == 512
@@ -93,24 +89,6 @@ def test_auto_block_selection_matches_small_blocks(monkeypatch):
                                block_q=128, block_k=128)
     assert jnp.allclose(auto.astype(jnp.float32),
                         explicit.astype(jnp.float32), atol=2e-2)
-
-
-def test_block_env_override(monkeypatch):
-    """TPUJOB_FLASH_BLOCK_Q/K deploy a sweep-found block config without a
-    code change; invalid/non-dividing values fall back to auto."""
-    from paddle_operator_tpu.ops.attention_pallas import _auto_block
-
-    monkeypatch.setenv("TPUJOB_FLASH_BLOCK_Q", "256")
-    monkeypatch.setenv("TPUJOB_FLASH_BLOCK_K", "1024")
-    assert _auto_block(4096, "q") == 256
-    assert _auto_block(4096, "k") == 1024
-    # doesn't divide the sequence: auto wins
-    assert _auto_block(384, "q") == 128
-    # garbage / sub-minimum: auto wins, never raises
-    monkeypatch.setenv("TPUJOB_FLASH_BLOCK_Q", "banana")
-    assert _auto_block(4096, "q") == 512
-    monkeypatch.setenv("TPUJOB_FLASH_BLOCK_Q", "64")
-    assert _auto_block(4096, "q") == 512
 
 
 # ---------------------------------------------------------------------------
@@ -159,8 +137,8 @@ def test_bf16_cases_cover_both_bodies_and_both_forms():
 
     counts = set()
     for causal, seq, d, bq, bk in _bf16_cases():
-        bq = bq or _auto_block(seq, "q")
-        bk = bk or _auto_block(seq, "k")
+        bq = bq or _auto_block(seq)
+        bk = bk or _auto_block(seq)
         counts.add(_tile_counts(seq, bq, bk, causal))
     assert (1, 1) in counts                       # every tile on the diagonal
     assert any(masked == 0 for _, masked in counts)             # none
@@ -280,8 +258,6 @@ def test_flash_plan_is_emitted_once_a_plan(monkeypatch):
     from paddle_operator_tpu.ops import attention_pallas as ap
     from paddle_operator_tpu.utils import trace
 
-    monkeypatch.delenv("TPUJOB_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("TPUJOB_FLASH_BLOCK_K", raising=False)
     monkeypatch.setattr(trace, "_global", trace.Tracer(enabled=True))
     monkeypatch.setattr(ap, "_plans_seen", set())
 
@@ -295,8 +271,7 @@ def test_flash_plan_is_emitted_once_a_plan(monkeypatch):
     jax.eval_shape(call, q, q, q)
     jax.eval_shape(functools.partial(ap.flash_attention_lse, causal=True),
                    q, q, q)
-    bq = ap._auto_block(1024, "q")
-    bk = ap._auto_block(1024, "k")
+    bq = bk = ap._auto_block(1024)
     live, masked = ap._tile_counts(1024, bq, bk, True)
     assert plans() == [dict(seq=1024, head_dim=64, operand="bfloat16",
                             block_q=bq, block_k=bk, tiles_live=live,
