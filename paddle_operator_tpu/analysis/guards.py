@@ -123,6 +123,8 @@ SPECS: Tuple[GuardSpec, ...] = (
     GuardSpec("paddle_operator_tpu.serving.kv_cache", "KvBlockAllocator",
               "_lock",
               ("_free", "_tables", "_lens", "_reserved", "_peak_used")),
+    GuardSpec("paddle_operator_tpu.serving.kv_cache", "SlotBlockAllocator",
+              "_slot_lock", ("_slots_free", "_slot_of")),
     GuardSpec("paddle_operator_tpu.serving.metrics", "ServeMetrics",
               "_lock",
               ("_requests", "_tokens", "_queue_depth", "_replicas",

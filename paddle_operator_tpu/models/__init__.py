@@ -13,6 +13,10 @@
 * ouro — a stack of layers run several times over with the same weights,
   a key-value cache of its own for every loop step, an exit gate
   (serving only; imported where it is served, as evabyte is)
+* minicpm_sala — layers of two kinds in a published order: lightning
+  linear attention (one fixed-size state a sequence) beside block-sparse
+  grouped-query attention (the top blocks of a paged cache read in place)
+  (serving only; imported where it is served)
 
 All models are (init, apply) pure functions over dict pytrees, bf16 compute,
 built from `paddle_operator_tpu.ops.nn`.

@@ -1172,3 +1172,254 @@ def mla_selected_decode(q_lat, q_rope, pages, block_tables, chosen, count,
         q_lat, q_rope, rows.reshape(b * per_seq, block_size, width),
         jnp.arange(b * per_seq, dtype=jnp.int32).reshape(b, per_seq),
         count, scale, interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# block-sparse grouped-query decode (InfLLM-v2; ``models.minicpm_sala``)
+# ---------------------------------------------------------------------------
+#
+# A few key/value heads serve many query heads (a GROUP of R query heads
+# a key/value head), and a decode row attends over a SELECTION of blocks
+# of ``block`` tokens, one selection a (row, key/value head): the blocks
+# are scored on compressed keys (one row a ``stride`` tokens: the mean of
+# a window of two strides, kept at the row of the stride it ENDS with),
+# a few are forced, the top ``topk`` are read. The kernel's grid walks
+# (row, key/value head, selected block); a cell's page IS
+# ``pool[layer, page_of[row, head, j]]`` through the scalar-prefetched
+# list, cut to the block's rows and the head's 128 lanes by the block
+# spec, so a selected block is read where it lies and no gathered copy is
+# made (``mla_selected_decode``'s gather costs more than its kernels:
+# PERF.md section 3). The group's R query heads are the query tile: one
+# [R, D] x [D, block] product and one [R, block] x [block, D] a cell.
+
+
+def gqa_block_scores(q, ckeys, lens, per_block: int, stride: int, scale):
+    """Block scores of a decode row: q ``[B, G, R, D]``, ckeys ``[B, J,
+    G, D]`` (row ``r`` the window of two strides that ends with stride
+    ``r``; row 0 none; ``[J, G, D]`` where every row reads the same
+    sequence, as a prefill's queries do), lens ``[B]`` the tokens a row
+    holds, the new one counted -> ``[B, G, J // per_block]`` float32, 0
+    and up.
+
+    A head's softmax runs over the rows whose window is complete (``r >=
+    1`` and ``stride (r + 1) <= lens``); the group's heads are summed;
+    block ``m`` (``per_block`` strides) takes the largest of rows
+    ``per_block m .. per_block (m + 1)``: the windows that overlap it (a
+    max-pool of width ``per_block + 1``, stride ``per_block``, padding 1
+    in the windows' own numbering). bfloat16 operands, float32 sums."""
+    b, j = q.shape[0], ckeys.shape[-3]
+    s = jnp.einsum("bgrd,jgd->bgrj" if ckeys.ndim == 3 else "bgrd,bjgd->bgrj",
+                   q.astype(jnp.bfloat16), ckeys.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32) * scale
+    r = jnp.arange(j)
+    valid = ((r >= 1)[None, :]
+             & (stride * (r + 1)[None, :] <= lens[:, None]))[:, None, None]
+    p = jnp.sum(jax.nn.softmax(jnp.where(valid, s, NEG_INF), axis=-1)
+                * valid, axis=2)                              # [B, G, J]
+    own = p.reshape(b, -1, j // per_block, per_block).max(-1)
+    after = jnp.concatenate(
+        [p[..., per_block::per_block], jnp.zeros_like(p[..., :1])], axis=-1)
+    return jnp.maximum(own, after)
+
+
+def select_blocks(scores, lens, block: int, topk: int, init_blocks: int,
+                  local_blocks: int, dense_len: int):
+    """scores ``[B, G, NB]`` (0 and up), lens ``[B]`` -> (chosen ``[B, G,
+    width]`` int32 block ids, count ``[B, G]``: the first ``count`` of a
+    list are read), ``width`` = the larger of ``topk`` and the blocks of
+    a dense context.
+
+    A row of fewer than ``dense_len`` tokens reads every block it has,
+    in order. Any other reads ``topk``: the first ``init_blocks`` and the
+    ``local_blocks`` that end with its newest token's own are forced
+    (scored +inf) and COUNT among the ``topk``; the rest by score, equal
+    scores to the lower block (``lax.top_k`` is stable). A pad row
+    (``lens`` 0) reads nothing."""
+    b, g, nb = scores.shape
+    own = (lens - 1) // block                         # -1 for a pad row
+    m = jnp.arange(nb)[None, :]
+    forced = (m < init_blocks) | (m > own[:, None] - local_blocks)
+    ranked = jnp.where((m <= own[:, None])[:, None],
+                       jnp.where(forced[:, None], jnp.inf, scores), -jnp.inf)
+    top = min(topk, nb)
+    width = min(max(top, -(-dense_len // block)), nb)
+    _, idx = jax.lax.top_k(ranked, top)
+    idx = jnp.pad(idx.astype(jnp.int32), ((0, 0), (0, 0), (0, width - top)))
+    dense = (lens < dense_len)[:, None, None]
+    chosen = jnp.where(dense, jnp.arange(width, dtype=jnp.int32), idx)
+    count = jnp.where(dense[..., 0], jnp.minimum(own + 1, width)[:, None],
+                      jnp.minimum(top, own + 1)[:, None])
+    return chosen, jnp.broadcast_to(jnp.maximum(count, 0), (b, g)
+                                    ).astype(jnp.int32)
+
+
+def _reference_gqa_block_decode(q, k_pages, v_pages, block_tables, chosen,
+                                count, lens, layer, block: int, scale):
+    """Gather-then-einsum reference of :func:`gqa_block_decode`: the
+    same arguments -> ``[B, G, R, D]`` float32 (zeros for a row that
+    reads nothing)."""
+    b, g, r, d = q.shape
+    page_size = k_pages.shape[2]
+    width = chosen.shape[-1]
+    pos = (chosen[..., None] * block + jnp.arange(block)).reshape(b, g, -1)
+    page = jnp.take_along_axis(block_tables[:, None, :], pos // page_size,
+                               axis=2)
+    seen = (jnp.repeat(jnp.arange(width)[None, None] < count[..., None],
+                       block, axis=-1) & (pos < lens[:, None, None]))
+
+    def rows(pool):                  # [B, G, N, D]: head g's own lanes
+        got = pool[layer, page, pos % page_size].astype(jnp.float32)
+        return jnp.stack([got[:, i, :, i * d:(i + 1) * d]
+                          for i in range(g)], axis=1)
+
+    s = jnp.einsum("bgrd,bgnd->bgrn", q.astype(jnp.float32),
+                   rows(k_pages)) * scale
+    p = jax.nn.softmax(jnp.where(seen[:, :, None], s, NEG_INF), axis=-1)
+    out = jnp.einsum("bgrn,bgnd->bgrd", p, rows(v_pages))
+    return jnp.where((count > 0)[..., None, None], out, 0.0)
+
+
+def _gqa_block_decode_kernel(lens_ref, count_ref, page_ref, block_ref,
+                             layer_ref, q_ref, k_ref, v_ref, zeros_ref,
+                             o_ref, acc_ref, m_ref, l_ref, *, scale, block,
+                             width, groups, operand_dtype, precision):
+    """One (row, key/value head, selected block) cell: the group's R
+    query heads against the block's tokens on that head's lanes, folded
+    into the running softmax in scratch. Float32 statistics; operands
+    the pool's type."""
+    del page_ref, layer_ref             # read by the index maps
+    del zeros_ref                       # what o_ref starts as
+    b, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n = lens_ref[b]
+    c = count_ref[b * groups + g]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(j < c)
+    def _block():
+        q = q_ref[0, 0].astype(operand_dtype)                # [R, D]
+        k = k_ref[0, 0].astype(operand_dtype)                # [block, D]
+        v = v_ref[0, 0].astype(operand_dtype)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32) * scale      # [R, block]
+        pos = block_ref[b, g * width + j] * block \
+            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < n, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a block wholly past the row's tokens (none is selected) would
+        # leave m at NEG_INF and exp(0) = 1 for every masked score
+        p = jnp.where(pos < n, jnp.exp(s - m_new), 0.0)
+        correction = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * correction \
+            + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
+            p.astype(operand_dtype), v, (((1,), (0,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _write():
+        l = l_ref[...]
+        o_ref[0, 0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
+                       ).astype(o_ref.dtype)
+
+
+def gqa_block_decode(q, k_pages, v_pages, block_tables, chosen, count, lens,
+                     layer, block: int, scale=None, interpret: bool = False):
+    """Single-token grouped-query attention over SELECTED blocks of a
+    paged cache, read in place.
+
+    q: ``[B, G, R, D]`` (G key/value heads, R query heads a group) —
+    k_pages / v_pages: ``[L, P, page_size, W]``, a token's G heads of D
+    side by side on the lanes, ``page_size`` whole blocks of ``block``
+    tokens — block_tables ``[B, T]`` a row's pages in order — chosen
+    ``[B, G, width]`` the block ids a (row, head) reads, the first
+    ``count`` ``[B, G]`` of them (:func:`select_blocks`) — lens ``[B]``
+    the tokens a row holds (a selected block's tokens past them are
+    masked; 0 for a pad row, which reads nothing and gets zeros) —
+    layer: an int32 scalar, traced or not. -> ``[B, G, R, D]`` float32.
+
+    The grid is (rows, G, blocks) and ends at the last live row and at
+    the longest list; a cell past its own list's end repeats that list's
+    last block (the pipeline fetches nothing new) and skips its body.
+    Inference only. Numerics match :func:`_reference_gqa_block_decode`
+    to the online softmax's reassociation."""
+    b, g, r, d = q.shape
+    if k_pages.ndim != 4 or v_pages.shape != k_pages.shape:
+        raise ValueError(
+            "page pools %r/%r are not one stacked [L, P, size, W] a side"
+            % (k_pages.shape, v_pages.shape))
+    _, _, page_size, lanes = k_pages.shape
+    if lanes < g * d or page_size % block or (
+            not interpret and (d % MIN_BLOCK or block % 8)):
+        raise ValueError(
+            "page pools %r do not hold %d heads of %d on whole lane tiles "
+            "in whole blocks of %d rows" % (k_pages.shape, g, d, block))
+    if chosen.shape[:2] != (b, g) or count.shape != (b, g) \
+            or lens.shape != (b,) or block_tables.shape[0] != b:
+        raise ValueError(
+            "chosen %r / count %r / lens %r / block_tables %r do not "
+            "cover %d rows of %d heads" % (chosen.shape, count.shape,
+                                           lens.shape, block_tables.shape,
+                                           b, g))
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    width = chosen.shape[-1]
+    per_page = page_size // block
+    chosen = chosen.astype(jnp.int32).reshape(b, g * width)
+    page_of = jnp.take_along_axis(block_tables.astype(jnp.int32),
+                                  chosen // per_page, axis=1)
+    count = count.astype(jnp.int32)
+    lens = lens.astype(jnp.int32)
+    exact = k_pages.dtype == jnp.float32 or interpret
+
+    def q_index(bi, gi, ji, *_):
+        return (bi, gi, 0, 0)
+
+    def page_index(bi, gi, ji, lens_ref, count_ref, page_ref, block_ref,
+                   layer_ref):
+        at = gi * width + jnp.minimum(
+            ji, jnp.maximum(count_ref[bi * g + gi] - 1, 0))
+        return (layer_ref[0], page_ref[bi, at],
+                block_ref[bi, at] % per_page, gi)
+
+    rows = jnp.max(jnp.where(lens > 0, jnp.arange(1, b + 1), 1))
+    steps = jnp.clip(jnp.max(count), 1, width)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(rows, g, steps),
+        in_specs=[
+            pl.BlockSpec((1, 1, r, d), q_index),
+            pl.BlockSpec((1, 1, block, d), page_index),
+            pl.BlockSpec((1, 1, block, d), page_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, 1, r, d), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((r, d), jnp.float32),        # ctx accumulator
+            pltpu.VMEM((r, 1), jnp.float32),        # running max
+            pltpu.VMEM((r, 1), jnp.float32),        # running denom
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _gqa_block_decode_kernel, scale=scale, block=block, width=width,
+            groups=g,
+            operand_dtype=jnp.float32 if exact else k_pages.dtype,
+            precision=jax.lax.Precision.HIGHEST if exact else None),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, g, r, d), jnp.float32),
+        # rows past the grid keep the zeros handed in
+        input_output_aliases={8: 0},
+        interpret=interpret,
+        name="gqa_block_decode",
+    )(lens, count.reshape(b * g), page_of, chosen,
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q.astype(k_pages.dtype), k_pages, v_pages,
+      jnp.zeros((b, g, r, d), jnp.float32))

@@ -14,11 +14,12 @@ is finally cheap enough to build. The plane has three layers:
 * **data plane** (:mod:`.batching`, :mod:`.kv_cache`, :mod:`.engine`) —
   a continuous-batching engine over a model's module (:mod:`..models.gpt`,
   :mod:`..models.axk1`, :mod:`..models.dsv32`, :mod:`..models.evabyte`,
-  :mod:`..models.ouro`): a request queue
+  :mod:`..models.ouro`, :mod:`..models.minicpm_sala`): a request queue
   with admission / load-shedding, iteration-level scheduling that admits
   new sequences into in-flight batches, and a paged KV-cache (block-table
   allocator + the ``paged_decode_attention`` Pallas kernel in
-  :mod:`..ops.attention_pallas`);
+  :mod:`..ops.attention_pallas`; for a model with recurrent layers a
+  state a sequence beside the pages, :class:`.kv_cache.StateKvCache`);
 * **autoscaler** (:mod:`.autoscaler`) — replica count driven by queue
   depth and the ``ttft``/``tpot`` SLO burn rates
   (:func:`..obs.slo.serving_slos` on the stock burn-window evaluator),
@@ -43,7 +44,7 @@ from .controller import (  # noqa: F401
 )
 from .kv_cache import (  # noqa: F401
     KvBlockAllocator, KvCacheFull, LatentKvCache, PagedKvCache,
-    WindowKvCache,
+    SlotBlockAllocator, StateKvCache, WindowKvCache,
 )
 from .metrics import ServeMetrics  # noqa: F401
 
@@ -53,6 +54,7 @@ __all__ = [
     "Request",
     "RequestQueue", "SERVING_DEFAULTS", "SHED_POLICIES", "ScaleDecision",
     "ServeMetrics", "ServingAutoscaler", "ServingEngine",
+    "SlotBlockAllocator", "StateKvCache",
     "apply_desired_replicas", "serving_config", "serving_replicas",
     "sync_serving_spec",
 ]

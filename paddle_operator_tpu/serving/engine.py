@@ -9,7 +9,8 @@ their spans. The layer stack, the cache and the two step functions come
 from the MODEL'S MODULE (``model=``, default :mod:`..models.gpt`), which
 offers:
 
-* ``serve_cache(config, num_blocks, block_size)`` — the cache: page
+* ``serve_cache(config, num_blocks, block_size[, max_batch])`` — the
+  cache (``max_batch`` is handed to a hook that names it): page
   memory behind a :class:`.kv_cache.KvBlockAllocator`
   (:class:`.kv_cache.PagedKvCache`: one K and one V pool for all
   layers, a token's heads side by side in a row;
@@ -17,7 +18,11 @@ offers:
   block table, one compressed row a token in each;
   :class:`.kv_cache.WindowKvCache`: the paged pools holding an exact
   window's rows beside one summary row a chunk of the windows before
-  it). Each hands the decode step its pools donated: the step updates
+  it; :class:`.kv_cache.StateKvCache`: pages of keys, values and
+  compressed keys beside one fixed-size state a SEQUENCE, whose slot
+  the allocator reserves with the pages and the cache hands over as
+  column 0 of a decode row's table). Each hands the decode step its
+  pools donated: the step updates
   them where they lie. WHAT A SEQUENCE KEEPS FOR ITS TOKENS IS THE
   CACHE'S TO SAY, and the engine asks it instead of computing:
   ``pages_for(tokens)`` (the pages a budget reserves, which is what
@@ -44,7 +49,9 @@ offers:
   (``moe.pairs_here``, ``moe.experts_hit``, ``dsa.rows_live``,
   ``dsa.rows_selected``, ``eva.rows_read``, ``eva.tokens_live``,
   ``eva.windows_closed``, ``loop.layer_passes``, ``loop.rows_live``,
-  ``loop.rows_read``, ``loop.exit_steps``).
+  ``loop.rows_read``, ``loop.exit_steps``, ``sala.blocks_read``,
+  ``sala.blocks_live``, ``sala.ckeys_read``, ``lin.state_updates``,
+  ``lin.rows_live``).
 
 Models served: :mod:`..models.gpt` (float32; no expert configuration:
 its Switch layer drops tokens over capacity and has no decode path),
@@ -62,7 +69,14 @@ and caches the open window and the summaries only) and
 with the same weights, every loop step's keys and values in cache
 layers of its own — the paged cache is handed loop steps x layers and
 the model indexes it —, GPT's decode kernel once a layer and loop step,
-an exit gate that chooses which loop step's output the head reads).
+an exit gate that chooses which loop step's output the head reads) and
+:mod:`..models.minicpm_sala` (bfloat16; layers of two kinds: lightning
+linear attention, whose memory of a sequence is one float32 state in a
+pool of slots that the decode step advances in place, and block-sparse
+grouped-query attention, which scores compressed keys, takes the top
+blocks and reads them in place from the paged cache; a prefill that
+walks its prompt in chunks carrying the states; its ``serve_cache``
+takes ``max_batch`` besides, a slot of state for every row).
 
 Both steps compile through :func:`..compile_cache.cached_jit`, as a
 training worker's step does, so a replica takes them from whichever rung
@@ -92,6 +106,7 @@ cache's page write.
 
 from __future__ import annotations
 
+import inspect
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -131,7 +146,13 @@ class ServingEngine:
         self.attn = attn
         self.eos_id = eos_id
         self.label = label
-        self.cache = model.serve_cache(self.config, num_blocks, block_size)
+        # a cache with a pool whose unit is a SEQUENCE asks how many
+        # there can be: its hook takes ``max_batch`` besides
+        sized = "max_batch" in inspect.signature(
+            model.serve_cache).parameters
+        self.cache = model.serve_cache(
+            self.config, num_blocks, block_size,
+            **({"max_batch": max_batch} if sized else {}))
         #: the decode block-table's width: the pages one sequence's
         #: attention may read, which its cache knows
         self.pages_per_seq = self.cache.table_width(config["max_seq"])
@@ -288,8 +309,11 @@ class ServingEngine:
             ids, length = jax.device_put((ids, np.int32(n)))
         with timed("serve.prefill.dispatch", request_id=rid, bucket=pad):
             token, rows = self._prefill_fns[pad](self.params, ids, length)
+        # a cache that keeps more than pages says where (a state slot)
+        where = getattr(self.cache, "scatter_attrs", None)
         with timed("serve.prefill.scatter", request_id=rid,
-                   pages=self.cache.pages_for(n)):
+                   pages=self.cache.pages_for(n),
+                   **(where(rid) if where else {})):
             self.cache.write_rows(rid, rows, n)
         with timed("serve.prefill.wait", request_id=rid):
             return int(token)

@@ -43,9 +43,9 @@ the pools donated and writes whole pages in place) and ``donate_pools``
 (whether the decode step may overwrite the pools it is handed: all say
 yes).
 
-Thread safety: every allocator field is owned by ``_lock`` (declared in
-analysis/guards.py — the static OPS9xx passes and the runtime race
-detector both enforce it).
+Thread safety: every allocator field is owned by ``_lock``, the slots'
+two by ``_slot_lock`` (declared in analysis/guards.py — the static
+OPS9xx passes and the runtime race detector both enforce it).
 """
 
 from __future__ import annotations
@@ -550,3 +550,178 @@ class LatentKvCache(_RowAToken):
             self._scatter = jax.jit(scatter, donate_argnums=(0,))
         self.k_pages = self._scatter(self.k_pages, list(rows),
                                      jnp.asarray(blocks))
+
+
+class SlotBlockAllocator(KvBlockAllocator):
+    """:class:`KvBlockAllocator` that reserves, with a sequence's pages,
+    ONE of ``slots`` state slots: both or neither (a sequence that finds
+    pages and no slot raises :class:`KvCacheFull` and holds nothing, so
+    the admission layer defers it as it defers one that finds no pages),
+    and ``free_sequence`` hands both back. ``stats()`` keeps its meaning
+    (rows of cache) and says how many slots are taken beside it. The
+    slots' two fields are owned by ``_slot_lock`` (analysis/guards.py);
+    it is never held across a call into the pages' half."""
+
+    def __init__(self, num_blocks: int, block_size: int, slots: int) -> None:
+        if slots <= 0:
+            raise ValueError("slots must be positive")
+        super().__init__(num_blocks, block_size)
+        self.slots = slots
+        self._slot_lock = threading.Lock()
+        # LIFO as the pages are: a just-freed slot is taken first
+        self._slots_free: List[int] = list(range(slots - 1, -1, -1))
+        self._slot_of: Dict[str, int] = {}
+
+    def alloc_sequence(self, seq_id: str, num_tokens: int,
+                       live_tokens: Optional[int] = None) -> List[int]:
+        with self._slot_lock:
+            if not self._slots_free:
+                raise KvCacheFull("no free state slot of %d for %r"
+                                  % (self.slots, seq_id))
+            slot = self._slots_free.pop()
+        try:
+            table = super().alloc_sequence(seq_id, num_tokens, live_tokens)
+        except BaseException:
+            with self._slot_lock:
+                self._slots_free.append(slot)
+            raise
+        with self._slot_lock:
+            self._slot_of[seq_id] = slot
+        return table
+
+    def free_sequence(self, seq_id: str) -> int:
+        freed = super().free_sequence(seq_id)
+        with self._slot_lock:
+            slot = self._slot_of.pop(seq_id, None)
+            if slot is not None:
+                self._slots_free.append(slot)
+        return freed
+
+    def slot(self, seq_id: str) -> int:
+        with self._slot_lock:
+            return self._slot_of[seq_id]
+
+    def stats(self) -> Dict[str, int]:
+        out = super().stats()
+        with self._slot_lock:
+            out.update(slots_total=self.slots,
+                       slots_used=self.slots - len(self._slots_free))
+        return out
+
+    def check(self) -> List[str]:
+        errs = super().check()
+        paged = self.sequences()
+        with self._slot_lock:
+            held = sorted(self._slot_of.values())
+            if sorted(held + self._slots_free) != list(range(self.slots)):
+                errs.append("slot conservation broken: %d held + %d free "
+                            "!= %d" % (len(held), len(self._slots_free),
+                                       self.slots))
+            if sorted(self._slot_of) != paged:
+                errs.append("the sequences with a slot are not the "
+                            "sequences with pages")
+        return errs
+
+
+class StateKvCache(_RowAToken):
+    """The array half for a model of two kinds of layer
+    (``models.minicpm_sala``): ``layers`` attention layers that keep a
+    key and a value row a token (``kv_heads`` heads of ``head_dim`` side
+    by side, as :class:`PagedKvCache`'s) and ONE compressed key for
+    every ``stride`` tokens, and ``state_layers`` recurrent layers that
+    keep one state ``state_shape`` a SEQUENCE whatever its length.
+
+    Four pools, all taken donated by the decode step and handed back
+    (``pools()`` = (K, V, compressed keys, states)):
+
+    * K and V ``[layers, num_blocks + 1, block_size, W]`` behind the
+      allocator's block table, a token a row;
+    * the compressed keys ``[layers, num_blocks + 1, block_size //
+      stride, W]`` behind the SAME table: row ``r`` of a sequence (page
+      ``r // (block_size // stride)``) holds the window that ENDS with
+      stride ``r``, so a window's row lies in the page of its last
+      token, which is reserved when the decode step writes it;
+    * the states ``[state_layers, slots + 1, *state_shape]`` float32:
+      slot ``slots`` is the pad rows' target, as the last page is.
+
+    The cache owns the table's meaning: ``decode_row`` hands the
+    sequence's state slot as column 0 of its table and its pages after
+    it (``table_width`` counts the column), so the engine's one-array
+    step carries the slot without knowing of it. ``write_rows`` lands a
+    prefill's pages AND its final states in one donating program: a
+    slot's state is whatever its sequence's prefill left, never its
+    last owner's. ``k_pages`` / ``v_pages`` are lists of the arrays
+    (the names the other caches answer to): K, the compressed keys and
+    the states; V.
+    """
+
+    donate_pools = True
+
+    def __init__(self, num_blocks: int, block_size: int, layers: int,
+                 kv_heads: int, head_dim: int, stride: int,
+                 state_layers: int, slots: int,
+                 state_shape: Tuple[int, ...], dtype: Any = None) -> None:
+        import jax.numpy as jnp
+
+        if block_size % stride:
+            raise ValueError("pages of %d rows hold no whole strides of %d"
+                             % (block_size, stride))
+        self.allocator = SlotBlockAllocator(num_blocks, block_size, slots)
+        self.layers, self.state_layers = layers, state_layers
+        self.slots = slots
+        self.dummy_page = num_blocks
+        dtype = dtype or jnp.bfloat16
+        width = _stored(kv_heads * head_dim)
+        rows = (layers, num_blocks + 1, block_size, width)
+        self.k_pages = [
+            jnp.zeros(rows, dtype),
+            jnp.zeros(rows[:2] + (block_size // stride, width), dtype),
+            jnp.zeros((state_layers, slots + 1) + tuple(state_shape),
+                      jnp.float32)]
+        self.v_pages = [jnp.zeros(rows, dtype)]
+        self._write: Optional[Any] = None
+
+    def pools(self) -> Tuple[Any, Any, Any, Any]:
+        return (self.k_pages[0], self.v_pages[0], self.k_pages[1],
+                self.k_pages[2])
+
+    def set_pools(self, pools: Tuple[Any, Any, Any, Any]) -> None:
+        self.k_pages = [pools[0], pools[2], pools[3]]
+        self.v_pages = [pools[1]]
+
+    def table_width(self, max_seq: int) -> int:
+        return 1 + self.pages_for(max_seq)
+
+    def decode_row(self, seq_id: str) -> Tuple[int, List[int], int]:
+        """(the next token's position, [the state slot] + the pages, the
+        rows live before it)."""
+        position, pages, live = super().decode_row(seq_id)
+        return position, [self.allocator.slot(seq_id)] + pages, live
+
+    def scatter_attrs(self, seq_id: str) -> Dict[str, int]:
+        """What ``serve.prefill.scatter`` says beside its pages."""
+        return {"state_slot": self.allocator.slot(seq_id)}
+
+    def write_rows(self, seq_id: str, rows: Tuple[Any, Any, Any, Any],
+                   n: int) -> None:
+        """A prefill's keys and values (each ``[layers, pad, W]``, the
+        first ``n`` rows the prompt's), its compressed keys ``[layers,
+        pad // stride, W]`` and its final states ``[state_layers,
+        *state_shape]``: whole pages of the three paged pools and the
+        sequence's slot of the fourth, one program a padded length, all
+        four donated."""
+        import jax
+        import jax.numpy as jnp
+
+        blocks = _prompt_pages(self, seq_id, n, rows[0].shape[1])
+        if self._write is None:
+            def write(pools, rows, blocks, slot):
+                paged = [_write_pages(p, r, blocks)
+                         for p, r in zip(pools[:3], rows[:3])]
+                return tuple(paged) + (
+                    pools[3].at[:, slot].set(rows[3].astype(pools[3].dtype)),)
+
+            self._write = jax.jit(write, donate_argnums=(0,))
+        self.set_pools(self._write(
+            self.pools(), tuple(rows), jnp.asarray(blocks),
+            jnp.asarray(self.allocator.slot(seq_id), jnp.int32)))

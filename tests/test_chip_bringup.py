@@ -273,6 +273,59 @@ def test_the_looped_decode_step_compiles_for_v5e_copying_no_pool_and_no_weight(
         r"(?:copy|transpose|dynamic-slice)\(", text)
 
 
+def test_the_two_state_decode_step_compiles_for_v5e_with_every_pool_in_place(
+        v5e, monkeypatch):
+    """``minicpm-sala-pp2.serve-doc-16k``'s decode step at the published
+    sizes (4 block-sparse and 12 lightning layers, 16 rows; K and V
+    ``bf16[4, 4353, 128, 256]``, compressed keys ``bf16[4, 4353, 8,
+    256]``, states ``f32[12, 17, 32, 128, 128]``, all donated: 2.78 GB
+    beside 10.08 GB of weights) for a described v5e: the grouped-query
+    block kernel compiles (4 Mosaic calls, its blocks read in place
+    through the prefetched page list), all four pools are aliased to the
+    step's results, the temporaries are a hundredth of them, K, V and
+    the compressed keys are written by scatters in place and a lightning
+    layer's whole pass over its states is ONE update in place."""
+    from paddle_operator_tpu.models import minicpm_sala as sala
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sh = jax.sharding.SingleDeviceSharding(v5e[0])
+    cfg = dict(sala.BASE_CONFIG, layers=16, layer_offset=8,
+               mixer_types=sala.BASE_CONFIG["mixer_types"][8:24],
+               max_seq=34816)
+    params = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, sh),
+        jax.eval_shape(lambda: sala.init(jax.random.PRNGKey(0), cfg)))
+    shapes = jax.eval_shape(lambda: sala.serve_cache(cfg, 4352, 128, 16).pools())
+    pools = tuple(_sds(a.shape, a.dtype, sh) for a in shapes)
+    assert [a.shape for a in pools] == [
+        (4, 4353, 128, 256), (4, 4353, 128, 256), (4, 4353, 8, 256),
+        (12, 17, 32, 128, 128)]
+    row = _sds((16,), jnp.int32, sh)
+    decode = sala.serve_decode(cfg, "paged", 128, 4352)
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, pools, row, row, _sds((16, 273), jnp.int32, sh), row,
+        _sds((16,), jnp.bool_, sh)).compile()
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                     for a in pools)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 64
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+
+    def yields(shape):
+        return re.findall(r"= " + re.escape(shape) + r"\S* ([\w-]+)\(",
+                          text)
+
+    in_place = {"parameter", "scatter", "fusion", "get-tuple-element",
+                "bitcast", "dynamic-update-slice"}
+    for shape in ("bf16[4,4353,128,256]", "bf16[4,4353,8,256]",
+                  "f32[12,17,32,128,128]"):
+        assert set(yields(shape)) <= in_place, shape
+    assert yields("f32[12,17,32,128,128]").count(
+        "dynamic-update-slice") == 12
+
+
 @pytest.mark.parametrize("layer", [0, 5, 11])
 def test_paged_decode_matches_reference_interpreted(layer):
     """The kernel (products on the VPU, a head's sum over its lanes as a
