@@ -282,18 +282,20 @@ def _sparse_args(cfg) -> Dict[str, int]:
 _plans_seen = set()
 
 
-def _plan(cfg, chunk: int) -> None:
-    """Event ``sala.plan``, once a distinct plan: a trace-time fact."""
+def _plan(cfg, chunk: int, blocks_per_cell: int = 0) -> None:
+    """Event ``sala.plan``, once a distinct plan: a trace-time fact.
+    ``blocks_per_cell``: the selected blocks a grid cell of the decode
+    kernel reads; 0 where the program runs no such kernel."""
     from ..utils.trace import tracer
 
     kinds = list(cfg["mixer_types"])
     plan = (kinds.count(SPARSE), kinds.count(LIGHTNING),
-            cfg["sparse_block"], cfg["sparse_topk"], chunk)
+            cfg["sparse_block"], cfg["sparse_topk"], chunk, blocks_per_cell)
     if tracer().enabled and plan not in _plans_seen:
         _plans_seen.add(plan)
         tracer().event("sala.plan", layers_sparse=plan[0],
                        layers_lightning=plan[1], block=plan[2],
-                       topk=plan[3], chunk=plan[4])
+                       topk=plan[3], chunk=plan[4], blocks_per_cell=plan[5])
 
 
 # -- prefill -------------------------------------------------------------
@@ -453,14 +455,17 @@ def decode(config: Optional[dict], params: Dict, pools: Tuple,
     slot), live [B]. -> (next tokens [B], the pools, counters):
     ``sala.blocks_read`` (sum over the live rows and the key/value heads
     of the blocks the LAST sparse layer's attention was handed) /
+    ``sala.kernel_cells`` (the grid cells that layer's kernel call ran:
+    rows x key/value heads x cells of ``blocks_per_cell`` entries of the
+    longest list; 0 for the gather reference, which runs none) /
     ``sala.blocks_live`` (of the blocks a dense read would visit) /
     ``sala.ckeys_read`` (compressed rows that layer scored for the rows
     past ``dense_len``) / ``lin.state_updates`` (live rows, added to
     where a lightning layer's state advances) / ``lin.rows_live``: they
     follow what ran."""
     from ..ops.attention_pallas import (
-        _reference_gqa_block_decode, gqa_block_decode, gqa_block_scores,
-        select_blocks)
+        _reference_gqa_block_decode, gqa_block_decode, gqa_block_grid,
+        gqa_block_scores, select_blocks)
 
     cfg = _config(config)
     k_pages, v_pages, c_pages, states = pools
@@ -468,7 +473,6 @@ def decode(config: Optional[dict], params: Dict, pools: Tuple,
     if bs % sb:
         raise ValueError("pages of %d rows hold no whole blocks of %d"
                          % (bs, sb))
-    _plan(cfg, 1)
     g, d = cfg["kv_heads"], cfg["head_dim"]
     batch = tokens.shape[0]
     slots = states.shape[1] - 1
@@ -511,15 +515,21 @@ def decode(config: Optional[dict], params: Dict, pools: Tuple,
             chosen, count = select_blocks(scores, new_lens,
                                           **_sparse_args(cfg))
             if attn_impl == "paged":
+                grid, per_cell = gqa_block_grid(count, new_lens,
+                                                chosen.shape[-1])
+                _plan(cfg, 1, per_cell)
+                cells = grid[0] * grid[1] * grid[2]
                 ctx = gqa_block_decode(q, k_pages, v_pages, pages, chosen,
                                        count, new_lens, at, sb, scale,
                                        interpret=interpret)
             else:
+                _plan(cfg, 1)
+                cells = jnp.zeros((), jnp.int32)
                 ctx = _reference_gqa_block_decode(
                     q.astype(jnp.bfloat16), k_pages, v_pages, pages, chosen,
                     count, new_lens, at, sb, scale)
         return (_finish(cfg, layer, x, ctx.reshape(batch, -1) * gate),
-                k_pages, v_pages, c_pages, count)
+                k_pages, v_pages, c_pages, count, cells)
 
     @jax.jit
     def lightning(layer, at, lam, x, states):
@@ -540,11 +550,12 @@ def decode(config: Optional[dict], params: Dict, pools: Tuple,
     rows = jnp.sum(live.astype(jnp.int32))
     updates = jnp.zeros((), jnp.int32)
     count = jnp.zeros((batch, g), jnp.int32)
+    cells = jnp.zeros((), jnp.int32)
     x = _embed(cfg, params, tokens)
     for li, (layer, (kind, at)) in enumerate(zip(params["layers"],
                                                  _kinds(cfg))):
         if kind == SPARSE:
-            x, k_pages, v_pages, c_pages, count = sparse(
+            x, k_pages, v_pages, c_pages, count, cells = sparse(
                 layer, at, x, k_pages, v_pages, c_pages)
         else:
             x, states = lightning(layer, at, decay(cfg, li), x, states)
@@ -554,6 +565,7 @@ def decode(config: Optional[dict], params: Dict, pools: Tuple,
     sparse_rows = live & (new_lens >= cfg["dense_len"])
     counters = {
         "sala.blocks_read": jnp.sum(jnp.where(live[:, None], count, 0)),
+        "sala.kernel_cells": cells,
         "sala.blocks_live": jnp.sum(jnp.where(
             live, g * ((new_lens - 1) // sb + 1), 0)),
         "sala.ckeys_read": jnp.sum(jnp.where(

@@ -1184,13 +1184,30 @@ def mla_selected_decode(q_lat, q_rope, pages, block_tables, chosen, count,
 # are scored on compressed keys (one row a ``stride`` tokens: the mean of
 # a window of two strides, kept at the row of the stride it ENDS with),
 # a few are forced, the top ``topk`` are read. The kernel's grid walks
-# (row, key/value head, selected block); a cell's page IS
-# ``pool[layer, page_of[row, head, j]]`` through the scalar-prefetched
-# list, cut to the block's rows and the head's 128 lanes by the block
-# spec, so a selected block is read where it lies and no gathered copy is
-# made (``mla_selected_decode``'s gather costs more than its kernels:
-# PERF.md section 3). The group's R query heads are the query tile: one
-# [R, D] x [D, block] product and one [R, block] x [block, D] a cell.
+# (row, key/value head, cell of ``SPARSE_BLOCKS_PER_CELL`` selected
+# blocks); a cell's k-th tile IS ``pool[layer, page_of[row, head,
+# j per_cell + k]]`` through the scalar-prefetched list, cut to the
+# block's rows and the head's 128 lanes by a block spec of its own, so a
+# selected block is read where it lies and no gathered copy is made
+# (``mla_selected_decode``'s gather costs more than its kernels: PERF.md
+# section 3). The group's R query heads are the query tile: one [R, D] x
+# [D, N] product and one [R, N] x [N, D] a cell, N the cell's tokens.
+
+#: selected blocks one grid cell of ``gqa_block_decode`` reads. Swept on a
+#: v5e at ``minicpm-sala-pp2``'s shapes (16 query heads a group, blocks of
+#: 64 x 128 bfloat16, lists of 64 of width 128, 16 rows; PR 50,
+#: docs/perf/PR-50.md), microseconds a call at 4 / 8 live rows:
+#:     1: 257 / 502   2: 177 / 336   4: 134 / 251   8: 119 / 217
+#:     16: 108 / 194  32: 103 / 185  (one block a cell before: 261 / 502)
+#: A cell costs about 0.35 us whatever it reads and 0.16-0.17 us a block:
+#: the part a block is its two DMAs of 64 rows of 256 bytes (the kernel
+#: with its products left out: 240 us at 1 a cell, 166 at 8 and at 32),
+#: so past 8 a cell only the cells' fixed part is left to save. 32 is
+#: 4-5% under 16 for the kernel (0.04 ms of a 19 ms step) and costs the
+#: cell's warm set-up 3.5-5 s of 54 (a kernel of 2 x 32 tile specs takes
+#: 1.1 s to build where 16 take 0.6 and 8 take 0.5), so 16. Lists
+#: shorter than this (``width``) make a cell of all they have.
+SPARSE_BLOCKS_PER_CELL = 16
 
 
 def gqa_block_scores(q, ckeys, lens, per_block: int, stride: int, scale):
@@ -1280,18 +1297,21 @@ def _reference_gqa_block_decode(q, k_pages, v_pages, block_tables, chosen,
 
 
 def _gqa_block_decode_kernel(lens_ref, count_ref, page_ref, block_ref,
-                             layer_ref, q_ref, k_ref, v_ref, zeros_ref,
-                             o_ref, acc_ref, m_ref, l_ref, *, scale, block,
+                             layer_ref, q_ref, *refs, per_cell, scale, block,
                              width, groups, operand_dtype, precision):
-    """One (row, key/value head, selected block) cell: the group's R
-    query heads against the block's tokens on that head's lanes, folded
-    into the running softmax in scratch. Float32 statistics; operands
-    the pool's type."""
+    """One (row, key/value head, cell of selected blocks) grid cell: the
+    group's R query heads against the tokens of the cell's blocks on
+    that head's lanes, as ONE score tile ``[R, blocks x block]``, folded
+    into the running softmax in scratch once. Float32 statistics;
+    operands the pool's type."""
     del page_ref, layer_ref             # read by the index maps
-    del zeros_ref                       # what o_ref starts as
+    k_refs, v_refs = refs[:per_cell], refs[per_cell:2 * per_cell]
+    # after them the zeros o_ref starts as, the output, the scratch
+    _, o_ref, acc_ref, m_ref, l_ref = refs[2 * per_cell:]
     b, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n = lens_ref[b]
     c = count_ref[b * groups + g]
+    first = j * per_cell
 
     @pl.when(j == 0)
     def _init():
@@ -1299,22 +1319,33 @@ def _gqa_block_decode_kernel(lens_ref, count_ref, page_ref, block_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(j < c)
-    def _block():
+    @pl.when(first < c)
+    def _blocks():
         q = q_ref[0, 0].astype(operand_dtype)                # [R, D]
-        k = k_ref[0, 0].astype(operand_dtype)                # [block, D]
-        v = v_ref[0, 0].astype(operand_dtype)
+        k, v = (jnp.concatenate([ref[0, 0] for ref in tiles], axis=0
+                                ).astype(operand_dtype)      # [N, D]
+                for tiles in (k_refs, v_refs))
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), precision=precision,
-            preferred_element_type=jnp.float32) * scale      # [R, block]
-        pos = block_ref[b, g * width + j] * block \
-            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < n, s, NEG_INF)
+            preferred_element_type=jnp.float32) * scale      # [R, N]
+        # tile i holds entry first + i of the list: its tokens before
+        # the row's length are seen, none where the entry is past the
+        # list's end (the tile then repeats the list's last block)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, s.shape[1]), 1)
+        edge = jnp.zeros_like(lane)
+        for i in range(per_cell):
+            at = jnp.minimum(first + i, width - 1)
+            room = jnp.where(first + i < c,
+                             n - block_ref[b, g * width + at] * block, 0)
+            edge = jnp.where(lane >= i * block,
+                             i * block + jnp.clip(room, 0, block), edge)
+        seen = lane < edge
+        s = jnp.where(seen, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # a block wholly past the row's tokens (none is selected) would
-        # leave m at NEG_INF and exp(0) = 1 for every masked score
-        p = jnp.where(pos < n, jnp.exp(s - m_new), 0.0)
+        # a cell wholly past the row's tokens (no such block is selected)
+        # would leave m at NEG_INF and exp(0) = 1 for every masked score
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
         correction = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * correction \
             + jnp.sum(p, axis=1, keepdims=True)
@@ -1328,6 +1359,19 @@ def _gqa_block_decode_kernel(lens_ref, count_ref, page_ref, block_ref,
         l = l_ref[...]
         o_ref[0, 0] = (acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
                        ).astype(o_ref.dtype)
+
+
+def gqa_block_grid(count, lens, width: int):
+    """The grid :func:`gqa_block_decode` walks for these lists — count
+    ``[B, G]``, lens ``[B]``, lists ``width`` long — and the blocks a
+    cell of it reads: ``((rows, G, cells), per_cell)``. It ends at the
+    last live row and at the longest list's last cell; ``rows`` and
+    ``cells`` are traced int32 scalars."""
+    b, g = count.shape
+    per_cell = min(SPARSE_BLOCKS_PER_CELL, width)
+    rows = jnp.max(jnp.where(lens > 0, jnp.arange(1, b + 1), 1))
+    steps = jnp.clip(jnp.max(count), 1, width)
+    return (rows, g, (steps + per_cell - 1) // per_cell), per_cell
 
 
 def gqa_block_decode(q, k_pages, v_pages, block_tables, chosen, count, lens,
@@ -1345,11 +1389,14 @@ def gqa_block_decode(q, k_pages, v_pages, block_tables, chosen, count, lens,
     masked; 0 for a pad row, which reads nothing and gets zeros) —
     layer: an int32 scalar, traced or not. -> ``[B, G, R, D]`` float32.
 
-    The grid is (rows, G, blocks) and ends at the last live row and at
-    the longest list; a cell past its own list's end repeats that list's
-    last block (the pipeline fetches nothing new) and skips its body.
-    Inference only. Numerics match :func:`_reference_gqa_block_decode`
-    to the online softmax's reassociation."""
+    The grid is (rows, G, cells) and ends at the last live row and at
+    the longest list (:func:`gqa_block_grid`); a cell reads
+    ``SPARSE_BLOCKS_PER_CELL`` entries of its list, each through a block
+    spec of its own into the one pool; an entry past its list's end
+    repeats that list's last block (the pipeline fetches nothing new)
+    and is masked, and a cell wholly past it skips its body. Inference
+    only. Numerics match :func:`_reference_gqa_block_decode` to the
+    online softmax's reassociation (statistics once a cell)."""
     b, g, r, d = q.shape
     if k_pages.ndim != 4 or v_pages.shape != k_pages.shape:
         raise ValueError(
@@ -1378,28 +1425,27 @@ def gqa_block_decode(q, k_pages, v_pages, block_tables, chosen, count, lens,
     count = count.astype(jnp.int32)
     lens = lens.astype(jnp.int32)
     exact = k_pages.dtype == jnp.float32 or interpret
+    grid, per_cell = gqa_block_grid(count, lens, width)
 
     def q_index(bi, gi, ji, *_):
         return (bi, gi, 0, 0)
 
-    def page_index(bi, gi, ji, lens_ref, count_ref, page_ref, block_ref,
-                   layer_ref):
-        at = gi * width + jnp.minimum(
-            ji, jnp.maximum(count_ref[bi * g + gi] - 1, 0))
-        return (layer_ref[0], page_ref[bi, at],
-                block_ref[bi, at] % per_page, gi)
+    def page_index(k):
+        def index(bi, gi, ji, lens_ref, count_ref, page_ref, block_ref,
+                  layer_ref):
+            at = gi * width + jnp.minimum(
+                ji * per_cell + k, jnp.maximum(count_ref[bi * g + gi] - 1, 0))
+            return (layer_ref[0], page_ref[bi, at],
+                    block_ref[bi, at] % per_page, gi)
+        return index
 
-    rows = jnp.max(jnp.where(lens > 0, jnp.arange(1, b + 1), 1))
-    steps = jnp.clip(jnp.max(count), 1, width)
+    tiles = [pl.BlockSpec((1, 1, block, d), page_index(k))
+             for k in range(per_cell)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(rows, g, steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, r, d), q_index),
-            pl.BlockSpec((1, 1, block, d), page_index),
-            pl.BlockSpec((1, 1, block, d), page_index),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        grid=grid,
+        in_specs=[pl.BlockSpec((1, 1, r, d), q_index)] + tiles + tiles
+        + [pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, 1, r, d), q_index),
         scratch_shapes=[
             pltpu.VMEM((r, d), jnp.float32),        # ctx accumulator
@@ -1409,17 +1455,17 @@ def gqa_block_decode(q, k_pages, v_pages, block_tables, chosen, count, lens,
     )
     return pl.pallas_call(
         functools.partial(
-            _gqa_block_decode_kernel, scale=scale, block=block, width=width,
-            groups=g,
+            _gqa_block_decode_kernel, per_cell=per_cell, scale=scale,
+            block=block, width=width, groups=g,
             operand_dtype=jnp.float32 if exact else k_pages.dtype,
             precision=jax.lax.Precision.HIGHEST if exact else None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, r, d), jnp.float32),
         # rows past the grid keep the zeros handed in
-        input_output_aliases={8: 0},
+        input_output_aliases={6 + 2 * per_cell: 0},
         interpret=interpret,
         name="gqa_block_decode",
     )(lens, count.reshape(b * g), page_of, chosen,
       jnp.asarray(layer, jnp.int32).reshape(1),
-      q.astype(k_pages.dtype), k_pages, v_pages,
-      jnp.zeros((b, g, r, d), jnp.float32))
+      q.astype(k_pages.dtype), *([k_pages] * per_cell),
+      *([v_pages] * per_cell), jnp.zeros((b, g, r, d), jnp.float32))

@@ -50,8 +50,8 @@ offers:
   ``dsa.rows_selected``, ``eva.rows_read``, ``eva.tokens_live``,
   ``eva.windows_closed``, ``loop.layer_passes``, ``loop.rows_live``,
   ``loop.rows_read``, ``loop.exit_steps``, ``sala.blocks_read``,
-  ``sala.blocks_live``, ``sala.ckeys_read``, ``lin.state_updates``,
-  ``lin.rows_live``).
+  ``sala.kernel_cells``, ``sala.blocks_live``, ``sala.ckeys_read``,
+  ``lin.state_updates``, ``lin.rows_live``).
 
 Models served: :mod:`..models.gpt` (float32; no expert configuration:
 its Switch layer drops tokens over capacity and has no decode path),

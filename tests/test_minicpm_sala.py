@@ -267,9 +267,11 @@ def test_prefill_then_decode_through_the_cache_gives_the_references_logits(
     assert worst < LOGIT_TOL
     first = counted[0]
     # rows of 21, 42 and 78 tokens: 3, 6 and 10 blocks a key/value head;
-    # the third reads its top 4 and scores 78 // 4 - 1 compressed rows
+    # the third reads its top 4 and scores 78 // 4 - 1 compressed rows;
+    # the kernel's lists are 6 wide, one cell a row and head
     assert first == {"sala.blocks_live": 2 * (3 + 6 + 10),
                      "sala.blocks_read": 2 * (3 + 6 + 4),
+                     "sala.kernel_cells": 3 * 2 if attn == "paged" else 0,
                      "sala.ckeys_read": 18,
                      "lin.state_updates": 3 * 3, "lin.rows_live": 3}
     # the second row is past dense_len by the 7th step (48 tokens)
@@ -379,22 +381,75 @@ def test_a_blocks_score_is_the_largest_over_the_windows_that_overlap_it():
                                atol=1e-6)
 
 
-def test_the_kernel_reads_the_selected_blocks_in_place():
+def _lists(*rows):
+    """(lens, chosen, count) from a ``(tokens, [a head's list, ...])`` a
+    row; a list is padded to the longest with block 0, as the selection
+    pads it."""
+    width = max(len(ids) for _, heads in rows for ids in heads)
+    return (jnp.asarray([n for n, _ in rows], jnp.int32),
+            jnp.asarray([[list(ids) + [0] * (width - len(ids))
+                          for ids in heads] for _, heads in rows], jnp.int32),
+            jnp.asarray([[len(ids) for ids in heads] for _, heads in rows],
+                        jnp.int32))
+
+
+#: what a grid of cells of several blocks can get wrong, at 4 blocks a
+#: cell unless a case says otherwise: rows of (tokens, a list a head)
+#: over block tables of 6 pages of 2 blocks of 8 tokens
+KERNEL_CASES = {
+    # PR 49's: lists of different lengths, a selected block that ends
+    # past the row's tokens (43 = 5 blocks and 3 tokens), a pad row
+    "lists-of-4-2-1-4-and-a-pad-row": (4, [
+        (43, [[0, 5, 3, 1], [5, 4]]), (0, [[], []]),
+        (30, [[3], [0, 1, 2, 3]])]),
+    "a-list-shorter-than-one-cell": (4, [
+        (70, [[8, 2, 5], [1]]), (61, [[7, 0], [3, 6, 4]])]),
+    "a-list-that-ends-in-the-middle-of-a-cell": (4, [
+        (90, [[0, 11, 4, 9, 2, 7], [3, 1, 10, 6, 8]])]),
+    "a-list-exactly-width-long": (4, [
+        (96, [list(range(12)), [11, 3, 7, 0, 9, 1, 5, 10, 2, 8, 4, 6]]),
+        (41, [[0, 1, 2, 3, 4, 5], [5, 0]])]),
+    "two-heads-of-one-row-a-cell-apart": (4, [
+        (85, [[10, 2], [0, 1, 2, 3, 4, 5, 6, 7, 8, 9]])]),
+    "a-pad-row-between-live-rows": (4, [
+        (50, [[6, 1, 4, 0, 2], [3, 6]]), (0, [[], []]), (0, [[], []]),
+        (77, [[9, 0, 5, 3, 8, 1, 7], [2, 9, 4, 6, 0]])]),
+    # 59 tokens: block 7 holds 3 of them, in a cell whose others are whole
+    "a-block-past-the-tokens-among-whole-ones": (4, [
+        (59, [[1, 7, 4, 2], [0, 3, 7, 5, 6]])]),
+    "the-last-live-row-reads-one-block": (4, [
+        (33, [[4, 0, 1, 2, 3], [2, 4, 0]]), (3, [[0], [0]])]),
+    "width-smaller-than-the-constant": (8, [
+        (47, [[5, 0, 2], [1, 4, 3, 0, 5]]), (0, [[], []]),
+        (20, [[2], [0, 1, 2]])]),
+    "one-block-a-cell": (1, [
+        (43, [[0, 5, 3, 1], [5, 4]]), (30, [[3], [0, 1, 2, 3]])]),
+    "a-cell-of-two-and-an-odd-list": (2, [
+        (66, [[8, 0, 3], [1, 7, 2, 5, 4]])]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_kernel_reads_the_selected_blocks_in_place(case, monkeypatch):
     """``gqa_block_decode`` in interpret mode against its gather-einsum
-    reference: two pages a block table in another order than the pool's,
-    lists of different lengths, a selected block that ends past the
-    row's tokens, a pad row inside the batch."""
+    reference: pages in block tables in another order than the pool's,
+    ``KERNEL_CASES``' lists, both layers of the pool."""
+    per_cell, rows = KERNEL_CASES[case]
+    monkeypatch.setattr(kernels, "SPARSE_BLOCKS_PER_CELL", per_cell)
     g, r, d, block, page = 2, 2, 16, 8, 16
     keys = jax.random.split(jax.random.key(5), 3)
-    k_pages = jax.random.normal(keys[0], (2, 7, page, 128), jnp.bfloat16)
-    v_pages = jax.random.normal(keys[1], (2, 7, page, 128), jnp.bfloat16)
-    q = jax.random.normal(keys[2], (3, g, r, d), jnp.float32)
-    tables = jnp.asarray([[5, 2, 0], [0, 0, 0], [1, 4, 3]], jnp.int32)
-    lens = jnp.asarray([43, 0, 30], jnp.int32)
-    chosen = jnp.asarray([[[0, 5, 3, 1], [5, 4, 0, 0]],
-                          [[0, 0, 0, 0], [0, 0, 0, 0]],
-                          [[3, 0, 0, 0], [0, 1, 2, 3]]], jnp.int32)
-    count = jnp.asarray([[4, 2], [0, 0], [1, 4]], jnp.int32)
+    k_pages = jax.random.normal(keys[0], (2, 25, page, 128), jnp.bfloat16)
+    v_pages = jax.random.normal(keys[1], (2, 25, page, 128), jnp.bfloat16)
+    q = jax.random.normal(keys[2], (len(rows), g, r, d), jnp.float32)
+    tables = jnp.asarray(np.random.RandomState(3).permutation(24).reshape(
+        4, 6)[:len(rows)], jnp.int32)
+    lens, chosen, count = _lists(*rows)
+    (n_rows, heads, cells), per = kernels.gqa_block_grid(
+        count, lens, chosen.shape[-1])
+    assert per == min(per_cell, chosen.shape[-1])
+    assert (int(n_rows), heads, int(cells)) == (
+        max(i + 1 for i, (n, _) in enumerate(rows) if n), g,
+        -(-int(count.max()) // per))
     for layer in (0, 1):
         got = kernels.gqa_block_decode(q, k_pages, v_pages, tables, chosen,
                                        count, lens, layer, block,
@@ -402,10 +457,10 @@ def test_the_kernel_reads_the_selected_blocks_in_place():
         want = kernels._reference_gqa_block_decode(
             q.astype(jnp.bfloat16), k_pages, v_pages, tables, chosen, count,
             lens, layer, block, d ** -0.5)
-        assert got.shape == (3, g, r, d)
+        assert got.shape == (len(rows), g, r, d)
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-        assert float(jnp.max(jnp.abs(got[1]))) == 0.0
-        assert float(jnp.max(jnp.abs(got[0]))) > 0.1
+        for i, (n, _) in enumerate(rows):
+            assert (float(jnp.max(jnp.abs(got[i]))) > 0.1) == (n > 0)
 
 
 # -- through the engine and the batcher ---------------------------------
@@ -451,6 +506,56 @@ def test_the_paged_kernel_and_the_gather_serve_the_same_tokens(params):
         assert all(len(r.generated) == r.max_new_tokens for r in requests)
         served[attn] = [r.generated for r in requests]
     assert served["paged"] == served["reference"]
+
+
+def _kernel_calls(jaxpr):
+    """The names of a jaxpr's Pallas calls, calls and loops looked into."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params["name"])
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += _kernel_calls(sub)
+    return found
+
+
+def test_the_decode_step_calls_the_kernel_once_a_sparse_layer_in_cells(
+        params, monkeypatch):
+    """Three rows through the model's hook at 4 blocks a cell: lists of
+    3, 6 and 4 blocks a head (the second row dense, all 6 of its blocks)
+    make a grid of 3 rows x 2 heads x 2 cells; once the second row is
+    past ``dense_len`` every list is 4 long and a cell a (row, head) is
+    left. The tokens and every other counter are the gather's."""
+    from paddle_operator_tpu.utils import trace
+
+    monkeypatch.setattr(kernels, "SPARSE_BLOCKS_PER_CELL", 4)
+    monkeypatch.setattr(trace, "_global", trace.Tracer(enabled=True))
+    monkeypatch.setattr(sala, "_plans_seen", set())
+    served = {attn: _serve(params, attn, _prompts(20, 41, 77), 8)
+              for attn in ("paged", "reference")}
+    assert served["paged"][2] == served["reference"][2]
+    cells = [c.pop("sala.kernel_cells") for c in served["paged"][1]]
+    assert {c.pop("sala.kernel_cells") for c in served["reference"][1]} == {0}
+    assert served["paged"][1] == served["reference"][1]
+    assert [c["sala.blocks_read"] for c in served["paged"][1][:7:6]] \
+        == [2 * (3 + 6 + 4), 2 * (4 + 4 + 4)]
+    assert cells == [3 * 2 * 2] * 6 + [3 * 2 * 1] * 2
+    assert sorted(e["attrs"]["blocks_per_cell"]
+                  for e in trace.tracer().events
+                  if e["name"] == "sala.plan" and e["attrs"]["chunk"] == 1
+                  ) == [0, 4]
+    cfg = _cfg()
+    cache = sala.serve_cache(cfg, BLOCKS, PAGE, BATCH)
+    row = jax.ShapeDtypeStruct((BATCH,), jnp.int32)
+    step = jax.make_jaxpr(lambda *a: sala.decode(
+        cfg, *a, attn_impl="paged", block_size=PAGE, dummy_page=BLOCKS))(
+        params, cache.pools(), row, row,
+        jax.ShapeDtypeStruct((BATCH, cache.table_width(cfg["max_seq"])),
+                             jnp.int32),
+        row, jax.ShapeDtypeStruct((BATCH,), jnp.bool_))
+    assert _kernel_calls(step.jaxpr) == ["gqa_block_decode"] \
+        * TINY["mixer_types"].count("minicpm4")
 
 
 def test_a_request_that_finds_pages_and_no_slot_is_deferred(params):
@@ -505,11 +610,17 @@ def test_the_engine_banks_the_counters_and_says_its_plan_once(
     _run(engine, _requests((20, 77), (5, 7)))
     counts = engine.times.counts()
     assert {"sala.blocks_read", "sala.blocks_live", "sala.ckeys_read",
-            "lin.state_updates", "lin.rows_live"} <= set(counts)
+            "sala.kernel_cells", "lin.state_updates",
+            "lin.rows_live"} <= set(counts)
     assert counts["lin.state_updates"]["total"] \
         == 3 * counts["lin.rows_live"]["total"]
     assert counts["sala.blocks_read"]["total"] \
         < counts["sala.blocks_live"]["total"]
+    # the gather reference ran no kernel cell; an operator reads so
+    from paddle_operator_tpu.serving import ServeMetrics
+    assert ('tpujob_serve_step_counter_total{job="default/serve",'
+            'counter="sala.kernel_cells"} 0') in ServeMetrics(
+        job="default/serve", stages=(engine.times,)).metrics_block()
     # tracing a program says its plan, once however often it is traced
     # (the engine may take a program from the compile ladder untraced)
     ids = jax.ShapeDtypeStruct((1, PAD), jnp.int32)
@@ -519,5 +630,6 @@ def test_the_engine_banks_the_counters_and_says_its_plan_once(
     plans = [e["attrs"] for e in trace.tracer().events
              if e["name"] == "sala.plan"]
     assert sorted(plans, key=lambda a: a["chunk"]) == [
-        dict(layers_sparse=1, layers_lightning=3, block=8, topk=4, chunk=c)
+        dict(layers_sparse=1, layers_lightning=3, block=8, topk=4, chunk=c,
+             blocks_per_cell=0)
         for c in (1, 96)]
