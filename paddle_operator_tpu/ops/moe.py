@@ -152,12 +152,23 @@ def moe_share_apply(params, z, held, top_k: int, scale: float = 1.0,
     (``n_group`` equal runs of experts), the ``topk_group`` best groups
     kept, ``I`` the top-k of ``s'`` inside them; the gates stay those of
     ``s``. The pairs routed to a held
-    expert are sorted by expert; each expert then multiplies its own
-    contiguous group, ``block`` rows at a time, in a loop whose trip
-    count is the group's size — so the work follows the pairs that are
-    here, not the T x top_k that a static shape would have to assume
-    (``jax.lax.ragged_dot`` takes that static count as its M). Plain
-    XLA; operands in ``dtype``, sums, scores and gates in float32.
+    expert are sorted by expert; each expert's contiguous group is then
+    multiplied ``block`` rows at a time, in ONE loop over the row blocks
+    that exist (trip count ``sum(ceil(counts / block))``, the expert
+    looked up from the block's index) — so the work follows the pairs
+    that are here, not the T x top_k that a static shape would have to
+    assume (``jax.lax.ragged_dot`` takes that static count as its M),
+    and so do the weights: an expert without a pair has no block and its
+    kernels are not read. Keep it ONE loop. Written as a loop over the
+    experts around a loop over each one's blocks, the slice of an
+    expert's three kernels depends on the expert and the layer and not
+    on the inner index, so the chip's compiler lifts it out of the inner
+    loop and copies every HELD expert's kernels into fast memory every
+    layer and step, hit or not (4.9 of a 16.1 ms decode step at 2 pairs
+    a step); here nothing is loop invariant and each product reads its
+    kernel in place (PERF.md section 6, PR 51;
+    ``tests/test_chip_bringup.py`` reads it from the compiled text).
+    Plain XLA; operands in ``dtype``, sums, scores and gates in float32.
 
     Returns ``(out [T, D] float32, counters)`` with ``counters`` the
     int32 scalars ``pairs_here`` (pairs computed on this chip) and
@@ -203,22 +214,24 @@ def moe_share_apply(params, z, held, top_k: int, scale: float = 1.0,
             params[k], (layer, e, 0, 0), (1, 1) + params[k].shape[2:])[0, 0]
             for k in ("gate", "up", "down")}
 
-    def expert(e, out):
+    # ONE loop over the row blocks that exist, expert after expert in slot
+    # order: block b belongs to the first expert whose blocks end past it
+    blocks = (counts + rows - 1) // rows                            # [G]
+    block_ends = jnp.cumsum(blocks)
+
+    def rows_block(b, out):
+        e = jnp.sum(b >= block_ends, dtype=jnp.int32)
+        j = b - (block_ends[e] - blocks[e])
+        weights = kernels(e)
         end = starts[e] + counts[e]
+        lo = starts[e] + j * rows
+        tokens = jax.lax.dynamic_slice(token_of, (lo,), (rows,))
+        gate = jax.lax.dynamic_slice(gate_of, (lo,), (rows,))
+        gate = jnp.where(lo + jnp.arange(rows) < end, gate, 0.0)
+        y = nn.gated_mlp(weights, zb[tokens], dtype)
+        return out.at[tokens].add(y * gate[:, None])
 
-        def rows_block(j, out):
-            weights = kernels(e)
-            lo = starts[e] + j * rows
-            tokens = jax.lax.dynamic_slice(token_of, (lo,), (rows,))
-            gate = jax.lax.dynamic_slice(gate_of, (lo,), (rows,))
-            gate = jnp.where(lo + jnp.arange(rows) < end, gate, 0.0)
-            y = nn.gated_mlp(weights, zb[tokens], dtype)
-            return out.at[tokens].add(y * gate[:, None])
-
-        return jax.lax.fori_loop(0, (counts[e] + rows - 1) // rows,
-                                 rows_block, out)
-
-    out = jax.lax.fori_loop(0, g, expert,
+    out = jax.lax.fori_loop(0, block_ends[-1], rows_block,
                             nn.gated_mlp(params["shared"], zb, dtype))
     return out, {"pairs_here": jnp.sum(counts),
                  "experts_hit": jnp.sum(counts > 0, dtype=jnp.int32)}

@@ -254,6 +254,12 @@ def _share(layer, held):
                 down=layer["down"][idx])
 
 
+def _stacked(share):
+    """The share's expert kernels as layer 1 of two, layer 0 zeros."""
+    return dict(share, **{k: jnp.stack([jnp.zeros_like(share[k]), share[k]])
+                          for k in ("gate", "up", "down")})
+
+
 def test_the_four_shares_add_up_to_the_uncut_layer():
     """What each of four chips computes of one expert layer (its quarter
     of the 16 routed experts, routed over all 16, plus the shared expert
@@ -315,12 +321,68 @@ def test_padding_rows_route_nowhere_and_layers_index_stacked_kernels():
     np.testing.assert_allclose(
         out[7:], nn.gated_mlp(layer["shared"], z[7:], jnp.float32),
         atol=1e-5)
-    stacked = dict(share, **{k: jnp.stack([jnp.zeros_like(share[k]),
-                                           share[k]])
-                             for k in ("gate", "up", "down")})
-    indexed, _ = moe.moe_share_apply(stacked, z, held, 4, 2.5, live=live,
-                                     layer=jnp.asarray(1), dtype=jnp.float32)
+    indexed, _ = moe.moe_share_apply(_stacked(share), z, held, 4, 2.5,
+                                     live=live, layer=jnp.asarray(1),
+                                     dtype=jnp.float32)
     np.testing.assert_allclose(indexed, out, atol=1e-6)
+
+
+#: which of the held experts (0, 5, 9, 12) a router sends its tokens to,
+#: and which rows are tokens: the cases an expert without pairs decides
+#: (its kernels are not read: ``moe_share_apply``)
+HIT_CASES = {
+    "every-row-dead": dict(forced=(0, 5, 9, 12), live=0, hit=()),
+    "all-routed-elsewhere": dict(forced=(1, 2, 3, 4), live=12, hit=()),
+    "elsewhere-and-padding": dict(forced=(1, 2, 3, 4), live=7, hit=()),
+    "first-held-only": dict(forced=(0, 1, 2, 3), live=12, hit=(0,)),
+    "last-held-only": dict(forced=(12, 1, 2, 3), live=7, hit=(12,)),
+    "first-and-last": dict(forced=(0, 12, 2, 3), live=12, hit=(0, 12)),
+}
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["flat", "layer"])
+@pytest.mark.parametrize("case", sorted(HIT_CASES))
+def test_an_expert_without_pairs_adds_nothing_and_the_hit_ones_all(
+        case, stacked):
+    """A router forced onto four experts for every token (their columns
+    score 1, so they ARE the top 4). Held experts that nobody is routed
+    to add nothing — with none hit the result is the shared expert's
+    alone, bit for bit — and the ones that are hit add what a chip
+    holding them alone adds, bit for bit: the same rows in the same
+    blocks. Against the reference on the live rows; with and without the
+    layers as the kernels' leading axis."""
+    spec = HIT_CASES[case]
+    layer = _expert_layer(seed=11)
+    held = (0, 5, 9, 12)
+    z = jnp.abs(jax.random.normal(jax.random.PRNGKey(12), (12, 64))) + 0.1
+    for column in spec["forced"]:
+        layer["router"] = layer["router"].at[:, column].set(4.0)
+    n = spec["live"]
+    live = jnp.arange(12) < n
+
+    def apply(held):
+        share = _share(layer, held)
+        if stacked:
+            return moe.moe_share_apply(
+                _stacked(share), z, held, 4, 2.5, live=live,
+                layer=jnp.asarray(1), dtype=jnp.float32, block=8)
+        return moe.moe_share_apply(share, z, held, 4, 2.5, live=live,
+                                   dtype=jnp.float32, block=8)
+
+    out, counters = apply(held)
+    assert int(counters["experts_hit"]) == len(spec["hit"])
+    assert int(counters["pairs_here"]) == n * len(spec["hit"])
+    shared = nn.gated_mlp(layer["shared"], z, jnp.float32)
+    np.testing.assert_array_equal(out[n:], shared[n:])
+    if not spec["hit"]:
+        np.testing.assert_array_equal(out, shared)
+        return
+    alone, _ = apply(spec["hit"])
+    np.testing.assert_array_equal(out, alone)
+    config = dict(TINY, held_experts=list(held))
+    np.testing.assert_allclose(
+        out[:n], reference.expert_ffn(_share(layer, held), z, config,
+                                      "f32")[:n], atol=2e-4)
 
 
 # -- the other model the engine serves ------------------------------------

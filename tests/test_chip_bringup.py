@@ -326,6 +326,74 @@ def test_the_two_state_decode_step_compiles_for_v5e_with_every_pool_in_place(
         "dynamic-update-slice") == 12
 
 
+def _written_out(text, shapes):
+    """The instructions of a compiled module's text that write an array
+    of one of ``shapes`` out: a fusion, a slice or a copy that stands in
+    a computation that is not itself a fusion's."""
+    fused, found, comp = set(), [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        if " fusion(" in line:
+            fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+        yields = re.search(r"%([\w.\-]+) = (\w+\[[\d,]*\])\S* "
+                           r"(fusion|dynamic-slice|copy)\(", line)
+        if yields and yields.group(2) in shapes:
+            found.append((comp, yields.group(1)))
+    return [name for comp, name in found if comp not in fused]
+
+
+@pytest.mark.parametrize("rows", [16, 1024], ids=["decode", "prefill-chunk"])
+def test_a_missed_experts_kernels_are_not_copied_for_v5e(v5e, rows):
+    """``dsv32-share32``'s expert layer under its scan over five layers
+    (8 held of 256 experts of 7168 x 2048, top-8 in 4 of 8 groups; a
+    decode step's 16 rows, a prefill chunk's 1024) for a described v5e.
+    An expert's three kernels are 29.4 MB each. Sliced inside a loop
+    over an expert's row blocks inside a loop over the experts, they do
+    not depend on the inner index: the compiler lifts the slices into
+    the loop over the EXPERTS and copies every held expert's 88 MB into
+    fast memory every layer and step, hit or not
+    (``constant_dynamic-slice_fusion bf16[1,1,...]``, 4.9 ms of
+    ``dsv32-share32.serve-long-8k``'s 16.1 ms step at 2 pairs a step:
+    PERF.md, section 6, PR 51). In ``moe_share_apply``'s ONE loop over
+    the blocks that exist nothing writes an array of an expert's kernel
+    out: each product's fusion slices the stacked ``bf16[5,8,...]`` in
+    place, and an expert without a pair has no block."""
+    from paddle_operator_tpu.ops import moe
+
+    sh = jax.sharding.SingleDeviceSharding(v5e[0])
+    layers, g, d, f, routed_experts = 5, 8, 7168, 2048, 256
+    bf = jnp.bfloat16
+    routed = {"gate": _sds((layers, g, d, f), bf, sh),
+              "up": _sds((layers, g, d, f), bf, sh),
+              "down": _sds((layers, g, f, d), bf, sh)}
+    sliced = {"router": _sds((layers, d, routed_experts), bf, sh),
+              "bias": _sds((layers, routed_experts), jnp.float32, sh),
+              "shared": {"gate": _sds((layers, d, f), bf, sh),
+                         "up": _sds((layers, d, f), bf, sh),
+                         "down": _sds((layers, f, d), bf, sh)}}
+
+    def stack(routed, sliced, z, live):
+        def body(z, xs):
+            index, p = xs
+            out, counters = moe.moe_share_apply(
+                dict(routed, router=p["router"], shared=p["shared"]), z,
+                tuple(range(g)), 8, 2.5, live=live, layer=index, n_group=8,
+                topk_group=4, bias=p["bias"])
+            return z + out, counters
+
+        return jax.lax.scan(body, z,
+                            (jnp.arange(layers, dtype=jnp.int32), sliced))
+
+    text = jax.jit(stack).lower(
+        routed, sliced, _sds((rows, d), jnp.float32, sh),
+        _sds((rows,), jnp.bool_, sh)).compile().as_text()
+    assert not _written_out(
+        text, {"bf16[1,1,%d,%d]" % (d, f), "bf16[1,1,%d,%d]" % (f, d)})
+
+
 @pytest.mark.parametrize("layer", [0, 5, 11])
 def test_paged_decode_matches_reference_interpreted(layer):
     """The kernel (products on the VPU, a head's sum over its lanes as a

@@ -716,8 +716,9 @@ def test_one_group_and_no_bias_is_plain_top_k():
 
 #: sha256 of the lowered text of ``models.axk1``'s serving programs at
 #: ``axk1.TINY_CONFIG`` with ``max_seq`` 64 (max_batch 2, prompt_pad 16,
-#: 8 pages of 8), less the names of ``main``'s results: the prefill's AS
-#: PR 29 LEFT IT (d0b961a), the decode steps' AS PR 38 LEFT THEM. PR 38
+#: 8 pages of 8), less the names of ``main``'s results, AS PR 51 LEFT
+#: THEM (until then the prefill's as PR 29 left it, d0b961a, and the
+#: decode steps' as PR 38 left them). PR 38
 #: meant to change the two decode steps and re-pinned them: the engine
 #: hands a step ONE ``int32[max_batch, 4 + pages_per_seq]`` where it
 #: handed five arrays, and takes ONE ``int32[max_batch + counters]``
@@ -727,16 +728,30 @@ def test_one_group_and_no_bias_is_plain_top_k():
 #: reshapes, the ``!= 0`` of the live column) and 3 at its end (the two
 #: counters broadcast to ``[1]`` and one concatenate); the stack, the
 #: expert layer and the kernel are the parent's line for line.
+#: PR 51 meant to change the expert layer of all four and re-pinned
+#: them: ``moe_share_apply`` walks ONE loop over the row blocks that
+#: exist where it walked a loop over the held experts around a loop
+#: over each one's blocks. With the names of the values normalised, as
+#: multisets of lines a decode step loses 31 lines and gains 36 (1901
+#: -> 1906; the prefill 34 and 35, 1022 -> 1023), every one of them
+#: integer bookkeeping of that loop: the outer ``while``, the function
+#: that was its body and the scalar ``ceil(counts[e] / rows)`` go; the
+#: blocks a held expert (``ceil`` over ``[4]``), their running sum and
+#: its last entry before the one ``while``, and in its body the expert
+#: looked up from the block's index (``sum(b >= block_ends)``) come. The
+#: private functions are emitted in another order, so a line-by-line
+#: diff is long. Nothing in the stack, the attention, the routing, the
+#: gather of a block's rows, the three products or the scatter-add.
 #: jax 0.9.0.
 PARENT_AXK1_PROGRAMS = {
     ("paged", "serve-prefill"):
-        "20f13cff0bfcdf261ff8099a5127d57cf8084fb06ebcfcb4cfc1ea69f7594c8e",
+        "b6dbd5edb673c5f201d8b39a9b499f1f2fa0bab16cbc737ca4216e7e34d6430c",
     ("paged", "serve-decode"):
-        "4beb04a521e9036720caf0e85c1656e689f310c03c2a4cbde1316d31d1f7c1b5",
+        "f418f2c4685c4f2bcf1842d6b3a68a4b23067c21863b010a206e52b39629504e",
     ("reference", "serve-prefill"):
-        "20f13cff0bfcdf261ff8099a5127d57cf8084fb06ebcfcb4cfc1ea69f7594c8e",
+        "b6dbd5edb673c5f201d8b39a9b499f1f2fa0bab16cbc737ca4216e7e34d6430c",
     ("reference", "serve-decode"):
-        "51f299203a1d8fe3f4fe3e5600593a9c95a04125ca993e8e50e67b568ebc7749",
+        "a0a28380f2fbf605c6604d5c6813da0e7538aebe675031387d4b08002fdb120b",
 }
 
 
